@@ -16,22 +16,12 @@ base layer.  (The NACK->RTX path is exercised by the slow-tier e2e in
 tests/test_sfu_bridge.py.)
 
 Run:  PYTHONPATH=. python examples/sfu_video.py
-(first JAX compile takes ~20-40 s; the demo runs ~30 ticks and prints
-the per-receiver layer/forwarding stats.)
+(runs on whatever platform JAX gives; the first compiles take a while,
+then the demo runs ~30 ticks and prints the per-receiver
+layer/forwarding stats.)
 """
 
-import os
-
-import jax
 import numpy as np
-
-if os.environ.get("LIBJITSI_TPU_DEMO_DEVICE", "cpu") != "accel":
-    jax.config.update("jax_platforms", "cpu")
-else:
-    try:
-        jax.devices()
-    except RuntimeError:
-        jax.config.update("jax_platforms", "cpu")
 
 import libjitsi_tpu
 from libjitsi_tpu.codecs import vp8

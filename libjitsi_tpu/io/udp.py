@@ -52,41 +52,41 @@ def _load():
     lib.udp_send_batch.argtypes = [
         ctypes.c_int, ctypes.c_void_p, ctypes.c_int, ctypes.c_void_p,
         ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int]
-    if hasattr(lib, "udp_send_batch_idx"):  # older sanitized builds
-        lib.udp_send_batch_idx.restype = ctypes.c_int
-        lib.udp_send_batch_idx.argtypes = [
-            ctypes.c_int, ctypes.c_void_p, ctypes.c_int, ctypes.c_void_p,
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-            ctypes.c_int]
-    if hasattr(lib, "udp_enable_timestamps"):  # older sanitized builds
-        lib.udp_enable_timestamps.restype = ctypes.c_int
-        lib.udp_enable_timestamps.argtypes = [ctypes.c_int]
-        lib.udp_recv_batch_ts.restype = ctypes.c_int
-        lib.udp_recv_batch_ts.argtypes = [
-            ctypes.c_int, ctypes.c_void_p, ctypes.c_int, ctypes.c_int,
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-            ctypes.c_void_p, ctypes.c_int]
-    if hasattr(lib, "udp_uring_supported"):  # pre-gen-2 builds lack it
-        lib.udp_uring_supported.restype = ctypes.c_int
-        lib.udp_uring_create.restype = ctypes.c_void_p
-        lib.udp_uring_create.argtypes = [ctypes.c_int] * 4
-        lib.udp_uring_arm.restype = ctypes.c_int
-        lib.udp_uring_arm.argtypes = [
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int, ctypes.c_int,
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-            ctypes.c_void_p]
-        lib.udp_uring_recv.restype = ctypes.c_int
-        lib.udp_uring_recv.argtypes = [
-            ctypes.c_void_p, ctypes.c_int, ctypes.c_int, ctypes.c_void_p]
-        lib.udp_uring_send_idx.restype = ctypes.c_int
-        lib.udp_uring_send_idx.argtypes = [
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int,
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-            ctypes.c_void_p, ctypes.c_int]
-        lib.udp_uring_stat.restype = ctypes.c_long
-        lib.udp_uring_stat.argtypes = [ctypes.c_void_p, ctypes.c_int]
-        lib.udp_uring_destroy.restype = None
-        lib.udp_uring_destroy.argtypes = [ctypes.c_void_p]
+    # every entry point below is in udp_engine.cpp as committed (the
+    # udp_uring_* ones stub to ENOSYS without the kernel header), and
+    # the library is rebuilt whenever the source is newer
+    lib.udp_send_batch_idx.restype = ctypes.c_int
+    lib.udp_send_batch_idx.argtypes = [
+        ctypes.c_int, ctypes.c_void_p, ctypes.c_int, ctypes.c_void_p,
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_int]
+    lib.udp_enable_timestamps.restype = ctypes.c_int
+    lib.udp_enable_timestamps.argtypes = [ctypes.c_int]
+    lib.udp_recv_batch_ts.restype = ctypes.c_int
+    lib.udp_recv_batch_ts.argtypes = [
+        ctypes.c_int, ctypes.c_void_p, ctypes.c_int, ctypes.c_int,
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_void_p, ctypes.c_int]
+    lib.udp_uring_supported.restype = ctypes.c_int
+    lib.udp_uring_create.restype = ctypes.c_void_p
+    lib.udp_uring_create.argtypes = [ctypes.c_int] * 4
+    lib.udp_uring_arm.restype = ctypes.c_int
+    lib.udp_uring_arm.argtypes = [
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int, ctypes.c_int,
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_void_p]
+    lib.udp_uring_recv.restype = ctypes.c_int
+    lib.udp_uring_recv.argtypes = [
+        ctypes.c_void_p, ctypes.c_int, ctypes.c_int, ctypes.c_void_p]
+    lib.udp_uring_send_idx.restype = ctypes.c_int
+    lib.udp_uring_send_idx.argtypes = [
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int,
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_void_p, ctypes.c_int]
+    lib.udp_uring_stat.restype = ctypes.c_long
+    lib.udp_uring_stat.argtypes = [ctypes.c_void_p, ctypes.c_int]
+    lib.udp_uring_destroy.restype = None
+    lib.udp_uring_destroy.argtypes = [ctypes.c_void_p]
     _lib = lib
     return lib
 
@@ -111,9 +111,7 @@ def uring_available() -> bool:
     it off.  Cached C-side; cheap to call repeatedly."""
     if _uring_env_disabled():
         return False
-    lib = _load()
-    return bool(hasattr(lib, "udp_uring_supported")
-                and lib.udp_uring_supported())
+    return bool(_load().udp_uring_supported())
 
 
 def probe_engine_mode() -> str:
@@ -215,8 +213,7 @@ class UdpEngine:
         self.port = lib.udp_local_port(fd)
         self.kernel_timestamps = False
         if kernel_timestamps:
-            if hasattr(lib, "udp_enable_timestamps"):
-                self.kernel_timestamps = lib.udp_enable_timestamps(fd) == 0
+            self.kernel_timestamps = lib.udp_enable_timestamps(fd) == 0
             if not self.kernel_timestamps:
                 from libjitsi_tpu.utils.logging import get_logger
 
@@ -512,9 +509,7 @@ class UdpEngine:
             dst_ip = ip_to_u32(dst_ip)
         lib = _load()
         data = batch.data
-        if (not hasattr(lib, "udp_send_batch_idx")
-                or data.dtype != np.uint8
-                or not data.flags["C_CONTIGUOUS"]):
+        if data.dtype != np.uint8 or not data.flags["C_CONTIGUOUS"]:
             sub = PacketBatch(data[rows],  # jitlint: disable=hotpath-alloc
                               np.asarray(batch.length)[rows],
                               np.asarray(batch.stream)[rows])

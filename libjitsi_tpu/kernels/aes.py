@@ -262,9 +262,8 @@ def get_core() -> str:
         # resolved lazily so importing this module never forces a
         # backend init (conftest flips platforms before first use).
         # Measured pick first: AES_CORES.json holds per-backend
-        # chained above-floor blocks/s (the only timing protocol that
-        # survived round 5 — single-launch spans sit inside the
-        # scalar-fetch floor's jitter and emit junk, see BASELINE.md),
+        # chained above-floor blocks/s (single-launch spans can sit
+        # inside the scalar-fetch floor's jitter and emit junk),
         # and measured_aes_core returns the fastest status=="ok" core
         # for this backend or None when none exists.  Heuristic
         # fallback mirrors what the measurements have shown so far:

@@ -477,8 +477,7 @@ def aes_encrypt_bitsliced32_nd(round_keys, blocks):
 
 # ------------------------------------------------------------ Pallas provider
 #
-# Round-2 postmortem (BENCH_r02 "error: MosaicError"): the first Pallas
-# twin ran `reshape(-1, 4, 4).transpose(0, 2, 1)` on uint8 INSIDE the
+# The first Pallas twin (refused with a MosaicError on the chip) ran `reshape(-1, 4, 4).transpose(0, 2, 1)` on uint8 INSIDE the
 # kernel — minor-dim relayout + 8-bit shifts, exactly what Mosaic
 # declines to lower.  This version is lane-native instead: the batch
 # rides the 128-wide lane axis, each bit plane is a [4, 4, 128] int32

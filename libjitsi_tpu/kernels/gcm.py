@@ -211,7 +211,7 @@ def _grouped_tag(round_keys, gmat_g, enc, aad_len, ct_len, j0,
 
     The per-row `_tag` gathers a 16 KiB GHASH matrix per packet — at
     batch 65536 that is 1 GiB of HBM traffic for key material, which
-    capped the GCM launch size (BENCH_r02).  Here the host pre-groups
+    capped the GCM launch size.  Here the host pre-groups
     rows by stream into a [G, P] grid (`grid_rows`: row index or -1
     padding) so each stream's matrix is read ONCE and applied to all its
     rows as one MXU matmul per Horner step (`ghash_grouped`), then the

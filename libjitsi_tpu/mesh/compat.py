@@ -1,25 +1,12 @@
-"""shard_map across jax versions.
-
-`jax.shard_map` (with its `check_vma=` kwarg) only exists on newer jax;
-the pinned container ships 0.4.x where the API lives at
-`jax.experimental.shard_map.shard_map` and the kwarg is spelled
-`check_rep=`.  Every mesh module routes through this one symbol so the
-version probe happens exactly once at import.
-"""
+"""The one `shard_map` symbol every mesh module routes through
+(`jax.shard_map`, replication checking off by default: the sharded
+seams return per-shard results that are not replicated)."""
 
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "shard_map"):
 
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

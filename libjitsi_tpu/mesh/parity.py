@@ -51,7 +51,11 @@ def assert_table_parity(mesh, capacity: int, batch_size: int,
     sh_tx, pl_tx = build_pair()
     sh_rx, pl_rx = build_pair()
     for k in range(rounds):
-        seq0 = 100 * (k + 1)
+        # a round's seqs start past the previous round's: a stream hit
+        # late in one round and early in the next must still see its
+        # seq advance, or the 64-packet replay window rejects it at
+        # any batch wider than the window
+        seq0 = 100 + k * (batch_size + 100)
         w_sh = sh_tx.protect_rtp(batch(seq0))
         w_pl = pl_tx.protect_rtp(batch(seq0))
         for i in range(w_sh.batch_size):
@@ -145,7 +149,7 @@ def assert_bridge_parity(cfg, mesh, capacity: int,
     """Assembled mesh-mode ConferenceBridge egress must be byte-
     identical to the single-chip SYNC bridge for the same conference
     (with `pipelined`, the overlapped-dispatch mesh bridge rides the
-    same contract — VERDICT r4 #2)."""
+    same contract)."""
     plain = run_bridge_once(cfg, None, capacity)
     meshed = run_bridge_once(cfg, mesh, capacity, pipelined=pipelined)
     if len(plain) < 2:
@@ -209,7 +213,7 @@ def assert_sfu_parity(cfg, mesh, capacity: int,
                      pipelined: bool = False) -> None:
     """Assembled mesh-mode SfuBridge fan-out must be byte-identical to
     the single-chip SYNC bridge for the same conference (pipelined
-    mesh dispatch included — VERDICT r4 #2)."""
+    mesh dispatch included)."""
     plain = run_sfu_once(cfg, None, capacity)
     meshed = run_sfu_once(cfg, mesh, capacity, pipelined=pipelined)
     if len(plain) < 6:

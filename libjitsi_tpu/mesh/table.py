@@ -1,6 +1,6 @@
 """ShardedSrtpTable — the production SRTP table running on a device mesh.
 
-VERDICT r3 #2: round 3 sharded raw *kernels* (mesh/sharded.py) but every
+Round 3 sharded raw *kernels* (mesh/sharded.py) but every
 product object stayed single-chip.  This table is the product object
 sharded: the same `SrtpStreamTable` host control plane (header parse,
 RFC 3711 App A index estimation, replay windows, kdr epochs, size-class
@@ -19,14 +19,14 @@ partitioned over a `jax.sharding.Mesh`:
   scatter back to wire order is deferred (`_LazyArray`), so
   `protect_rtp_async` keeps its launch-overlap contract in mesh mode
   and the bridges compose `mesh=...` with `pipelined=True`
-  (VERDICT r4 #2 — the 8-chip deployment is exactly the one that needs
+  (the 8-chip deployment is exactly the one that needs
   launch overlap).
 
 Reference: `SRTPTransformer`'s per-SSRC context map scaled by running
 more JVMs; here the ONE table spans the mesh and `RTPTranslatorImpl`-
 scale fan-outs (SURVEY §3.4) ride the same row partition.
 
-Profile scope: ALL four cipher modes shard (VERDICT r4 #6).  AES-CM /
+Profile scope: ALL four cipher modes shard.  AES-CM /
 NULL ride the two-table seam; AES-F8's second key schedule is one more
 `[S, R, 16]` tensor on the same row partition; AES-GCM shards both its
 per-row form AND the grouped-GHASH form (per-device group grids —
@@ -206,8 +206,8 @@ class _OwnerPlan:
     [n_dev, per] gathers batch rows into per-device lanes (pads repeat a
     real row — crypto on device is stateless, pads are dropped at
     scatter); `inv` [B] maps each original row to its flat lane.
-    Fully vectorized — no Python loop over devices (VERDICT r4 weak #6:
-    the loop showed at 64k-batch x 8-device shapes)."""
+    Fully vectorized — no Python loop over devices (the loop showed
+    at 64k-batch x 8-device shapes)."""
 
     __slots__ = ("slot", "inv", "per", "affine")
 
@@ -255,7 +255,7 @@ class _OwnerPlan:
 
 def mesh_gcm_grid(local: np.ndarray):
     """Per-device grouped-GHASH grids over an `_OwnerPlan`'s lane
-    layout — the mesh form of `context._gcm_grid` (VERDICT r4 #4: the
+    layout — the mesh form of `context._gcm_grid` (the
     sharded table must not be pinned to the per-row form the round-4
     data showed losing 2.3x at 64k rows).
 
@@ -451,7 +451,7 @@ class ShardedSrtpTable(ShardedRowsMixin, SrtpStreamTable):
                     # SRTCP ladder (the GCM SRTCP seam reuses the RTP
                     # gcm programs above — same _shard_fn cache key).
                     # Capped at 256 lanes: control traffic is low-rate,
-                    # and every ladder rung is a tunnel compile.
+                    # and every ladder rung is a fresh compile.
                     self._warmup_rtcp(rtcp_tabs, cap, lanes, tag,
                                       encrypt)
                 if lanes >= top:
@@ -542,7 +542,7 @@ class ShardedSrtpTable(ShardedRowsMixin, SrtpStreamTable):
     # ------------------------------------------------------------------ F8
     def _f8_rtp_protect_call(self, stream, batch, hdr, iv, v):
         """Sharded AES-F8: the second key schedule `[S, R, 16]` rides
-        the same row partition as the first (VERDICT r4 #6)."""
+        the same row partition as the first."""
         data, olen = self._run_sharded("f8_protect", stream, batch, hdr,
                                        batch.length, [iv, self._roc32(v)])
         return data, olen.astype(np.int32)
@@ -559,7 +559,7 @@ class ShardedSrtpTable(ShardedRowsMixin, SrtpStreamTable):
         GHASH matrix gathers chip-local) and grouped-GHASH (per-device
         group grids, `mesh_gcm_grid`); the winner is picked per shape
         by registry measurement, exactly like the single-chip table
-        (VERDICT r4 #4 closed the hardcoded per-row regression)."""
+        (never a hardcoded per-row choice)."""
         off_const = _uniform_off(hdr.payload_off, batch.capacity)
         data, olen = _registry.call(
             "mesh_gcm_rtp_protect", self._token(),
@@ -612,7 +612,7 @@ class ShardedSrtpTable(ShardedRowsMixin, SrtpStreamTable):
     def _rtcp_protect_call(self, stream, batch, iv, index_word,
                            encrypting: bool, f8: bool = False):
         """Sharded SRTCP protect on the row-partitioned RTCP tables
-        (VERDICT r4 #6: a mesh deployment must not silently hop to a
+        (a mesh deployment must not silently hop to a
         single-chip path for control traffic)."""
         fn = self._shard_fn("rtcp_f8_protect" if f8 else "rtcp_protect",
                             self.policy.auth_tag_len, encrypting, None)
@@ -671,8 +671,8 @@ class ShardedSrtpTable(ShardedRowsMixin, SrtpStreamTable):
         else:
             fn = self._build_rtp_fn(op, tag_len, encrypt, f8, off_const,
                                     row3, lanes)
-        self._sh_fns[key] = fn
-        return fn
+        # setdefault: concurrent warm-ups of one key share ONE jit
+        return self._sh_fns.setdefault(key, fn)
 
     def _build_rtp_fn(self, op, tag_len, encrypt, f8, off_const, row3,
                       lanes):

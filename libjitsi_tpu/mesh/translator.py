@@ -117,8 +117,8 @@ class ShardedRtpTranslator(ShardedRowsMixin, RtpTranslator):
                       P(None, None), P(None)),
             out_specs=(P(self._axes, None, None, None),),
             check_vma=False))
-        self._sh_fns[key] = fn
-        return fn
+        # setdefault: concurrent warm-ups of one key share ONE jit
+        return self._sh_fns.setdefault(key, fn)
 
     def _gcm_fanout_fn(self, off_const):
         key = ("gcm_fanout", off_const)
@@ -139,8 +139,8 @@ class ShardedRtpTranslator(ShardedRowsMixin, RtpTranslator):
             _run, mesh=self.mesh,
             in_specs=(row3, row3, lanes, row3, lanes, lanes, row3),
             out_specs=(row3, lanes), check_vma=False))
-        self._sh_fns[key] = fn
-        return fn
+        # setdefault: concurrent warm-ups of one key share ONE jit
+        return self._sh_fns.setdefault(key, fn)
 
     def _fanout_fn(self, off_const=None):
         key = ("fanout", self.policy.auth_tag_len,
@@ -165,5 +165,5 @@ class ShardedRtpTranslator(ShardedRowsMixin, RtpTranslator):
             in_specs=(row3, row3, lanes, row3, lanes, lanes, row3,
                       lanes),
             out_specs=(row3, lanes), check_vma=False))
-        self._sh_fns[key] = fn
-        return fn
+        # setdefault: concurrent warm-ups of one key share ONE jit
+        return self._sh_fns.setdefault(key, fn)

@@ -71,7 +71,7 @@ class ConferenceBridge:
         # NACK->RTX->FEC->PLC ladder's last rung; see sfu/recovery.py)
         self._plc = plc
         self.registry = StreamRegistry(config, capacity=capacity)
-        # mesh mode (SURVEY §2.7, VERDICT r3 #2): the bridge's SRTP
+        # mesh mode (SURVEY §2.7): the bridge's SRTP
         # tables row-partition over the device mesh and the mixer's
         # participant axis psums over ICI — the ASSEMBLED bridge tick
         # runs sharded, not just its kernels

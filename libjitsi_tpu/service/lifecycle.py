@@ -58,7 +58,8 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
 from libjitsi_tpu.core.packet import ROW_CLASSES
-from libjitsi_tpu.utils.compile_cache import compile_stats
+from libjitsi_tpu.utils.compile_cache import (compile_concurrently,
+                                               compile_stats)
 from libjitsi_tpu.utils.flight import FlightRecorder
 from libjitsi_tpu.utils.logging import get_logger
 
@@ -1019,27 +1020,36 @@ class StreamLifecycleManager:
                 if rc <= cover and rc not in self._warm_rows]
         if not want and ROW_CLASSES[0] not in self._warm_rows:
             want = [ROW_CLASSES[0]]
-        tr = getattr(self.bridge, "translator", None)
         for rc in want:
-            self.bridge.rx_table.warmup_rtp(
-                rc, payload_len=self.cfg.warm_payload_len)
-            self.bridge.tx_table.warmup_rtp(
-                rc, payload_len=self.cfg.warm_payload_len)
-            if tr is not None and hasattr(tr, "warmup_fanout"):
-                # the fan-out expansion (packets x receivers) has its own
-                # class-padded shape space — compile it here, off-tick
-                tr.warmup_fanout(rc, payload_len=self.cfg.warm_payload_len)
-            if hasattr(self.bridge.rx_table, "warmup_rtcp"):
-                # control traffic (NACK/RR/SR) rides the same
-                # zero-recompile discipline as media
-                self.bridge.rx_table.warmup_rtcp(rc)
-                self.bridge.tx_table.warmup_rtcp(rc)
+            self._warm_class(rc, rtp=True)
             self._warm_rows.add(rc)
         self.flight.record("bucket_warm", tick=self.ticks(),
                            bucket=bucket, rows=sorted(self._warm_rows))
         _log.info("bucket_warm", bucket=bucket,
                   row_classes=sorted(self._warm_rows))
         self._warm_bucket = bucket
+
+    def _warm_class(self, rc: int, rtp: bool) -> None:
+        """Compile every program one row class can drive: uplink RTP
+        (`rtp`; listener rows have none), the fan-out expansion
+        (packets x receivers has its own class-padded shape space) and
+        control traffic (NACK/RR/SR ride the same zero-recompile
+        discipline as media).  The three kinds share no program, so
+        they compile side by side; rx then tx inside one thunk, since
+        the second table finds the first one's programs warm."""
+        rx, tx = self.bridge.rx_table, self.bridge.tx_table
+        plen = self.cfg.warm_payload_len
+        tr = getattr(self.bridge, "translator", None)
+        thunks = []
+        if rtp:
+            thunks.append(lambda: (rx.warmup_rtp(rc, payload_len=plen),
+                                   tx.warmup_rtp(rc, payload_len=plen)))
+        if tr is not None and hasattr(tr, "warmup_fanout"):
+            thunks.append(lambda: tr.warmup_fanout(rc, payload_len=plen))
+        if hasattr(rx, "warmup_rtcp"):
+            thunks.append(lambda: (rx.warmup_rtcp(rc),
+                                   tx.warmup_rtcp(rc)))
+        compile_concurrently(thunks)
 
     def _ensure_warm_listeners(self, population: int) -> None:
         """The fanout-only twin of `_ensure_warm`: listener rows never
@@ -1059,14 +1069,8 @@ class StreamLifecycleManager:
                 if rc <= cover and rc not in self._warm_lrows]
         if not want and ROW_CLASSES[0] not in self._warm_lrows:
             want = [ROW_CLASSES[0]]
-        tr = getattr(self.bridge, "translator", None)
         for rc in want:
-            if tr is not None and hasattr(tr, "warmup_fanout"):
-                tr.warmup_fanout(rc,
-                                 payload_len=self.cfg.warm_payload_len)
-            if hasattr(self.bridge.rx_table, "warmup_rtcp"):
-                self.bridge.rx_table.warmup_rtcp(rc)
-                self.bridge.tx_table.warmup_rtcp(rc)
+            self._warm_class(rc, rtp=False)
             self._warm_lrows.add(rc)
         self.flight.record("listener_bucket_warm", tick=self.ticks(),
                            bucket=bucket,

@@ -170,7 +170,7 @@ class SfuBridge:
         # rx_table: what endpoints SEND us (media + their SRTCP);
         # tx_table: what we send THEM (our SRTCP feedback; media forward
         # crypto is the translator's per-leg fan-out).  Mesh mode
-        # (SURVEY §2.7, VERDICT r3 #2): tables row-partition and the
+        # (SURVEY §2.7): tables row-partition and the
         # fan-out shards by receiver leg — the assembled SFU tick runs
         # sharded, not just its kernels.
         self._mesh = mesh

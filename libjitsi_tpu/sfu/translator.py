@@ -37,6 +37,7 @@ from libjitsi_tpu.rtp import header as rtp_header
 from libjitsi_tpu.transform.srtp import kernel
 from libjitsi_tpu.transform.srtp.kdf import derive_session_keys
 from libjitsi_tpu.transform.srtp.policy import Cipher, SrtpProfile
+from libjitsi_tpu.utils.compile_cache import compile_concurrently
 
 
 def _round_width(w: int) -> int:
@@ -254,32 +255,40 @@ class RtpTranslator:
         if rows > 1:
             mixed[0] = 16            # non-uniform: off_const=None entry
         offs.append(mixed)
-        for w in widths:
+
+        def one(w: int, off) -> None:
             data = np.zeros((rows, w), dtype=np.uint8)
             data[:, 0] = 0x80
-            for off in offs:
-                if self._gcm:
-                    iv12 = np.zeros((rows, 12), dtype=np.uint8)
-                    out, _ = self._gcm_fanout_call(recv, data, length,
-                                                   off, iv12, w)
-                else:
-                    iv = np.zeros((rows, 16), dtype=np.uint8)
-                    out, _ = self._cm_fanout_call(recv, data, length,
-                                                  off, iv, idx)
-                np.asarray(out)      # block: compile NOW, off-tick
             if self._gcm:
-                # grouped full-mesh path: legs = this bucket, packets =
-                # the smallest row class (both axes class-padded live)
-                p = _round_rows(1)
-                pdata = np.zeros((p, w), dtype=np.uint8)
-                plen = np.full(p, 12 + payload_len, dtype=np.int32)
-                iv = np.zeros((rows, p, 12), dtype=np.uint8)
-                for aad in (12, 20):
-                    out_gp, out_len_p = self._gcm_uniform_fanout_call(
-                        recv, pdata, plen, iv, aad)
-                    out_pm, _ = _fanout_packet_major(
-                        jnp.asarray(out_gp), jnp.asarray(out_len_p))
-                    np.asarray(out_pm)
+                iv12 = np.zeros((rows, 12), dtype=np.uint8)
+                out, _ = self._gcm_fanout_call(recv, data, length,
+                                               off, iv12, w)
+            else:
+                iv = np.zeros((rows, 16), dtype=np.uint8)
+                out, _ = self._cm_fanout_call(recv, data, length,
+                                              off, iv, idx)
+            np.asarray(out)          # block: compile NOW, off-tick
+
+        def grouped(w: int, aad: int) -> None:
+            # grouped full-mesh path: legs = this bucket, packets =
+            # the smallest row class (both axes class-padded live)
+            p = _round_rows(1)
+            pdata = np.zeros((p, w), dtype=np.uint8)
+            plen = np.full(p, 12 + payload_len, dtype=np.int32)
+            iv = np.zeros((rows, p, 12), dtype=np.uint8)
+            out_gp, out_len_p = self._gcm_uniform_fanout_call(
+                recv, pdata, plen, iv, aad)
+            out_pm, _ = _fanout_packet_major(
+                jnp.asarray(out_gp), jnp.asarray(out_len_p))
+            np.asarray(out_pm)
+
+        thunks = [functools.partial(one, w, off)
+                  for w in widths for off in offs]
+        if self._gcm:
+            thunks += [functools.partial(grouped, w, aad)
+                       for w in widths for aad in (12, 20)]
+        # every variant is its own program: compile them side by side
+        compile_concurrently(thunks)
 
     def _device(self):
         if self._dev is None:
@@ -381,10 +390,8 @@ class RtpTranslator:
         output rows by owning receiver chip; everything above (routing,
         expansion, IVs) is shared verbatim.  Uniform payload offsets
         (the fan-out common case: one sender's fixed header replicated
-        per leg) take the static-pad keystream alignment — a
-        fetch-verified ~1.2x win at 128x512 rows under the bitsliced
-        core (larger under the table core, where the offset gathers
-        compound with the S-box gathers)."""
+        per leg) take the static-pad keystream alignment instead of
+        the per-row offset gathers."""
         from libjitsi_tpu.transform.srtp.context import _uniform_off
 
         tab_rk, tab_mid = self._device()

@@ -1,22 +1,34 @@
-"""Persistent XLA compilation cache setup (shared by bench + driver).
+"""Persistent XLA compilation cache setup (shared by every entry point).
 
-The 65536-row SRTP programs take minutes to compile cold; caching them
-on disk makes fresh benchmark/entry processes start in seconds.  Always
-best-effort: the cache is an optimization, never a requirement.
+The wide SRTP programs take a minute each to compile cold; caching them
+on disk makes a second process in the same checkout start warm.  The
+directory is part of the cache key, so it never moves: where
+`JAX_COMPILATION_CACHE_DIR` is set JAX already reads it and nothing is
+set in code; otherwise it is `<checkout>/.jax_cache`.  Failures
+propagate — a cache that cannot be set up is a broken checkout, not a
+slow one.
 """
 
 from __future__ import annotations
 
 import os
-from typing import Optional
+from concurrent.futures import ThreadPoolExecutor
+from typing import Callable, Iterable, Optional
+
+#: fixed default, beside the package (listed in .gitignore)
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
 class CompileCacheStats:
     """Process-wide compile/cache counters fed by `jax.monitoring`
-    events.  Event names differ across jax versions, so matching is
-    by substring ("cache_hit" / "cache_miss" / "compil") and always
-    best-effort; the counters exist (and render as 0) even when no
-    listener ever fires.  `PhaseProfiler.register_metrics` exports
+    events, matched by substring: "cache_hit" / "cache_miss" are the
+    persistent cache's `/jax/compilation_cache/cache_hits|cache_misses`
+    (a miss is an entry WRITTEN, so compiles under the min-compile-time
+    threshold count as neither), "compil" covers the trace / lower /
+    backend-compile durations every in-process jit miss emits.
+    `PhaseProfiler.register_metrics` exports
     them as `compile_cache_hits` / `compile_cache_misses` /
     `compile_events` (+ `compile_seconds_total`): a recompile landing
     on the data path shows up as a counter step in the scrape, not a
@@ -50,29 +62,42 @@ def compile_stats() -> CompileCacheStats:
     so exactly one registration per process)."""
     global _STATS
     if _STATS is None:
-        _STATS = CompileCacheStats()
-        try:
-            from jax import monitoring
+        from jax import monitoring
 
-            monitoring.register_event_listener(_STATS.on_event)
-            monitoring.register_event_duration_secs_listener(
-                _STATS.on_duration)
-        except Exception:
-            pass                 # counters still exist, just never fed
+        _STATS = CompileCacheStats()
+        monitoring.register_event_listener(_STATS.on_event)
+        monitoring.register_event_duration_secs_listener(
+            _STATS.on_duration)
     return _STATS
 
 
-def enable_compile_cache(path: str = "") -> None:
-    try:
-        import jax
+def compile_concurrently(thunks: Iterable[Callable[[], None]]) -> None:
+    """Run independent warm-up thunks on a thread pool and wait for all.
 
-        if not path:
-            path = os.path.join(
-                os.path.dirname(os.path.dirname(
-                    os.path.dirname(os.path.abspath(__file__)))),
-                ".jax_cache")
+    Each thunk's time is an XLA compile, which releases the GIL and is
+    CPU-bound on a few cores, so the programs of one warm-up rung
+    compile side by side instead of one after another (the v5e
+    compiler takes 10-60 s per SRTP program; a cold ladder compiled in
+    sequence runs to a quarter of an hour).  The first exception
+    propagates once every thunk has finished."""
+    thunks = list(thunks)
+    if len(thunks) < 2:
+        for t in thunks:
+            t()
+        return
+    with ThreadPoolExecutor(max_workers=len(thunks)) as pool:
+        for fut in [pool.submit(t) for t in thunks]:
+            fut.result()
+
+
+def enable_compile_cache() -> str:
+    """Turn the persistent cache on; returns the directory in use."""
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = DEFAULT_CACHE_DIR
         os.makedirs(path, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        pass
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    return path

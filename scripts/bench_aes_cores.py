@@ -1,13 +1,9 @@
 """Chained, fetch-verified AES-core re-measurement.
 
-Why this exists: the round-5 BENCH_DETAIL record shows
-`xla_bitsliced32` at 231.6M blocks/s — 20x the tower core — but that
-number is floor-noise (VERDICT r5 Weak #1): `_time_fn` times ONE launch
-per sample and subtracts a ~98 ms scalar-fetch floor, so any core whose
-net device time is smaller than the floor's own jitter emits junk.
-Meanwhile `kernels/aes.py` said bitsliced32 measured *at parity* with
-the addition-chain bitslice.  Both claims cannot be true, and neither
-was trustworthy.
+Why this exists: bench.py's `_time_fn` times ONE launch per sample and
+subtracts the scalar-fetch floor, so any core whose net device time is
+smaller than the floor's own jitter emits junk — a core once read 20x
+faster than its peers that way.
 
 The fix: run the core k times inside ONE jitted program with a data
 dependence (each iteration's ciphertext becomes the next iteration's

@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """Continuous perf-regression gate over a pinned fast bench subset.
 
-The BENCH_r0x records chart a trajectory but nothing *compares* them —
-a PR that halves loop-echo throughput lands silently.  This gate runs
+Records chart a trajectory but nothing *compares* them — a PR that
+halves loop-echo throughput lands silently.  This gate runs
 a pinned set of fast scenarios (small-shape twins of bench.py's heavy
 ones),
 compares each against the checked-in `PERF_BASELINE.json`, appends a
@@ -203,7 +203,7 @@ def _run_loop_echo(n_pkts=64, cycles=16, pipeline_depth=3,
 
 
 def _scenario_loop_echo():
-    """Deep-pipelined loop-echo twin of bench.py `_loop_rtt_child`:
+    """Deep-pipelined loop-echo twin of bench.py `_loop_rtt_body`:
     loopback UDP -> MediaLoop at depth 3 (demux + unprotect + echo +
     re-protect, recv/compute/send overlapped) -> client recv.  Returns
     authenticated echoed pps."""

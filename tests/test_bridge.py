@@ -150,7 +150,7 @@ def test_bridge_participant_churn_clears_row_residue():
 
 @pytest.mark.slow
 def test_bridge_levels_ext_and_speaker_events():
-    """VERDICT r2 #8: egress packets carry the RFC 6465 audio-level
+    """egress packets carry the RFC 6465 audio-level
     extension, and the dominant-speaker detector fires change events
     when the loud tone moves to another participant."""
     from libjitsi_tpu.rtp import ext as rtp_ext
@@ -223,7 +223,7 @@ def test_bridge_levels_ext_and_speaker_events():
 
 @pytest.mark.slow
 def test_bridge_mixed_rate_g711_and_g722():
-    """VERDICT r2 #9: a G.711 8 kHz phone and a G.722 16 kHz endpoint
+    """a G.711 8 kHz phone and a G.722 16 kHz endpoint
     share one conference; each hears the other's tone at its own rate
     (deposit path upsamples to the bridge clock, egress path resamples
     the mix back down/up per leg)."""
@@ -379,7 +379,7 @@ def test_conference_bridge_snapshot_resume_mid_call():
 
 @pytest.mark.slow
 def test_bridge_opus_conference_degraded_resume():
-    """VERDICT r3 #5: an OPUS conference (stateful C codec on every
+    """an OPUS conference (stateful C codec on every
     leg) snapshots and resumes: SRTP counters/replay windows carry over
     exactly, codec state re-initializes (decoder PLC warms up, encoder
     restarts clean), and after a bounded startup artifact the mix-minus

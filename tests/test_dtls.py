@@ -158,7 +158,7 @@ def test_exported_keys_drive_srtp_tables():
 @needs_openssl
 @pytest.mark.slow
 def test_lossy_handshake_completes_via_retransmission():
-    """VERDICT r2 #5: 30% datagram loss each way; the RFC 6347 flight
+    """30% datagram loss each way; the RFC 6347 flight
     timers (DtlsSrtpEndpoint.tick) must still complete the handshake.
     Real-time test: OpenSSL's initial flight timer is 1 s."""
     import time as _t

@@ -215,11 +215,11 @@ def test_gcm_snapshot_restore():
 
 
 def test_gcm_grouped_table_path_matches_per_row():
-    """VERDICT r2 #7: the grouped-GHASH table path (one matrix read per
+    """the grouped-GHASH table path (one matrix read per
     stream per launch) must be bit-identical to the per-row path on a
     mixed-stream batch, and round-trip through a grouped unprotect.
     Paths are pinned via the kernels registry (the measured-choice
-    mechanism, VERDICT r3 #6), not a batch-size constant."""
+    mechanism), not a batch-size constant."""
     from libjitsi_tpu.kernels import registry
     from libjitsi_tpu.transform.srtp import context as ctx_mod
 

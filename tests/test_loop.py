@@ -165,7 +165,7 @@ def test_loop_kernel_arrival_ns_aligned_with_media_rows():
 
 
 def test_send_media_async_flush_matches_sync():
-    """The pipelined seam (VERDICT r2 #3): dispatch-only protect +
+    """The pipelined seam: dispatch-only protect +
     next-tick flush must emit byte-identical datagrams to the sync
     path, with TX state advancing identically."""
     import libjitsi_tpu

@@ -62,9 +62,8 @@ def test_sharded_mix_matches_single(mesh):
     np.testing.assert_array_equal(np.asarray(got_lvl), np.asarray(want_lvl))
 
 
-@pytest.mark.slow   # ~75s: full driver dryrun incl. round-5 pipelined/
-# F8/GCM parity + async-overlap steps; the DRIVER runs this same entry
-# every round (MULTICHIP_r{N}.json), so core-tier coverage is redundant
+@pytest.mark.slow   # ~75s: the full dryrun incl. pipelined/F8/GCM parity
+# + async-overlap steps; the mesh parity tests below cover the core tier
 def test_dryrun_multichip():
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 virtual devices")

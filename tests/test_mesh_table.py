@@ -1,5 +1,5 @@
 """ShardedSrtpTable: the PRODUCT table sharded over the mesh must be
-bit-identical to the single-chip SrtpStreamTable (VERDICT r3 #2 — shard
+bit-identical to the single-chip SrtpStreamTable (shard
 the product objects, not just the kernels)."""
 
 import numpy as np
@@ -82,7 +82,7 @@ def test_sharded_table_rejects_indivisible_capacity():
 
 
 def test_sharded_f8_parity():
-    """AES-F8 on the sharded table (VERDICT r4 #6): the second key
+    """AES-F8 on the sharded table: the second key
     schedule shards on the same row partition — protect/unprotect must
     be bit-identical to the single-chip F8 table."""
     from libjitsi_tpu.mesh.parity import assert_table_parity
@@ -98,9 +98,8 @@ def test_sharded_f8_parity():
     (SrtpProfile.AEAD_AES_128_GCM, 12),
 ])
 def test_sharded_srtcp_parity(profile, salt):
-    """SRTCP runs SHARDED on the mesh table's RTCP key tables (VERDICT
-    r4 #6: control traffic must not silently hop to a single-chip
-    path) — wire and decrypt byte-identical to the plain table."""
+    """SRTCP runs SHARDED on the mesh table's RTCP key tables (control
+    traffic must not silently hop to a single-chip path) — wire and decrypt byte-identical to the plain table."""
     from libjitsi_tpu.core.packet import PacketBatch
 
     rng = np.random.default_rng(3)
@@ -136,7 +135,7 @@ def test_sharded_srtcp_parity(profile, salt):
 
 
 def test_sharded_async_protect_matches_sync():
-    """`protect_rtp_async` on the MESH table (VERDICT r4 #2): the
+    """`protect_rtp_async` on the MESH table: the
     deferred-scatter seam must produce bit-identical wire to the sync
     mesh path, with host TX state committed at dispatch."""
     sh_a, _ = _tables()
@@ -159,7 +158,7 @@ def test_sharded_async_protect_matches_sync():
 
 
 def test_mesh_gcm_grouped_and_per_row_parity():
-    """The sharded GCM table's grouped-GHASH path (VERDICT r4 #4) must
+    """The sharded GCM table's grouped-GHASH path must
     match the sharded per-row path and the single-chip table bit for
     bit; the live seam picks between them by registry measurement."""
     from libjitsi_tpu.kernels import registry
@@ -219,7 +218,7 @@ def test_mesh_bridge_tick_matches_single_chip():
     cfg = libjitsi_tpu.configuration_service()
     mesh = make_media_mesh()
     assert_bridge_parity(cfg, mesh, capacity=16)
-    # mesh COMPOSES with pipelined (VERDICT r4 #2): the deferred-scatter
+    # mesh COMPOSES with pipelined: the deferred-scatter
     # seam lets the dispatch overlap, and the wire stays byte-identical
     assert_bridge_parity(cfg, mesh, capacity=16, pipelined=True)
 
@@ -304,7 +303,7 @@ def test_mesh_sfu_bridge_fanout_matches_single_chip():
     cfg = libjitsi_tpu.configuration_service()
     mesh = make_media_mesh()
     assert_sfu_parity(cfg, mesh, capacity=16)
-    # mesh + pipelined composes (VERDICT r4 #2): the pipelined MESH
+    # mesh + pipelined composes: the pipelined MESH
     # bridge's forwarded wire matches the sync single-chip bridge
     assert_sfu_parity(cfg, mesh, capacity=16, pipelined=True)
     # a mesh snapshot refuses a single-chip restore (un-sharding a

@@ -165,7 +165,7 @@ class _BweSender(_Endpoint):
 
 @pytest.mark.slow
 def test_sfu_bwe_congestion_drives_remb_down_and_back_up():
-    """VERDICT r2 #2: the bridge's OWN receive-side estimate (abs-send-
+    """the bridge's OWN receive-side estimate (abs-send-
     time GCC over the sender->bridge leg) governs the REMB it advertises:
     a growing-queue trace cuts it, recovery raises it again."""
     libjitsi_tpu.stop()
@@ -218,7 +218,7 @@ def test_sfu_bwe_congestion_drives_remb_down_and_back_up():
 
 @pytest.mark.slow
 def test_sfu_dtls_keyed_endpoint_e2e():
-    """VERDICT r2 #5: a sender joins the SfuBridge keyed by DTLS-SRTP
+    """a sender joins the SfuBridge keyed by DTLS-SRTP
     over the real UDP port (loop first-byte demux -> on_dtls), media
     sent the instant the client completes flows to a static-keyed
     receiver — any packets racing the install are queued and replayed."""
@@ -282,7 +282,7 @@ def test_sfu_dtls_keyed_endpoint_e2e():
 
 @pytest.mark.slow
 def test_sfu_video_simulcast_layer_switch_and_rtx():
-    """VERDICT r2 #4: the assembled video SFU.  A 3-layer VP8 simulcast
+    """the assembled video SFU.  A 3-layer VP8 simulcast
     sender (real libvpx bitstreams) feeds the bridge over loopback UDP;
     the receiver's REMB drives keyframe-gated layer selection (PLI goes
     upstream until the target layer's keyframe lands), a NACKed packet
@@ -645,7 +645,7 @@ def test_sfu_svc_track_projection_e2e():
 
 
 def test_sfu_video_simulcast_forward_and_switch_core():
-    """Core-gate video SFU (VERDICT r3 #4): tiny-shape simulcast
+    """Core-gate video SFU: tiny-shape simulcast
     forward + REMB-driven layer switch with SYNTHETIC VP8 frames (every
     frame a keyframe, so switches land without a PLI round trip) — no
     libvpx, few packets, seconds not minutes.  The per-change gate now

@@ -278,7 +278,7 @@ def test_zrtp_alerts_bounded():
 
 
 def test_zrtp_retained_secret_continuity_across_sessions():
-    """VERDICT r3 #8 (RFC 6189 §4.3/§4.9): a second session between the
+    """a second session between the
     same endpoints mixes the cached retained secret into s0 — key
     continuity holds and the caches rotate in lockstep."""
     from libjitsi_tpu.control.zrtp import ZidCache
