@@ -284,14 +284,13 @@ def phase_a(rows: int, seed: int) -> None:
 
 # -- phase B: kernels ------------------------------------------------------
 
-def _check_mixer(rng) -> None:
+def _check_mixer(rng, n: int, f: int = 960) -> None:
     """A mixer frame through the product object — its constructor races
     the providers on the real shape, as a bridge's would — vs NumPy."""
     import numpy as np
 
     from libjitsi_tpu.conference.mixer import AudioMixer
 
-    n, f = 256, 960
     mixer = AudioMixer(capacity=n, frame_samples=f)
     pcm = rng.integers(-20000, 20000, (n, f)).astype(np.int16)
     for sid in range(n):
@@ -301,15 +300,17 @@ def _check_mixer(rng) -> None:
     want = np.clip(pcm.astype(np.int64).sum(axis=0)[None, :]
                    - pcm.astype(np.int64), -32768, 32767)
     if not np.array_equal(np.asarray(mixed, np.int64), want):
-        raise AssertionError("B mixer: mix-minus != NumPy reference")
+        raise AssertionError(f"B mixer [{n},{f}]: mix-minus != NumPy "
+                             "reference")
     if np.asarray(levels).shape != (n,):
-        raise AssertionError("B mixer: levels shape")
+        raise AssertionError(f"B mixer [{n},{f}]: levels shape")
     say(f"B mixer [{n},{f}] matches NumPy")
 
 
-def _check_pallas_twins(rng, interpret: bool) -> None:
+def _check_pallas_twins(rng, rows: int, interpret: bool) -> None:
     """Every Pallas provider, compiled for the device, bit-compared
-    with its XLA twin."""
+    with its XLA twin — the mixer from one block up to a grid of row
+    tiles at the table's width."""
     import jax
     import numpy as np
 
@@ -319,7 +320,7 @@ def _check_pallas_twins(rng, interpret: bool) -> None:
         aes_encrypt_bitsliced, aes_encrypt_pallas_bitsliced)
     from libjitsi_tpu.kernels.pallas_ops import mix_minus_pallas
 
-    for n, f in ((256, 960), (8, 160)):
+    for n, f in ((256, 960), (8, 160), (4096, 960), (rows, 960)):
         pcm = rng.integers(-20000, 20000, (n, f)).astype(np.int16)
         act = rng.random(n) < 0.8
         out_p, lvl_p = mix_minus_pallas(pcm, act, interpret=interpret)
@@ -356,13 +357,14 @@ def _check_registry() -> None:
         raise AssertionError(f"B registry: provider errors {errors}")
 
 
-def phase_b(seed: int, on_chip: bool) -> None:
+def phase_b(rows: int, seed: int, on_chip: bool) -> None:
     import numpy as np
 
     rng = np.random.default_rng(seed)
-    _check_mixer(rng)
+    for n in sorted({256, rows}):     # a conference, and a full bridge
+        _check_mixer(rng, n)
     # Mosaic lowers for the TPU only: a rehearsal interprets
-    _check_pallas_twins(rng, interpret=not on_chip)
+    _check_pallas_twins(rng, rows, interpret=not on_chip)
     _check_registry()
 
 
@@ -461,12 +463,15 @@ def phase_c(rows: int, seed: int, on_chip: bool, latch_ticks: int = 6,
     t0 = time.perf_counter()
     bridge = SfuBridge(cfg, port=0, capacity=rows, recv_window_ms=0)
     reg = bridge.loop.metrics
-    # the soak's watchdog deadline; XLA:CPU runs a tick's crypto far
-    # over any real-time budget, so a rehearsal must not trip the
-    # overload ladder (which sheds streams, by design)
+    # the soak's watchdog deadline, NOT the product default of
+    # `SupervisorConfig()` (a 20 ms tick): "healthy" below means "no
+    # tick over 1 s", and the line that reports it says so.  XLA:CPU
+    # runs a tick's crypto far over any real-time budget, so a
+    # rehearsal must not trip the overload ladder (which sheds
+    # streams, by design)
+    deadline_ms = 1000.0 if on_chip else 60_000.0
     sup = BridgeSupervisor(
-        bridge, SupervisorConfig(
-            deadline_ms=1000.0 if on_chip else 60_000.0), metrics=reg)
+        bridge, SupervisorConfig(deadline_ms=deadline_ms), metrics=reg)
     lc = StreamLifecycleManager(bridge, supervisor=sup, metrics=reg)
     lc.enable_placement(1)         # conference-scoped forwarding
     clients = []
@@ -586,7 +591,11 @@ def phase_c(rows: int, seed: int, on_chip: bool, latch_ticks: int = 6,
         say(f"C {len(clients)} clients in {len(confs)} conferences: "
             f"{want} forwarded packets each opened to the sender's "
             f"plaintext under the oracle, none missing, none foreign; "
-            f"health {HEALTHY} on all {sup.ticks} ticks; 0 compiles in "
+            f"health {HEALTHY} on all {sup.ticks} ticks under a "
+            f"{deadline_ms:.0f} ms watchdog deadline (product default "
+            f"{SupervisorConfig().deadline_ms:.0f} ms: "
+            f"{int((ts > SupervisorConfig().deadline_ms).sum())} of "
+            f"{media_ticks} media ticks were over it); 0 compiles in "
             f"{media_ticks} media ticks; tick wall ms median "
             f"{np.median(ts):.2f} max {ts.max():.2f} "
             f"({len(clients)} pkts in, {len(clients) * (CONF_SIZE - 1)} "
@@ -655,37 +664,64 @@ def phase_mesh(rows: int, seed: int, n_dev: int = 4) -> None:
         bridge.commit_endpoints(list(range(rows)))
         for k in range(3):
             bridge.tick(now=200.0 + 0.02 * k)
+        held = {}
         for name in ("rx_table", "tx_table", "translator"):
-            _assert_spread(f"bridge.{name}",
-                           getattr(bridge, name)._sharded_device(),
-                           n_dev)
-        # ... and every device must report memory in use (the CPU
-        # backend of a rehearsal keeps no such statistics)
+            for dev_id, nbytes in _assert_spread(
+                    f"bridge.{name}",
+                    getattr(bridge, name)._sharded_device(),
+                    n_dev).items():
+                held[dev_id] = held.get(dev_id, 0) + nbytes
+        # ... and every device must report at least its share of that
+        # state as memory in use (the CPU backend of a rehearsal keeps
+        # no such statistics)
         stats = {d.id: d.memory_stats() for d in devs}
         used = {i: int(st["bytes_in_use"]) for i, st in stats.items()
                 if st is not None}
-        say(f"mesh bytes_in_use per device: {used}")
+        say(f"mesh bytes_in_use per device: {used}; of it the bridge's "
+            f"table shards: {held}")
         if len(used) != n_dev and devs[0].platform == "tpu":
             raise AssertionError("mesh: a device reports no memory "
                                  f"statistics: {stats}")
-        if not all(used.values()):
-            raise AssertionError(f"mesh: a device holds nothing: {used}")
+        short = {i: (used[i], held[i]) for i in used if used[i] < held[i]}
+        if short:
+            raise AssertionError("mesh: a device reports less memory in "
+                                 f"use than the shards it holds: {short}")
     finally:
         bridge.close()
 
 
-def _assert_spread(name: str, arrays, n_dev: int) -> None:
+def _assert_spread(name: str, arrays, n_dev: int) -> dict:
+    """Every leaf is row-sharded over `n_dev` distinct devices: read
+    from the buffers (`addressable_shards`: which device holds which
+    rows), not only from the sharding they were asked to take.
+    Returns device id -> bytes of these arrays it holds."""
     import jax
 
-    for a in jax.tree_util.tree_leaves(arrays):
-        ds = getattr(getattr(a, "sharding", None), "device_set", None)
-        if ds is None:
-            continue
-        if len(ds) != n_dev:
+    held = {}
+    leaves = jax.tree_util.tree_leaves(arrays)
+    if not leaves:
+        raise AssertionError(f"mesh: {name} has no device arrays")
+    for a in leaves:
+        if len(a.sharding.device_set) != n_dev:
             raise AssertionError(
-                f"mesh: {name} array {a.shape} lives on {len(ds)} "
-                f"device(s), wanted {n_dev}")
-    say(f"mesh {name}: device arrays span {n_dev} distinct devices")
+                f"mesh: {name} array {a.shape} is sharded over "
+                f"{len(a.sharding.device_set)} device(s), wanted {n_dev}")
+        shards = a.addressable_shards
+        if len({sh.device.id for sh in shards}) != n_dev:
+            raise AssertionError(
+                f"mesh: {name} array {a.shape} has buffers on devices "
+                f"{sorted(sh.device.id for sh in shards)}, wanted "
+                f"{n_dev} distinct")
+        for sh in shards:
+            if sh.data.shape[0] * n_dev != a.shape[0]:
+                raise AssertionError(
+                    f"mesh: {name} device {sh.device.id} holds "
+                    f"{sh.data.shape[0]} of {a.shape[0]} rows, wanted "
+                    f"a {n_dev}th")
+            held[sh.device.id] = held.get(sh.device.id, 0) + sh.data.nbytes
+    say(f"mesh {name}: {len(leaves)} arrays, each device holds a "
+        f"{n_dev}th of the rows ({n_dev} distinct devices)")
+    return held
 
 
 # -- main ------------------------------------------------------------------
@@ -721,7 +757,7 @@ def main(argv=None) -> int:
     phases = ([("mesh", lambda: phase_mesh(rows, args.seed))]
               if args.chips == 4 else
               [("A", lambda: phase_a(rows, args.seed)),
-               ("B", lambda: phase_b(args.seed, on_chip)),
+               ("B", lambda: phase_b(rows, args.seed, on_chip)),
                ("C", lambda: phase_c(rows, args.seed, on_chip))])
     for name, fn in phases:
         t0 = time.perf_counter()

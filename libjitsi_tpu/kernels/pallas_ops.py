@@ -11,10 +11,14 @@ wins on the deployment's hardware (`chip_smoke.py` prints the v5e's
 pick and both timings).
 
 Kernel design notes
-- One fused VMEM pass per conference frame: the [N, F] PCM block is read
-  once; total-sum, mix-minus, clipping and the RFC 6465 level reduction
-  all happen before anything returns to HBM.  The XLA path materializes
-  the same math as two programs (mix and levels) when called separately.
+- A grid over the participant axis, two passes: the first walks the
+  [N, F] PCM in row tiles, accumulates the [1, F] total in a resident
+  output block and writes each tile's RFC 6465 levels; the second walks
+  the same tiles and writes clip(total - contrib).  A tile is sized to
+  VMEM (`_tile_rows`), so N is bounded by HBM, not by VMEM: one
+  whole-array VMEM block compiled for the v5e up to [2048, 960] and was
+  refused (vmem exhausted) at [4096, 960], under the capacity of a
+  bridge.  N <= one tile is the single-block case (grid of 1).
 - No gathers: Mosaic on this toolchain rejects table gathers (the AES
   S-box experiment fails to lower), so only gather-free ops live here.
 - Outputs are int32 (int16/uint8 tiles need (16,128)/(32,128) sublane
@@ -38,19 +42,42 @@ I16_MIN = -32768
 I16_MAX = 32767
 
 
-def _mix_kernel(pcm_ref, active_ref, out_ref, lvl_ref):
-    """Fused mix-minus + RFC 6465 levels over one [N, F] frame block."""
-    pcm = pcm_ref[:].astype(jnp.int32)
-    active = active_ref[:].astype(jnp.int32)  # [N, 1] 0/1
-    contrib = pcm * active
-    total = jnp.sum(contrib, axis=0, keepdims=True)       # [1, F]
-    out_ref[:] = jnp.clip(total - contrib, I16_MIN, I16_MAX)
+#: VMEM budget of one int32 [rows, F] tile; a grid step holds the int16
+#: input tile, the int32 output tile (both double-buffered) and a few
+#: int32/f32 temporaries of this size inside the default scoped limit
+_TILE_BYTES = 2 << 20
+_TILE_ROWS_MAX = 512
+
+
+def _tile_rows(n: int, f: int) -> int:
+    """Rows per grid step: a multiple of 16 (the int16 sublane tile),
+    or all of N when that is no more."""
+    rows = max(16, min(_TILE_ROWS_MAX, _TILE_BYTES // (4 * f) // 16 * 16))
+    return n if n <= rows else rows
+
+
+def _sum_levels_kernel(pcm_ref, active_ref, total_ref, lvl_ref):
+    """Pass 1 over one row tile: add the tile's contribution to the
+    resident [1, F] total, and write the tile's RFC 6465 levels."""
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        total_ref[...] = jnp.zeros_like(total_ref)
+
+    pcm = pcm_ref[...].astype(jnp.int32)
+    active = active_ref[...]                              # [T, 1] 0/1
+    total_ref[...] += jnp.sum(pcm * active, axis=0, keepdims=True)
     x = pcm.astype(jnp.float32) * (1.0 / 32768.0)
-    ms = jnp.mean(x * x, axis=-1, keepdims=True)          # [N, 1]
+    ms = jnp.mean(x * x, axis=-1, keepdims=True)          # [T, 1]
     db = 10.0 * jnp.log10(jnp.maximum(ms, 1e-12))
     lvl = jnp.clip(jnp.round(-db), 0, 127).astype(jnp.int32)
     silent = jnp.logical_or(ms <= 1e-12, active == 0)
-    lvl_ref[:] = jnp.where(silent, jnp.int32(127), lvl)
+    lvl_ref[...] = jnp.where(silent, jnp.int32(127), lvl)
+
+
+def _minus_kernel(pcm_ref, active_ref, total_ref, out_ref):
+    """Pass 2 over one row tile: everyone else's sum, clipped."""
+    contrib = pcm_ref[...].astype(jnp.int32) * active_ref[...]
+    out_ref[...] = jnp.clip(total_ref[...] - contrib, I16_MIN, I16_MAX)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -62,15 +89,35 @@ def mix_minus_pallas(pcm, active, interpret: bool = False):
     rounding, inactive/silent rows report 127).
     """
     n, f = pcm.shape
-    act = jnp.asarray(active, dtype=jnp.int32).reshape(n, 1)
-    out, lvl = pl.pallas_call(
-        _mix_kernel,
-        out_shape=(jax.ShapeDtypeStruct((n, f), jnp.int32),
-                   jax.ShapeDtypeStruct((n, 1), jnp.int32)),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
-                  pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=(pl.BlockSpec(memory_space=pltpu.VMEM),
-                   pl.BlockSpec(memory_space=pltpu.VMEM)),
+    t = _tile_rows(n, f)
+    steps = pl.cdiv(n, t)
+    pad = steps * t - n           # inactive zero rows: add 0 to the sum
+    pcm = jnp.pad(jnp.asarray(pcm), ((0, pad), (0, 0)))
+    act = jnp.pad(jnp.asarray(active, dtype=jnp.int32).reshape(n, 1),
+                  ((0, pad), (0, 0)))
+    rows = pl.BlockSpec((t, f), lambda i: (i, 0))
+    col = pl.BlockSpec((t, 1), lambda i: (i, 0))
+    whole = pl.BlockSpec((1, f), lambda i: (0, 0))
+    total, lvl = pl.pallas_call(
+        _sum_levels_kernel,
+        grid=(steps,),
+        out_shape=(jax.ShapeDtypeStruct((1, f), jnp.int32),
+                   jax.ShapeDtypeStruct((n + pad, 1), jnp.int32)),
+        in_specs=[rows, col],
+        out_specs=(whole, col),
+        # the total's block is revisited by every step: sequential
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(jnp.asarray(pcm), act)
-    return out.astype(jnp.int16), lvl.reshape(n).astype(jnp.uint8)
+    )(pcm, act)
+    out = pl.pallas_call(
+        _minus_kernel,
+        grid=(steps,),
+        out_shape=jax.ShapeDtypeStruct((n + pad, f), jnp.int32),
+        in_specs=[rows, col, whole],
+        out_specs=rows,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(pcm, act, total)
+    return out[:n].astype(jnp.int16), lvl[:n, 0].astype(jnp.uint8)

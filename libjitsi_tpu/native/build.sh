@@ -29,28 +29,37 @@ if [ -e /usr/include/linux/io_uring.h ] || \
   URING_FLAGS="-DHAVE_IO_URING"
 fi
 
+# Every library is linked under a private name and renamed into place:
+# a rename is atomic, so several processes that find the .so missing at
+# once (xdist workers in a fresh checkout) each install a whole file,
+# and a process that has the old one mapped keeps its inode.
+link() {   # link <output.so> <g++ arguments...>
+  out="$1"; shift
+  g++ "$@" -o "$out.$$" && mv -f "$out.$$" "$out"
+}
+
 # C++ OpenSSL differential oracle (no dev headers in the image: the
 # .cpp declares the stable EVP ABI; link the versioned lib directly)
 build_oracle() {
-  g++ -O2 -Wall -shared -fPIC -o libcrypto_oracle.so \
+  link libcrypto_oracle.so -O2 -Wall -shared -fPIC \
       crypto_oracle.cpp /usr/lib/x86_64-linux-gnu/libcrypto.so.3
 }
 
 case "${1:-}" in
   tsan)
-    g++ -O1 -g -Wall $URING_FLAGS -fsanitize=thread -shared -fPIC \
-        -o libudp_engine_tsan.so udp_engine.cpp
+    link libudp_engine_tsan.so -O1 -g -Wall $URING_FLAGS \
+        -fsanitize=thread -shared -fPIC udp_engine.cpp
     echo "built $(pwd)/libudp_engine_tsan.so" ;;
   asan)
-    g++ -O1 -g -Wall $URING_FLAGS -fsanitize=address -shared -fPIC \
-        -o libudp_engine_asan.so udp_engine.cpp
+    link libudp_engine_asan.so -O1 -g -Wall $URING_FLAGS \
+        -fsanitize=address -shared -fPIC udp_engine.cpp
     echo "built $(pwd)/libudp_engine_asan.so" ;;
   oracle)
     build_oracle
     echo "built $(pwd)/libcrypto_oracle.so" ;;
   *)
-    g++ -O2 -Wall $URING_FLAGS -shared -fPIC \
-        -o libudp_engine.so udp_engine.cpp
+    link libudp_engine.so -O2 -Wall $URING_FLAGS -shared -fPIC \
+        udp_engine.cpp
     # oracle is best-effort here: a box without libcrypto.so.3 still
     # gets the UDP engine (tests needing the oracle build it
     # explicitly via `build.sh oracle` and fail loudly there)

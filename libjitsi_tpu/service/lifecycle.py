@@ -1034,19 +1034,26 @@ class StreamLifecycleManager:
         (`rtp`; listener rows have none), the fan-out expansion
         (packets x receivers has its own class-padded shape space) and
         control traffic (NACK/RR/SR ride the same zero-recompile
-        discipline as media).  The three kinds share no program, so
-        they compile side by side; rx then tx inside one thunk, since
-        the second table finds the first one's programs warm."""
+        discipline as media).
+
+        The RTP warm-up runs first and alone: for a GCM table it is
+        where the registry TIMES grouped against per-row and pins the
+        winner for the process, and a race decided while other
+        compiles load the host and other launches fill the device
+        queue would pin noise.  Nothing after it is timed — the
+        fan-out variants and the SRTCP pair share no program, so they
+        compile side by side in one pool."""
         rx, tx = self.bridge.rx_table, self.bridge.tx_table
         plen = self.cfg.warm_payload_len
         tr = getattr(self.bridge, "translator", None)
-        thunks = []
         if rtp:
-            thunks.append(lambda: (rx.warmup_rtp(rc, payload_len=plen),
-                                   tx.warmup_rtp(rc, payload_len=plen)))
-        if tr is not None and hasattr(tr, "warmup_fanout"):
-            thunks.append(lambda: tr.warmup_fanout(rc, payload_len=plen))
+            rx.warmup_rtp(rc, payload_len=plen)
+            tx.warmup_rtp(rc, payload_len=plen)
+        thunks = []
+        if tr is not None and hasattr(tr, "fanout_warmups"):
+            thunks += tr.fanout_warmups(rc, payload_len=plen)
         if hasattr(rx, "warmup_rtcp"):
+            # rx then tx: the second table finds the programs warm
             thunks.append(lambda: (rx.warmup_rtcp(rc),
                                    tx.warmup_rtcp(rc)))
         compile_concurrently(thunks)

@@ -18,7 +18,7 @@ Key observations that make the dense layout small (RFC 3711):
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence, Set, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 import functools
 
@@ -37,7 +37,6 @@ from libjitsi_tpu.rtp import header as rtp_header
 from libjitsi_tpu.transform.srtp import kernel
 from libjitsi_tpu.transform.srtp.kdf import derive_session_keys
 from libjitsi_tpu.transform.srtp.policy import Cipher, SrtpProfile
-from libjitsi_tpu.utils.compile_cache import compile_concurrently
 
 
 def _round_width(w: int) -> int:
@@ -228,15 +227,19 @@ class RtpTranslator:
         self._routes.pop(sender_sid, None)
 
     # ------------------------------------------------------------- warmup
-    def warmup_fanout(self, rows: int, payload_len: int = 160) -> None:
-        """Pre-compile the fan-out kernels for one ROW_CLASSES bucket —
-        off the data path (StreamLifecycleManager calls this when the
+    def fanout_warmups(self, rows: int, payload_len: int = 160
+                       ) -> List[Callable[[], None]]:
+        """Pre-compiling the fan-out kernels of one ROW_CLASSES bucket,
+        off the data path, as one thunk per program: each compiles its
+        own program when called and they share nothing, so a caller may
+        run them side by side (StreamLifecycleManager does, when the
         population bucket grows, before any admit can drive traffic at
-        the new scale).  Covers the class-padded shapes translate_async
-        produces: the common uniform payload offsets (bare RTP header at
-        12, header + one-byte abs-send-time ext at 20) plus the general
-        mixed-offset entry.  Reads the live key tables (row 0, key
-        material irrelevant); outputs are garbage and discarded.
+        the new scale).  Covers the class-padded shapes
+        translate_async produces: the common uniform payload offsets
+        (bare RTP header at 12, header + one-byte abs-send-time ext at
+        20) plus the general mixed-offset entry.  Reads the live key
+        tables (row 0, key material irrelevant); outputs are garbage
+        and discarded.
 
         Widths: the data path clips the fan-out buffer to the tick's
         largest packet's LENGTH_CLASSES bucket, so this warms the class
@@ -287,8 +290,7 @@ class RtpTranslator:
         if self._gcm:
             thunks += [functools.partial(grouped, w, aad)
                        for w in widths for aad in (12, 20)]
-        # every variant is its own program: compile them side by side
-        compile_concurrently(thunks)
+        return thunks
 
     def _device(self):
         if self._dev is None:

@@ -72,20 +72,19 @@ def compile_stats() -> CompileCacheStats:
 
 
 def compile_concurrently(thunks: Iterable[Callable[[], None]]) -> None:
-    """Run independent warm-up thunks on a thread pool and wait for all.
+    """Run independent warm-up thunks on one thread pool, at most one
+    per core, and wait for all.
 
-    Each thunk's time is an XLA compile, which releases the GIL and is
-    CPU-bound on a few cores, so the programs of one warm-up rung
-    compile side by side instead of one after another (the v5e
-    compiler takes 10-60 s per SRTP program; a cold ladder compiled in
-    sequence runs to a quarter of an hour).  The first exception
+    Each thunk's time is an XLA compile, which releases the GIL, so the
+    programs of one warm-up rung compile side by side instead of one
+    after another.  Nothing that is TIMED belongs in here (see
+    `StreamLifecycleManager._warm_class`).  The first exception
     propagates once every thunk has finished."""
     thunks = list(thunks)
-    if len(thunks) < 2:
-        for t in thunks:
-            t()
+    if not thunks:
         return
-    with ThreadPoolExecutor(max_workers=len(thunks)) as pool:
+    workers = min(len(thunks), os.cpu_count() or 1)
+    with ThreadPoolExecutor(max_workers=workers) as pool:
         for fut in [pool.submit(t) for t in thunks]:
             fut.result()
 

@@ -96,24 +96,22 @@ def _fits(compiled, limit=16 << 30):
     assert total < limit, f"{total} bytes on a 16 GB chip"
 
 
-def test_srtp_protect_cm_served_shape(one_chip, no_persistent_cache,
-                                      tower_core):
+# Tier 1 keeps one CM program at the full 12,288 rows (the donated
+# ingest twin, which only an accelerator runs) and the other direction
+# at the 4,096 class; the remaining twins are `slow` — each big compile
+# is a minute of several cores beside the loopback tests.
+@pytest.mark.parametrize("fn_name,rows", [
+    ("_protect_rtp_dev", 4096),
+    ("_unprotect_rtp_dev_donated", ROWS),
+    pytest.param("_protect_rtp_dev", ROWS, marks=pytest.mark.slow),
+    pytest.param("_unprotect_rtp_dev", 4096, marks=pytest.mark.slow),
+])
+def test_srtp_cm_served_shape(one_chip, no_persistent_cache, tower_core,
+                              fn_name, rows):
     from libjitsi_tpu.transform.srtp import context as ctx
-    _fits(ctx._protect_rtp_dev.lower(
-        *_cm_args(one_chip), tag_len=10, encrypt=True,
+    _fits(getattr(ctx, fn_name).lower(
+        *_cm_args(one_chip, rows=rows), tag_len=10, encrypt=True,
         off_const=12).compile())
-
-
-@pytest.mark.parametrize("twin", ["plain", "donated"])
-def test_srtp_unprotect_cm_served_shape(one_chip, no_persistent_cache,
-                                        tower_core, twin):
-    from libjitsi_tpu.transform.srtp import context as ctx
-    # the donated twin is what the chip runs (context._donate_ingest):
-    # full batch; the plain one at the top row class
-    fn, rows = ((ctx._unprotect_rtp_dev_donated, ROWS)
-                if twin == "donated" else (ctx._unprotect_rtp_dev, 4096))
-    _fits(fn.lower(*_cm_args(one_chip, rows=rows), tag_len=10,
-                   encrypt=True, off_const=12).compile())
 
 
 def test_gcm_grouped_protect_served_shape(one_chip, no_persistent_cache,
@@ -131,6 +129,7 @@ def test_gcm_grouped_protect_served_shape(one_chip, no_persistent_cache,
         aad_const=12).compile())
 
 
+@pytest.mark.slow
 def test_keystream_fill_chunk(one_chip, no_persistent_cache, tower_core):
     from libjitsi_tpu.transform.srtp import keystream as ks
 
@@ -143,7 +142,10 @@ def test_keystream_fill_chunk(one_chip, no_persistent_cache, tower_core):
         s((n,), jnp.int32), nblocks=16).compile())
 
 
-@pytest.mark.parametrize("n,f", [(256, 960), (8, 160)])
+@pytest.mark.parametrize("n,f", [
+    (256, 960), (8, 160),           # one block
+    (4096, 960), (CAP, 960),        # a grid of row tiles: one whole-array
+])                                  # VMEM block is refused from 4096 rows
 def test_mixer_xla_and_pallas(one_chip, no_persistent_cache, n, f):
     from libjitsi_tpu.conference.mixer import _mix_jit
     from libjitsi_tpu.kernels.pallas_ops import mix_minus_pallas

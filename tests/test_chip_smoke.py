@@ -156,3 +156,27 @@ def test_compile_concurrently_runs_all_and_raises_first():
     with pytest.raises(ValueError, match="thunk failed"):
         compile_cache.compile_concurrently([ok(4), bad, ok(5)])
     assert {4, 5} <= set(ran)                   # the others finished
+
+
+def test_assert_spread_reads_the_buffers(smoke):
+    """`--chips 4`'s placement check: a row-sharded table passes and
+    reports each device's bytes; state that sits on one device, or a
+    table with no device arrays, fails."""
+    from libjitsi_tpu.mesh import ShardedSrtpTable, make_media_mesh
+
+    rows, n_dev = 64, 4
+    rng = np.random.default_rng(3)
+    tab = ShardedSrtpTable(rows, make_media_mesh(jax.devices()[:n_dev]))
+    tab.add_streams(np.arange(rows),
+                    rng.integers(0, 256, (rows, 16), dtype=np.uint8),
+                    rng.integers(0, 256, (rows, 14), dtype=np.uint8))
+    arrays = tab._sharded_device("rtp")
+    held = smoke._assert_spread("table", arrays, n_dev)
+    assert sorted(held) == [d.id for d in jax.devices()[:n_dev]]
+    assert len(set(held.values())) == 1 and min(held.values()) > 0
+    assert sum(held.values()) == sum(a.nbytes for a in arrays)
+    with pytest.raises(AssertionError, match="1 device"):
+        smoke._assert_spread("one", [jax.device_put(np.zeros((rows, 4)))],
+                             n_dev)
+    with pytest.raises(AssertionError, match="no device arrays"):
+        smoke._assert_spread("empty", (), n_dev)

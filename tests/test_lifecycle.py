@@ -282,6 +282,45 @@ def test_warmups_fire_only_at_bucket_boundaries():
     assert lc._warm_bucket == 16
 
 
+def test_rtp_warmup_is_alone_and_the_rest_side_by_side(monkeypatch):
+    """The RTP warm-up holds the registry's TIMED provider race (GCM):
+    nothing else may compile beside it.  The fan-out variants and the
+    SRTCP pair are not timed and run together, after it."""
+    import threading
+
+    monkeypatch.setattr(os, "cpu_count", lambda: 4)   # pool: 1 per core
+    lc, bridge = _lc(capacity=64, min_bucket=4)
+    log, lock = [], threading.Lock()
+    together = threading.Barrier(3, timeout=30)   # 2 fan-outs + SRTCP
+
+    def span(name, meet):
+        def run(*_a, **_k):
+            with lock:
+                log.append(("in", name))
+            if meet:
+                together.wait()    # passes only if all three overlap
+            with lock:
+                log.append(("out", name))
+        return run
+
+    bridge.rx_table.warmup_rtp = span("rx_rtp", False)
+    bridge.tx_table.warmup_rtp = span("tx_rtp", False)
+    bridge.rx_table.warmup_rtcp = span("rtcp", True)
+    bridge.tx_table.warmup_rtcp = lambda *_a, **_k: None
+    bridge.translator = types.SimpleNamespace(
+        fanout_warmups=lambda rc, payload_len: [span("fan0", True),
+                                                span("fan1", True)])
+    lc._warm_class(16, rtp=True)
+    assert log[:4] == [("in", "rx_rtp"), ("out", "rx_rtp"),
+                       ("in", "tx_rtp"), ("out", "tx_rtp")]
+    assert sorted(log[4:7]) == [("in", "fan0"), ("in", "fan1"),
+                                ("in", "rtcp")]
+    log.clear()
+    together.reset()
+    lc._warm_class(64, rtp=False)              # listener rows: no RTP
+    assert {n for _, n in log} == {"fan0", "fan1", "rtcp"}
+
+
 # -------------------------------------------- tick compile bracket
 
 def test_tick_bracket_counts_in_window_compiles(monkeypatch):

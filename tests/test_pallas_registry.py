@@ -18,8 +18,13 @@ def _rand_frame(n=32, f=960, seed=0):
     return pcm, active
 
 
-def test_pallas_mixer_bit_identical_to_xla():
-    pcm, active = _rand_frame()
+@pytest.mark.parametrize("n,f", [
+    (32, 960),      # one block: the whole frame
+    (600, 960),     # two row tiles, the second padded
+    (1536, 160),    # three full tiles of a narrow frame
+])
+def test_pallas_mixer_bit_identical_to_xla(n, f):
+    pcm, active = _rand_frame(n, f)
     out_x, lvl_x = _mix_jit(pcm, active)
     out_p, lvl_p = mix_minus_pallas(pcm, active, interpret=True)
     np.testing.assert_array_equal(np.asarray(out_x), np.asarray(out_p))
