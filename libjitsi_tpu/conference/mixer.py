@@ -85,12 +85,14 @@ def mix_minus_many(pcm, active=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     analog for this — it is the TPU-first inversion of §2.4.
     """
     pcm = jnp.asarray(pcm, dtype=jnp.int32)
-    if active is None:
-        contrib = pcm
-    else:
-        contrib = jnp.where(active[:, :, None], pcm, 0)
-    total = jnp.sum(contrib, axis=1, keepdims=True)     # [C, 1, F]
-    out = jnp.clip(total - contrib, I16_MIN, I16_MAX).astype(jnp.int16)
+    with jax.named_scope("mix"):
+        if active is None:
+            contrib = pcm
+        else:
+            contrib = jnp.where(active[:, :, None], pcm, 0)
+        total = jnp.sum(contrib, axis=1, keepdims=True)     # [C, 1, F]
+        out = jnp.clip(total - contrib, I16_MIN,
+                       I16_MAX).astype(jnp.int16)
     return out, audio_levels(pcm, active)
 
 

@@ -348,6 +348,9 @@ class MediaLoop:
     # -------------------------------------------------------------- tick
     def tick(self) -> int:
         """One batching window; returns packets processed."""
+        # every span of this tick carries the id its batch is about to
+        # get (`trace_id` steps once per tick, after the recv window)
+        self.tracer.tick = self.trace_id + 1
         self.perf.begin_tick()
         try:
             return self._tick_inner()
@@ -437,6 +440,19 @@ class MediaLoop:
         split, rtcp-mux demux, holds/fanout/shed masks, shard-major
         reorder, reverse-chain dispatch.  Shared by every drain ring;
         DTLS replies and arena pins stay with the ring they came in on."""
+        with self.tracer.span("demux", rows=batch.batch_size):
+            split = self._demux_batch(eng, batch, sip, sport, ats)
+        if split is None:
+            self._release_token(token, eng)
+            return
+        sub, ats, rtp_rows, rtcp_rows, reordered = split
+        self._dispatch_rows(eng, sub, ats, token, deep, rtp_rows,
+                            rtcp_rows, reordered)
+
+    def _demux_batch(self, eng, batch, sip, sport, ats):
+        """The `demux` stage: everything between the socket and the
+        reverse chain.  Returns None for a batch with no media row,
+        else (sub, ats, rtp_rows, rtcp_rows, reordered)."""
         n = batch.batch_size
         self.pkt_size_hist.observe_array(np.asarray(batch.length)[:n])
         if self.pcap is not None:
@@ -465,8 +481,7 @@ class MediaLoop:
                     eng.send_batch(out, addr[0], addr[1])
             media_rows = np.nonzero(~is_dtls_row)[0]
             if len(media_rows) == 0:
-                self._release_token(token, eng)
-                return
+                return None
             sub = PacketBatch(batch.data[media_rows],  # jitlint: disable=hotpath-alloc
                               np.asarray(batch.length)[media_rows],
                               batch.stream[media_rows])
@@ -547,7 +562,10 @@ class MediaLoop:
                 rtp_rows = rtp_rows[np.argsort(shard, kind="stable")]
                 self.shard_major_reorders += 1
                 reordered = True
+        return sub, ats, rtp_rows, rtcp_rows, reordered
 
+    def _dispatch_rows(self, eng, sub, ats, token, deep, rtp_rows,
+                       rtcp_rows, reordered) -> None:
         with self.tracer.span("reverse_chain"):
             if len(rtp_rows):
                 if len(rtp_rows) == sub.batch_size and not reordered:

@@ -288,20 +288,17 @@ def aes_encrypt(round_keys, blocks):
     core = get_core()
     if core == "bitsliced":
         from libjitsi_tpu.kernels.aes_bitsliced import \
-            aes_encrypt_bitsliced_nd
-
-        return aes_encrypt_bitsliced_nd(round_keys, blocks)
-    if core == "bitsliced_tower":
+            aes_encrypt_bitsliced_nd as fn
+    elif core == "bitsliced_tower":
         from libjitsi_tpu.kernels.aes_bitsliced import \
-            aes_encrypt_bitsliced_tower_nd
-
-        return aes_encrypt_bitsliced_tower_nd(round_keys, blocks)
-    if core == "bitsliced32":
+            aes_encrypt_bitsliced_tower_nd as fn
+    elif core == "bitsliced32":
         from libjitsi_tpu.kernels.aes_bitsliced import \
-            aes_encrypt_bitsliced32_nd
-
-        return aes_encrypt_bitsliced32_nd(round_keys, blocks)
-    return aes_encrypt_table(round_keys, blocks)
+            aes_encrypt_bitsliced32_nd as fn
+    else:
+        fn = aes_encrypt_table
+    with jax.named_scope("aes_rounds"):
+        return fn(round_keys, blocks)
 
 
 def _iv_to_limbs(iv):
@@ -434,8 +431,10 @@ def f8_crypt_uniform(round_keys, f8_round_keys, iv, data, offset: int,
     nblocks = max(0, (width - offset + 15) // 16)
     if nblocks == 0:
         return data
-    ks = f8_keystream(round_keys, f8_round_keys, iv, nblocks)
-    return _xor_window_uniform(data, ks, offset, length)
+    with jax.named_scope("keystream"):
+        ks = f8_keystream(round_keys, f8_round_keys, iv, nblocks)
+    with jax.named_scope("xor_payload"):
+        return _xor_window_uniform(data, ks, offset, length)
 
 
 @functools.partial(jax.jit, static_argnames=("offset",))
@@ -454,8 +453,10 @@ def ctr_crypt_uniform(round_keys, iv, data, offset: int, length):
     nblocks = max(0, (width - offset + 15) // 16)
     if nblocks == 0:            # offset beyond the buffer: nothing to crypt
         return data
-    ks = ctr_keystream(round_keys, iv, nblocks)  # [B, nblocks*16]
-    return _xor_window_uniform(data, ks, offset, length)
+    with jax.named_scope("keystream"):
+        ks = ctr_keystream(round_keys, iv, nblocks)  # [B, nblocks*16]
+    with jax.named_scope("xor_payload"):
+        return _xor_window_uniform(data, ks, offset, length)
 
 
 @jax.jit
@@ -471,8 +472,10 @@ def ctr_crypt_offset(round_keys, iv, data, offset, length):
     data = jnp.asarray(data, dtype=jnp.uint8)
     bsz, width = data.shape
     nblocks = (width + 15) // 16
-    ks = ctr_keystream(round_keys, iv, nblocks)  # [B, nblocks*16]
-    return _xor_window_offset(data, ks, offset, length)
+    with jax.named_scope("keystream"):
+        ks = ctr_keystream(round_keys, iv, nblocks)  # [B, nblocks*16]
+    with jax.named_scope("xor_payload"):
+        return _xor_window_offset(data, ks, offset, length)
 
 
 def _xor_window_offset(data, ks, offset, length):
@@ -492,5 +495,7 @@ def f8_crypt_offset(round_keys, f8_round_keys, iv, data, offset, length):
     """F8-encrypt/decrypt per-row payload windows (general offsets)."""
     data = jnp.asarray(data, dtype=jnp.uint8)
     nblocks = (data.shape[1] + 15) // 16
-    ks = f8_keystream(round_keys, f8_round_keys, iv, nblocks)
-    return _xor_window_offset(data, ks, offset, length)
+    with jax.named_scope("keystream"):
+        ks = f8_keystream(round_keys, f8_round_keys, iv, nblocks)
+    with jax.named_scope("xor_payload"):
+        return _xor_window_offset(data, ks, offset, length)

@@ -137,19 +137,22 @@ def _inc32(block):
 
 def _scatter_tag(data, pos, tag):
     # gather-free (kernels/scatter.py has the perf story)
-    return scatter_bytes(data, pos, tag, TAG_LEN)
+    with jax.named_scope("scatter_tag"):
+        return scatter_bytes(data, pos, tag, TAG_LEN)
 
 
 def _tag(round_keys, gmat, data, aad_len, ct_len, j0, width: int,
          aad_const=None):
-    if aad_const is not None:
-        gin, nblk = _build_ghash_input_uniform(data, aad_const, ct_len,
-                                               width)
-    else:
-        gin, nblk = _build_ghash_input(data, aad_len, ct_len, width)
-    s = ghash(gmat, gin, nblk, width // 16)
-    ek_j0 = aes_encrypt(round_keys, j0)
-    return jnp.bitwise_xor(s, ek_j0)
+    with jax.named_scope("tag"):
+        if aad_const is not None:
+            gin, nblk = _build_ghash_input_uniform(data, aad_const,
+                                                   ct_len, width)
+        else:
+            gin, nblk = _build_ghash_input(data, aad_len, ct_len, width)
+        with jax.named_scope("ghash"):
+            s = ghash(gmat, gin, nblk, width // 16)
+        ek_j0 = aes_encrypt(round_keys, j0)
+        return jnp.bitwise_xor(s, ek_j0)
 
 
 @functools.partial(jax.jit, static_argnames=("aad_const",))
@@ -219,19 +222,21 @@ def _grouped_tag(round_keys, gmat_g, enc, aad_len, ct_len, j0,
     """
     from libjitsi_tpu.kernels.ghash import ghash_grouped
 
-    if aad_const is not None:
-        gin, nblk = _build_ghash_input_uniform(enc, aad_const, ct_len,
-                                               width)
-    else:
-        gin, nblk = _build_ghash_input(enc, aad_len, ct_len, width)
-    g, p = grid_rows.shape
-    safe = jnp.clip(grid_rows.reshape(-1), 0, enc.shape[0] - 1)
-    gin_g = gin[safe].reshape(g, p, width)
-    nblk_g = jnp.where(grid_rows >= 0, nblk[safe].reshape(g, p), 0)
-    s = ghash_grouped(gmat_g, gin_g, nblk_g, width // 16)
-    s_rows = s.reshape(g * p, 16)[inv_pos]
-    ek_j0 = aes_encrypt(round_keys, j0)
-    return jnp.bitwise_xor(s_rows, ek_j0)
+    with jax.named_scope("tag"):
+        if aad_const is not None:
+            gin, nblk = _build_ghash_input_uniform(enc, aad_const, ct_len,
+                                                   width)
+        else:
+            gin, nblk = _build_ghash_input(enc, aad_len, ct_len, width)
+        g, p = grid_rows.shape
+        safe = jnp.clip(grid_rows.reshape(-1), 0, enc.shape[0] - 1)
+        gin_g = gin[safe].reshape(g, p, width)
+        nblk_g = jnp.where(grid_rows >= 0, nblk[safe].reshape(g, p), 0)
+        with jax.named_scope("ghash"):
+            s = ghash_grouped(gmat_g, gin_g, nblk_g, width // 16)
+        s_rows = s.reshape(g * p, 16)[inv_pos]
+        ek_j0 = aes_encrypt(round_keys, j0)
+        return jnp.bitwise_xor(s_rows, ek_j0)
 
 
 @functools.partial(jax.jit, static_argnames=("aad_const",))
@@ -370,7 +375,8 @@ def _cached_grouped_digest(gmat_g, enc, ct_len, grid_rows, inv_pos,
     gin_g = gin[safe].reshape(g, p, width)
     nblk_g = jnp.where(grid_rows >= 0, nblk[safe].reshape(g, p), 0)
     fn = ghash_grouped_packed if packed else ghash_grouped
-    s = fn(gmat_g, gin_g, nblk_g, width // 16)
+    with jax.named_scope("ghash"):
+        s = fn(gmat_g, gin_g, nblk_g, width // 16)
     return s.reshape(g * p, 16)[inv_pos]
 
 
@@ -437,11 +443,14 @@ def gcm_protect_fanout(data, length, round_keys, gmat, iv12,
     ct_len = length_r - aad_const
     enc = ctr_crypt_uniform(rk_rows, ctr0, data_gp, aad_const, ct_len)
     width = _ghash_width(w)
-    gin, nblk = _build_ghash_input_uniform(enc, aad_const, ct_len, width)
-    s = ghash_grouped(jnp.asarray(gmat), gin.reshape(g, p, width),
-                      nblk.reshape(g, p), width // 16)
-    ek_j0 = aes_encrypt(rk_rows, j0)
-    tag = jnp.bitwise_xor(s.reshape(rows, 16), ek_j0)
+    with jax.named_scope("tag"):
+        gin, nblk = _build_ghash_input_uniform(enc, aad_const, ct_len,
+                                               width)
+        with jax.named_scope("ghash"):
+            s = ghash_grouped(jnp.asarray(gmat), gin.reshape(g, p, width),
+                              nblk.reshape(g, p), width // 16)
+        ek_j0 = aes_encrypt(rk_rows, j0)
+        tag = jnp.bitwise_xor(s.reshape(rows, 16), ek_j0)
     out = _scatter_tag(enc, length_r, tag)
     return out.reshape(g, p, w), length + TAG_LEN
 

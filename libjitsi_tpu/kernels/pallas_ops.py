@@ -98,26 +98,27 @@ def mix_minus_pallas(pcm, active, interpret: bool = False):
     rows = pl.BlockSpec((t, f), lambda i: (i, 0))
     col = pl.BlockSpec((t, 1), lambda i: (i, 0))
     whole = pl.BlockSpec((1, f), lambda i: (0, 0))
-    total, lvl = pl.pallas_call(
-        _sum_levels_kernel,
-        grid=(steps,),
-        out_shape=(jax.ShapeDtypeStruct((1, f), jnp.int32),
-                   jax.ShapeDtypeStruct((n + pad, 1), jnp.int32)),
-        in_specs=[rows, col],
-        out_specs=(whole, col),
-        # the total's block is revisited by every step: sequential
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(pcm, act)
-    out = pl.pallas_call(
-        _minus_kernel,
-        grid=(steps,),
-        out_shape=jax.ShapeDtypeStruct((n + pad, f), jnp.int32),
-        in_specs=[rows, col, whole],
-        out_specs=rows,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)),
-        interpret=interpret,
-    )(pcm, act, total)
+    with jax.named_scope("mix"):
+        total, lvl = pl.pallas_call(
+            _sum_levels_kernel,
+            grid=(steps,),
+            out_shape=(jax.ShapeDtypeStruct((1, f), jnp.int32),
+                       jax.ShapeDtypeStruct((n + pad, 1), jnp.int32)),
+            in_specs=[rows, col],
+            out_specs=(whole, col),
+            # the total's block is revisited by every step: sequential
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret,
+        )(pcm, act)
+        out = pl.pallas_call(
+            _minus_kernel,
+            grid=(steps,),
+            out_shape=jax.ShapeDtypeStruct((n + pad, f), jnp.int32),
+            in_specs=[rows, col, whole],
+            out_specs=rows,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",)),
+            interpret=interpret,
+        )(pcm, act, total)
     return out[:n].astype(jnp.int16), lvl[:n, 0].astype(jnp.uint8)

@@ -217,6 +217,7 @@ class SfuBridge:
             # reverse pipelining doesn't engage here — depth > 1 still
             # turns on pipelined replies/fan-out (loop.pipelined)
             pipeline_depth=pipeline_depth)
+        self._share_tracer()
         self.port = self.loop.engine.port
         # SO_REUSEPORT multi-queue: sibling drain rings on the SAME
         # port, kernel-sharded by flow hash; each tick drains every
@@ -280,6 +281,13 @@ class SfuBridge:
         # speaker set is relayed across the trunk (top-K speaker bus,
         # never raw per-participant fan-out)
         self._trunks: Dict[int, object] = {}
+
+    def _share_tracer(self) -> None:
+        """Hand the loop's tracer and phase profiler to the pieces
+        that span their own parts of the tick (the way the supervisor
+        hands out `flight`); again after a restore replaced a table."""
+        for obj in (self.rx_table, self.translator):
+            obj.tracer, obj.perf = self.loop.tracer, self.loop.perf
 
     # ---------------------------------------------------------- endpoints
     def add_endpoint(self, ssrc: int, rx_key: Tuple[bytes, bytes],
@@ -902,37 +910,41 @@ class SfuBridge:
         (after the recv window — the launch overlaps the socket wait),
         same seam as MediaLoop's pipelined replies."""
         self._media_ran = True
-        perf = self.loop.perf
+        perf, tracer = self.loop.perf, self.loop.tracer
         if self._pending_fanout:
             self._flush_fanout()
         perf.note_h2d(batch.data.nbytes +
                       np.asarray(batch.length).nbytes)
-        # sync unprotect blends dispatch+compute+d2h — attributed
-        # wholesale to device_compute, same as the loop's reverse chain
-        with perf.phase("device_compute"):
+        # the table splits the call into its host part and the wait for
+        # the device (`unprotect_host` / `unprotect_wait`, the latter
+        # the `device_compute` phase)
+        with tracer.span("unprotect"):
             dec, ok, idx = self.rx_table.unprotect_rtp(
                 batch, return_index=True)
         perf.note_d2h(dec.data.nbytes)
-        rows = np.nonzero(ok)[0]
-        if len(rows) == 0:
-            return None
-        sub = PacketBatch(dec.data[rows],
-                          np.asarray(dec.length)[rows],
-                          dec.stream[rows])
-        hdr = rtp_header.parse(sub)
+        with tracer.span("parse", rows=batch.batch_size):
+            rows = np.nonzero(ok)[0]
+            if len(rows) == 0:
+                return None
+            sub = PacketBatch(dec.data[rows],
+                              np.asarray(dec.length)[rows],
+                              dec.stream[rows])
+            hdr = rtp_header.parse(sub)
         # uplink loss detection: gaps in each sender's seq space queue
         # upstream NACKs (drained toward the sender by emit_feedback)
-        with self.loop.tracer.span("recovery"):
+        with tracer.span("recovery"):
             self.recovery.observe_rx(hdr.ssrc, hdr.seq, self._now)
-        self._feed_bwe(sub, rows, hdr=hdr)
+        with tracer.span("bwe"):
+            self._feed_bwe(sub, rows, hdr=hdr)
         if self._trunks:
             # cascade relay taps the PROTECTED ingress rows (the trunk
             # re-wraps them; participant SRTP crosses intact)
             self._relay_trunk(batch, rows, sub.stream, hdr.ssrc)
         # stamp the bridge's own abs-send-time before the fan-out so
         # every receiver leg can run receive-side GCC on its downlink
-        sub, _ = self._ast.rtp_transformer.transform(sub)
-        idx_sel = idx[rows]
+        with tracer.span("abs_send_time"):
+            sub, _ = self._ast.rtp_transformer.transform(sub)
+            idx_sel = idx[rows]
         if self._video:
             vmask = np.isin(sub.stream, list(self._video.keys()))
             if vmask.any():
@@ -944,19 +956,21 @@ class SfuBridge:
                                   np.asarray(sub.length)[keep],
                                   sub.stream[keep])
                 idx_sel = idx_sel[keep]
+        # the translator books route / expand / fanout_dispatch inside
+        # `translate_async` and fanout_wait / fanout_d2h inside
+        # `result()`, each with the phase it is (the route loop and the
+        # expansion are host_python, the residual)
         if self.pipelined:
-            with self.loop.tracer.span("forward_chain"):
+            with tracer.span("forward_chain"):
                 # dispatch carries its ingress origin: the flush lands
                 # on a LATER tick, and the journey must charge the
                 # pipelining delay to the tick the packets arrived on
-                with perf.phase("dispatch"):
-                    pend = self.translator.translate_async(sub, idx_sel)
+                pend = self.translator.translate_async(sub, idx_sel)
                 self._pending_fanout.append(
                     (pend, self.loop.journey_origin()))
             return None
-        with self.loop.tracer.span("forward_chain"):
-            with perf.phase("device_compute"):
-                wire, recv = self.translator.translate(sub, idx_sel)
+        with tracer.span("forward_chain"):
+            wire, recv = self.translator.translate(sub, idx_sel)
         self._emit_fanout(wire, recv)
         return None
 
@@ -972,38 +986,38 @@ class SfuBridge:
             self._flush_fanout()
 
     def _flush_fanout(self) -> None:
-        perf = self.loop.perf
         pending, self._pending_fanout = self._pending_fanout, []
         for pend, origin in pending:
-            perf.fence(pend)
-            with perf.phase("d2h_transfer"):
-                out = pend.result()
-            self._emit_fanout(*out, origin=origin)
+            self._emit_fanout(*pend.result(), origin=origin)
 
     def _emit_fanout(self, wire: PacketBatch, recv: np.ndarray,
                      origin=None) -> None:
         if wire.batch_size == 0:
             return
-        # a just-joined leg has no latched address yet: sending to
-        # 0.0.0.0:0 would EINVAL out of sendmmsg and crash the tick
-        ready = self.loop.addr_port[recv] != 0
-        if not ready.any():
-            return
-        rr = np.nonzero(ready)[0]
-        wire = PacketBatch(wire.data[rr],
-                           np.asarray(wire.length)[rr],
-                           wire.stream[rr])
-        recv = recv[rr]
-        # cache each leg's protected copy for NACK service, keyed by
-        # (leg sid, SENDER ssrc) + original seq — seq survives the
-        # fan-out, and two senders' seq ranges must never collide in
-        # one leg's cache
-        hdr = rtp_header.parse(wire)
-        copies = [wire.to_bytes(i) for i in range(wire.batch_size)]
-        self.cache.insert_batch(
-            (recv.astype(np.int64) << 32) | hdr.ssrc.astype(np.int64),
-            hdr.seq, copies, now=self._now)
-        with self.loop.tracer.span("egress"):
+        with self.loop.tracer.span("nack_cache", rows=wire.batch_size):
+            # a just-joined leg has no latched address yet: sending to
+            # 0.0.0.0:0 would EINVAL out of sendmmsg and crash the tick
+            ready = self.loop.addr_port[recv] != 0
+            if not ready.any():
+                return
+            rr = np.nonzero(ready)[0]
+            wire = PacketBatch(wire.data[rr],
+                               np.asarray(wire.length)[rr],
+                               wire.stream[rr])
+            recv = recv[rr]
+            # cache each leg's protected copy for NACK service, keyed
+            # by (leg sid, SENDER ssrc) + original seq — seq survives
+            # the fan-out, and two senders' seq ranges must never
+            # collide in one leg's cache
+            hdr = rtp_header.parse(wire)
+            copies = [wire.to_bytes(i) for i in range(wire.batch_size)]
+            self.cache.insert_batch(
+                (recv.astype(np.int64) << 32)
+                | hdr.ssrc.astype(np.int64),
+                hdr.seq, copies, now=self._now)
+        with self.loop.tracer.span(
+                "egress", rows=wire.batch_size,
+                bytes=int(np.asarray(wire.length).sum())):
             sent = self.loop.engine.send_batch(
                 wire, self.loop.addr_ip[recv], self.loop.addr_port[recv])
             self.loop.note_journey_at(
@@ -1274,6 +1288,7 @@ class SfuBridge:
         else:
             bridge.rx_table = _T.restore(snap["rx_table"])
             bridge.tx_table = _T.restore(snap["tx_table"])
+        bridge._share_tracer()
         bridge.bwe = BatchedRemoteBitrateEstimator.restore(snap["bwe"])
         bridge._bwe_fed = np.asarray(snap["bwe_fed"]).copy()
         bridge._rx_keys = dict(snap["rx_keys"])

@@ -40,9 +40,12 @@ recovery are in scope (SURVEY §5 robustness gap).  One
 
 from __future__ import annotations
 
+import gc
 import os
 import pickle
+import threading
 import time
+import weakref
 from collections import deque
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional
@@ -53,7 +56,7 @@ from libjitsi_tpu.core.packet import PacketBatch
 from libjitsi_tpu.utils.flight import FlightRecorder
 from libjitsi_tpu.utils.health import (ExponentialBackoff, SlidingWindowCounter,
                                        Watchdog, retrying, state_code)
-from libjitsi_tpu.utils.tracing import PipelineTracer
+from libjitsi_tpu.utils.tracing import PipelineTracer, span_of
 
 CKPT_MAGIC = "ljt-ckpt"
 CKPT_VERSION = 1
@@ -122,6 +125,22 @@ class BridgeSupervisor:
         self.tracer: Optional[PipelineTracer] = getattr(
             self.loop, "tracer", None)
         self.last_ledger: Dict[str, float] = {}
+        # the same tick by SELF time (a stage less its child spans) and
+        # its counts: what the ladder steers on — on an SfuBridge the
+        # inclusive ledger's largest entry is always the container
+        # round everything, `reverse_chain`
+        self.last_self_ledger: Dict[str, float] = {}
+        self.last_counts: Dict[str, Dict[str, float]] = {}
+        # the span tree's owner: `tick` spans `supervise`, roots every
+        # tick and books the interpreter's collections as `gc` spans
+        # (test stubs carry only take_ledger: no tree)
+        self._tree: Optional[PipelineTracer] = (
+            self.tracer if hasattr(self.tracer, "tick_root") else None)
+        self._tick_thread: Optional[int] = None
+        self.gc_pause_s = 0.0
+        self._gc_t0 = 0.0
+        self._gc_span = None
+        self._gc_unhook = self._hook_gc() if self._tree else None
         # host/device phase ledger (utils/perf.PhaseProfiler via the
         # tracer): escalations say host-bound vs device-bound, not just
         # which stage.  getattr-guarded — test stubs carry only
@@ -186,9 +205,69 @@ class BridgeSupervisor:
             if obj is not None and hasattr(obj, "flight"):
                 obj.flight = self.flight
 
+    # -------------------------------------------------- gc accounting
+
+    def _hook_gc(self):
+        """Book every collection of the interpreter: its pause into
+        `gc_pause_seconds_total`, and on the tick thread a `gc` span
+        wherever in the tree it lands (a full collection stalls the
+        tick for half a second at 10k streams).  The hook holds the
+        supervisor weakly and goes with it; `close` removes it."""
+        ref = weakref.ref(self)
+
+        def on_gc(phase, info):
+            sup = ref()
+            if sup is not None:
+                sup._on_gc(phase, info)
+
+        def unhook():
+            if on_gc in gc.callbacks:
+                gc.callbacks.remove(on_gc)
+
+        gc.callbacks.append(on_gc)
+        return weakref.finalize(self, unhook)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._gc_t0 = time.perf_counter()
+            # the tree belongs to the tick thread: a collection another
+            # thread triggers is a pause here all the same, and counted
+            if threading.get_ident() == self._tick_thread:
+                self._gc_span = self._tree.span(
+                    "gc", generation=info["generation"])
+                self._gc_span.__enter__()
+            return
+        self.gc_pause_s += time.perf_counter() - self._gc_t0
+        if self._gc_span is not None:
+            self._gc_span.__exit__(None, None, None)
+            self._gc_span = None
+
+    def close(self) -> None:
+        """Take the collector hook out (the bridge is the caller's to
+        close)."""
+        if self._gc_unhook is not None:
+            self._gc_unhook()
+
     # ------------------------------------------------------------- tick
 
     def tick(self, now: Optional[float] = None):
+        tree = self._tree
+        if tree is None:
+            return self._tick(now)
+        self._tick_thread = threading.get_ident()
+        rx0 = getattr(self.loop, "rx_packets", 0)
+        # one anchor per tick between the profiler's clock and the
+        # wall clock senders stamp with: a late delivery can be put to
+        # a tick, and a tick to what the host did in it
+        root = tree.tick_root(getattr(self.loop, "trace_id", self.ticks)
+                              + 1, wall_ns=time.time_ns())
+        with root:
+            result = self._tick(now)
+            root.set_metadata(
+                rx=getattr(self.loop, "rx_packets", 0) - rx0)
+        return result
+
+    def _tick(self, now: Optional[float]):
         lc = self.lifecycle
         if lc is not None:
             # bracket the data path with the compile-cache guard: any
@@ -199,44 +278,61 @@ class BridgeSupervisor:
         result = (self.bridge.tick(now=now) if now is not None
                   else self.bridge.tick())
         self.last_tick_s = self.clock() - t0
-        over = self.watchdog.observe(self.last_tick_s)
-        if lc is not None:
-            lc.tick_end()
-        if self.tracer is not None:
-            self.last_ledger = self.tracer.take_ledger()
-            take_phases = getattr(self.tracer, "take_phase_ledger",
-                                  None)
-            if take_phases is not None:
-                phases = take_phases()
-                if phases:       # sampled ticks only; keep last split
-                    self.last_phases = phases
-        self.ticks += 1
-        if self.slo is not None:
-            self.slo.on_tick()
-        if self.batcher is not None:
-            self.batcher.on_tick()
-        if self.capacity is not None:
-            self.capacity.on_tick(self)
-        self._update_quarantine()
-        if over:
-            self._good = 0
-            # one rung per `overload_after` consecutive overruns: graded
-            # pressure, not a free-fall to full shedding
-            if (self.watchdog.consecutive % self.cfg.overload_after) == 0:
-                self._escalate()
-        else:
-            self._good += 1
-            if self.level > 0 and self._good >= self.cfg.overload_exit:
-                self._deescalate()
+        with span_of(self._tree, "supervise"):
+            over = self.watchdog.observe(self.last_tick_s)
+            if lc is not None:
+                lc.tick_end()
+            self._take_ledgers()
+            self.ticks += 1
+            if self.slo is not None:
+                self.slo.on_tick()
+            if self.batcher is not None:
+                self.batcher.on_tick()
+            if self.capacity is not None:
+                self.capacity.on_tick(self)
+            self._update_quarantine()
+            if over:
                 self._good = 0
-        if (self.cfg.checkpoint_every
-                and self.ticks % self.cfg.checkpoint_every == 0):
-            self.save_checkpoint()
-        if lc is not None:
-            # between-ticks window: flip staged streams live (commit
-            # barrier), then stage the next admit/evict wave off-tick
-            lc.run_between_ticks(now=now)
+                # one rung per `overload_after` consecutive overruns:
+                # graded pressure, not a free-fall to full shedding
+                if (self.watchdog.consecutive
+                        % self.cfg.overload_after) == 0:
+                    self._escalate()
+            else:
+                self._good += 1
+                if (self.level > 0
+                        and self._good >= self.cfg.overload_exit):
+                    self._deescalate()
+                    self._good = 0
+            if (self.cfg.checkpoint_every
+                    and self.ticks % self.cfg.checkpoint_every == 0):
+                self.save_checkpoint()
+            if lc is not None:
+                # between-ticks window: flip staged streams live
+                # (commit barrier), then stage the next admit/evict
+                # wave off-tick
+                lc.run_between_ticks(now=now)
+        if self._tree is not None:
+            # `supervise` closed after the drain above: fold it in, so
+            # that the ledgers a caller reads now hold THIS tick's own
+            self._tree.take_ledger(fold=True)
         return result
+
+    def _take_ledgers(self) -> None:
+        """Drain the tracer: the ladder below judges this tick."""
+        if self.tracer is None:
+            return
+        self.last_ledger = self.tracer.take_ledger()
+        # where no span nests (a stub, a MediaLoop alone) the two
+        # ledgers are equal
+        self.last_self_ledger = getattr(
+            self.tracer, "last_self_ledger", self.last_ledger)
+        self.last_counts = getattr(self.tracer, "last_counts", {})
+        take_phases = getattr(self.tracer, "take_phase_ledger", None)
+        if take_phases is not None:
+            phases = take_phases()
+            if phases:       # sampled ticks only; keep last split
+                self.last_phases = phases
 
     # ------------------------------------------- overload escalation
 
@@ -300,8 +396,8 @@ class BridgeSupervisor:
         # went, not just that it overran — the dominant stage, its
         # ledger share, the chosen rung, and the SLO state ride on
         # every escalation event for the post-mortem
-        stage, stage_s = PipelineTracer.dominant(self.last_ledger)
-        total = sum(self.last_ledger.values())
+        stage, stage_s = PipelineTracer.dominant(self.last_self_ledger)
+        total = sum(self.last_self_ledger.values())
         share = (stage_s / total) if total > 0 else 0.0
         rung = self._pick_rung(stage, share, rec)
         phase, _phase_s, phase_share, bound = self._phase_attr()
@@ -372,7 +468,7 @@ class BridgeSupervisor:
                  if s not in self._shed_set and s not in self._quarantined
                  and s not in staged and s != dominant]
         cands.sort(key=lambda s: (self.priorities.get(s, 0), -s))
-        stage, stage_s = PipelineTracer.dominant(self.last_ledger)
+        stage, stage_s = PipelineTracer.dominant(self.last_self_ledger)
         for sid in cands[:k]:
             self._shed.append(sid)
             self._shed_set.add(sid)
@@ -661,6 +757,10 @@ class BridgeSupervisor:
             f"{prefix}_checkpoints_written",
             lambda: self.checkpoints_written, kind="counter")
         registry.register_scalar(
+            "gc_pause_seconds_total", lambda: self.gc_pause_s,
+            help_="time the interpreter's collections stopped the "
+                  "process for, every thread's", kind="counter")
+        registry.register_scalar(
             f"{prefix}_inbound_dropped",
             lambda: self.loop.inbound_dropped_total,
             help_="packets dropped by shed/quarantine masks",
@@ -798,6 +898,9 @@ class BridgeSupervisor:
                 "quarantined": sorted(self._quarantined),
                 "ticks": self.ticks, "overruns": self.watchdog.overruns,
                 "last_ledger": dict(self.last_ledger),
+                "last_self_ledger": dict(self.last_self_ledger),
+                "last_counts": {k: dict(v) for k, v in
+                                self.last_counts.items()},
                 "last_phases": dict(self.last_phases),
                 "bound": self._phase_attr()[3],
                 "slo_state": self._slo_state(),

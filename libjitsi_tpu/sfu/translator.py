@@ -37,6 +37,8 @@ from libjitsi_tpu.rtp import header as rtp_header
 from libjitsi_tpu.transform.srtp import kernel
 from libjitsi_tpu.transform.srtp.kdf import derive_session_keys
 from libjitsi_tpu.transform.srtp.policy import Cipher, SrtpProfile
+from libjitsi_tpu.utils.perf import phase_of
+from libjitsi_tpu.utils.tracing import span_of
 
 
 def _round_width(w: int) -> int:
@@ -47,6 +49,10 @@ def _round_width(w: int) -> int:
         if w <= c + CLASS_HEADROOM:
             return c + CLASS_HEADROOM
     return w
+
+
+def _nbytes(*arrays) -> int:
+    return sum(int(a.nbytes) for a in arrays)
 
 
 def _cycle_rows(n: int) -> Optional[np.ndarray]:
@@ -64,17 +70,18 @@ def _cycle_rows(n: int) -> Optional[np.ndarray]:
                    donate_argnums=(3,))
 def _fanout_protect(tab_rk, tab_mid, recv, data, length, payload_off, iv,
                     roc, tag_len: int, encrypt: bool, off_const=None):
+    rk, mid = kernel.gather_keys(recv, tab_rk, tab_mid)
     return kernel.srtp_protect(
-        data, length, payload_off, tab_rk[recv], iv, tab_mid[recv], roc,
+        data, length, payload_off, rk, iv, mid, roc,
         tag_len, encrypt, payload_off_const=off_const)
 
 
 @functools.partial(jax.jit, static_argnames=("aad_const",), donate_argnums=(3,))
 def _fanout_protect_gcm(tab_rk, tab_gm, recv, data, length, aad_len, iv12,
                         aad_const=None):
+    rk, gm = kernel.gather_keys(recv, tab_rk, tab_gm)
     return gcm_kernel.gcm_protect(
-        data, length, aad_len, tab_rk[recv], tab_gm[recv], iv12,
-        aad_const=aad_const)
+        data, length, aad_len, rk, gm, iv12, aad_const=aad_const)
 
 
 @jax.jit
@@ -115,6 +122,10 @@ class RtpTranslator:
         self._dev = None
         # routing: sender sid -> sorted receiver id array
         self._routes: Dict[int, np.ndarray] = {}
+        # the bridge hands its loop's PipelineTracer and PhaseProfiler
+        # here; a translator standing alone spans and samples nothing
+        self.tracer = None
+        self.perf = None
 
     # ---------------------------------------------------------- receivers
     def add_receiver(self, rid: int, master_key: bytes,
@@ -321,35 +332,44 @@ class RtpTranslator:
         """Dispatch-only `translate`: the fan-out launch is enqueued,
         results materialize on `.result()` — the SFU's pipelined tick
         overlaps the launch with its next recv window."""
+        tracer = self.tracer
         stream = np.asarray(batch.stream, dtype=np.int64)
         index = np.asarray(index, dtype=np.int64)
         # build the (packet, receiver) expansion on host
         rows: List[int] = []
         recvs: List[np.ndarray] = []
-        for i, sid in enumerate(stream):
-            rr = self._routes.get(int(sid))
-            if rr is None or len(rr) == 0:
-                continue
-            rows.append(i)
-            recvs.append(rr)
+        with span_of(tracer, "route", packets=len(stream)):
+            for i, sid in enumerate(stream):
+                rr = self._routes.get(int(sid))
+                if rr is None or len(rr) == 0:
+                    continue
+                rows.append(i)
+                recvs.append(rr)
         if not rows:
             return PendingTranslate(None, None, np.zeros(0, np.int64),
                                     batch.capacity)
-        counts = np.array([len(r) for r in recvs])
-        src = np.repeat(np.array(rows, dtype=np.int64), counts)
-        recv = np.concatenate(recvs)
-        if not np.all(self.active[recv]):
-            raise KeyError("route to receiver without installed keys")
+        with span_of(tracer, "expand") as sp:
+            counts = np.array([len(r) for r in recvs])
+            src = np.repeat(np.array(rows, dtype=np.int64), counts)
+            recv = np.concatenate(recvs)
+            if not np.all(self.active[recv]):
+                raise KeyError("route to receiver without installed keys")
 
-        data = batch.data[src]
-        length = np.asarray(batch.length, dtype=np.int32)[src]
-        hdr = rtp_header.parse(batch)
-        payload_off = hdr.payload_off[src]
-        ssrc = hdr.ssrc[src]
-        idx = index[src]
-        if int(np.max(length, initial=0)) + self.policy.auth_tag_len > \
-                batch.capacity:
-            raise ValueError("fan-out rows need tag headroom in capacity")
+            data = batch.data[src]
+            length = np.asarray(batch.length, dtype=np.int32)[src]
+            hdr = rtp_header.parse(batch)
+            payload_off = hdr.payload_off[src]
+            ssrc = hdr.ssrc[src]
+            idx = index[src]
+            if int(np.max(length, initial=0)) + self.policy.auth_tag_len \
+                    > batch.capacity:
+                raise ValueError(
+                    "fan-out rows need tag headroom in capacity")
+            if not self._gcm:
+                cm = self._expand_cm(recv, data, length, payload_off,
+                                     ssrc, idx)
+                sp.note(rows=len(recv), rows_padded=len(cm[0]),
+                        width=cm[1].shape[-1])
 
         pg = None
         if self._gcm:
@@ -357,34 +377,44 @@ class RtpTranslator:
                 batch, rows, recvs, src, recv, data, length,
                 hdr, payload_off, ssrc, idx)
         else:
-            # per-row IV from the receiver's salt + sender's ssrc/index
-            iv = self._salt[recv].copy()
-            for k in range(4):
-                iv[:, 4 + k] ^= ((ssrc >> (8 * (3 - k))) & 0xFF
-                                 ).astype(np.uint8)
-            for k in range(6):
-                iv[:, 8 + k] ^= ((idx >> (8 * (5 - k))) & 0xFF
-                                 ).astype(np.uint8)
+            # staged: the four arrays as they are, receiver and ROC
+            # as 32-bit words
+            with span_of(tracer, "fanout_dispatch",
+                         h2d_bytes=_nbytes(*cm[1:5]) + 8 * len(cm[0])), \
+                    phase_of(self.perf, "dispatch"):
+                out, out_len = self._cm_fanout_call(*cm)
+        return PendingTranslate(out, out_len, recv, batch.capacity, pg=pg,
+                                tracer=tracer, perf=self.perf)
 
-            # class-pad rows AND width: under churn the receiver count
-            # changes every tick, so raw (packets x receivers) shapes
-            # would retrace the fan-out jit unboundedly — bucketing
-            # keeps the compiled-shape space at LENGTH x ROW classes
-            rr_idx = _cycle_rows(len(recv))
-            if rr_idx is None:
-                rr_idx = np.arange(len(recv))
-            # width clips to the tick's largest packet's class, not the
-            # wire buffer: voice riding full-MTU rx buffers would pay
-            # ~7x keystream over every leg
-            pw = _round_width(int(np.max(length, initial=12))
-                              + self.policy.auth_tag_len)
-            cw = min(pw, data.shape[-1])
-            pdata = np.zeros((len(rr_idx), pw), dtype=np.uint8)
-            pdata[:, :cw] = data[rr_idx][:, :cw]
-            out, out_len = self._cm_fanout_call(
-                recv[rr_idx], pdata, length[rr_idx],
+    def _expand_cm(self, recv, data, length, payload_off, ssrc, idx):
+        """The CM fan-out call's arguments: per-row IVs, rows and width
+        padded to their classes."""
+        # per-row IV from the receiver's salt + sender's ssrc/index
+        iv = self._salt[recv].copy()
+        for k in range(4):
+            iv[:, 4 + k] ^= ((ssrc >> (8 * (3 - k))) & 0xFF
+                             ).astype(np.uint8)
+        for k in range(6):
+            iv[:, 8 + k] ^= ((idx >> (8 * (5 - k))) & 0xFF
+                             ).astype(np.uint8)
+
+        # class-pad rows AND width: under churn the receiver count
+        # changes every tick, so raw (packets x receivers) shapes
+        # would retrace the fan-out jit unboundedly — bucketing
+        # keeps the compiled-shape space at LENGTH x ROW classes
+        rr_idx = _cycle_rows(len(recv))
+        if rr_idx is None:
+            rr_idx = np.arange(len(recv))
+        # width clips to the tick's largest packet's class, not the
+        # wire buffer: voice riding full-MTU rx buffers would pay
+        # ~7x keystream over every leg
+        pw = _round_width(int(np.max(length, initial=12))
+                          + self.policy.auth_tag_len)
+        cw = min(pw, data.shape[-1])
+        pdata = np.zeros((len(rr_idx), pw), dtype=np.uint8)
+        pdata[:, :cw] = data[rr_idx][:, :cw]
+        return (recv[rr_idx], pdata, length[rr_idx],
                 payload_off[rr_idx], iv[rr_idx], idx[rr_idx])
-        return PendingTranslate(out, out_len, recv, batch.capacity, pg=pg)
 
     def _cm_fanout_call(self, recv, data, length, payload_off, iv, idx):
         """AES-CM fan-out device call — the mesh translator
@@ -419,6 +449,7 @@ class RtpTranslator:
         Reference: RTPTranslatorImpl's cipher-agnostic per-leg
         transform (SURVEY §3.4).
         """
+        tracer, perf = self.tracer, self.perf
         off0 = np.asarray(hdr.payload_off)[rows]
         # the offset bound mirrors _uniform_off: a forged ext_words field
         # can claim a header larger than the packet; such batches take
@@ -432,60 +463,76 @@ class RtpTranslator:
                    and off0.size and np.all(off0 == off0[0])
                    and 0 <= int(off0[0]) < batch.capacity)
         if uniform:
-            rr = recvs[0]
-            p_rows = np.asarray(rows, dtype=np.int64)
-            pidx = np.asarray(idx).reshape(len(rows), len(rr))[:, 0] \
-                if len(rr) else np.zeros(0, np.int64)
-            # class-pad BOTH grouped axes (legs and packets, cycled)
-            # plus the data width: churn varies the leg count every
-            # tick, and raw (G, P) shapes would retrace unboundedly
-            g_real, p_real = len(rr), len(p_rows)
-            g_idx = _cycle_rows(g_real)
-            rr_p = rr[g_idx] if g_idx is not None else rr
-            p_idx = _cycle_rows(p_real)
-            if p_idx is None:
-                p_idx = np.arange(p_real)
-            pr = p_rows[p_idx]
-            plen = np.asarray(batch.length, dtype=np.int32)[pr]
-            # width clips to the largest packet's class (see the CM path)
-            pw = _round_width(int(np.max(plen, initial=12))
-                              + self.policy.auth_tag_len)
-            cw = min(pw, batch.capacity)
-            pdata = np.zeros((len(pr), pw), dtype=np.uint8)
-            pdata[:, :cw] = batch.data[pr][:, :cw]
-            pssrc = hdr.ssrc[pr]
-            pidx = pidx[p_idx]
-            # iv [G, P, 12]: leg salt x sender ssrc/index
-            iv = gcm_kernel.srtp_gcm_iv(
-                np.broadcast_to(self._salt[rr_p][:, None, :12],
-                                (len(rr_p), len(pr), 12)),
-                pssrc[None, :], pidx[None, :])
-            out_gp, out_len_p = self._gcm_uniform_fanout_call(
-                rr_p, pdata, plen, iv, int(off0[0]))
-            # grouped output is leg-major [G, P, W]; the contract is
-            # packet-major rows (p0r0, p0r1, ...) matching `src`/`recv`.
-            # The flip stays jitted at the class-PADDED shape (one
-            # compile per class combo); cropping to the raw (P, G) is
-            # numpy work at result() time — eager device slices here
-            # compiled per raw shape, which churn varies every tick.
-            out_pm, len_pm = _fanout_packet_major(jnp.asarray(out_gp),
-                                                  jnp.asarray(out_len_p))
+            with span_of(tracer, "expand") as sp:
+                rr = recvs[0]
+                p_rows = np.asarray(rows, dtype=np.int64)
+                pidx = np.asarray(idx).reshape(len(rows), len(rr))[:, 0] \
+                    if len(rr) else np.zeros(0, np.int64)
+                # class-pad BOTH grouped axes (legs and packets,
+                # cycled) plus the data width: churn varies the leg
+                # count every tick, and raw (G, P) shapes would retrace
+                # unboundedly
+                g_real, p_real = len(rr), len(p_rows)
+                g_idx = _cycle_rows(g_real)
+                rr_p = rr[g_idx] if g_idx is not None else rr
+                p_idx = _cycle_rows(p_real)
+                if p_idx is None:
+                    p_idx = np.arange(p_real)
+                pr = p_rows[p_idx]
+                plen = np.asarray(batch.length, dtype=np.int32)[pr]
+                # width clips to the largest packet's class (see the
+                # CM path)
+                pw = _round_width(int(np.max(plen, initial=12))
+                                  + self.policy.auth_tag_len)
+                cw = min(pw, batch.capacity)
+                pdata = np.zeros((len(pr), pw), dtype=np.uint8)
+                pdata[:, :cw] = batch.data[pr][:, :cw]
+                pssrc = hdr.ssrc[pr]
+                pidx = pidx[p_idx]
+                # iv [G, P, 12]: leg salt x sender ssrc/index
+                iv = gcm_kernel.srtp_gcm_iv(
+                    np.broadcast_to(self._salt[rr_p][:, None, :12],
+                                    (len(rr_p), len(pr), 12)),
+                    pssrc[None, :], pidx[None, :])
+                sp.note(rows=g_real * p_real,
+                        rows_padded=len(rr_p) * len(pr), width=pw)
+            with span_of(tracer, "fanout_dispatch",
+                         h2d_bytes=_nbytes(pdata, plen, iv)
+                         + 4 * len(rr_p)), \
+                    phase_of(perf, "dispatch"):
+                out_gp, out_len_p = self._gcm_uniform_fanout_call(
+                    rr_p, pdata, plen, iv, int(off0[0]))
+                # grouped output is leg-major [G, P, W]; the contract
+                # is packet-major rows (p0r0, p0r1, ...) matching
+                # `src`/`recv`.  The flip stays jitted at the
+                # class-PADDED shape (one compile per class combo);
+                # cropping to the raw (P, G) is numpy work at result()
+                # time — eager device slices here compiled per raw
+                # shape, which churn varies every tick.
+                out_pm, len_pm = _fanout_packet_major(
+                    jnp.asarray(out_gp), jnp.asarray(out_len_p))
             return out_pm, len_pm, (p_real, g_real)
-        rr_idx = _cycle_rows(len(recv))
-        if rr_idx is None:
-            rr_idx = np.arange(len(recv))
-        # width clips to the largest packet's class (see the CM path)
-        pw = _round_width(int(np.max(length, initial=12))
-                          + self.policy.auth_tag_len)
-        cw = min(pw, data.shape[-1])
-        pdata = np.zeros((len(rr_idx), pw), dtype=np.uint8)
-        pdata[:, :cw] = data[rr_idx][:, :cw]
-        iv = gcm_kernel.srtp_gcm_iv(self._salt[recv[rr_idx]],
-                                    ssrc[rr_idx], idx[rr_idx])
-        out, out_len = self._gcm_fanout_call(recv[rr_idx], pdata,
-                                             length[rr_idx],
-                                             payload_off[rr_idx], iv,
-                                             pdata.shape[-1])
+        with span_of(tracer, "expand", rows=len(recv)) as sp:
+            rr_idx = _cycle_rows(len(recv))
+            if rr_idx is None:
+                rr_idx = np.arange(len(recv))
+            # width clips to the largest packet's class (see the CM
+            # path)
+            pw = _round_width(int(np.max(length, initial=12))
+                              + self.policy.auth_tag_len)
+            cw = min(pw, data.shape[-1])
+            pdata = np.zeros((len(rr_idx), pw), dtype=np.uint8)
+            pdata[:, :cw] = data[rr_idx][:, :cw]
+            iv = gcm_kernel.srtp_gcm_iv(self._salt[recv[rr_idx]],
+                                        ssrc[rr_idx], idx[rr_idx])
+            plen, poff = length[rr_idx], payload_off[rr_idx]
+            sp.note(rows_padded=len(rr_idx), width=pw)
+        with span_of(tracer, "fanout_dispatch",
+                     h2d_bytes=_nbytes(pdata, plen, poff, iv)
+                     + 4 * len(rr_idx)), \
+                phase_of(perf, "dispatch"):
+            out, out_len = self._gcm_fanout_call(
+                recv[rr_idx], pdata, plen, poff, iv, pdata.shape[-1])
         return out, out_len, None
 
     def _gcm_uniform_fanout_call(self, rr, pdata, plen, iv, aad_const):
@@ -523,11 +570,13 @@ class PendingTranslate:
     """
 
     def __init__(self, out, out_len, recv: np.ndarray, capacity: int,
-                 pg=None):
+                 pg=None, tracer=None, perf=None):
         self._out = out
         self._out_len = out_len
         self.recv = recv
         self._capacity = capacity
+        self._tracer = tracer
+        self._perf = perf
         # (p_real, g_real) when `out` is the uniform fan-out's padded
         # packet-major grid [P_pad, G_pad, W]; None for flat rows
         self._pg = pg
@@ -537,26 +586,35 @@ class PendingTranslate:
         if self._done is None:
             if self._out is None:
                 wire = PacketBatch.empty(0, self._capacity)
-            elif self._pg is not None:
+            else:
+                wire = self._materialize()
+            self._done = (wire, self.recv)
+            self._out = self._out_len = None
+        return self._done
+
+    def _materialize(self) -> PacketBatch:
+        """Wait for the launch (`fanout_wait`, the `device_compute`
+        phase), then copy its rows back (`fanout_d2h`,
+        `d2h_transfer`)."""
+        with span_of(self._tracer, "fanout_wait"), \
+                phase_of(self._perf, "device_compute"):
+            jax.block_until_ready((self._out, self._out_len))
+        with span_of(self._tracer, "fanout_d2h") as sp, \
+                phase_of(self._perf, "d2h_transfer"):
+            arr = np.asarray(self._out)
+            lens = np.asarray(self._out_len, dtype=np.int32)
+            sp.note(d2h_bytes=arr.nbytes + lens.nbytes)
+            if self._pg is not None:
                 # crop the padded (P, G) grid to the real counts and
                 # flatten packet-major — numpy on the materialized
                 # buffer, so no per-raw-shape device programs
                 p, g = self._pg
-                arr = np.asarray(self._out)[:p, :g]
-                lens = np.asarray(self._out_len,
-                                  dtype=np.int32)[:p, :g]
-                wire = PacketBatch(arr.reshape(p * g, arr.shape[-1]),
-                                   lens.reshape(-1),
-                                   self.recv.astype(np.int32))
+                arr = arr[:p, :g].reshape(p * g, arr.shape[-1])
+                lens = lens[:p, :g].reshape(-1)
             else:
                 # drop the class-padding rows (cycled copies appended
                 # by translate_async to keep the fan-out shapes on the
                 # ROW_CLASSES grid)
                 n = len(self.recv)
-                wire = PacketBatch(np.asarray(self._out)[:n],
-                                   np.asarray(self._out_len,
-                                              dtype=np.int32)[:n],
-                                   self.recv.astype(np.int32))
-            self._done = (wire, self.recv)
-            self._out = self._out_len = None
-        return self._done
+                arr, lens = arr[:n], lens[:n]
+            return PacketBatch(arr, lens, self.recv.astype(np.int32))

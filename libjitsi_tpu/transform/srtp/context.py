@@ -47,6 +47,8 @@ from libjitsi_tpu.transform.srtp import kernel, replay
 from libjitsi_tpu.transform.srtp.kdf import (derive_session_keys,
                                              derive_session_keys_batch)
 from libjitsi_tpu.transform.srtp.policy import Cipher, SrtpPolicy, SrtpProfile
+from libjitsi_tpu.utils.perf import phase_of
+from libjitsi_tpu.utils.tracing import span_of
 
 
 # --- jitted wrappers: gather per-stream key material on device -------------
@@ -56,19 +58,19 @@ from libjitsi_tpu.transform.srtp.policy import Cipher, SrtpPolicy, SrtpProfile
 def _protect_rtp_dev(tab_rk, tab_mid, stream, data, length, payload_off, iv,
                      roc, tag_len: int, encrypt: bool, off_const=None,
                      tab_f8=None):
+    rk, mid, f8 = kernel.gather_keys(stream, tab_rk, tab_mid, tab_f8)
     return kernel.srtp_protect(
-        data, length, payload_off, tab_rk[stream], iv, tab_mid[stream], roc,
-        tag_len, encrypt, payload_off_const=off_const,
-        f8_round_keys=None if tab_f8 is None else tab_f8[stream])
+        data, length, payload_off, rk, iv, mid, roc,
+        tag_len, encrypt, payload_off_const=off_const, f8_round_keys=f8)
 
 
 def _unprotect_rtp_impl(tab_rk, tab_mid, stream, data, length, payload_off,
                         iv, roc, tag_len: int, encrypt: bool, off_const=None,
                         tab_f8=None):
+    rk, mid, f8 = kernel.gather_keys(stream, tab_rk, tab_mid, tab_f8)
     return kernel.srtp_unprotect(
-        data, length, payload_off, tab_rk[stream], iv, tab_mid[stream], roc,
-        tag_len, encrypt, payload_off_const=off_const,
-        f8_round_keys=None if tab_f8 is None else tab_f8[stream])
+        data, length, payload_off, rk, iv, mid, roc,
+        tag_len, encrypt, payload_off_const=off_const, f8_round_keys=f8)
 
 
 _unprotect_rtp_dev = jax.jit(
@@ -150,16 +152,16 @@ def _rtcp_row_pad(n: int):
 @functools.partial(jax.jit, static_argnames=("aad_const",))
 def _protect_gcm_dev(tab_rk, tab_gm, stream, data, length, aad_len, iv12,
                      aad_const=None):
+    rk, gm = kernel.gather_keys(stream, tab_rk, tab_gm)
     return gcm_kernel.gcm_protect(
-        data, length, aad_len, tab_rk[stream], tab_gm[stream], iv12,
-        aad_const=aad_const)
+        data, length, aad_len, rk, gm, iv12, aad_const=aad_const)
 
 
 def _unprotect_gcm_impl(tab_rk, tab_gm, stream, data, length, aad_len, iv12,
                         aad_const=None):
+    rk, gm = kernel.gather_keys(stream, tab_rk, tab_gm)
     return gcm_kernel.gcm_unprotect(
-        data, length, aad_len, tab_rk[stream], tab_gm[stream], iv12,
-        aad_const=aad_const)
+        data, length, aad_len, rk, gm, iv12, aad_const=aad_const)
 
 
 _unprotect_gcm_dev = jax.jit(
@@ -452,6 +454,11 @@ class SrtpStreamTable:
         # instead of recomputed + re-device_put per batch (the cached
         # fast path is host-bound without this)
         self._grid_memo: dict = {}
+        # a bridge hands its loop's PipelineTracer and PhaseProfiler
+        # here (`unprotect_host` / `unprotect_wait`); a table standing
+        # alone spans and samples nothing
+        self.tracer = None
+        self.perf = None
 
     def enable_keystream_cache(self, window: int = 64,
                                ks_bytes: int = 256,
@@ -1375,24 +1382,32 @@ class SrtpStreamTable:
                     return out, ok, idx
                 return out, ok
             self._apply_epochs(stream0, r, rtcp=False)
-        parts = bucket_by_size(batch)
+        # the padding to the row and width classes and the reassembly
+        # are host work round the device call: `unprotect_host`, like
+        # the two host stretches inside `_unprotect_rtp_direct` (the
+        # leaves are entered once per size class and sum)
+        with span_of(self.tracer, "unprotect_host",
+                     rows=batch.batch_size):
+            parts = bucket_by_size(batch)
         done, masks = [], []
         idx_parts = []
         for rows, part, n in parts:
-            o, okp, idxp = self._unprotect_rtp_direct(part, True)
+            o, okp, idxp = self._unprotect_rtp_direct(part, True, n)
             done.append((rows, o, n))
             masks.append(np.asarray(okp))
             idx_parts.append((rows, idxp[:n]))
-        out, ok = unbucket(done, batch.batch_size, batch.capacity, masks)
-        # ok=False rows keep their original bytes (contract above)
-        out.data[~ok, :] = 0
-        take = min(out.capacity, batch.capacity)
-        out.data[~ok, :take] = batch.data[~ok, :take]
-        out.length[~ok] = np.asarray(batch.length)[~ok]
-        if return_index:
+        with span_of(self.tracer, "unprotect_host"):
+            out, ok = unbucket(done, batch.batch_size, batch.capacity,
+                               masks)
+            # ok=False rows keep their original bytes (contract above)
+            out.data[~ok, :] = 0
+            take = min(out.capacity, batch.capacity)
+            out.data[~ok, :take] = batch.data[~ok, :take]
+            out.length[~ok] = np.asarray(batch.length)[~ok]
             idx = np.zeros(batch.batch_size, dtype=np.int64)
             for rows, idxp in idx_parts:
                 idx[rows] = idxp
+        if return_index:
             return out, ok, idx
         return out, ok
 
@@ -1473,56 +1488,86 @@ class SrtpStreamTable:
                 "auth_ok": auth_ok}
 
     def _unprotect_rtp_direct(self, batch: PacketBatch,
-                              return_index: bool = False):
+                              return_index: bool = False,
+                              n_real: Optional[int] = None):
+        """One size class: host, device, host.  `n_real` of the
+        batch's rows are packets, the rest pad the row class."""
         p = self.policy
-        hdr = rtp_header.parse(batch)
-        stream = np.asarray(batch.stream, dtype=np.int64)
-        length = np.asarray(batch.length, dtype=np.int32)
-        # NOTE: hdr.valid is deliberately not used here — its padding-length
-        # sanity check reads the last byte, which at this point is still
-        # ciphertext/tag; padded packets would be dropped at random.
-        valid = ((hdr.version == 2)
-                 & (length >= hdr.header_len + p.auth_tag_len)
-                 & self.active[stream] & (stream >= 0))
+        tracer = self.tracer
+        with span_of(tracer, "unprotect_host"):
+            hdr = rtp_header.parse(batch)
+            stream = np.asarray(batch.stream, dtype=np.int64)
+            length = np.asarray(batch.length, dtype=np.int32)
+            # NOTE: hdr.valid is deliberately not used here — its
+            # padding-length sanity check reads the last byte, which at
+            # this point is still ciphertext/tag; padded packets would
+            # be dropped at random.
+            valid = ((hdr.version == 2)
+                     & (length >= hdr.header_len + p.auth_tag_len)
+                     & self.active[stream] & (stream >= 0))
 
-        idx = self._estimate_rx_indices(stream, hdr.seq)
-        v = idx >> 16
-        not_replayed = replay.check(self.rx_max, self.rx_mask, stream, idx)
+            idx = self._estimate_rx_indices(stream, hdr.seq)
+            v = idx >> 16
+            not_replayed = replay.check(self.rx_max, self.rx_mask, stream,
+                                        idx)
+            if self._gcm:
+                # the keystream cache builds its own; a miss needs ours
+                iv = (self._gcm_rtp_iv(self._salt_rtp[stream], hdr.ssrc,
+                                       idx)
+                      if self._ks_cache is None else None)
+            elif self._f8:
+                iv = self._f8_rtp_iv(hdr, v)
+            else:
+                iv = self._cm_iv(self._salt_rtp[stream], hdr.ssrc, idx)
 
-        if self._gcm:
-            out = (None if self._ks_cache is None
-                   else self._gcm_rtp_unprotect_cached(stream, batch,
-                                                       hdr, idx, length))
-            if out is None:
-                iv12 = self._gcm_rtp_iv(self._salt_rtp[stream],
-                                        hdr.ssrc, idx)
-                out = self._gcm_rtp_unprotect_call(stream, batch, hdr,
-                                                   iv12, length)
-            data, mlen, auth_ok = out
-        elif self._f8:
-            iv = self._f8_rtp_iv(hdr, v)
-            data, mlen, auth_ok = self._f8_rtp_unprotect_call(
-                stream, batch, hdr, iv, v, length)
-        else:
-            iv = self._cm_iv(self._salt_rtp[stream], hdr.ssrc, idx)
-            data, mlen, auth_ok = self._cm_rtp_unprotect_call(
-                stream, batch, hdr, iv, v, length)
-        auth_ok = np.asarray(auth_ok)
-        srow = np.clip(stream, 0, self.capacity - 1)
-        np.add.at(self.auth_fail, srow, valid & not_replayed & ~auth_ok)
-        np.add.at(self.replay_reject, srow, valid & ~not_replayed)
-        ok = valid & not_replayed & auth_ok
-        # in-batch duplicate indices: keep the first *authenticated*
-        # occurrence (a forged front-runner fails auth and must not block
-        # the genuine copy later in the batch)
-        ok &= ~replay.dedup_first(stream, idx, ok)
-        replay.update(self.rx_max, self.rx_mask, stream, idx, ok)
+        # from the staging of the arguments to the outputs as host
+        # arrays: what the tick thread waits on the device for
+        with span_of(tracer, "unprotect_wait",
+                     rows=batch.batch_size if n_real is None else n_real,
+                     rows_padded=batch.batch_size,
+                     # data, lengths and IVs as they are; stream, payload
+                     # offset and ROC as one 32-bit word a row each
+                     h2d_bytes=batch.data.nbytes + length.nbytes
+                     + (0 if iv is None else iv.nbytes)
+                     + 12 * batch.batch_size) as sp, \
+                phase_of(self.perf, "device_compute"):
+            if self._gcm:
+                out = (None if self._ks_cache is None
+                       else self._gcm_rtp_unprotect_cached(
+                           stream, batch, hdr, idx, length))
+                if out is None:
+                    if iv is None:
+                        iv = self._gcm_rtp_iv(self._salt_rtp[stream],
+                                              hdr.ssrc, idx)
+                    out = self._gcm_rtp_unprotect_call(stream, batch, hdr,
+                                                       iv, length)
+                data, mlen, auth_ok = out
+            elif self._f8:
+                data, mlen, auth_ok = self._f8_rtp_unprotect_call(
+                    stream, batch, hdr, iv, v, length)
+            else:
+                data, mlen, auth_ok = self._cm_rtp_unprotect_call(
+                    stream, batch, hdr, iv, v, length)
+            auth_ok = np.asarray(auth_ok)
+            data = np.asarray(data)
+            mlen = np.asarray(mlen, dtype=np.int32)
+            sp.note(d2h_bytes=data.nbytes + mlen.nbytes + auth_ok.nbytes)
 
-        data = np.asarray(data)
-        mlen = np.asarray(mlen, dtype=np.int32)
-        out_data = np.where(ok[:, None], data, batch.data)
-        out_len = np.where(ok, mlen, length).astype(np.int32)
-        out = PacketBatch(out_data, out_len, batch.stream)
+        with span_of(tracer, "unprotect_host"):
+            srow = np.clip(stream, 0, self.capacity - 1)
+            np.add.at(self.auth_fail, srow,
+                      valid & not_replayed & ~auth_ok)
+            np.add.at(self.replay_reject, srow, valid & ~not_replayed)
+            ok = valid & not_replayed & auth_ok
+            # in-batch duplicate indices: keep the first *authenticated*
+            # occurrence (a forged front-runner fails auth and must not
+            # block the genuine copy later in the batch)
+            ok &= ~replay.dedup_first(stream, idx, ok)
+            replay.update(self.rx_max, self.rx_mask, stream, idx, ok)
+
+            out_data = np.where(ok[:, None], data, batch.data)
+            out_len = np.where(ok, mlen, length).astype(np.int32)
+            out = PacketBatch(out_data, out_len, batch.stream)
         if return_index:
             return out, ok, idx
         return out, ok

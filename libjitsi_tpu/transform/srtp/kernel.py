@@ -26,6 +26,14 @@ from libjitsi_tpu.kernels.scatter import scatter_bytes
 from libjitsi_tpu.kernels.sha1 import hmac_sha1
 
 
+def gather_keys(rows, *tables):
+    """Row-gather per-stream key tensors (`None` passes through), under
+    the device scope `key_gather`: in a device trace the gathers of a
+    program can be told from its keystream and its HMAC."""
+    with jax.named_scope("key_gather"):
+        return tuple(None if t is None else t[rows] for t in tables)
+
+
 def _scatter_word(data, pos, word):
     """Write 4 bytes `word` [B, 4] at per-row byte offset `pos` [B]
     (gather-free — kernels/scatter.py has the perf story)."""
@@ -34,7 +42,8 @@ def _scatter_word(data, pos, word):
 
 def _scatter_tag(data, pos, tag, tag_len: int):
     """Write tag[:, :tag_len] at per-row byte offset `pos`."""
-    return scatter_bytes(data, pos, tag, tag_len)
+    with jax.named_scope("scatter_tag"):
+        return scatter_bytes(data, pos, tag, tag_len)
 
 
 def _auth_tags(data, mlen, extra_word, midstates):
@@ -43,8 +52,9 @@ def _auth_tags(data, mlen, extra_word, midstates):
     `_pad_and_blockify` masks bytes at/after the length argument, so stale
     bytes past `mlen` in `data` never leak into the MAC.
     """
-    buf = _scatter_word(data, mlen, extra_word)
-    return hmac_sha1(midstates, buf, mlen + 4)
+    with jax.named_scope("auth"):
+        buf = _scatter_word(data, mlen, extra_word)
+        return hmac_sha1(midstates, buf, mlen + 4)
 
 
 def _u32_bytes(x):
@@ -209,7 +219,9 @@ def srtcp_unprotect(
     e_bit = index_word >> 31
     index = index_word & 0x7FFFFFFF
     if tag_len:
-        tags = hmac_sha1(midstates, data, mlen + 4)  # MAC covers packet || index word
+        with jax.named_scope("auth"):
+            # MAC covers packet || index word
+            tags = hmac_sha1(midstates, data, mlen + 4)
         stored = _gather_span(data, mlen + 4, tag_len)
         auth_ok = jnp.all(stored == tags[:, :tag_len], axis=1)
     else:

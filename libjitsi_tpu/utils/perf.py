@@ -38,6 +38,7 @@ from typing import Callable, Dict, Iterable, Optional
 from libjitsi_tpu.utils.compile_cache import compile_stats
 from libjitsi_tpu.utils.metrics import (MetricsRegistry,
                                         exponential_buckets)
+from libjitsi_tpu.utils.tracing import NULL_SPAN
 
 #: the phase taxonomy; `host_python` is always the residual so the six
 #: sum to the sampled tick's wall time exactly
@@ -105,19 +106,12 @@ class _PhaseSpan:
                              time.perf_counter() - self._t0)
 
 
-class _NullSpan:
-    """Fence-free tick: phase regions cost one attribute lookup."""
-
-    __slots__ = ()
-
-    def __enter__(self) -> "_NullSpan":
-        return self
-
-    def __exit__(self, *exc) -> None:
-        pass
-
-
-_NULL_SPAN = _NullSpan()
+def phase_of(perf: "Optional[PhaseProfiler]", name: str):
+    """`perf.phase(name)`, or the shared no-op for a component standing
+    alone (`perf` None: the bridge hands its loop's profiler to the
+    SRTP table and the translator, tests and the mesh seams hand
+    none)."""
+    return NULL_SPAN if perf is None else perf.phase(name)
 
 
 class PhaseProfiler:
@@ -229,7 +223,7 @@ class PhaseProfiler:
         """Context manager attributing the region to `name` on sampled
         ticks; free (a shared no-op) otherwise."""
         if not self.sampled:
-            return _NULL_SPAN
+            return NULL_SPAN
         return _PhaseSpan(self, name)
 
     def add_phase(self, name: str, seconds: float) -> None:

@@ -1,52 +1,19 @@
-"""Tracing/profiling (SURVEY §5 aux subsystems).
+"""Device memory stats (SURVEY §5 aux subsystems).
 
 The reference has no built-in tracer beyond level-guarded logging — users
 attach JVM profilers, and `PacketLoggingService` gives pcap-level data-path
-tracing (we have the pcap tap in `io/pcap.py`).  The TPU-native equivalents
-here:
-
-- `trace(...)`: context manager around `jax.profiler.trace` — captures an
-  XLA/TPU trace viewable in TensorBoard/Perfetto (the jax trace directory
-  contains a `.trace.json.gz` Perfetto can load directly).
-- `annotate(name)`: `jax.profiler.TraceAnnotation` wrapper so host-side
-  phases (batching window, chain stages) show up on the same timeline as
-  device kernels.
-- `device_memory()`: current live-buffer stats per device, the analog of
-  eyeballing a JVM heap profiler for leaks.
-
-Per-batch wall-time rings live in `utils.metrics.MetricsRegistry.timing`
-(already wired into the host I/O loop's reverse/forward chain stages).
+tracing (we have the pcap tap in `io/pcap.py`).  Here a trace is
+`jax.profiler.trace(dir)`, into which `utils.tracing.PipelineTracer`
+writes the host's stage spans; what is left in this module is
+`device_memory()`: current live-buffer stats per device, the analog of
+eyeballing a JVM heap profiler for leaks.
 """
 
 from __future__ import annotations
 
-import contextlib
-import os
-from typing import Iterator, Optional
+from typing import Optional
 
 import jax
-
-
-@contextlib.contextmanager
-def trace(log_dir: str = "/tmp/libjitsi_tpu_trace",
-          create_perfetto_link: bool = False) -> Iterator[str]:
-    """Capture a jax profiler trace for the enclosed block.
-
-    Yields the log directory; load it in TensorBoard's profile plugin or
-    open the contained `*.trace.json.gz` in ui.perfetto.dev.
-    """
-    os.makedirs(log_dir, exist_ok=True)
-    jax.profiler.start_trace(log_dir,
-                             create_perfetto_link=create_perfetto_link)
-    try:
-        yield log_dir
-    finally:
-        jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Name a host-side phase on the profiler timeline."""
-    return jax.profiler.TraceAnnotation(name)
 
 
 def device_memory(device: Optional[object] = None) -> dict:

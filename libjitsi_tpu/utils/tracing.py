@@ -1,82 +1,158 @@
-"""Pipeline stage tracing: nested timed spans per tick stage.
+"""Pipeline stage tracing: one span tree per tick.
 
 The supervisor's overload ladder used to see one number — bridge.tick
 wall time — so "we're over budget" never said *where* the budget went
-(ingress? reverse chain? the mixer?).  `PipelineTracer` wraps each
-stage of a tick (ingress batch → reverse transform chain →
-SFU/recovery → mixer → forward chain → egress) in a span that feeds
-three sinks at once:
+(ingress? the route loop? the wait for the fan-out launch?).
+`PipelineTracer` wraps each stage of a tick in a span.  Spans nest:
+the tracer keeps the innermost open span, every span remembers the one
+that was open when it began (its parent) and the tick it belongs to
+(`loop.trace_id`, the identifier the journey histogram's exemplars
+carry).  A span feeds four sinks at once:
 
   1. a per-stage `TimingRing` in the `MetricsRegistry` (rendered as a
      Prometheus summary, `stage_<name>_seconds{quantile=...}`), so
      /metrics carries p50/p99 per stage;
-  2. a per-tick **budget ledger** (stage -> seconds this tick) the
-     supervisor drains with `take_ledger()` and uses to attribute an
-     overrun to its dominant stage in flight-recorder events;
-  3. an optional `jax.profiler.TraceAnnotation`, so when a Perfetto
-     trace is captured (utils/profiling.trace) the host-side stage
-     spans line up with the TPU timeline on the same clock.
+  2. the per-tick **inclusive ledger** (stage -> seconds this tick,
+     children included) and the **self-time ledger** (the same less
+     the time its child spans covered).  Self times of a tick sum to
+     the time inside its outermost spans, which is what makes a share
+     of them meaningful: the supervisor steers on the self ledger, the
+     benchmark's `stage_*` metrics read the inclusive one.  Both are
+     drained together by `take_ledger()`;
+  3. the per-tick **counts ledger** (`span(stage, rows=...)` or
+     `sp.note(rows=...)`: stage -> {count: sum}), drained with them;
+  4. a `jax.profiler.TraceAnnotation` named `stage:<name>` carrying
+     `tick=<id>` and the counts as stats, so in a captured trace the
+     host spans line up with the TPU timeline on the profiler's own
+     clock and say which tick they belong to and how much they carried.
+     It does nothing unless a profiler session is active.
 
-Spans are `SpanTimer` tokens — each holds its own t0 — so nesting
-(recovery inside reverse_chain) and overlapping (pipelined dispatch)
-both record correctly.  Nested spans accumulate into the ledger
-independently: the ledger is per-stage *inclusive* time, and callers
-that want exclusive attribution compare parent vs child entries.
+Spans are per tick or per batch, never per packet or per row.  A stage
+entered twice in a tick sums.  Spans open and close on the tick thread,
+innermost first (`with`); the tree has no lock.
 """
 
 from __future__ import annotations
 
+import time
 from typing import Dict, Optional, Tuple
 
-from libjitsi_tpu.utils.metrics import MetricsRegistry, SpanTimer
+from libjitsi_tpu.utils.metrics import MetricsRegistry
 
 try:                                    # annotation sink is optional:
     from jax.profiler import TraceAnnotation as _TraceAnnotation
 except Exception:                       # pragma: no cover - jax present
     _TraceAnnotation = None
 
+#: the leaves of an SfuBridge tick, in the order they run: spans with
+#: no child (but `gc`, which opens wherever a collection lands).  They
+#: tile the tick: what lies between them is in no span, and the
+#: benchmark's `tick_unspanned_pct` watches that it stays small
+LEAF_STAGES = ("ingress", "demux", "unprotect_host", "unprotect_wait",
+               "parse", "recovery", "bwe", "abs_send_time", "route",
+               "expand", "fanout_dispatch", "fanout_wait", "fanout_d2h",
+               "nack_cache", "egress", "supervise", "gc")
+
 #: canonical stage names (a tracer accepts any string; these are the
-#: ones the acceptance scrape asserts on)
-STAGES = ("ingress", "reverse_chain", "recovery", "decode", "mixer",
-          "forward_chain", "egress")
+#: ones the dashboards are generated from): the leaves, the containers
+#: round them and the mixer bridge's stages
+STAGES = LEAF_STAGES + ("reverse_chain", "unprotect", "forward_chain",
+                        "decode", "mixer")
+
+
+class _NullSpan:
+    """What a component with no tracer opens: costs one call."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+    def note(self, **counts) -> None:
+        pass
+
+    set_metadata = note          # standing in for a TraceAnnotation
+
+
+NULL_SPAN = _NullSpan()
+
+
+def span_of(tracer: "Optional[PipelineTracer]", stage: str, **counts):
+    """`tracer.span(stage, **counts)`, or the shared no-op span for a
+    component standing alone (`tracer` None: tests, the mesh seams)."""
+    if tracer is None:
+        return NULL_SPAN
+    return tracer.span(stage, **counts)
 
 
 class _StageSpan:
-    """Context manager for one stage entry; independent token per
-    entry, safe to nest and overlap."""
+    """One entry of one stage: name, start, end (`seconds`), parent,
+    tick."""
 
-    __slots__ = ("_tracer", "stage", "_timer", "_ann")
+    __slots__ = ("_tracer", "stage", "counts", "tick", "parent", "t0",
+                 "seconds", "_child_s", "_ann")
 
-    def __init__(self, tracer: "PipelineTracer", stage: str):
+    def __init__(self, tracer: "PipelineTracer", stage: str, counts):
         self._tracer = tracer
         self.stage = stage
-        self._timer: Optional[SpanTimer] = None
+        self.counts = counts
+        self.tick = 0
+        self.parent: Optional["_StageSpan"] = None
+        self.t0 = 0.0
+        self.seconds: Optional[float] = None
+        self._child_s = 0.0
         self._ann = None
 
     def __enter__(self) -> "_StageSpan":
         t = self._tracer
+        self.tick = t.tick
         if t.annotate:
-            self._ann = _TraceAnnotation(f"{t.prefix}:{self.stage}")
+            self._ann = _TraceAnnotation(t._sink(self.stage)[1],
+                                         tick=self.tick, **self.counts)
             self._ann.__enter__()
-        self._timer = t.metrics.timing(
-            f"{t.prefix}_{self.stage}").span()
+        # clock first, link second: a `gc` span may open anywhere, and
+        # must find a parent whose time covers it
+        self.t0 = time.perf_counter()
+        self.parent, t._open = t._open, self
         return self
 
+    def note(self, **counts) -> None:
+        """Counts known only once the work is under way (a padded row
+        count, the bytes that came back)."""
+        self.counts.update(counts)
+        if self._ann is not None:
+            self._ann.set_metadata(**counts)
+
     def __exit__(self, *exc) -> None:
-        seconds = self._timer.stop()
+        seconds = self.seconds = time.perf_counter() - self.t0
+        t = self._tracer
+        t._open = self.parent
         if self._ann is not None:
             self._ann.__exit__(*exc if exc else (None, None, None))
             self._ann = None
-        led = self._tracer._ledger
-        led[self.stage] = led.get(self.stage, 0.0) + seconds
+        if self.parent is not None:
+            self.parent._child_s += seconds
+        stage = self.stage
+        t._sink(stage)[0].record(seconds)
+        led = t._ledger
+        led[stage] = led.get(stage, 0.0) + seconds
+        led = t._self_ledger
+        led[stage] = led.get(stage, 0.0) + seconds - self._child_s
+        if self.counts:
+            mine = t._counts.setdefault(stage, {})
+            for k, v in self.counts.items():
+                mine[k] = mine.get(k, 0) + v
 
 
 class PipelineTracer:
-    """Per-stage span timing + per-tick budget ledger.
+    """Per-stage span timing + the per-tick ledgers.
 
     One tracer per media loop / bridge; share it across the pieces of
-    one pipeline (loop + SFU + mixer) so their stages land in the same
-    ledger.  `annotate=True` (default) also emits
+    one pipeline (loop + SRTP table + translator + supervisor) so their
+    stages land in one tree.  `annotate=True` (default) also emits
     jax.profiler.TraceAnnotation spans when jax is importable — they
     are no-ops unless a profiler trace is active.
     """
@@ -87,8 +163,17 @@ class PipelineTracer:
             MetricsRegistry()
         self.prefix = prefix
         self.annotate = bool(annotate) and _TraceAnnotation is not None
+        #: id of the tick under way; the loop sets it as a tick begins
+        self.tick = 0
+        self._open: Optional[_StageSpan] = None
+        # stage -> (ring, annotation name), resolved once per name
+        self._sinks: Dict[str, tuple] = {}
         self._ledger: Dict[str, float] = {}
+        self._self_ledger: Dict[str, float] = {}
+        self._counts: Dict[str, Dict[str, float]] = {}
         self.last_ledger: Dict[str, float] = {}
+        self.last_self_ledger: Dict[str, float] = {}
+        self.last_counts: Dict[str, Dict[str, float]] = {}
         # host/device *phase* ledger (host_python/dispatch/h2d/... from
         # utils.perf.PhaseProfiler) — kept separate from the stage
         # ledger so phase rows can never outrank stages in the
@@ -96,8 +181,28 @@ class PipelineTracer:
         self._phase_ledger: Dict[str, float] = {}
         self.last_phase_ledger: Dict[str, float] = {}
 
-    def span(self, stage: str) -> _StageSpan:
-        return _StageSpan(self, stage)
+    def _sink(self, stage: str) -> tuple:
+        sink = self._sinks.get(stage)
+        if sink is None:
+            sink = self._sinks[stage] = (
+                self.metrics.timing(f"{self.prefix}_{stage}"),
+                f"{self.prefix}:{stage}")
+        return sink
+
+    def span(self, stage: str, **counts) -> _StageSpan:
+        return _StageSpan(self, stage, counts)
+
+    def tick_root(self, tick: int, **counts):
+        """Root of one tick's tree: an annotation `<prefix>:tick` with
+        counts only (`set_metadata` adds those known at the end).  It
+        is booked in neither ledger — it closes after they are drained,
+        and the supervisor's `last_tick_s` already is its time.  Sets
+        the id every span of the tick carries."""
+        self.tick = tick
+        if not self.annotate:
+            return NULL_SPAN
+        return _TraceAnnotation(f"{self.prefix}:tick", tick=tick,
+                                **counts)
 
     def merge_phases(self, phases: Dict[str, float]) -> None:
         """Accumulate a tick's phase split (phase -> seconds) into the
@@ -115,17 +220,31 @@ class PipelineTracer:
             self.last_phase_ledger = led
         return led
 
-    def ledger(self) -> Dict[str, float]:
-        """The accumulating (not-yet-taken) ledger, read-only view."""
-        return dict(self._ledger)
-
-    def take_ledger(self) -> Dict[str, float]:
-        """Drain and return this tick's stage->seconds ledger; the
-        supervisor calls this once per bridge tick.  Also retained as
-        `last_ledger` for health()/debug surfaces."""
-        led, self._ledger = self._ledger, {}
-        self.last_ledger = led
-        return led
+    def take_ledger(self, fold: bool = False) -> Dict[str, float]:
+        """Drain this tick's ledgers and return the inclusive one
+        (stage -> seconds); the supervisor calls this once per bridge
+        tick.  All three are retained (`last_ledger`,
+        `last_self_ledger`, `last_counts`) for health()/debug
+        surfaces.  `fold` drains into the retained three in place
+        instead: for what closed after the tick's drain (the
+        supervisor's own `supervise`)."""
+        if not fold:
+            self.last_ledger, self._ledger = self._ledger, {}
+            self.last_self_ledger, self._self_ledger = \
+                self._self_ledger, {}
+            self.last_counts, self._counts = self._counts, {}
+            return self.last_ledger
+        for last, late in ((self.last_ledger, self._ledger),
+                           (self.last_self_ledger, self._self_ledger)):
+            for stage, seconds in late.items():
+                last[stage] = last.get(stage, 0.0) + seconds
+            late.clear()
+        for stage, counts in self._counts.items():
+            mine = self.last_counts.setdefault(stage, {})
+            for k, v in counts.items():
+                mine[k] = mine.get(k, 0) + v
+        self._counts.clear()
+        return self.last_ledger
 
     @staticmethod
     def dominant(ledger: Dict[str, float]

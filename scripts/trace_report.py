@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Offline device-occupancy report from a jax profiler trace.
 
-`utils/profiling.trace()` writes a Perfetto/Chrome-format trace
+`jax.profiler.trace()` writes a Perfetto/Chrome-format trace
 (`*.trace.json.gz`) that ui.perfetto.dev renders beautifully — but a
 browser tab is not checked-in evidence.  This tool parses the trace
 with stdlib only (gzip + json) and prints the numbers ROADMAP #1
@@ -66,7 +66,7 @@ def find_trace_file(path: str) -> str:
     if not hits:
         raise FileNotFoundError(
             f"no *.trace.json[.gz] under {path!r} — did the "
-            "profiling.trace() block run any device work?")
+            "jax.profiler.trace() block run any device work?")
     return hits[-1]           # newest run sorts last (timestamped dirs)
 
 
@@ -195,7 +195,7 @@ def capture_loop_echo(log_dir: str) -> dict:
     """
     import perf_gate
     from libjitsi_tpu.utils import perf as perf_mod
-    from libjitsi_tpu.utils.profiling import trace
+    from jax.profiler import trace
 
     profilers = []
     warm_marks = []

@@ -30,3 +30,66 @@ jax.config.update("jax_platforms", "cpu")
 from libjitsi_tpu.utils.compile_cache import enable_compile_cache  # noqa: E402
 
 enable_compile_cache()
+
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture
+def sfu_with_traffic():
+    """`(sfu, sup, send)`: an SfuBridge of three keyed endpoints behind
+    a supervisor; `send()` puts one protected packet of each endpoint
+    on the bridge's socket, `send.until_forwarded()` sends and ticks
+    until a tick fans out (addresses latch on an endpoint's first
+    packet, so the first round forwards to nobody)."""
+    import time
+
+    import libjitsi_tpu
+    from libjitsi_tpu.io import UdpEngine
+    from libjitsi_tpu.rtp import header as rtp_header
+    from libjitsi_tpu.service.sfu_bridge import SfuBridge
+    from libjitsi_tpu.service.supervisor import (BridgeSupervisor,
+                                                 SupervisorConfig)
+    from libjitsi_tpu.transform.srtp import SrtpStreamTable
+
+    libjitsi_tpu.stop()
+    libjitsi_tpu.init()
+    sfu = SfuBridge(libjitsi_tpu.configuration_service(), port=0,
+                    capacity=8, recv_window_ms=0)
+    sup = BridgeSupervisor(sfu, SupervisorConfig(deadline_ms=60_000.0),
+                           metrics=sfu.loop.metrics)
+    eps = []
+    for k in range(3):
+        ssrc = 0x100 + 7 * k
+        rx = (bytes([ssrc & 0xFF]) * 16, bytes([(ssrc + 1) & 0xFF]) * 14)
+        tx = (bytes([(ssrc + 2) & 0xFF]) * 16,
+              bytes([(ssrc + 3) & 0xFF]) * 14)
+        sfu.add_endpoint(ssrc, rx, tx)
+        tab = SrtpStreamTable(capacity=1)
+        tab.add_stream(0, *rx)
+        eps.append((ssrc, tab, UdpEngine(port=0, max_batch=64)))
+    seq = [500]
+
+    def send():
+        for ssrc, tab, eng in eps:
+            b = rtp_header.build([b"m-%08x" % ssrc], [seq[0]], [0],
+                                 [ssrc], [96], stream=[0])
+            eng.send_batch(tab.protect_rtp(b), "127.0.0.1", sfu.port)
+        seq[0] += 1
+
+    def until_forwarded(ticks=50):
+        for _ in range(ticks):
+            send()
+            time.sleep(0.01)
+            before = sfu.forwarded
+            sup.tick(now=50.0)
+            if sfu.forwarded > before:
+                return
+        raise AssertionError("the bridge never forwarded")
+
+    send.until_forwarded = until_forwarded
+    yield sfu, sup, send
+    sup.close()
+    sfu.close()
+    for _ssrc, _tab, eng in eps:
+        eng.close()

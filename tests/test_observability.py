@@ -297,11 +297,258 @@ def test_tracer_feeds_rings_and_ledger():
     led = tr.take_ledger()
     assert set(led) == {"ingress", "recovery"}
     assert led["ingress"] >= led["recovery"] >= 0.0
-    assert tr.ledger() == {}             # drained
     assert tr.last_ledger == led
+    assert tr.take_ledger() == {}        # drained
     stage, secs = PipelineTracer.dominant(led)
     assert stage == "ingress" and secs == led["ingress"]
     assert PipelineTracer.dominant({}) == (None, 0.0)
+
+
+def _spin(seconds):
+    import time
+    t_end = time.perf_counter() + seconds
+    while time.perf_counter() < t_end:
+        pass
+
+
+def test_tracer_self_time_of_nested_and_twice_entered_spans():
+    """Self time is a span's time less its children's: self times of a
+    tick sum to the outermost span's inclusive time, the inclusive
+    ledger is what it always was, and a stage entered twice sums in
+    both."""
+    tr = PipelineTracer(MetricsRegistry(), annotate=False)
+    tr.tick = 41
+    with tr.span("reverse_chain") as outer:
+        _spin(0.002)
+        with tr.span("unprotect") as mid:
+            for _ in range(2):              # once per size class
+                with tr.span("unprotect_wait") as leaf:
+                    _spin(0.002)
+                assert leaf.parent is mid and leaf.tick == 41
+        assert mid.parent is outer and outer.parent is None
+        with tr.span("forward_chain"):
+            _spin(0.001)
+    led = tr.take_ledger()
+    own, self_led = dict(led), tr.last_self_ledger
+    assert set(led) == set(self_led) == {
+        "reverse_chain", "unprotect", "unprotect_wait", "forward_chain"}
+    assert sum(self_led.values()) == pytest.approx(led["reverse_chain"])
+    assert self_led["unprotect_wait"] == led["unprotect_wait"] >= 0.004
+    assert self_led["forward_chain"] == led["forward_chain"]
+    assert self_led["reverse_chain"] == pytest.approx(
+        led["reverse_chain"] - led["unprotect"] - led["forward_chain"])
+    assert 0.0 <= self_led["unprotect"] < led["unprotect_wait"]
+    # the container's inclusive time still holds everything inside it
+    assert led["reverse_chain"] >= led["unprotect"] \
+        >= led["unprotect_wait"]
+    assert PipelineTracer.dominant(self_led)[0] == "unprotect_wait"
+    assert PipelineTracer.dominant(led)[0] == "reverse_chain"
+    assert tr.last_ledger == own and tr.take_ledger() == {}
+    assert tr.last_self_ledger == {}        # drained with the first
+
+
+def test_tracer_fold_adds_late_spans_to_the_drained_tick():
+    tr = PipelineTracer(MetricsRegistry(), annotate=False)
+    with tr.span("egress", rows=3):
+        pass
+    led = tr.take_ledger()
+    with tr.span("supervise"):
+        with tr.span("gc", generation=2):
+            pass
+    with tr.span("egress", rows=2):
+        pass
+    before = led["egress"]
+    assert tr.take_ledger(fold=True) is led     # in place: same dicts
+    assert set(led) == {"egress", "supervise", "gc"}
+    assert led["egress"] > before
+    assert tr.last_self_ledger["supervise"] == pytest.approx(
+        led["supervise"] - led["gc"])
+    assert tr.last_counts == {"egress": {"rows": 5},
+                              "gc": {"generation": 2}}
+    assert tr.take_ledger() == {}
+
+
+def test_tracer_counts_reach_ledger_and_profiler_stats(tmp_path):
+    """`span(stage, **counts)` and `note()` land in the counts ledger
+    and, inside a profiler session, as stats of the host event, beside
+    the tick id every span of the tick carries."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tr = PipelineTracer(MetricsRegistry())
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with tr.tick_root(7, wall_ns=123) as root:
+            with tr.span("expand", rows=594) as sp:
+                sp.note(rows_padded=1024, width=256)
+            with tr.span("expand", rows=6):
+                pass
+            root.set_metadata(rx=85)
+    finally:
+        jax.profiler.stop_trace()
+    tr.take_ledger()
+    assert tr.last_counts == {
+        "expand": {"rows": 600, "rows_padded": 1024, "width": 256}}
+    assert "tick" not in tr.last_ledger         # the root books nothing
+    path = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))[0]
+    got = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("stage:"):
+                    got.setdefault(ev.name, []).append(dict(ev.stats))
+    assert got["stage:tick"] == [{"tick": 7, "wall_ns": 123, "rx": 85}]
+    assert got["stage:expand"] == [
+        {"tick": 7, "rows": 594, "rows_padded": 1024, "width": 256},
+        {"tick": 7, "rows": 6}]
+
+
+def test_sfu_tick_emits_every_leaf_and_self_times_tile_it(
+        sfu_with_traffic):
+    """One SfuBridge tick with traffic opens every leaf stage, and
+    `tracing.LEAF_STAGES` is exactly that set (with `gc`, which the
+    next test forces); self times sum to the outermost spans' time."""
+    import gc
+
+    from libjitsi_tpu.utils import tracing
+
+    sfu, sup, send = sfu_with_traffic
+    gc.disable()                   # no `gc` span lands in this tick
+    try:
+        send.until_forwarded()
+        led, self_led = sup.last_ledger, sup.last_self_ledger
+        containers = {"reverse_chain", "unprotect", "forward_chain"}
+        assert set(led) - containers == set(tracing.LEAF_STAGES) - {"gc"}
+        assert set(tracing.STAGES) >= set(led)
+        # a leaf has no child: both ledgers agree on it
+        for stage in set(led) - containers:
+            assert self_led[stage] == led[stage], stage
+        assert sum(self_led.values()) == pytest.approx(
+            led["ingress"] + led["demux"] + led["reverse_chain"]
+            + led["supervise"])
+        counts = sup.last_counts
+        assert counts["demux"]["rows"] == 3
+        assert counts["unprotect_wait"]["rows"] == 3
+        assert counts["unprotect_wait"]["rows_padded"] >= 3
+        assert counts["route"]["packets"] == 3
+        assert counts["expand"]["rows"] == 6
+        assert counts["expand"]["rows_padded"] >= 6
+        assert counts["fanout_dispatch"]["h2d_bytes"] > 0
+        assert counts["fanout_d2h"]["d2h_bytes"] > 0
+        assert counts["nack_cache"]["rows"] == 6
+        assert counts["egress"]["rows"] == 6
+        assert counts["egress"]["bytes"] > 6 * 12
+        assert "gc_pause_seconds_total" in sfu.loop.metrics.render()
+    finally:
+        gc.enable()
+
+
+def test_sfu_tick_spans_in_profile_match_the_ledger(sfu_with_traffic,
+                                                    tmp_path):
+    """Read back through the benchmark's `xstats`: every `stage:*`
+    event of a profiled tick carries its tick id, the root its
+    `wall_ns`, and a tick's leaf durations on the profiler's clock
+    agree with the same tick's ledger within 2 %."""
+    import os
+    import sys
+    import time
+
+    import jax
+
+    from libjitsi_tpu.utils import tracing
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
+                                    "benchmarks"))
+    import xstats
+
+    sfu, sup, send = sfu_with_traffic
+    send.until_forwarded()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    ledgers = {}
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        for _ in range(3):
+            send()
+            time.sleep(0.01)
+            t_wall = time.time_ns()
+            with jax.profiler.TraceAnnotation("bench:tick"):
+                sup.tick(now=50.0)
+            ledgers[sfu.loop.trace_id] = (dict(sup.last_ledger),
+                                          dict(sup.last_counts), t_wall)
+    finally:
+        jax.profiler.stop_trace()
+    import reduce
+    path = reduce.find_xplane(str(tmp_path))
+    evs = xstats.load(path)["host"]
+    assert evs and all("tick" in st for _n, _s, _d, st in evs)
+    roots = [st for n, _s, _d, st in evs if n == "stage:tick"]
+    assert [r["tick"] for r in roots] == sorted(ledgers)
+    leaves = [k for k in tracing.LEAF_STAGES if k not in ("supervise",
+                                                          "gc")]
+    for root in roots:
+        led, counts, t_wall = ledgers[root["tick"]]
+        assert root["rx"] == 3
+        assert 0 <= root["wall_ns"] - t_wall < 50e6
+        mine = [(n, d, st) for n, _s, d, st in evs
+                if st["tick"] == root["tick"]]
+        by_profile = sum(d for n, d, _st in mine
+                         if n.split(":")[1] in leaves) / 1e9
+        by_ledger = sum(led[k] for k in leaves)
+        assert by_profile == pytest.approx(by_ledger, rel=0.02)
+        (exp,) = [st for n, _d, st in mine if n == "stage:expand"]
+        assert exp["rows"] == counts["expand"]["rows"] == 6
+    ctx = {"trace": {"xplane": path}}
+    assert xstats.count_ratio_pct(ctx, "expand", "rows", "rows_padded") \
+        == pytest.approx(600.0 / exp["rows_padded"])
+    # from the batch in hand to the last datagram out: inside the
+    # tick's `demux` + `reverse_chain`
+    assert 0.0 < xstats.residence_p50_ms(ctx) < 1e3 * max(
+        led["demux"] + led["reverse_chain"]
+        for led, _c, _t in ledgers.values())
+    assert xstats.scope_share_pct(ctx, "jit__fanout_protect", "auth") \
+        is None                     # no device plane off the chip
+    assert xstats.slice_events({"trace": None}) is None
+
+
+def test_sfu_tick_gc_span_and_hook_removed_by_close(sfu_with_traffic):
+    import gc
+
+    sfu, sup, send = sfu_with_traffic
+    n_hooks = len(gc.callbacks)
+    send.until_forwarded()
+    before = sup.gc_pause_s
+    gc.collect()
+    assert sup.gc_pause_s > before
+    sup.tick(now=50.0)
+    assert sup.last_ledger["gc"] > 0.0
+    assert sup.last_counts["gc"]["generation"] == 2
+    sup.close()
+    assert len(gc.callbacks) == n_hooks - 1
+    paused = sup.gc_pause_s
+    gc.collect()
+    assert sup.gc_pause_s == paused         # the hook is gone
+
+
+def test_supervisor_gc_hook_goes_with_the_supervisor():
+    import gc
+
+    class _Loop:
+        registry = types.SimpleNamespace(capacity=CAP)
+        tracer = PipelineTracer(MetricsRegistry(), annotate=False)
+
+    n_hooks = len(gc.callbacks)
+    sup = BridgeSupervisor(types.SimpleNamespace(loop=_Loop()))
+    assert len(gc.callbacks) == n_hooks + 1
+    del sup
+    gc.collect()
+    assert len(gc.callbacks) == n_hooks
 
 
 # ------------------------------------------------------ flight recorder

@@ -1,37 +1,7 @@
-"""Tracing/profiling hooks (SURVEY §5 aux): jax trace capture, timeline
-annotations, device memory stats."""
-
-import glob
-import os
-
-import jax
-import jax.numpy as jnp
-import numpy as np
-import pytest
+"""Device memory stats (SURVEY §5 aux); the tracer's spans and their
+profiler annotations are tested in test_observability.py."""
 
 from libjitsi_tpu.utils import profiling
-
-
-@pytest.mark.slow   # jax profiler start/stop serializes a full trace
-def test_trace_captures_device_work(tmp_path):
-    d = str(tmp_path / "trace")
-    with profiling.trace(d) as logdir:
-        with profiling.annotate("test-phase"):
-            x = jnp.asarray(np.arange(1024, dtype=np.float32))
-            jax.block_until_ready(jnp.dot(x, x))
-    files = glob.glob(os.path.join(logdir, "**", "*"), recursive=True)
-    assert any(os.path.isfile(f) for f in files), "no trace artifacts"
-
-
-def test_annotate_without_trace_is_noop():
-    """Fast twin of the trace test: the annotation context must be
-    transparent when no trace is active (the hot path wears these
-    markers permanently; they may cost nothing outside a capture)."""
-    with profiling.annotate("fast-twin"):
-        x = jnp.asarray(np.arange(16, dtype=np.float32))
-        jax.block_until_ready(x + 1)
-    with profiling.annotate("outer"), profiling.annotate("inner"):
-        pass
 
 
 def test_device_memory_stats_shape():

@@ -182,6 +182,38 @@ def test_stage_skew_forward_chain_sheds_fec_before_recv_window():
     assert bridge.loop.recv_window_ms == 1 and not bridge.degraded
 
 
+def test_sfu_overrun_in_forward_chain_escalates_on_self_time(
+        sfu_with_traffic, monkeypatch):
+    """On an SfuBridge `reverse_chain` CONTAINS the whole of
+    `_on_media`, so by inclusive time it always dominates and its
+    share's base counts nested stages twice.  The ladder judges by
+    self time: a tick lost inside `forward_chain` says so, over the
+    sum of self times, and takes the rung that acts on it."""
+    import time
+
+    sfu, sup, send = sfu_with_traffic
+    send.until_forwarded()                  # shapes compiled, warm
+    translate = sfu.translator.translate
+
+    def slow(batch, index):
+        time.sleep(1.0)                     # forward_chain's own time
+        return translate(batch, index)
+
+    monkeypatch.setattr(sfu.translator, "translate", slow)
+    sup.cfg.overload_after = 1
+    sup.watchdog.deadline_s = 0.5
+    send.until_forwarded()
+    (ev,) = _escalations(sup)
+    led, self_led = sup.last_ledger, sup.last_self_ledger
+    assert max(led, key=led.get) == "reverse_chain"     # as it was
+    assert ev["stage"] == "forward_chain"
+    assert ev["stage_s"] == pytest.approx(self_led["forward_chain"])
+    assert ev["stage_share"] == pytest.approx(
+        self_led["forward_chain"] / sum(self_led.values()), abs=1e-3)
+    assert ev["stage_share"] > 0.6 and ev["rung"] == "shed_fec", self_led
+    assert sup.health()["last_ledger"] == led
+
+
 def test_stage_skew_ingress_shrinks_recv_window_and_unwinds_lifo():
     ledger = {"ingress": 0.008, "forward_chain": 0.001,
               "egress": 0.001}
