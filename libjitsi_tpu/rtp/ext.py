@@ -5,8 +5,11 @@ The reference's `RawPacket.getHeaderExtension(byte id)` /
 stamp extensions on the hot path (`AbsSendTimeEngine`,
 `TransportCCEngine`, `CsrcTransformEngine`'s audio level) all use the
 one-byte form (profile 0xBEDE).  Here the walk is a bounded vectorized
-cursor loop over the whole batch and the insert is one batched shift —
-no per-packet Python.
+cursor loop over the whole batch, which ends once every row is decided,
+and the insert is two slice copies per LAYOUT GROUP: rows that insert
+the same number of bytes at the same offset move together, and a tick
+of one kind of sender is one group.  Per-row work is `[rows]`-sized
+index arithmetic; the only `[rows, capacity]` array made is the output.
 
 Only the one-byte element form is handled (id 1..14, len 1..16); 0xBEDE
 is the only recognized profile, matching what WebRTC interop actually
@@ -51,6 +54,8 @@ def find_one_byte_ext(batch: PacketBatch, hdr: RtpHeaders, ext_id: int
     cap = batch.capacity
     for _ in range(MAX_ELEMENTS):
         inb = (cur < end) & ~found
+        if not inb.any():
+            break  # every row is found or past its block: no round can change one
         safe = np.minimum(np.maximum(cur, 0), cap - 1).astype(np.int32)
         b = np.take_along_axis(d, safe[:, None], axis=1)[:, 0].astype(np.int64)
         eid = b >> 4
@@ -74,8 +79,7 @@ def set_one_byte_ext(batch: PacketBatch, hdr: RtpHeaders, ext_id: int,
 
     payload: uint8 [B, L] with one static L for the whole call (each
     engine stamps one fixed-size element: abs-send-time L=3, transport-cc
-    seq L=2, ssrc-audio-level L=1).  Three per-row cases, all handled in
-    one vectorized shift pass:
+    seq L=2, ssrc-audio-level L=1).  Three per-row cases:
 
     - element already present with length L: rewritten in place;
     - 0xBEDE block present, element absent: element appended after the
@@ -87,6 +91,25 @@ def set_one_byte_ext(batch: PacketBatch, hdr: RtpHeaders, ext_id: int,
     PacketBatch (host-side NumPy; stamping happens before SRTP in the
     send chain, exactly as the reference orders its engines).
     """
+    return stamp_one_byte_ext(batch, hdr, ext_id, payload, enable)[0]
+
+
+def _put(out: np.ndarray, rows: np.ndarray, col: np.ndarray, val) -> None:
+    """out[rows[i], col[i]] = val[i].  A row whose offset lies past the
+    buffer is skipped: a malformed block length points anywhere."""
+    ok = col < out.shape[1]
+    if not ok.all():
+        rows, col, val = rows[ok], col[ok], np.broadcast_to(val, ok.shape)[ok]
+    out[rows, col] = val
+
+
+def stamp_one_byte_ext(batch: PacketBatch, hdr: RtpHeaders, ext_id: int,
+                       payload: np.ndarray, enable=None
+                       ) -> Tuple[PacketBatch, int]:
+    """`set_one_byte_ext`, and the number of layout groups the call had:
+    distinct (insertion offset, growth) among its rows, the rows that do
+    not grow being one.  Near 1 the copies are whole-batch slices; near
+    the row count each row is copied alone."""
     payload = np.asarray(payload, dtype=np.uint8)
     n, L = payload.shape
     if not (1 <= ext_id <= 14) or not (1 <= L <= 16):
@@ -94,6 +117,7 @@ def set_one_byte_ext(batch: PacketBatch, hdr: RtpHeaders, ext_id: int,
     enable = np.ones(n, bool) if enable is None else np.asarray(enable, bool)
 
     d = batch.data
+    cap = batch.capacity
     ln = np.asarray(batch.length, dtype=np.int64)
     ext_start = (RTP_FIXED_HEADER_LEN + 4 * hdr.cc).astype(np.int64)
     has_block = (hdr.extension == 1) & (hdr.ext_profile == ONE_BYTE_PROFILE)
@@ -102,68 +126,67 @@ def set_one_byte_ext(batch: PacketBatch, hdr: RtpHeaders, ext_id: int,
     append = enable & has_block & ~rewrite
     fresh = enable & ~has_block & (hdr.extension == 0)
 
-    # same id already present at a DIFFERENT length: blank the stale
-    # element to padding zeros before appending, or receivers scanning in
-    # order would keep seeing the old value shadowing the new one
-    stale = enable & present & (elen != L)
-    if np.any(stale):
-        d = d.copy()
-        scols = np.arange(batch.capacity, dtype=np.int64)[None, :]
-        zone = (scols >= (eoff - 1)[:, None]) & \
-            (scols < (eoff + elen)[:, None]) & stale[:, None]
-        d = np.where(zone, 0, d)
-
     elem_sz = _ceil4(1 + L)
     grow = np.where(append, elem_sz, np.where(fresh, 4 + elem_sz, 0)
                     ).astype(np.int64)
-    if np.any(ln + grow > batch.capacity):
+    if np.any(ln + grow > cap):
         raise ValueError("extension stamp would exceed batch capacity")
 
     # insertion point: end of existing block (append) or ext_start (fresh)
     block_end = ext_start + 4 + 4 * hdr.ext_words.astype(np.int64)
     ins = np.where(append, block_end, ext_start)
+    tag = (ext_id << 4) | (L - 1)
 
-    # batched shift: out[:, j] = d[:, j - grow] for j >= ins + grow
-    cols = np.arange(batch.capacity, dtype=np.int64)[None, :]
-    src = np.where(cols >= (ins + grow)[:, None], cols - grow[:, None], cols)
-    out = np.take_along_axis(d, src.astype(np.int32), axis=1)
+    # rows of one (ins, grow) share a layout; growth is at most 24
+    key = np.where(grow > 0, ins * 32 + grow, 0)
+    order = np.argsort(key, kind="stable")
+    groups = np.split(order, np.flatnonzero(np.diff(key[order])) + 1) \
+        if n else []
+    out = np.empty_like(d)
+    for g in groups:
+        # a run of neighbours is a slice: no gathered copy of its rows
+        rows = slice(g[0], g[-1] + 1) if g[-1] - g[0] + 1 == len(g) else g
+        i, w = int(ins[g[0]]), int(grow[g[0]])
+        if w == 0:
+            out[rows] = d[rows]
+            continue
+        # the inserted bytes: [0xBEDE | words] on a fresh row, then
+        # tag || payload, then zeros (implicit padding)
+        blk = np.zeros((len(g), w), dtype=np.uint8)
+        head = w - elem_sz  # 4 on a fresh row, 0 on an appending one
+        if head:
+            blk[:, :4] = (ONE_BYTE_PROFILE >> 8, ONE_BYTE_PROFILE & 0xFF,
+                          0, elem_sz // 4)
+        blk[:, head] = tag
+        blk[:, head + 1:head + 1 + L] = payload[rows]
+        out[rows, :i] = d[rows, :i]
+        out[rows, i:i + w] = blk[:, :max(cap - i, 0)]
+        out[rows, i + w:] = d[rows, i:cap - w]
+        if head:
+            out[rows, 0] |= 0x10  # the X bit
 
-    # write the inserted region (zeros first: implicit padding)
-    ins_region = (cols >= ins[:, None]) & (cols < (ins + grow)[:, None])
-    out = np.where(ins_region, 0, out)
+    rw = np.flatnonzero(rewrite)
+    at = eoff[rw] - 1
+    _put(out, rw, at, tag)
+    for k in range(L):
+        _put(out, rw, at + 1 + k, payload[rw, k])
+    # an appended element lengthens its block: patch the header's length
+    # field (`has_block` read the 0xBEDE before it)
+    ap = np.flatnonzero(append)
+    words = hdr.ext_words[ap].astype(np.int64) + elem_sz // 4
+    _put(out, ap, ext_start[ap] + 2, (words >> 8) & 0xFF)
+    _put(out, ap, ext_start[ap] + 3, words & 0xFF)
+    # same id already present at a DIFFERENT length: blank the stale
+    # element to padding zeros, or receivers scanning in order would keep
+    # seeing the old value shadowing the new one.  It starts inside the
+    # block, before the insertion point; a malformed length can run it
+    # past that point, and that part has moved by the row's growth
+    for r in np.flatnonzero(append & present):
+        a, b, i, w = (int(v) for v in
+                      (eoff[r] - 1, eoff[r] + elen[r], ins[r], grow[r]))
+        out[r, a:min(b, i)] = 0
+        if b > i:
+            out[r, i + w:b + w] = 0
 
-    def _write_at(arr, pos, vals):
-        """Scatter vals [B, K] at per-row byte offset pos (masked rows only)."""
-        k = vals.shape[1]
-        rel = cols - pos[:, None]
-        sel = (rel >= 0) & (rel < k)
-        gathered = np.take_along_axis(
-            vals, np.clip(rel, 0, k - 1).astype(np.int32), axis=1)
-        return np.where(sel, gathered, arr)
-
-    # fresh rows: block header 0xBEDE | words
-    words = np.where(fresh, elem_sz // 4,
-                     hdr.ext_words.astype(np.int64) + np.where(append, elem_sz // 4, 0))
-    bh = np.zeros((n, 4), dtype=np.uint8)
-    bh[:, 0] = ONE_BYTE_PROFILE >> 8
-    bh[:, 1] = ONE_BYTE_PROFILE & 0xFF
-    bh[:, 2] = (words >> 8) & 0xFF
-    bh[:, 3] = words & 0xFF
-    out = _write_at(out, np.where(fresh, ext_start, np.int64(1) << 40), bh)
-    # append rows: patch the existing block header's length field
-    out = _write_at(out, np.where(append, ext_start, np.int64(1) << 40), bh)
-
-    # element bytes: tag || payload
-    elem = np.zeros((n, 1 + L), dtype=np.uint8)
-    elem[:, 0] = (ext_id << 4) | (L - 1)
-    elem[:, 1:] = payload
-    elem_pos = np.where(rewrite, eoff - 1,
-                        np.where(append, ins, ins + 4))
-    elem_pos = np.where(rewrite | append | fresh, elem_pos, np.int64(1) << 40)
-    out = _write_at(out, elem_pos, elem)
-
-    # set the X bit on fresh rows
-    x = out[:, 0] | np.where(fresh, 0x10, 0).astype(np.uint8)
-    out[:, 0] = x
     new_len = (ln + grow).astype(np.int32)
-    return PacketBatch(out, new_len, batch.stream)
+    return PacketBatch(out, new_len, batch.stream), len(groups)

@@ -942,8 +942,9 @@ class SfuBridge:
             self._relay_trunk(batch, rows, sub.stream, hdr.ssrc)
         # stamp the bridge's own abs-send-time before the fan-out so
         # every receiver leg can run receive-side GCC on its downlink
-        with tracer.span("abs_send_time"):
+        with tracer.span("abs_send_time", rows=sub.batch_size) as sp:
             sub, _ = self._ast.rtp_transformer.transform(sub)
+            sp.note(groups=self._ast.last_groups)
             idx_sel = idx[rows]
         if self._video:
             vmask = np.isin(sub.stream, list(self._video.keys()))

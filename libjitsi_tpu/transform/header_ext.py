@@ -39,11 +39,15 @@ class _RtpOnlyEngine(TransformEngine):
 
 
 class AbsSendTimeEngine(_RtpOnlyEngine):
-    """Stamp abs-send-time (24-bit 6.18 fixed-point) on outgoing RTP."""
+    """Stamp abs-send-time (24-bit 6.18 fixed-point) on outgoing RTP.
+
+    `last_groups`: the layout groups of the last batch stamped
+    (`rtp_ext.stamp_one_byte_ext`), for the caller's span."""
 
     def __init__(self, ext_id: int, clock: Callable[[], float] = time.time):
         self.ext_id = ext_id
         self.clock = clock
+        self.last_groups = 0
         eng = self
 
         class _T(PacketTransformer):
@@ -55,8 +59,8 @@ class AbsSendTimeEngine(_RtpOnlyEngine):
                 pay = np.tile(np.array(
                     [(v >> 16) & 0xFF, (v >> 8) & 0xFF, v & 0xFF],
                     dtype=np.uint8), (batch.batch_size, 1))
-                out = rtp_ext.set_one_byte_ext(batch, hdr, eng.ext_id, pay,
-                                               enable=mask)
+                out, eng.last_groups = rtp_ext.stamp_one_byte_ext(
+                    batch, hdr, eng.ext_id, pay, enable=mask)
                 return out, (np.ones(batch.batch_size, bool)
                              if mask is None else mask)
 

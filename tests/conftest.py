@@ -39,7 +39,8 @@ import pytest  # noqa: E402
 def sfu_with_traffic():
     """`(sfu, sup, send)`: an SfuBridge of three keyed endpoints behind
     a supervisor; `send()` puts one protected packet of each endpoint
-    on the bridge's socket, `send.until_forwarded()` sends and ticks
+    on the bridge's socket (`send.csrcs[k]`: endpoint k's CSRC list,
+    empty to begin with), `send.until_forwarded()` sends and ticks
     until a tick fans out (addresses latch on an endpoint's first
     packet, so the first round forwards to nobody)."""
     import time
@@ -71,9 +72,9 @@ def sfu_with_traffic():
     seq = [500]
 
     def send():
-        for ssrc, tab, eng in eps:
+        for (ssrc, tab, eng), csrcs in zip(eps, send.csrcs):
             b = rtp_header.build([b"m-%08x" % ssrc], [seq[0]], [0],
-                                 [ssrc], [96], stream=[0])
+                                 [ssrc], [96], csrcs=[csrcs], stream=[0])
             eng.send_batch(tab.protect_rtp(b), "127.0.0.1", sfu.port)
         seq[0] += 1
 
@@ -87,6 +88,7 @@ def sfu_with_traffic():
                 return
         raise AssertionError("the bridge never forwarded")
 
+    send.csrcs = [[] for _ in eps]
     send.until_forwarded = until_forwarded
     yield sfu, sup, send
     sup.close()
