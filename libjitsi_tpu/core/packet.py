@@ -48,6 +48,10 @@ class PacketBatch:
     data: np.ndarray
     length: np.ndarray
     stream: np.ndarray
+    # the wider staging plane `data` is the leading columns of, where
+    # `bucket_by_size(tail=...)` left room behind the packet bytes for
+    # a device seam to pack its per-row arguments (core/staging.py)
+    plane: Optional[np.ndarray] = None
 
     # ---- constructors -------------------------------------------------
     @staticmethod
@@ -133,7 +137,8 @@ def _round_rows(n: int) -> int:
 
 def bucket_by_size(batch: "PacketBatch",
                    length_classes=LENGTH_CLASSES,
-                   headroom: int = CLASS_HEADROOM):
+                   headroom: int = CLASS_HEADROOM,
+                   tail: int = 0):
     """Split a batch into width/row-class sub-batches.
 
     Returns a list of (orig_rows, sub_batch, n_real): `orig_rows` are the
@@ -145,6 +150,12 @@ def bucket_by_size(batch: "PacketBatch",
     grid's skew statistics see the real distribution, not a pad
     artifact (a single repeated row used to read as one hot stream and
     force the per-row path).
+
+    `tail` > 0 allocates each sub-batch `tail` columns wider than its
+    class: `sub_batch.data` is then the leading `capacity` columns (a
+    view) of `sub_batch.plane`, and a device seam packs its per-row
+    arguments into the rest (core/staging.py) instead of staging them
+    as arrays of their own — the rows are copied once either way.
     """
     ln = np.asarray(batch.length)
     out = []
@@ -160,12 +171,14 @@ def bucket_by_size(batch: "PacketBatch",
         n_real = len(rows)
         n_pad = _round_rows(n_real)
         idx = np.resize(rows, n_pad)     # pads cycle the real rows
-        data = np.zeros((n_pad, cap), dtype=np.uint8)
+        plane = np.zeros((n_pad, cap + tail), dtype=np.uint8)
         take = min(cap, batch.capacity)
-        data[:, :take] = batch.data[idx, :take]
+        plane[:, :take] = batch.data[idx, :take]
         out.append((rows,
-                    PacketBatch(data, ln[idx].astype(np.int32),
-                                np.asarray(batch.stream)[idx].copy()),
+                    PacketBatch(plane[:, :cap] if tail else plane,
+                                ln[idx].astype(np.int32),
+                                np.asarray(batch.stream)[idx].copy(),
+                                plane if tail else None),
                     n_real))
     return out
 

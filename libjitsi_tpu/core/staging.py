@@ -1,0 +1,135 @@
+"""One packed array in and one out per device launch.
+
+A device call over a packet batch needs, beside the bytes, a handful of
+per-row values: the key row, the length, the payload offset, the ROC
+and the IV.  Staged as arrays of their own, each is a host-to-device
+transfer (and, where its NumPy dtype is not the program's, a
+`convert_element_type` program) and each output a blocking copy back:
+a dozen fixed costs a tick round two programs.  Here they ride in
+`TAIL` trailing columns of the `uint8` packet plane instead:
+
+    [ packet bytes ............ | w0 w1 w2 w3 | iv ]      in
+    [ packet bytes ............ | o0 o1 ..    |  0 ]      out
+      width                       4 x 4 B       16 B
+
+so a launch is one `jax.device_put` of a C-contiguous array whose dtype
+is the program's, and one `np.asarray` back.  The plane that comes back
+has the staged plane's shape, so a donated input can be written over.
+
+Words are little-endian on both sides, put together and taken apart by
+shifts in the program: no width-changing `bitcast_convert_type`, whose
+byte order would be the backend's to choose.
+
+Host side: `alloc` where the rows are copied anyway, `pack`, then one
+`jax.device_put`; `Launch.fetch` and `split_out`.  Inside the jitted program: `unpack`,
+`repack`.  The AES-CM unprotect (`transform/srtp/context.py`) and the
+AES-CM fan-out (`sfu/translator.py`) stage this way.
+"""
+
+from __future__ import annotations
+
+from typing import Callable, Optional, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+WORDS = 4                       # per-row 32-bit words in, and room out
+IV_BYTES = 16
+TAIL = 4 * WORDS + IV_BYTES     # 32: 224 -> 256, 544 -> 576, 1536 -> 1568
+
+
+# ---- host ----------------------------------------------------------------
+
+def alloc(rows: int, width: int) -> np.ndarray:
+    """A zeroed plane for `rows` packets of up to `width` bytes; the
+    packet bytes are `plane[:, :width]`."""
+    return np.zeros((rows, width + TAIL), dtype=np.uint8)
+
+
+def pack(plane: np.ndarray, words: Sequence, iv) -> None:
+    """Write the per-row words (each `[rows]`, any integer dtype, taken
+    modulo 2**32) and the 16-byte IVs behind the packet bytes."""
+    rows, w = plane.shape[0], plane.shape[1] - TAIL
+    side = np.empty((rows, WORDS), dtype="<u4")
+    for k, word in enumerate(words):
+        side[:, k] = word
+    plane[:, w:w + 4 * WORDS] = side.view(np.uint8)
+    plane[:, w + 4 * WORDS:] = iv
+
+
+def split_out(host: np.ndarray, n_words: int
+              ) -> Tuple[np.ndarray, np.ndarray]:
+    """A plane that came back -> (packet bytes, a view; `[rows,
+    n_words]` int32 of the program's output words)."""
+    w = host.shape[1] - TAIL
+    words = np.ascontiguousarray(host[:, w:w + 4 * n_words])
+    return host[:, :w], words.view("<i4")
+
+
+class Launch:
+    """A device call in flight: what crossed to the device for it, and
+    how its outputs come to the host.  `outs` are the program's outputs
+    as they stand on the device (one packed plane; a mesh seam's
+    deferred scatters); `split` turns their host copies into what the
+    caller reads.  `fetch` waits, copies each output once and caches;
+    `h2d_arrays` / `h2d_bytes` / `d2h_arrays` / `d2h_bytes` count the
+    arrays that really crossed."""
+
+    __slots__ = ("_outs", "_split", "_host", "h2d_arrays", "h2d_bytes",
+                 "d2h_arrays", "d2h_bytes")
+
+    def __init__(self, outs, split: Optional[Callable] = None,
+                 h2d_arrays: int = 0, h2d_bytes: int = 0):
+        self._outs = tuple(outs)
+        self._split = split
+        self._host = None
+        self.h2d_arrays, self.h2d_bytes = h2d_arrays, h2d_bytes
+        self.d2h_arrays = self.d2h_bytes = 0
+
+    def block_until_ready(self) -> "Launch":
+        if self._host is None:
+            jax.block_until_ready(self._outs)
+        return self
+
+    def fetch(self) -> tuple:
+        if self._host is None:
+            host = [np.asarray(o) for o in self._outs]
+            self.d2h_arrays = len(host)
+            self.d2h_bytes = sum(int(a.nbytes) for a in host)
+            self._host = (tuple(host) if self._split is None
+                          else self._split(*host))
+            self._outs = ()
+        return self._host
+
+
+# ---- inside the jitted program -------------------------------------------
+
+def unpack(plane):
+    """plane `[rows, width + TAIL]` uint8 -> (data `[rows, width]`,
+    words `[rows, WORDS]` uint32, iv `[rows, 16]`)."""
+    w = plane.shape[1] - TAIL
+    b = plane[:, w:w + 4 * WORDS].reshape(-1, WORDS, 4).astype(jnp.uint32)
+    words = (b[..., 0] | (b[..., 1] << 8) | (b[..., 2] << 16)
+             | (b[..., 3] << 24))
+    return plane[:, :w], words, plane[:, w + 4 * WORDS:]
+
+
+def as_i32(word):
+    """A packed word as the signed value the host put in (a stream id
+    of -1 stays -1)."""
+    return jax.lax.bitcast_convert_type(word, jnp.int32)
+
+
+def repack(data, *words):
+    """data `[rows, width]` and up to WORDS per-row outputs (bool or
+    32-bit integers) -> one plane of the staged plane's shape."""
+    rows = data.shape[0]
+    cols = [jax.lax.bitcast_convert_type(w, jnp.uint32)
+            if w.dtype == jnp.int32 else w.astype(jnp.uint32)
+            for w in words]
+    shifts = jnp.array((0, 8, 16, 24), dtype=jnp.uint32)
+    side = jnp.stack(cols, axis=1)[..., None] >> shifts
+    side = (side & 0xFF).astype(jnp.uint8).reshape(rows, 4 * len(cols))
+    pad = jnp.zeros((rows, TAIL - 4 * len(cols)), dtype=jnp.uint8)
+    return jnp.concatenate([data, side, pad], axis=1)

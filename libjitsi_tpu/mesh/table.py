@@ -47,6 +47,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from libjitsi_tpu.mesh.compat import shard_map
 
+from libjitsi_tpu.core import staging
 from libjitsi_tpu.kernels import registry as _registry
 from libjitsi_tpu.transform.srtp import kernel
 from libjitsi_tpu.transform.srtp.context import SrtpStreamTable, _uniform_off
@@ -533,11 +534,22 @@ class ShardedSrtpTable(ShardedRowsMixin, SrtpStreamTable):
                                        batch.length, [iv, self._roc32(v)])
         return data, olen.astype(np.int32)
 
-    def _cm_rtp_unprotect_call(self, stream, batch, hdr, iv, v, length):
+    def _cm_rtp_unprotect_call(self, stream, batch, hdr, iv, v, length
+                               ) -> staging.Launch:
+        """The seam's contract is `SrtpStreamTable`'s: the part comes
+        with a staging plane (`batch.plane`; `batch.data` is its
+        leading columns) and what goes back is a `staging.Launch` whose
+        `fetch()` gives host (data, media_len, auth_ok).  The sharded
+        call packs nothing: its arguments are routed to their owning
+        chips one array each, and the launch holds the three deferred
+        scatters."""
+        roc = self._roc32(v)
         data, mlen, auth_ok = self._run_sharded(
-            "unprotect", stream, batch, hdr, length,
-            [iv, self._roc32(v)])
-        return data, mlen.astype(np.int32), auth_ok
+            "unprotect", stream, batch, hdr, length, [iv, roc])
+        return staging.Launch(
+            (data, mlen.astype(np.int32), auth_ok), h2d_arrays=6,
+            h2d_bytes=batch.data.nbytes + iv.nbytes + roc.nbytes
+            + 12 * batch.batch_size)
 
     # ------------------------------------------------------------------ F8
     def _f8_rtp_protect_call(self, stream, batch, hdr, iv, v):

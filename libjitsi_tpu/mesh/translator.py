@@ -23,6 +23,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from libjitsi_tpu.core import staging
 from libjitsi_tpu.mesh.compat import shard_map
 
 from libjitsi_tpu.mesh.table import ShardedRowsMixin
@@ -54,17 +55,27 @@ class ShardedRtpTranslator(ShardedRowsMixin, RtpTranslator):
     def _sharded_tables(self, group: str = "rtp"):
         return self._rk, (self._gm if self._gcm else self._mid)
 
-    def _cm_fanout_call(self, recv, data, length, payload_off, iv, idx):
+    def _cm_fanout_call(self, recv, plane, length, payload_off, iv, idx
+                        ) -> staging.Launch:
+        """The seam's contract is `RtpTranslator._cm_fanout_call`'s:
+        `plane` holds the packet bytes in its leading columns, and what
+        comes back is a `staging.Launch` whose `fetch()` gives host
+        (wire bytes, wire lengths).  The sharded call packs nothing: its
+        arguments are routed to their owning chips one array each, and
+        the launch holds the two deferred scatters."""
         from libjitsi_tpu.transform.srtp.context import _uniform_off
 
+        data = plane[:, :plane.shape[-1] - staging.TAIL]
         roc = ((np.asarray(idx) >> 16) & 0xFFFFFFFF).astype(np.uint32)
+        lanes = [data, np.asarray(length, dtype=np.int32), payload_off, iv,
+                 roc]
         out, out_len = self._sharded_launch(
-            self._fanout_fn(_uniform_off(payload_off,
-                                         np.asarray(data).shape[-1])),
-            self._sharded_device(), recv,
-            [data, np.asarray(length, dtype=np.int32), payload_off, iv,
-             roc])
-        return out, out_len.astype(np.int32)
+            self._fanout_fn(_uniform_off(payload_off, data.shape[-1])),
+            self._sharded_device(), recv, lanes)
+        return staging.Launch(
+            (out, out_len.astype(np.int32)), h2d_arrays=1 + len(lanes),
+            h2d_bytes=4 * len(recv) + sum(
+                int(np.asarray(a).nbytes) for a in lanes))
 
     def _gcm_fanout_call(self, recv, data, length, payload_off, iv12,
                          capacity):

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from libjitsi_tpu.core import staging
 from libjitsi_tpu.core.packet import (CLASS_HEADROOM, DEFAULT_CAPACITY,
                                       LENGTH_CLASSES, PacketBatch,
                                       _round_rows)
@@ -67,13 +68,24 @@ def _cycle_rows(n: int) -> Optional[np.ndarray]:
 
 @functools.partial(jax.jit,
                    static_argnames=("tag_len", "encrypt", "off_const"),
-                   donate_argnums=(3,))
-def _fanout_protect(tab_rk, tab_mid, recv, data, length, payload_off, iv,
-                    roc, tag_len: int, encrypt: bool, off_const=None):
-    rk, mid = kernel.gather_keys(recv, tab_rk, tab_mid)
-    return kernel.srtp_protect(
-        data, length, payload_off, rk, iv, mid, roc,
-        tag_len, encrypt, payload_off_const=off_const)
+                   donate_argnums=(2,))
+def _fanout_protect(tab_rk, tab_mid, plane, tag_len: int, encrypt: bool,
+                    off_const=None):
+    """The CM fan-out on one packed plane (core/staging.py): words
+    receiver, length, payload offset, ROC; out word the wire length.
+    The plane that comes back has the donated plane's shape."""
+    data, w, iv = staging.unpack(plane)
+    rk, mid = kernel.gather_keys(staging.as_i32(w[:, 0]), tab_rk, tab_mid)
+    out, out_len = kernel.srtp_protect(
+        data, staging.as_i32(w[:, 1]), staging.as_i32(w[:, 2]), rk, iv,
+        mid, w[:, 3], tag_len, encrypt, payload_off_const=off_const)
+    return staging.repack(out, out_len)
+
+
+def _split_fanout(host):
+    """The fan-out's plane back on the host -> (wire bytes, lengths)."""
+    data, words = staging.split_out(host, 1)
+    return data, words[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("aad_const",), donate_argnums=(3,))
@@ -271,17 +283,20 @@ class RtpTranslator:
         offs.append(mixed)
 
         def one(w: int, off) -> None:
-            data = np.zeros((rows, w), dtype=np.uint8)
-            data[:, 0] = 0x80
+            # block on the output: compile NOW, off-tick
             if self._gcm:
+                data = np.zeros((rows, w), dtype=np.uint8)
+                data[:, 0] = 0x80
                 iv12 = np.zeros((rows, 12), dtype=np.uint8)
                 out, _ = self._gcm_fanout_call(recv, data, length,
                                                off, iv12, w)
+                np.asarray(out)
             else:
+                plane = staging.alloc(rows, w)
+                plane[:, 0] = 0x80
                 iv = np.zeros((rows, 16), dtype=np.uint8)
-                out, _ = self._cm_fanout_call(recv, data, length,
-                                              off, iv, idx)
-            np.asarray(out)          # block: compile NOW, off-tick
+                self._cm_fanout_call(recv, plane, length, off, iv,
+                                     idx).fetch()
 
         def grouped(w: int, aad: int) -> None:
             # grouped full-mesh path: legs = this bucket, packets =
@@ -346,7 +361,7 @@ class RtpTranslator:
                 rows.append(i)
                 recvs.append(rr)
         if not rows:
-            return PendingTranslate(None, None, np.zeros(0, np.int64),
+            return PendingTranslate(None, np.zeros(0, np.int64),
                                     batch.capacity)
         with span_of(tracer, "expand") as sp:
             counts = np.array([len(r) for r in recvs])
@@ -369,26 +384,27 @@ class RtpTranslator:
                 cm = self._expand_cm(recv, data, length, payload_off,
                                      ssrc, idx)
                 sp.note(rows=len(recv), rows_padded=len(cm[0]),
-                        width=cm[1].shape[-1])
+                        width=cm[1].shape[-1] - staging.TAIL)
 
         pg = None
         if self._gcm:
             out, out_len, pg = self._translate_gcm(
                 batch, rows, recvs, src, recv, data, length,
                 hdr, payload_off, ssrc, idx)
+            launch = staging.Launch((out, out_len))
         else:
-            # staged: the four arrays as they are, receiver and ROC
-            # as 32-bit words
-            with span_of(tracer, "fanout_dispatch",
-                         h2d_bytes=_nbytes(*cm[1:5]) + 8 * len(cm[0])), \
+            with span_of(tracer, "fanout_dispatch") as sp, \
                     phase_of(self.perf, "dispatch"):
-                out, out_len = self._cm_fanout_call(*cm)
-        return PendingTranslate(out, out_len, recv, batch.capacity, pg=pg,
+                launch = self._cm_fanout_call(*cm)
+                sp.note(h2d_arrays=launch.h2d_arrays,
+                        h2d_bytes=launch.h2d_bytes)
+        return PendingTranslate(launch, recv, batch.capacity, pg=pg,
                                 tracer=tracer, perf=self.perf)
 
     def _expand_cm(self, recv, data, length, payload_off, ssrc, idx):
         """The CM fan-out call's arguments: per-row IVs, rows and width
-        padded to their classes."""
+        padded to their classes; the packet bytes in a staging plane
+        (core/staging.py) with room behind them for the rest."""
         # per-row IV from the receiver's salt + sender's ssrc/index
         iv = self._salt[recv].copy()
         for k in range(4):
@@ -411,30 +427,40 @@ class RtpTranslator:
         pw = _round_width(int(np.max(length, initial=12))
                           + self.policy.auth_tag_len)
         cw = min(pw, data.shape[-1])
-        pdata = np.zeros((len(rr_idx), pw), dtype=np.uint8)
-        pdata[:, :cw] = data[rr_idx][:, :cw]
-        return (recv[rr_idx], pdata, length[rr_idx],
+        plane = staging.alloc(len(rr_idx), pw)
+        plane[:, :cw] = data[rr_idx][:, :cw]
+        return (recv[rr_idx], plane, length[rr_idx],
                 payload_off[rr_idx], iv[rr_idx], idx[rr_idx])
 
-    def _cm_fanout_call(self, recv, data, length, payload_off, iv, idx):
+    def _cm_fanout_call(self, recv, plane, length, payload_off, iv, idx
+                        ) -> staging.Launch:
         """AES-CM fan-out device call — the mesh translator
         (mesh/translator.py) overrides exactly this seam, sharding the
         output rows by owning receiver chip; everything above (routing,
-        expansion, IVs) is shared verbatim.  Uniform payload offsets
-        (the fan-out common case: one sender's fixed header replicated
-        per leg) take the static-pad keystream alignment instead of
-        the per-row offset gathers."""
+        expansion, IVs) is shared verbatim.
+
+        `plane` is `staging.alloc(rows, width)` with the packet bytes
+        in its first `width` columns; receiver, length, payload offset,
+        ROC (`idx >> 16` mod 2**32) and IV are packed behind them here,
+        so ONE array goes to the device and one plane comes back.
+        Returns the `staging.Launch` in flight, whose `fetch()` gives
+        host arrays (wire bytes `[rows, width]`, wire lengths).
+
+        Uniform payload offsets (the fan-out common case: one sender's
+        fixed header replicated per leg) take the static-pad keystream
+        alignment instead of the per-row offset gathers."""
         from libjitsi_tpu.transform.srtp.context import _uniform_off
 
         tab_rk, tab_mid = self._device()
-        return _fanout_protect(
-            tab_rk, tab_mid, jnp.asarray(recv, dtype=jnp.int32),
-            jnp.asarray(data), jnp.asarray(length),
-            jnp.asarray(payload_off), jnp.asarray(iv),
-            jnp.asarray((idx >> 16) & 0xFFFFFFFF, dtype=jnp.uint32),
-            self.policy.auth_tag_len,
-            self.policy.cipher != Cipher.NULL,
-            off_const=_uniform_off(payload_off, data.shape[-1]))
+        staging.pack(plane, (recv, length, payload_off,
+                             (idx >> 16) & 0xFFFFFFFF), iv)
+        out = _fanout_protect(
+            tab_rk, tab_mid, jax.device_put(plane),
+            self.policy.auth_tag_len, self.policy.cipher != Cipher.NULL,
+            off_const=_uniform_off(payload_off,
+                                   plane.shape[-1] - staging.TAIL))
+        return staging.Launch((out,), _split_fanout, h2d_arrays=1,
+                              h2d_bytes=plane.nbytes)
 
     # (see PendingTranslate at module scope)
 
@@ -569,10 +595,11 @@ class PendingTranslate:
     double-buffering seam, for the SFU's per-leg re-encrypt launch.
     """
 
-    def __init__(self, out, out_len, recv: np.ndarray, capacity: int,
+    def __init__(self, launch: "Optional[staging.Launch]",
+                 recv: np.ndarray, capacity: int,
                  pg=None, tracer=None, perf=None):
-        self._out = out
-        self._out_len = out_len
+        # the fan-out call in flight; `fetch()` -> (rows, lengths)
+        self._launch = launch
         self.recv = recv
         self._capacity = capacity
         self._tracer = tracer
@@ -584,26 +611,28 @@ class PendingTranslate:
 
     def result(self) -> Tuple[PacketBatch, np.ndarray]:
         if self._done is None:
-            if self._out is None:
+            if self._launch is None:
                 wire = PacketBatch.empty(0, self._capacity)
             else:
                 wire = self._materialize()
             self._done = (wire, self.recv)
-            self._out = self._out_len = None
+            self._launch = None
         return self._done
 
     def _materialize(self) -> PacketBatch:
         """Wait for the launch (`fanout_wait`, the `device_compute`
         phase), then copy its rows back (`fanout_d2h`,
         `d2h_transfer`)."""
+        launch = self._launch
         with span_of(self._tracer, "fanout_wait"), \
                 phase_of(self._perf, "device_compute"):
-            jax.block_until_ready((self._out, self._out_len))
+            launch.block_until_ready()
         with span_of(self._tracer, "fanout_d2h") as sp, \
                 phase_of(self._perf, "d2h_transfer"):
-            arr = np.asarray(self._out)
-            lens = np.asarray(self._out_len, dtype=np.int32)
-            sp.note(d2h_bytes=arr.nbytes + lens.nbytes)
+            arr, lens = launch.fetch()
+            lens = np.asarray(lens, dtype=np.int32)
+            sp.note(d2h_arrays=launch.d2h_arrays,
+                    d2h_bytes=launch.d2h_bytes)
             if self._pg is not None:
                 # crop the padded (P, G) grid to the real counts and
                 # flatten packet-major — numpy on the materialized

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from libjitsi_tpu.core import staging
 from libjitsi_tpu.core.packet import (PacketBatch, _round_rows,
                                       bucket_by_size, unbucket)
 from libjitsi_tpu.core.rtp_math import (
@@ -103,6 +104,38 @@ def _unprotect_rtp_dev_call(*args, **kwargs):
     fn = (_unprotect_rtp_dev_donated if _donate_ingest()
           else _unprotect_rtp_dev)
     return fn(*args, **kwargs)
+
+
+def _unprotect_rtp_packed_impl(tab_rk, tab_mid, plane, tag_len: int,
+                               encrypt: bool, off_const=None):
+    """`_unprotect_rtp_impl` on one packed plane (core/staging.py):
+    words stream, length, payload offset, ROC; out words media length,
+    auth verdict.  The arithmetic between is the unpacked call's."""
+    data, w, iv = staging.unpack(plane)
+    rk, mid = kernel.gather_keys(staging.as_i32(w[:, 0]), tab_rk, tab_mid)
+    out, mlen, auth_ok = kernel.srtp_unprotect(
+        data, staging.as_i32(w[:, 1]), staging.as_i32(w[:, 2]), rk, iv,
+        mid, w[:, 3], tag_len, encrypt, payload_off_const=off_const)
+    return staging.repack(out, mlen, auth_ok)
+
+
+_unprotect_rtp_packed = jax.jit(
+    _unprotect_rtp_packed_impl,
+    static_argnames=("tag_len", "encrypt", "off_const"))
+
+# donated twin, as `_unprotect_rtp_dev_donated`: the plane that comes
+# back has the staged plane's shape, so XLA writes it over the input
+_unprotect_rtp_packed_donated = jax.jit(
+    _unprotect_rtp_packed_impl,
+    static_argnames=("tag_len", "encrypt", "off_const"),
+    donate_argnums=(2,))
+
+
+def _split_unprotect(host):
+    """The unprotect's plane back on the host -> (data, media_len,
+    auth_ok)."""
+    data, words = staging.split_out(host, 2)
+    return data, words[:, 0], words[:, 1].astype(bool)
 
 
 def _uniform_off(payload_off, width: int) -> "int | None":
@@ -1331,18 +1364,46 @@ class SrtpStreamTable:
             self.policy.auth_tag_len, self.policy.cipher != Cipher.NULL,
             off_const=_uniform_off(hdr.payload_off, batch.capacity))
 
-    def _cm_rtp_unprotect_call(self, stream, batch, hdr, iv, v, length):
-        """AES-CM/NULL RTP unprotect device call (see
-        _cm_rtp_protect_call); returns (data, media_len, auth_ok)."""
+    def _cm_rtp_unprotect_call(self, stream, batch, hdr, iv, v, length
+                               ) -> staging.Launch:
+        """AES-CM/NULL RTP unprotect device call — the seam the mesh
+        table overrides (see _cm_rtp_protect_call).
+
+        Takes the part as `bucket_by_size(tail=staging.TAIL)` made it:
+        `batch.plane` holds the packet bytes and room behind them, into
+        which stream, length, payload offset, ROC (`v` mod 2**32) and
+        IV are packed here; ONE array goes to the device and one plane
+        comes back.  Returns the `staging.Launch` in flight, whose
+        `fetch()` gives host arrays (data, media_len, auth_ok)."""
         p = self.policy
         tab_rk, tab_mid, _, _ = self._device()
-        return _unprotect_rtp_dev_call(
-            tab_rk, tab_mid, jnp.asarray(stream, dtype=jnp.int32),
-            jnp.asarray(batch.data), jnp.asarray(length),
-            jnp.asarray(hdr.payload_off), jnp.asarray(iv),
-            jnp.asarray(v & 0xFFFFFFFF, dtype=jnp.uint32),
-            p.auth_tag_len, p.cipher != Cipher.NULL,
-            off_const=_uniform_off(hdr.payload_off, batch.capacity))
+        plane = batch.plane
+        staging.pack(plane, (stream, length, hdr.payload_off,
+                             v & 0xFFFFFFFF), iv)
+        fn = (_unprotect_rtp_packed_donated if _donate_ingest()
+              else _unprotect_rtp_packed)
+        out = fn(tab_rk, tab_mid, jax.device_put(plane), p.auth_tag_len,
+                 p.cipher != Cipher.NULL,
+                 off_const=_uniform_off(hdr.payload_off, batch.capacity))
+        return staging.Launch((out,), _split_unprotect, h2d_arrays=1,
+                              h2d_bytes=plane.nbytes)
+
+    def _unpacked_launch(self, out, batch, length, iv) -> staging.Launch:
+        """A GCM / F8 call's (data, media_len, auth_ok) behind the CM
+        seam's face: those calls stage data, lengths and IVs as they
+        are and stream, payload offset and ROC as a word a row each."""
+        data, mlen, auth_ok = out
+        return staging.Launch(
+            (data, mlen, auth_ok),
+            lambda d, m, a: (d, m.astype(np.int32), a),
+            h2d_arrays=6,
+            h2d_bytes=batch.data.nbytes + length.nbytes
+            + (0 if iv is None else iv.nbytes) + 12 * batch.batch_size)
+
+    def _part_tail(self) -> int:
+        """Room `bucket_by_size` leaves behind an unprotect part's
+        bytes: the CM call packs its arguments there."""
+        return 0 if self._gcm or self._f8 else staging.TAIL
 
     def unprotect_rtp(self, batch: PacketBatch, return_index: bool = False):
         """Auth-check, replay-check and decrypt incoming RTP.
@@ -1388,7 +1449,7 @@ class SrtpStreamTable:
         # leaves are entered once per size class and sum)
         with span_of(self.tracer, "unprotect_host",
                      rows=batch.batch_size):
-            parts = bucket_by_size(batch)
+            parts = bucket_by_size(batch, tail=self._part_tail())
         done, masks = [], []
         idx_parts = []
         for rows, part, n in parts:
@@ -1444,7 +1505,7 @@ class SrtpStreamTable:
             done = self.unprotect_rtp(batch, return_index)
             return PendingUnprotect(self, [], batch, return_index,
                                     done=done)
-        parts = bucket_by_size(batch)
+        parts = bucket_by_size(batch, tail=self._part_tail())
         pend = [(rows, self._unprotect_rtp_dispatch(part), n)
                 for rows, part, n in parts]
         p = PendingUnprotect(self, pend, batch, return_index)
@@ -1469,23 +1530,24 @@ class SrtpStreamTable:
             out = (None if self._ks_cache is None
                    else self._gcm_rtp_unprotect_cached(stream, batch,
                                                        hdr, idx, length))
+            iv12 = None
             if out is None:
                 iv12 = self._gcm_rtp_iv(self._salt_rtp[stream],
                                         hdr.ssrc, idx)
                 out = self._gcm_rtp_unprotect_call(stream, batch, hdr,
                                                    iv12, length)
-            data, mlen, auth_ok = out
+            launch = self._unpacked_launch(out, batch, length, iv12)
         elif self._f8:
             iv = self._f8_rtp_iv(hdr, v)
-            data, mlen, auth_ok = self._f8_rtp_unprotect_call(
-                stream, batch, hdr, iv, v, length)
+            launch = self._unpacked_launch(
+                self._f8_rtp_unprotect_call(stream, batch, hdr, iv, v,
+                                            length), batch, length, iv)
         else:
             iv = self._cm_iv(self._salt_rtp[stream], hdr.ssrc, idx)
-            data, mlen, auth_ok = self._cm_rtp_unprotect_call(
+            launch = self._cm_rtp_unprotect_call(
                 stream, batch, hdr, iv, v, length)
         return {"part": batch, "stream": stream, "length": length,
-                "valid": valid, "idx": idx, "data": data, "mlen": mlen,
-                "auth_ok": auth_ok}
+                "valid": valid, "idx": idx, "launch": launch}
 
     def _unprotect_rtp_direct(self, batch: PacketBatch,
                               return_index: bool = False,
@@ -1524,12 +1586,7 @@ class SrtpStreamTable:
         # arrays: what the tick thread waits on the device for
         with span_of(tracer, "unprotect_wait",
                      rows=batch.batch_size if n_real is None else n_real,
-                     rows_padded=batch.batch_size,
-                     # data, lengths and IVs as they are; stream, payload
-                     # offset and ROC as one 32-bit word a row each
-                     h2d_bytes=batch.data.nbytes + length.nbytes
-                     + (0 if iv is None else iv.nbytes)
-                     + 12 * batch.batch_size) as sp, \
+                     rows_padded=batch.batch_size) as sp, \
                 phase_of(self.perf, "device_compute"):
             if self._gcm:
                 out = (None if self._ks_cache is None
@@ -1541,17 +1598,19 @@ class SrtpStreamTable:
                                               hdr.ssrc, idx)
                     out = self._gcm_rtp_unprotect_call(stream, batch, hdr,
                                                        iv, length)
-                data, mlen, auth_ok = out
+                launch = self._unpacked_launch(out, batch, length, iv)
             elif self._f8:
-                data, mlen, auth_ok = self._f8_rtp_unprotect_call(
-                    stream, batch, hdr, iv, v, length)
+                launch = self._unpacked_launch(
+                    self._f8_rtp_unprotect_call(stream, batch, hdr, iv, v,
+                                                length), batch, length, iv)
             else:
-                data, mlen, auth_ok = self._cm_rtp_unprotect_call(
+                launch = self._cm_rtp_unprotect_call(
                     stream, batch, hdr, iv, v, length)
-            auth_ok = np.asarray(auth_ok)
-            data = np.asarray(data)
-            mlen = np.asarray(mlen, dtype=np.int32)
-            sp.note(d2h_bytes=data.nbytes + mlen.nbytes + auth_ok.nbytes)
+            data, mlen, auth_ok = launch.fetch()
+            sp.note(h2d_arrays=launch.h2d_arrays,
+                    h2d_bytes=launch.h2d_bytes,
+                    d2h_arrays=launch.d2h_arrays,
+                    d2h_bytes=launch.d2h_bytes)
 
         with span_of(tracer, "unprotect_host"):
             srow = np.clip(stream, 0, self.capacity - 1)
@@ -1979,11 +2038,8 @@ class PendingUnprotect:
         back (phase-profiler boundary)."""
         if self._done is None:
             try:
-                import jax
-
                 for _rows, rec, _n in self._parts:
-                    jax.block_until_ready(
-                        [rec["data"], rec["mlen"], rec["auth_ok"]])
+                    rec["launch"].block_until_ready()
             except Exception:
                 pass
         return self
@@ -2002,7 +2058,7 @@ class PendingUnprotect:
         self._ok_parts = []
         for _rows, rec, _n in self._parts:
             stream, idx, valid = rec["stream"], rec["idx"], rec["valid"]
-            auth_ok = np.asarray(rec["auth_ok"])
+            _data, _mlen, auth_ok = rec["launch"].fetch()
             not_replayed = replay.check(t.rx_max, t.rx_mask, stream, idx)
             srow = np.clip(stream, 0, t.capacity - 1)
             np.add.at(t.auth_fail, srow, valid & not_replayed & ~auth_ok)
@@ -2021,8 +2077,7 @@ class PendingUnprotect:
         batch = self._batch
         done, masks, idx_parts = [], [], []
         for (rows, rec, n), ok in zip(self._parts, self._ok_parts):
-            data = np.asarray(rec["data"])
-            mlen = np.asarray(rec["mlen"], dtype=np.int32)
+            data, mlen, _auth_ok = rec["launch"].fetch()
             pdat = rec["part"].data
             out_data = np.where(ok[:, None], data, pdat)
             out_len = np.where(ok, mlen, rec["length"]).astype(np.int32)

@@ -36,6 +36,37 @@ import pytest  # noqa: E402
 
 
 @pytest.fixture
+def warmed_launch_guard(monkeypatch):
+    """A context manager round calls that must run warmed and staged by
+    hand: inside it an implicit host-to-device transfer raises (a NumPy
+    array handed to a jitted call, `jnp.asarray(..., dtype=other)`;
+    `jax.device_put` may), and on leaving it no eager
+    `convert_element_type` program was started and nothing compiled."""
+    import contextlib
+
+    import jax
+    from jax import lax
+
+    from libjitsi_tpu.utils.compile_cache import compile_stats
+
+    @contextlib.contextmanager
+    def guard():
+        converts = []
+        impl = lax.convert_element_type_p.impl
+        monkeypatch.setattr(
+            lax.convert_element_type_p, "impl",
+            lambda *a, **k: (converts.append(k), impl(*a, **k))[1])
+        compiles0 = compile_stats().compile_events
+        with jax.transfer_guard_host_to_device("disallow"):
+            yield
+        monkeypatch.setattr(lax.convert_element_type_p, "impl", impl)
+        assert converts == []
+        assert compile_stats().compile_events == compiles0
+
+    return guard
+
+
+@pytest.fixture
 def sfu_with_traffic():
     """`(sfu, sup, send)`: an SfuBridge of three keyed endpoints behind
     a supervisor; `send()` puts one protected packet of each endpoint

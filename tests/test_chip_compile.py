@@ -114,6 +114,31 @@ def test_srtp_cm_served_shape(one_chip, no_persistent_cache, tower_core,
         off_const=12).compile())
 
 
+# The two CM calls the served tick makes, on one packed plane each
+# (core/staging.py) at the top warmed row class; the donated plane comes
+# back in the same shape, so the compiler may write over it.
+@pytest.mark.parametrize("module,fn_name,off_const", [
+    ("libjitsi_tpu.sfu.translator", "_fanout_protect", 12),
+    ("libjitsi_tpu.transform.srtp.context",
+     "_unprotect_rtp_packed_donated", 12),
+    pytest.param("libjitsi_tpu.sfu.translator", "_fanout_protect", None,
+                 marks=pytest.mark.slow),
+])
+def test_packed_cm_served_shape(one_chip, no_persistent_cache, tower_core,
+                                module, fn_name, off_const):
+    import importlib
+
+    from libjitsi_tpu.core import staging
+
+    s = _on(one_chip)
+    c = getattr(importlib.import_module(module), fn_name).lower(
+        s((CAP, 11, 16), jnp.uint8), s((CAP, 2, 5), jnp.uint32),
+        s((4096, WIDTH + staging.TAIL), jnp.uint8), tag_len=10,
+        encrypt=True, off_const=off_const).compile()
+    _fits(c)
+    assert "input_output_alias" in c.as_text()    # the donation took
+
+
 def test_gcm_grouped_protect_served_shape(one_chip, no_persistent_cache,
                                           tower_core):
     from libjitsi_tpu.transform.srtp import context as ctx

@@ -448,6 +448,31 @@ def test_sfu_tick_emits_every_leaf_and_self_times_tile_it(
         gc.enable()
 
 
+def test_sfu_warm_tick_is_two_launches_one_array_each_way(
+        sfu_with_traffic, warmed_launch_guard):
+    """A warmed SfuBridge tick with traffic stages ONE array for the
+    unprotect and one for the fan-out, copies one back from each,
+    compiles nothing and starts no `convert_element_type` program."""
+    import time
+
+    sfu, sup, send = sfu_with_traffic
+    send.until_forwarded()
+    send.until_forwarded()           # the forwarding tick's shapes: warm
+    send()                           # the clients' own protect: outside
+    time.sleep(0.01)
+    before = sfu.forwarded
+    with warmed_launch_guard():
+        sup.tick(now=50.0)
+    assert sfu.forwarded == before + 6
+    counts = sup.last_counts
+    up, down = counts["unprotect_wait"], counts["fanout_d2h"]
+    assert up["h2d_arrays"] == up["d2h_arrays"] == 1
+    assert up["h2d_bytes"] == up["d2h_bytes"] == 16 * (192 + 32 + 32)
+    assert counts["fanout_dispatch"] == {"h2d_arrays": 1,
+                                         "h2d_bytes": up["h2d_bytes"]}
+    assert down == {"d2h_arrays": 1, "d2h_bytes": up["h2d_bytes"]}
+
+
 def test_sfu_tick_spans_in_profile_match_the_ledger(sfu_with_traffic,
                                                     tmp_path):
     """Read back through the benchmark's `xstats`: every `stage:*`

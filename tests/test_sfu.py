@@ -291,3 +291,75 @@ def test_gcm_fanout_forged_ext_header_does_not_crash():
         b.data[i, 15] = 0xE8                    # 0x3E8 = 1000 words
     out, recv = tr.translate(b, np.array([1000, 1001]))
     assert out.batch_size == 2 * 3              # processed, not crashed
+
+
+# --------------------------------------------- packed CM fan-out (PR 28) ---
+
+# (packets, legs) one row short of each class, so the launch carries a
+# cycled pad row; both warmed widths; payload offset uniform 12,
+# uniform 20 and mixed
+PACKED_FANOUT_CASES = [
+    (3, 5, 100, 12), (9, 7, 100, 20), (51, 5, 1300, "mixed"),
+    (341, 3, 100, "mixed"), (585, 7, 100, 12), (21, 3, 1300, 20),
+]
+
+
+@pytest.mark.parametrize("packets,legs,payload_len,off",
+                         PACKED_FANOUT_CASES)
+def test_packed_cm_fanout_vs_oracle(packets, legs, payload_len, off):
+    """Every row of the packed CM fan-out is what the OpenSSL oracle
+    seals for that receiver: bytes and lengths, padded rows dropped."""
+    from libjitsi_tpu.core.packet import _round_rows
+    from test_srtp import _rtp_with_offset, protect_oracle_ext
+
+    rng = np.random.default_rng(packets * legs)
+    keys = {r: (bytes(rng.integers(0, 256, 16, dtype=np.uint8)),
+                bytes(rng.integers(0, 256, 14, dtype=np.uint8)))
+            for r in range(1, legs + 1)}
+    tr = RtpTranslator(capacity=16)
+    for r, (mk, ms) in keys.items():
+        tr.add_receiver(r, mk, ms)
+    tr.connect(0, list(keys))
+    plain, index = [], []
+    for i in range(packets):
+        o = off if off != "mixed" else (12, 20)[i % 2]
+        pay = bytes(rng.integers(0, 256, payload_len - (i % 7),
+                                 dtype=np.uint8))
+        # past a sequence wrap: the ROC word is not zero
+        index.append((3 << 16) + 100 + i)
+        plain.append(_rtp_with_offset(index[-1] & 0xFFFF, 0xAAA, pay, o))
+    batch = PacketBatch.from_payloads(plain, stream=[0] * packets)
+    out, recv = tr.translate(batch, np.asarray(index))
+    assert out.batch_size == packets * legs < _round_rows(packets * legs)
+    for j in range(out.batch_size):
+        mk, ms = keys[int(recv[j])]
+        i = j // legs
+        assert out.to_bytes(j) == protect_oracle_ext(
+            mk, ms, plain[i], index[i], 10), j
+        assert out.length[j] == len(plain[i]) + 10
+
+
+def test_packed_cm_fanout_one_array_each_way(warmed_launch_guard):
+    """With the tracer on, a warmed CM fan-out sends ONE array to the
+    device and copies one back, compiles nothing and starts no
+    `convert_element_type` program."""
+    from libjitsi_tpu.utils.tracing import PipelineTracer
+
+    tr = RtpTranslator(capacity=8)
+    for r, (mk, ms) in RECV_KEYS.items():
+        tr.add_receiver(r, mk, ms)
+    tr.connect(0, [1, 2, 3])
+    tr.tracer = tracer = PipelineTracer(annotate=False)
+    tr.translate(_sender_batch(), np.arange(1000, 1004))   # warms
+    tracer.take_ledger()
+    with warmed_launch_guard():
+        out, recv = tr.translate(_sender_batch(), np.arange(1004, 1008))
+    assert out.batch_size == 12
+    tracer.take_ledger()
+    counts = tracer.last_counts
+    plane = 16 * (192 + 32 + 32)       # 12 rows padded to 16, one class
+    assert counts["fanout_dispatch"] == {"h2d_arrays": 1,
+                                         "h2d_bytes": plane}
+    assert counts["fanout_d2h"] == {"d2h_arrays": 1, "d2h_bytes": plane}
+    assert counts["expand"] == {"rows": 12, "rows_padded": 16,
+                                "width": 224}

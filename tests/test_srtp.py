@@ -464,3 +464,134 @@ def test_key_mutation_while_protect_pending_is_safe():
     got = pend.result()
     for i in range(8):
         assert got.to_bytes(i) == want.to_bytes(i), i
+
+
+# ------------------------------------------- packed CM unprotect (PR 28) ---
+
+def _rtp_with_offset(seq, ssrc, payload, off):
+    """An RTP packet whose payload starts at byte `off`: 12 (bare
+    header) or 20 (X bit, a 0xBEDE block of one word)."""
+    p = rtp_pkt(seq, ssrc=ssrc, payload=payload)
+    if off == 12:
+        return p
+    assert off == 20
+    ext = b"\xbe\xde\x00\x01" + b"\x32\xaa\xbb\xcc"
+    return bytes([p[0] | 0x10]) + p[1:12] + ext + p[12:]
+
+
+def protect_oracle_ext(mk, ms, pkt, index, tag_len):
+    """`protect_oracle` for a header that may carry an extension."""
+    off = 12 + 4 * (pkt[0] & 0x0F)
+    if pkt[0] & 0x10:
+        off += 4 + 4 * int.from_bytes(pkt[off + 2:off + 4], "big")
+    ke = kdf_oracle(mk, ms, 0, len(mk))
+    ka = kdf_oracle(mk, ms, 1, 20)
+    ksalt = int.from_bytes(kdf_oracle(mk, ms, 2, 14), "big")
+    ssrc = int.from_bytes(pkt[8:12], "big")
+    iv = ((ksalt << 16) ^ (ssrc << 64) ^ (index << 16)).to_bytes(16, "big")
+    ct = pkt[:off] + aes_ctr(ke, iv, pkt[off:])
+    tag = hmac_mod.new(ka, ct + (index >> 16).to_bytes(4, "big"),
+                       hashlib.sha1).digest()
+    return ct + tag[:tag_len]
+
+
+# every row class, both warmed widths (the audio class 192 + 32 and the
+# full MTU 1504 + 32), payload offset uniform 12, uniform 20 and mixed.
+# Three rows short of the class, so the launch carries cycled pad rows.
+PACKED_CASES = [
+    (16, 1300, "mixed"), (64, 100, 20), (256, 1300, 12),
+    (1024, 100, "mixed"), (4096, 100, 12), (16, 100, 20),
+]
+
+
+@pytest.mark.parametrize("rows,payload_len,off", PACKED_CASES)
+def test_packed_cm_unprotect_vs_oracle(rows, payload_len, off):
+    """The packed CM unprotect opens what the OpenSSL oracle sealed:
+    bytes, lengths and verdicts, with a forged tag, an in-batch
+    duplicate, padded (cycled) rows and, in a second call, a replay."""
+    n = rows - 3
+    streams = 8
+    t = make_table(n=streams)
+    rng = np.random.default_rng(rows + payload_len)
+    plain, wire, sids = [], [], []
+    for i in range(n):
+        sid = i % streams
+        seq = 1000 + i // streams
+        o = off if off != "mixed" else (12, 20)[i % 2]
+        pay = bytes(rng.integers(0, 256, payload_len - (i % 5),
+                                 dtype=np.uint8))
+        p = _rtp_with_offset(seq, 0x1000 + sid, pay, o)
+        plain.append(p)
+        wire.append(protect_oracle_ext(MK, MS, p, seq, 10))
+        sids.append(sid)
+    forged, dup = 5, n - 1         # neither is a row the pads cycle
+    wire[forged] = wire[forged][:-1] + bytes([wire[forged][-1] ^ 1])
+    wire[dup], plain[dup], sids[dup] = wire[4], plain[4], sids[4]
+    batch = PacketBatch.from_payloads(wire, stream=sids)
+    out, ok, idx = t.unprotect_rtp(batch, return_index=True)
+    want_ok = np.ones(n, dtype=bool)
+    want_ok[[forged, dup]] = False
+    np.testing.assert_array_equal(ok, want_ok)
+    for i in range(n):
+        if want_ok[i]:
+            assert out.to_bytes(i) == plain[i], i
+            assert idx[i] == int.from_bytes(plain[i][2:4], "big")
+        else:                      # failed rows keep their bytes
+            assert out.to_bytes(i) == wire[i], i
+    assert t.auth_fail[sids[forged]] == 1
+    # a replayed index in a later call dies in the replay window
+    again = PacketBatch.from_payloads([wire[0], wire[7]],
+                                      stream=[sids[0], sids[7]])
+    _, ok2 = t.unprotect_rtp(again)
+    assert not ok2.any()
+    assert t.replay_reject[sids[0]] >= 1
+
+
+def test_packed_cm_unprotect_async_matches_sync():
+    """`unprotect_rtp_async` goes through the same packed seam: one
+    copy back serves both the commit and the result."""
+    t_sync, t_async, tx = make_table(n=4), make_table(n=4), make_table(n=4)
+    pkts = [rtp_pkt(300 + i // 4, ssrc=0x1000 + i % 4,
+                    payload=bytes([i]) * (20 + 30 * (i % 3)))
+            for i in range(12)]
+    wire = tx.protect_rtp(PacketBatch.from_payloads(
+        pkts, stream=[i % 4 for i in range(12)]))
+    bad = wire.copy()
+    bad.data[5, 14] ^= 0x40
+    want, want_ok = t_sync.unprotect_rtp(bad)
+    pend = t_async.unprotect_rtp_async(bad).block_until_ready()
+    got, ok = pend.result()
+    np.testing.assert_array_equal(ok, want_ok)
+    assert not ok[5] and ok.sum() == 11
+    for i in range(12):
+        assert got.to_bytes(i) == want.to_bytes(i), i
+    np.testing.assert_array_equal(t_async.rx_max, t_sync.rx_max)
+
+
+def test_packed_cm_unprotect_one_array_each_way(warmed_launch_guard):
+    """With the tracer on, a warmed CM unprotect sends ONE array to
+    the device and copies one back per launch, compiles nothing and
+    starts no `convert_element_type` program."""
+    from libjitsi_tpu.utils.tracing import PipelineTracer
+
+    t, tx = make_table(n=4), make_table(n=4)
+    t.tracer = tracer = PipelineTracer(annotate=False)
+
+    def wire(seq0):
+        pkts = [rtp_pkt(seq0 + i // 4, ssrc=0x1000 + i % 4,
+                        payload=bytes([i]) * 40) for i in range(12)]
+        return tx.protect_rtp(PacketBatch.from_payloads(
+            pkts, stream=[i % 4 for i in range(12)]))
+
+    first, second = wire(10), wire(20)
+    _, ok = t.unprotect_rtp(first)            # warms the program
+    assert ok.all()
+    tracer.take_ledger()
+    with warmed_launch_guard():
+        _, ok = t.unprotect_rtp(second)
+    assert ok.all()
+    tracer.take_ledger()
+    c = tracer.last_counts["unprotect_wait"]
+    assert c["h2d_arrays"] == 1 and c["d2h_arrays"] == 1
+    assert c["rows"] == 12 and c["rows_padded"] == 16
+    assert c["h2d_bytes"] == c["d2h_bytes"] == 16 * (192 + 32 + 32)
