@@ -32,9 +32,8 @@ Output protocol:
 - FINAL stdout line: a COMPACT headline (value, vs_baseline, p99,
   roofline verdict, section tally) sized for the driver's tail window;
 - every throughput figure carries an HBM-roofline annotation and is
-  CAPPED at the physically possible rate (median-of-passes banking; a
-  cross-check against the standalone AES core rate bounds the headline
-  too) — see _roofline/_aes_consistency_check.
+  CAPPED at the physically possible rate (median-of-passes banking)
+  — see _roofline.
 """
 
 from __future__ import annotations
@@ -139,14 +138,6 @@ def _fetch_floor() -> float:
         EXTRA["scalar_fetch_floor_ms"] = round(_FLOOR[0] * 1e3, 2)
         EXTRA["scalar_fetch_floor_jitter_ms"] = round(_FLOOR[1] * 1e3, 3)
     return _FLOOR[0]
-
-
-def _floor_jitter() -> float:
-    """Spread of the fetch-floor samples — the bar any net measurement
-    must clear (a net span inside this jitter is noise, not a
-    rate)."""
-    _fetch_floor()
-    return _FLOOR[1]
 
 
 def _roofline(key: str, pps: float, bytes_per_item: float,
@@ -255,8 +246,6 @@ def emit() -> None:
                     "hbm_gbps": ex.get("hbm_gbps"),
                     "headline_roofline": ex.get("roofline", {}).get(
                         "headline", {}),
-                    "consistency_vs_aes_core": ex.get(
-                        "consistency_vs_aes_core"),
                     "sections_ok": ok_n, "sections_total": len(sect),
                     "elapsed_s": ex.get("elapsed_s"),
                     "detail": ("BENCH_DETAIL.json + penultimate stdout "
@@ -484,105 +473,6 @@ def _time_fn(fn, args, deadline: float, iters: int = 4) -> float:
         if time.monotonic() > deadline and samples:
             break
     return max(float(np.median(samples)) - floor, 1e-9)
-
-
-def _chained_aes(fn, rks, k: int):
-    """jit( blocks -> checksum of fn applied k times, CHAINED ): round
-    i's ciphertext is round i+1's plaintext, so XLA cannot elide any
-    round and the program span scales with k.  This is what makes the
-    per-core numbers floor-proof (single-launch timings under the
-    fetch-floor jitter are noise, not a rate)."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    def prog(blk):
-        out = lax.fori_loop(0, k, lambda _i, v: fn(rks, v), blk)
-        return jnp.sum(out.astype(jnp.uint32))
-
-    return jax.jit(prog)
-
-
-def aes_core_blocks_per_sec(deadline: float, b: int = 65536) -> None:
-    """Provider sweep for the AES core (SURVEY §7 'hard parts'): the
-    table/S-box-gather core vs the gather-free bitsliced Boolean circuit
-    (kernels/aes_bitsliced.py), plus the Pallas bitsliced kernel (lane-
-    native; lowers since round 3).  Standalone block-encrypt rate via
-    CHAINED launches: k data-dependent encrypts per program, k doubled
-    until the net span clears 10x the fetch-floor jitter; a core that
-    cannot clear the bar inside the budget records "below_floor", never
-    a number.  The quick XLA providers run first so their numbers are
-    banked before the Pallas compile (the one potentially slow step —
-    its box is whatever remains of this section's)."""
-    import jax.numpy as jnp
-
-    from libjitsi_tpu.kernels.aes import aes_encrypt_table, \
-        expand_keys_batch
-    from libjitsi_tpu.kernels.aes_bitsliced import (
-        aes_encrypt_bitsliced, aes_encrypt_bitsliced32,
-        aes_encrypt_bitsliced_tower, aes_encrypt_pallas_bitsliced)
-
-    rng = np.random.default_rng(21)
-    rks = expand_keys_batch(rng.integers(0, 256, (b, 16), dtype=np.uint8))
-    blocks = rng.integers(0, 256, (b, 16), dtype=np.uint8)
-    rksd, blkd = jnp.asarray(rks), jnp.asarray(blocks)
-    floor, jitter = _fetch_floor(), _floor_jitter()
-    out: dict = {}
-    EXTRA["aes_core_blocks_per_sec"] = out
-    for name, fn in (("xla_table", aes_encrypt_table),
-                     ("xla_bitsliced", aes_encrypt_bitsliced),
-                     ("xla_bitsliced_tower", aes_encrypt_bitsliced_tower),
-                     ("xla_bitsliced32", aes_encrypt_bitsliced32),
-                     ("pallas_bitsliced", aes_encrypt_pallas_bitsliced)):
-        if time.monotonic() > deadline:
-            out[name] = "skipped: budget"
-            continue
-        try:
-            k = 4
-            while True:
-                g = _chained_aes(fn, rksd, k)
-                _ = np.asarray(g(blkd))          # compile + prime
-                spans = []
-                for _ in range(4):
-                    t0 = time.perf_counter()
-                    _ = np.asarray(g(blkd))
-                    spans.append(time.perf_counter() - t0)
-                    if time.monotonic() > deadline and spans:
-                        break
-                net = float(np.median(spans)) - floor
-                if net >= 10.0 * max(jitter, 1e-9):
-                    # 176B round keys + 16B in + 16B out per block
-                    out[name] = round(_roofline(
-                        f"aes_{name}", b * k / net, 208,
-                        "176 rk + 16 in + 16 out"), 1)
-                    break
-                if k >= 1 << 16 or time.monotonic() > deadline:
-                    out[name] = f"below_floor: k={k} net={net * 1e3:.3f}ms"
-                    break
-                k *= 2
-        except Exception as e:   # Mosaic lowering refusal, recorded
-            out[name] = f"error: {type(e).__name__}"
-    _aes_consistency_check(out)
-
-
-def _aes_consistency_check(core: dict) -> None:
-    """Cross-estimator sanity: a 172B packet needs ~10 AES keystream
-    blocks, so headline_pps * 10 cannot exceed the (roofline-capped)
-    standalone core rate by more than measurement slack.  A headline
-    that fails the check is capped instead of shipped."""
-    rates = [v for v in core.values() if isinstance(v, (int, float))]
-    if not rates or not RESULT["value"]:
-        return
-    blocks_per_pkt = -(-(PKT_LEN - 12) // 16)
-    allowed = max(rates) / blocks_per_pkt * 1.5
-    rec = {"blocks_per_pkt": blocks_per_pkt,
-           "core_rate_capped": round(max(rates), 1),
-           "allowed_headline_pps": round(allowed, 1), "ok": True}
-    if RESULT["value"] > allowed:
-        rec["ok"] = False
-        rec["headline_before_cap"] = RESULT["value"]
-        RESULT["value"] = round(allowed, 1)
-    EXTRA["consistency_vs_aes_core"] = rec
 
 
 def gcm_sweep(deadline: float) -> None:
@@ -1438,7 +1328,6 @@ def main():
         section("bridge_mixes", 6, 20, bridge_mixes)
         section("fanout", 8, 30, fanout)
         section("gcm_fanout", 8, 30, gcm_fanout)
-        section("aes_cores", 15, 90, aes_core_blocks_per_sec)
         section("table_roundtrip_probe", 25, 90, table_roundtrip_probe)
         section("gcm_sweep", 25, 90, gcm_sweep)
         section("table_path", 25, 75, table_path)

@@ -308,16 +308,12 @@ def _check_mixer(rng, n: int, f: int = 960) -> None:
 
 
 def _check_pallas_twins(rng, rows: int, interpret: bool) -> None:
-    """Every Pallas provider, compiled for the device, bit-compared
-    with its XLA twin — the mixer from one block up to a grid of row
-    tiles at the table's width."""
-    import jax
+    """The Pallas mixer, compiled for the device, bit-compared with
+    its XLA twin from one block up to a grid of row tiles at the
+    table's width."""
     import numpy as np
 
     from libjitsi_tpu.conference.mixer import _mix_jit
-    from libjitsi_tpu.kernels.aes import expand_keys_batch
-    from libjitsi_tpu.kernels.aes_bitsliced import (
-        aes_encrypt_bitsliced, aes_encrypt_pallas_bitsliced)
     from libjitsi_tpu.kernels.pallas_ops import mix_minus_pallas
 
     for n, f in ((256, 960), (8, 160), (4096, 960), (rows, 960)):
@@ -328,18 +324,8 @@ def _check_pallas_twins(rng, rows: int, interpret: bool) -> None:
         if not (np.array_equal(np.asarray(out_p), np.asarray(out_x))
                 and np.array_equal(np.asarray(lvl_p), np.asarray(lvl_x))):
             raise AssertionError(f"B pallas mixer [{n},{f}] != XLA twin")
-    for nb in (128, 16384):
-        rks = expand_keys_batch(rng.integers(0, 256, (nb, 16),
-                                             dtype=np.uint8))
-        blocks = rng.integers(0, 256, (nb, 16), dtype=np.uint8)
-        got = aes_encrypt_pallas_bitsliced(rks, blocks,
-                                           interpret=interpret)
-        ref = aes_encrypt_bitsliced(rks, blocks)
-        if not np.array_equal(np.asarray(jax.block_until_ready(got)),
-                              np.asarray(ref)):
-            raise AssertionError(f"B pallas AES nb={nb} != XLA twin")
-    say(f"B pallas mixer + pallas bitsliced AES bit-identical to their "
-        f"XLA twins (interpret={interpret})")
+    say(f"B pallas mixer bit-identical to its XLA twin "
+        f"(interpret={interpret})")
 
 
 def _check_registry() -> None:

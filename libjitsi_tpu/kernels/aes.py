@@ -13,9 +13,9 @@ Design notes
   consumes a dense `[B, rounds+1, 16]` round-key tensor gathered per packet
   row by stream id — this is how per-stream SRTP session keys batch.
 - The round loop is unrolled at trace time (constant 10/14 trip count).
-- S-box lookups are `jnp.take` gathers on a 256-byte constant; correctness
-  first.  A bitsliced boolean-circuit S-box (gather-free) is the planned
-  optimization — swap inside `_sub_bytes` without touching callers.
+- S-box lookups here are `jnp.take` gathers on a 256-byte constant: the
+  CPU's core and the tests' reference.  An accelerator runs the gather-free
+  circuit of kernels/aes_bitsliced.py instead (`get_core`).
 - State layout is the FIPS-197 flat byte order (index = row + 4*col), so
   blocks go in/out with no repacking.
 - The S-box and round constants are *generated* from GF(2^8) arithmetic at
@@ -220,31 +220,15 @@ def aes_encrypt_table(round_keys, blocks):
     return _shift_rows(_sub_bytes(st)) ^ rk[..., nr, :]
 
 
-# Selectable encrypt core (the reference's `.srtp.crypto.Aes`
-# benchmark-and-pick idea at the kernel level): "table" (S-box gather)
-# or a "bitsliced" variant (gather-free Boolean circuits,
-# kernels/aes_bitsliced.py).  Selection order in get_core():
-#   1. LIBJITSI_TPU_AES_CORE / set_core() — explicit pin, wins always;
-#   2. the measured record (AES_CORES.json via
-#      kernels/registry.py:measured_aes_core): per-backend chained
-#      above-floor numbers from the bench_aes_cores protocol, picked
-#      by blocks/s among status=="ok" cores only — below_floor and
-#      budget-skipped entries are refusals, never evidence;
-#   3. heuristic fallback when no record covers the backend: table on
-#      CPU (XLA:CPU's gather is cheap), composite-field tower bitslice
-#      on accelerators (per-byte S-box gathers are the vector unit's
-#      worst case, pure lane-parallel bit math its best).
-# The choice is read at TRACE time, so switch before the first jit of
-# the consuming kernels (set_core clears jax caches so later compiles
-# re-pick).
-import os as _os
-
-_CORES = ("table", "bitsliced", "bitsliced_tower", "bitsliced32")
-_CORE_NAME = _os.environ.get("LIBJITSI_TPU_AES_CORE")  # None = by backend
-if _CORE_NAME not in (None,) + _CORES:
-    raise ValueError(
-        f"LIBJITSI_TPU_AES_CORE={_CORE_NAME!r}: must be one of {_CORES} "
-        "(a typo would otherwise silently run the default)")
+# The encrypt core is picked by the platform, at TRACE time: "table"
+# (S-box gather; XLA:CPU's gather is cheap) on the CPU, "bitsliced_tower"
+# (gather-free composite-field circuit, kernels/aes_bitsliced.py) on an
+# accelerator, where a gather per byte is the vector unit's worst case.
+# `set_core` is the tests' seam (it lowers the chip's core on a CPU);
+# "bitsliced32" is reachable through it alone (kept for a `perf_opt`
+# to try on the chip: see its banner in aes_bitsliced.py).
+_CORES = ("table", "bitsliced_tower", "bitsliced32")
+_CORE_NAME = None           # set only by set_core
 
 
 def set_core(name: str) -> None:
@@ -257,39 +241,16 @@ def set_core(name: str) -> None:
 
 
 def get_core() -> str:
-    global _CORE_NAME
-    if _CORE_NAME is None:
-        # resolved lazily so importing this module never forces a
-        # backend init (conftest flips platforms before first use).
-        # Measured pick first: AES_CORES.json holds per-backend
-        # chained above-floor blocks/s (single-launch spans can sit
-        # inside the scalar-fetch floor's jitter and emit junk),
-        # and measured_aes_core returns the fastest status=="ok" core
-        # for this backend or None when none exists.  Heuristic
-        # fallback mirrors what the measurements have shown so far:
-        # table on CPU (chained: gathers are cheap there), the
-        # composite-field tower bitslice elsewhere (fetch-verified
-        # fastest credible core on v5e; bitsliced32 has no above-floor
-        # TPU number, so it can only win via a future measured record).
-        from libjitsi_tpu.kernels import registry as _registry
-
-        measured = _registry.measured_aes_core()
-        if measured is not None:
-            _CORE_NAME = measured
-        else:
-            _CORE_NAME = ("table" if jax.default_backend() == "cpu"
-                          else "bitsliced_tower")
-    return _CORE_NAME
+    if _CORE_NAME is not None:
+        return _CORE_NAME
+    return "table" if jax.default_backend() == "cpu" else "bitsliced_tower"
 
 
 def aes_encrypt(round_keys, blocks):
-    """Batched AES block encrypt via the selected core ([..., R, 16]
-    keys, [..., 16] blocks; see `set_core`)."""
+    """Batched AES block encrypt via the platform's core ([..., R, 16]
+    keys, [..., 16] blocks; see `get_core`)."""
     core = get_core()
-    if core == "bitsliced":
-        from libjitsi_tpu.kernels.aes_bitsliced import \
-            aes_encrypt_bitsliced_nd as fn
-    elif core == "bitsliced_tower":
+    if core == "bitsliced_tower":
         from libjitsi_tpu.kernels.aes_bitsliced import \
             aes_encrypt_bitsliced_tower_nd as fn
     elif core == "bitsliced32":

@@ -1,12 +1,10 @@
-"""Bitsliced, gather-free AES — the SURVEY §7 "hard parts" candidate.
+"""Bitsliced, gather-free AES: the core an accelerator runs.
 
-The production AES path (`kernels.aes.aes_encrypt`) uses a 256-entry
-S-box `jnp.take`, which XLA lowers well but Mosaic (Pallas TPU) refuses
-to lower at all.  This module builds AES-128/256 encryption as a pure
-Boolean circuit — XOR/AND/slice/concat only, no gathers — so the same
-body runs as an XLA program *and* as a Pallas kernel, and the provider
-registry (`kernels.registry`, the reference's `.srtp.crypto.Aes`
-benchmark-and-pick pattern) can measure all three and keep the winner.
+The CPU's core (`kernels.aes.aes_encrypt_table`) takes a 256-entry S-box
+`jnp.take`, a gather per byte: the vector unit's worst case.  This
+module builds AES-128/256 encryption as a pure Boolean circuit —
+XOR/AND/slice/concat only, no gathers — and `kernels.aes.get_core`
+picks its composite-field form wherever the platform is not the CPU.
 
 Circuit construction is derived, not transcribed: the S-box is computed
 as ``affine(x^254)`` over GF(2^8), with the squaring/power linear maps
@@ -14,7 +12,8 @@ and the polynomial-reduction matrix generated from field arithmetic at
 import time and the complete 256-entry truth table asserted against an
 independently generated S-box.  Inversion uses the addition chain
 x -> x^2 -> x^3 -> x^12 -> x^15 -> x^240 -> x^252 -> x^254
-(4 variable GF multiplications; squarings are linear).
+(4 variable GF multiplications; squarings are linear), or the tower
+GF((2^4)^2) below.
 
 State layout: 8 bit-planes, each ``[B, 4, 4]`` (byte i = row + 4*col),
 LSB-first bit order.  ShiftRows is slice+concat per row; MixColumns is
@@ -22,8 +21,6 @@ xtime/XOR over row variables — nothing here indexes by data.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -132,7 +129,7 @@ def _sbox_bits(x, ones=1):
 
 # ------------------------------------------- tower-field S-box circuit
 #
-# Round-5: the addition-chain inversion above costs 4 GF(2^8)
+# The addition-chain inversion above costs 4 GF(2^8)
 # bitsliced multiplies (~860 gate-ops per byte).  The classic
 # composite-field decomposition GF(2^8) ~ GF((2^4)^2) does the same
 # inversion with 5 GF(2^4) multiplies (~250 gate-ops): map through a
@@ -309,19 +306,19 @@ def _xtime_bits(v):
             v[4], v[5], v[6]]
 
 
-def _shift_rows_bits(bits, cat):
+def _shift_rows_bits(bits):
     out = []
     for p in bits:
         rows = []
         for r in range(4):
             row = p[:, r:r + 1, :]
-            rows.append(cat([row[..., r:], row[..., :r]], -1)
+            rows.append(jnp.concatenate([row[..., r:], row[..., :r]], -1)
                         if r else row)
-        out.append(cat(rows, 1))
+        out.append(jnp.concatenate(rows, 1))
     return out
 
 
-def _mix_columns_bits(bits, stack):
+def _mix_columns_bits(bits):
     rows = [[p[:, r, :] for p in bits] for r in range(4)]
     new_rows = []
     for r in range(4):
@@ -330,24 +327,22 @@ def _mix_columns_bits(bits, stack):
         new_rows.append(_vxor(_vxor(_xtime_bits(a), _vxor(_xtime_bits(b),
                                                           b)),
                               _vxor(c, d)))
-    return [stack([new_rows[r][p] for r in range(4)], 1)
+    return [jnp.stack([new_rows[r][p] for r in range(4)], 1)
             for p in range(8)]
 
 
-def _rounds(bits, rk_bits, nr: int, cat, stack, ones=1,
-            sbox=None):
-    """The shared round schedule over bit-plane state (`sbox` picks
-    the inversion circuit: addition-chain `_sbox_bits` or the
-    composite-field `_sbox_bits_tower`)."""
-    sbox = sbox or _sbox_bits
+def _rounds(bits, rk_bits, nr: int, sbox, ones=1):
+    """The round schedule over bit-plane state (`sbox` is the inversion
+    circuit: addition-chain `_sbox_bits` or the composite-field
+    `_sbox_bits_tower`; `ones` as in `_linear`)."""
     bits = _vxor(bits, rk_bits[0])
     for r in range(1, nr):
         bits = sbox(bits, ones)
-        bits = _shift_rows_bits(bits, cat)
-        bits = _mix_columns_bits(bits, stack)
+        bits = _shift_rows_bits(bits)
+        bits = _mix_columns_bits(bits)
         bits = _vxor(bits, rk_bits[r])
     bits = sbox(bits, ones)
-    bits = _shift_rows_bits(bits, cat)
+    bits = _shift_rows_bits(bits)
     return _vxor(bits, rk_bits[nr])
 
 
@@ -366,56 +361,44 @@ def _from_planes(bits):
     return acc.transpose(0, 2, 1).reshape(-1, 16).astype(jnp.uint8)
 
 
-def _make_plane_provider(sbox):
-    """Build the (jitted flat fn, leading-dim-agnostic wrapper) pair
-    for one S-box circuit — the plane setup and the `_nd` reshape
-    contract ([..., R, 16] broadcast keys from the CTR/GCM call sites)
-    exist ONCE, shared by the addition-chain and tower providers."""
-
-    @jax.jit
-    def flat(round_keys, blocks):
-        rk = jnp.asarray(round_keys, dtype=jnp.uint8)
-        nr = rk.shape[-2] - 1
-        bits = _to_planes(jnp.asarray(blocks, dtype=jnp.uint8))
-        rk_bits = [_to_planes(rk[:, r, :]) for r in range(nr + 1)]
-        out = _rounds(bits, rk_bits, nr, jnp.concatenate, jnp.stack,
-                      sbox=sbox)
-        return _from_planes(out)
-
-    def nd(round_keys, blocks):
-        rk = jnp.asarray(round_keys, dtype=jnp.uint8)
-        blk = jnp.asarray(blocks, dtype=jnp.uint8)
-        lead = blk.shape[:-1]
-        out = flat(rk.reshape((-1,) + rk.shape[-2:]),
-                   blk.reshape(-1, 16))
-        return out.reshape(lead + (16,))
-
-    return flat, nd
-
-
-# Drop-in twins of `kernels.aes.aes_encrypt_table`, gather-free:
+# Drop-in twin of `kernels.aes.aes_encrypt_table`, gather-free:
 # round_keys [B, R, 16] uint8; blocks [B, 16] uint8 -> [B, 16].  The
-# `_nd` forms take leading-dim-agnostic ([..., R, 16]) arguments.
-# `tower` uses the composite-field S-box (5 GF(2^4) multiplies instead
-# of 4 GF(2^8) ones; fetch-verified ~1.6x on v5e).
-aes_encrypt_bitsliced, aes_encrypt_bitsliced_nd = \
-    _make_plane_provider(_sbox_bits)
-aes_encrypt_bitsliced_tower, aes_encrypt_bitsliced_tower_nd = \
-    _make_plane_provider(_sbox_bits_tower)
+# jitted function's own name, `flat`, is in the lowered text and in the
+# profiler's op paths of every program that holds it: leave it.
+@jax.jit
+def flat(round_keys, blocks):
+    rk = jnp.asarray(round_keys, dtype=jnp.uint8)
+    nr = rk.shape[-2] - 1
+    bits = _to_planes(jnp.asarray(blocks, dtype=jnp.uint8))
+    rk_bits = [_to_planes(rk[:, r, :]) for r in range(nr + 1)]
+    return _from_planes(_rounds(bits, rk_bits, nr, _sbox_bits_tower))
+
+
+aes_encrypt_bitsliced_tower = flat
+
+
+def aes_encrypt_bitsliced_tower_nd(round_keys, blocks):
+    """Leading-dim-agnostic form: [..., R, 16] broadcast keys, as the
+    CTR/GCM call sites pass them."""
+    rk = jnp.asarray(round_keys, dtype=jnp.uint8)
+    blk = jnp.asarray(blocks, dtype=jnp.uint8)
+    lead = blk.shape[:-1]
+    out = flat(rk.reshape((-1,) + rk.shape[-2:]), blk.reshape(-1, 16))
+    return out.reshape(lead + (16,))
 
 
 # ----------------------------------------------- packed-word XLA provider
 #
-# Round-5: the provider above stores ONE bit per uint8 element; this
-# one packs 32 BLOCKS per uint32 word (plane p, word (g, byte): bit k
-# = bit p of byte of block 32g + k), so every XOR/AND in the identical
+# The provider above stores ONE bit per uint8 element; this one packs
+# 32 BLOCKS per uint32 word (plane p, word (g, byte): bit k = bit p of
+# byte of block 32g + k), so every XOR/AND in the addition-chain
 # circuit processes 32 blocks at once.  Per-block keys pack the same
 # way, which keeps the per-packet-key SRTP contract (each lane bit
-# carries its own block's key bit).  Fetch-verified on the v5e the two
-# providers measured at PARITY (~10-12M blocks/s net — XLA:TPU handles
-# the u8 planes better than the classic bitslice intuition predicts),
-# so this stays a selectable provider for the registry/`set_core`
-# rather than the default; other TPU generations may rank differently.
+# carries its own block's key bit).  NOTHING SELECTS IT: it is kept,
+# with its parity test, because the v5e ran its chained rounds 8.5x
+# faster than the tower's at 57,344 blocks (PERF.md section 6, PR 29);
+# making it the chip's core is a `perf_opt` with a gain to show in
+# place, where the key planes are packed per launch (ROADMAP Q1.8).
 
 def _to_packed_planes(blocks):
     """[B, 16] uint8 (B % 32 == 0) -> 8 planes [B/32, 4, 4] uint32."""
@@ -441,7 +424,7 @@ def _from_packed_planes(bits):
 
 @jax.jit
 def aes_encrypt_bitsliced32(round_keys, blocks):
-    """Packed-word twin of `aes_encrypt_bitsliced` (32 blocks/word).
+    """Packed-word twin of `aes_encrypt_bitsliced_tower` (32 blocks/word).
 
     round_keys [B, R, 16] uint8; blocks [B, 16] uint8 -> [B, 16].
     Pads B up to a multiple of 32 internally (zero blocks/keys) and
@@ -460,145 +443,15 @@ def aes_encrypt_bitsliced32(round_keys, blocks):
     ones = jnp.uint32(0xFFFFFFFF)
     bits = _to_packed_planes(blk)
     rk_bits = [_to_packed_planes(rk[:, r, :]) for r in range(nr + 1)]
-    out = _rounds(bits, rk_bits, nr, jnp.concatenate, jnp.stack,
-                  ones=ones)
+    out = _rounds(bits, rk_bits, nr, _sbox_bits, ones)
     return _from_packed_planes(out)[:n]
 
 
 def aes_encrypt_bitsliced32_nd(round_keys, blocks):
-    """Leading-dim-agnostic wrapper (see aes_encrypt_bitsliced_nd)."""
+    """Leading-dim-agnostic wrapper (see aes_encrypt_bitsliced_tower_nd)."""
     rk = jnp.asarray(round_keys, dtype=jnp.uint8)
     blk = jnp.asarray(blocks, dtype=jnp.uint8)
     lead = blk.shape[:-1]
     out = aes_encrypt_bitsliced32(rk.reshape((-1,) + rk.shape[-2:]),
                                   blk.reshape(-1, 16))
     return out.reshape(lead + (16,))
-
-
-# ------------------------------------------------------------ Pallas provider
-#
-# The first Pallas twin (refused with a MosaicError on the chip) ran `reshape(-1, 4, 4).transpose(0, 2, 1)` on uint8 INSIDE the
-# kernel — minor-dim relayout + 8-bit shifts, exactly what Mosaic
-# declines to lower.  This version is lane-native instead: the batch
-# rides the 128-wide lane axis, each bit plane is a [4, 4, 128] int32
-# tile (row, col, lane), bit extraction/packing happens OUTSIDE the
-# kernel as plain XLA, and the kernel body is nothing but elementwise
-# XOR/AND plus static sublane slice+concat (ShiftRows) and stacks
-# (MixColumns) — no transpose, no gather, no sub-32-bit arithmetic.
-
-_LANES = 128
-
-
-def _shift_rows_tile(bits):
-    """[4, 4, L] planes: row r rolls left by r columns (axis 1)."""
-    out = []
-    for p in bits:
-        rows = []
-        for r in range(4):
-            row = p[r]                       # [4 cols, L]
-            if r:
-                row = jnp.concatenate([row[r:], row[:r]], axis=0)
-            rows.append(row)
-        out.append(jnp.stack(rows, axis=0))
-    return out
-
-
-def _mix_columns_tile(bits):
-    rows = [[p[r] for p in bits] for r in range(4)]   # [4 cols, L] each
-    new_rows = []
-    for r in range(4):
-        a, b = rows[r], rows[(r + 1) % 4]
-        c, d = rows[(r + 2) % 4], rows[(r + 3) % 4]
-        new_rows.append(_vxor(_vxor(_xtime_bits(a),
-                                    _vxor(_xtime_bits(b), b)),
-                              _vxor(c, d)))
-    return [jnp.stack([new_rows[r][p] for r in range(4)], axis=0)
-            for p in range(8)]
-
-
-def _pallas_kernel(bits_ref, rk_ref, out_ref, *, nr: int):
-    """Bit-plane tile in VMEM: bits [8, 4, 4, L], rk [(nr+1)*8, 4, 4, L]."""
-    bits = [bits_ref[p] for p in range(8)]
-    rk_bits = [[rk_ref[r * 8 + p] for p in range(8)]
-               for r in range(nr + 1)]
-    bits = _vxor(bits, rk_bits[0])
-    for r in range(1, nr):
-        bits = _sbox_bits(bits)
-        bits = _shift_rows_tile(bits)
-        bits = _mix_columns_tile(bits)
-        bits = _vxor(bits, rk_bits[r])
-    bits = _sbox_bits(bits)
-    bits = _shift_rows_tile(bits)
-    bits = _vxor(bits, rk_bits[nr])
-    for p in range(8):
-        out_ref[p] = bits[p]
-
-
-def _to_lane_planes(x16):
-    """[B, 16] uint8 -> [8, 4, 4, B] int32 bit planes (row, col, lane).
-
-    byte i = row + 4*col, same state layout as the XLA provider."""
-    y = x16.reshape(-1, 4, 4).transpose(2, 1, 0)      # [row, col, B]
-    return jnp.stack([((y >> p) & 1).astype(jnp.int32)
-                      for p in range(8)], axis=0)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def aes_encrypt_pallas_bitsliced(round_keys, blocks,
-                                 interpret: bool = False):
-    """Pallas twin of `aes_encrypt_bitsliced` (lane-native layout)."""
-    from jax.experimental import pallas as pl
-
-    rk = jnp.asarray(round_keys, dtype=jnp.uint8)
-    blocks = jnp.asarray(blocks, dtype=jnp.uint8)
-    nr = rk.shape[-2] - 1
-    b = blocks.shape[0]
-    pad = (-b) % _LANES
-    if pad:
-        blocks = jnp.pad(blocks, ((0, pad), (0, 0)))
-        rk = jnp.pad(rk, ((0, pad), (0, 0), (0, 0)))
-    bp = b + pad
-    bits = _to_lane_planes(blocks)                    # [8, 4, 4, BP]
-    rkb = _to_lane_planes(
-        rk.transpose(1, 0, 2).reshape(-1, 16)
-    ).reshape(8, 4, 4, nr + 1, bp)
-    # [(nr+1)*8, 4, 4, BP]: round-major so the kernel indexes r*8+p
-    rkb = rkb.transpose(3, 0, 1, 2, 4).reshape((nr + 1) * 8, 4, 4, bp)
-    out = pl.pallas_call(
-        functools.partial(_pallas_kernel, nr=nr),
-        grid=(bp // _LANES,),
-        in_specs=[
-            pl.BlockSpec((8, 4, 4, _LANES), lambda i: (0, 0, 0, i)),
-            pl.BlockSpec(((nr + 1) * 8, 4, 4, _LANES),
-                         lambda i: (0, 0, 0, i)),
-        ],
-        out_specs=pl.BlockSpec((8, 4, 4, _LANES),
-                               lambda i: (0, 0, 0, i)),
-        out_shape=jax.ShapeDtypeStruct((8, 4, 4, bp), jnp.int32),
-        interpret=interpret,
-    )(bits, rkb)
-    acc = out[0]
-    for p in range(1, 8):
-        acc = acc | (out[p] << p)
-    res = acc.astype(jnp.uint8).transpose(2, 1, 0).reshape(-1, 16)
-    return res[:b] if pad else res
-
-
-# ------------------------------------------------------------------ registry
-
-def register_providers() -> None:
-    from libjitsi_tpu.kernels import aes as aes_mod
-    from libjitsi_tpu.kernels import registry
-
-    registry.register("aes_encrypt", "xla_table", aes_mod.aes_encrypt)
-    registry.register("aes_encrypt", "xla_bitsliced",
-                      aes_encrypt_bitsliced)
-    registry.register("aes_encrypt", "xla_bitsliced_tower",
-                      aes_encrypt_bitsliced_tower)
-    registry.register("aes_encrypt", "xla_bitsliced32",
-                      aes_encrypt_bitsliced32)
-    registry.register("aes_encrypt", "pallas_bitsliced",
-                      aes_encrypt_pallas_bitsliced)
-
-
-register_providers()

@@ -1,56 +1,71 @@
-"""Gather-free bitsliced AES vs the table core.
+"""The gather-free tower AES vs the table core, and the rule that picks.
 
-The circuit is derived from GF(2^8) algebra at import (and the module
-asserts its full S-box truth table then); these tests pin the batched
-device paths: XLA bitsliced, Pallas interpret mode, the nd wrapper the
-CTR/GCM call sites use, and the `set_core` seam end-to-end through
+The circuit is derived from GF((2^4)^2) algebra at import (and the
+module asserts its full S-box truth table then); these tests pin the
+assembled cipher, the nd wrapper the CTR/GCM call sites use, `get_core`'s
+one input (the platform) and the `set_core` seam end-to-end through
 `srtp_protect`.
 """
+
+import contextlib
+import os
+import signal
+import subprocess
+import sys
 
 import numpy as np
 import pytest
 
 from libjitsi_tpu.kernels import aes
-from libjitsi_tpu.kernels.aes import aes_encrypt_table, expand_keys_batch
 from libjitsi_tpu.kernels.aes_bitsliced import (
-    aes_encrypt_bitsliced, aes_encrypt_bitsliced_nd,
-    aes_encrypt_pallas_bitsliced)
+    aes_encrypt_bitsliced_tower, aes_encrypt_bitsliced_tower_nd)
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-@pytest.mark.slow      # the Boolean-circuit HLO is big; cold CPU
-@pytest.mark.parametrize("key_len", [16, 32])   # compiles take minutes
-def test_bitsliced_matches_table(key_len):
-    rng = np.random.default_rng(1)
-    rks = expand_keys_batch(
-        rng.integers(0, 256, (24, key_len), dtype=np.uint8))
-    blocks = rng.integers(0, 256, (24, 16), dtype=np.uint8)
-    want = np.asarray(aes_encrypt_table(rks, blocks))
-    assert np.array_equal(np.asarray(aes_encrypt_bitsliced(rks, blocks)),
-                          want)
-    got_p = np.asarray(aes_encrypt_pallas_bitsliced(rks, blocks,
-                                                    interpret=True))
-    assert np.array_equal(got_p, want)
+@pytest.fixture
+def unpinned_core(monkeypatch):
+    """`get_core()` as a fresh process sees it: no `set_core` before."""
+    monkeypatch.setattr(aes, "_CORE_NAME", None)
 
 
-@pytest.mark.slow   # compile-heavy; sibling tests keep core coverage
-def test_bitsliced_nd_wrapper_broadcast_keys():
-    """The CTR path calls with [B, n, R, 16] broadcast keys."""
-    rng = np.random.default_rng(2)
-    rks = expand_keys_batch(rng.integers(0, 256, (6, 16), dtype=np.uint8))
-    rk4 = np.broadcast_to(rks[:, None], (6, 3, 11, 16))
-    blocks = rng.integers(0, 256, (6, 3, 16), dtype=np.uint8)
-    want = np.asarray(aes_encrypt_table(rk4, blocks))
-    got = np.asarray(aes_encrypt_bitsliced_nd(rk4, blocks))
-    assert np.array_equal(got, want)
+@pytest.mark.parametrize("backend,core", [("cpu", "table"),
+                                          ("tpu", "bitsliced_tower")])
+def test_get_core_by_platform(unpinned_core, monkeypatch, backend, core):
+    import jax
+
+    if backend == "cpu":
+        assert jax.default_backend() == "cpu"       # conftest's platform
+    else:
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert aes.get_core() == core
+
+
+def test_get_core_unmoved_by_the_old_environment_switch():
+    """`LIBJITSI_TPU_AES_CORE` was read at import: a process that
+    imports `kernels.aes` afresh under it still answers by platform."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               LIBJITSI_TPU_AES_CORE="bitsliced")
+    res = subprocess.run(
+        [sys.executable, "-c",
+         "from libjitsi_tpu.kernels import aes; print(aes.get_core())"],
+        capture_output=True, text=True, timeout=120, cwd=REPO, env=env)
+    assert res.returncode == 0, res.stderr[-500:]
+    assert res.stdout.split() == ["table"]
+
+
+@pytest.mark.parametrize("name", ["bitsliced", "pallas_bitsliced"])
+def test_set_core_refuses_a_deleted_name(unpinned_core, name):
+    with pytest.raises(ValueError, match="aes core must be one of"):
+        aes.set_core(name)
+    assert aes._CORE_NAME is None
 
 
 @pytest.mark.slow          # set_core clears jax caches -> recompiles
-def test_set_core_switches_srtp_protect_bit_identically():
-    from libjitsi_tpu.core.packet import PacketBatch
+def test_set_core_switches_srtp_protect_bit_identically(unpinned_core):
     from libjitsi_tpu.rtp import header as rtp_header
     from libjitsi_tpu.transform.srtp import SrtpStreamTable
 
-    rng = np.random.default_rng(3)
     mk, ms = bytes(range(16)), bytes(range(40, 54))
 
     def protect():
@@ -64,19 +79,12 @@ def test_set_core_switches_srtp_protect_bit_identically():
     assert aes.get_core() == "table"
     want = protect()
     try:
-        aes.set_core("bitsliced")
+        aes.set_core("bitsliced_tower")   # what an accelerator runs
         assert protect() == want
-        aes.set_core("bitsliced_tower")   # the TPU production default
+        aes.set_core("bitsliced32")       # kept, unselected (PR 29)
         assert protect() == want
     finally:
-        aes.set_core("table")
-
-
-def test_registry_lists_aes_providers():
-    from libjitsi_tpu.kernels import registry
-
-    assert set(registry.providers("aes_encrypt")) >= {
-        "xla_table", "xla_bitsliced", "pallas_bitsliced"}
+        aes.set_core("table")             # drop the tower's programs
 
 
 @pytest.mark.slow   # two fresh packed-circuit compiles (~1-2 min cold)
@@ -96,52 +104,70 @@ def test_bitsliced32_packed_words_bit_exact():
         assert np.array_equal(got, want), (n, kl)
 
 
-@pytest.mark.slow   # full tower-cipher compile, AES-128 + AES-256
-def test_bitsliced_tower_sbox_and_provider_bit_exact():
+@contextlib.contextmanager
+def _time_limit(seconds: int):
+    """Fail the case, not the run: the alarm is delivered as soon as the
+    compile it may be waiting in returns to Python."""
+    def over(_sig, _frm):
+        raise TimeoutError(f"over {seconds} s")
+    was = signal.signal(signal.SIGALRM, over)
+    signal.alarm(seconds)
+    try:
+        yield
+    finally:
+        signal.alarm(0)
+        signal.signal(signal.SIGALRM, was)
+
+
+# FIPS-197 appendix C.1
+_FIPS_KEY = bytes(range(16))
+_FIPS_PT = bytes.fromhex("00112233445566778899aabbccddeeff")
+_FIPS_CT = bytes.fromhex("69c4e0d86a7b0430d8cdb78070b4c55a")
+
+
+@pytest.mark.parametrize("n,key_lens,nd_wrapper,limit_s", [
+    (8, (16,), False, 90),     # tier 1: one AES-128 compile, ~7 s cold
+    pytest.param(48, (16, 32), True, 900, marks=pytest.mark.slow),
+])
+def test_bitsliced_tower_sbox_and_provider_bit_exact(n, key_lens,
+                                                     nd_wrapper, limit_s):
     """The composite-field (GF((2^4)^2)) provider must match the table
-    core bit for bit — AES-128 and AES-256 (the tower parameters and
-    basis-change matrices are derived+asserted at import; this pins the
-    full cipher)."""
+    core bit for bit (the tower parameters and basis-change matrices are
+    derived+asserted at import; this pins the full cipher), and both
+    the FIPS-197 vector in row 0."""
     rng = np.random.default_rng(5)
-    from libjitsi_tpu.kernels.aes_bitsliced import \
-        aes_encrypt_bitsliced_tower
+    with _time_limit(limit_s):
+        for kl in key_lens:
+            keys = rng.integers(0, 256, (n, kl), dtype=np.uint8)
+            blocks = rng.integers(0, 256, (n, 16), dtype=np.uint8)
+            if kl == 16:
+                keys[0] = np.frombuffer(_FIPS_KEY, dtype=np.uint8)
+                blocks[0] = np.frombuffer(_FIPS_PT, dtype=np.uint8)
+            rks = aes.expand_keys_batch(keys)
+            want = np.asarray(aes.aes_encrypt_table(rks, blocks))
+            got = np.asarray(aes_encrypt_bitsliced_tower(rks, blocks))
+            assert np.array_equal(got, want), (n, kl)
+            if kl == 16:
+                assert got[0].tobytes() == _FIPS_CT
+        if nd_wrapper:
+            # BROADCAST keys — the exact shape the CTR/GCM call sites
+            # feed the accelerator's dispatch
+            rks = aes.expand_keys_batch(
+                rng.integers(0, 256, (6, 16), dtype=np.uint8))
+            blocks = rng.integers(0, 256, (6, 3, 16), dtype=np.uint8)
+            rk_b = np.broadcast_to(rks[:, None], (6, 3, 11, 16))
+            want = np.asarray(aes.aes_encrypt_table(
+                rks[:, None].repeat(3, 1).reshape(-1, 11, 16),
+                blocks.reshape(-1, 16))).reshape(6, 3, 16)
+            got = np.asarray(aes_encrypt_bitsliced_tower_nd(rk_b, blocks))
+            assert np.array_equal(got, want)
 
-    for n, kl in ((48, 16), (48, 32)):
-        rks = aes.expand_keys_batch(
-            rng.integers(0, 256, (n, kl), dtype=np.uint8))
-        blocks = rng.integers(0, 256, (n, 16), dtype=np.uint8)
-        want = np.asarray(aes.aes_encrypt_table(rks, blocks))
-        got = np.asarray(aes_encrypt_bitsliced_tower(rks, blocks))
-        assert np.array_equal(got, want), (n, kl)
-    # the _nd wrapper with BROADCAST keys — the exact shape the
-    # CTR/GCM call sites feed the TPU default dispatch
-    from libjitsi_tpu.kernels.aes_bitsliced import \
-        aes_encrypt_bitsliced_tower_nd
 
-    rks = aes.expand_keys_batch(
-        rng.integers(0, 256, (6, 16), dtype=np.uint8))
-    blocks = rng.integers(0, 256, (6, 3, 16), dtype=np.uint8)
-    rk_b = np.broadcast_to(rks[:, None], (6, 3, 11, 16))
-    want = np.asarray(aes.aes_encrypt_table(
-        rks[:, None].repeat(3, 1).reshape(-1, 11, 16),
-        blocks.reshape(-1, 16))).reshape(6, 3, 16)
-    got = np.asarray(aes_encrypt_bitsliced_tower_nd(rk_b, blocks))
-    assert np.array_equal(got, want)
+def test_sbox_circuits_match_table_fast():
+    """Both S-box circuits (addition chain, composite field) over all
+    256 inputs, in plain numpy (no jit, no full-cipher compile): the
+    module's own import-time check, which raises where a circuit and
+    the table differ."""
+    from libjitsi_tpu.kernels.aes_bitsliced import _self_check
 
-
-def test_tower_sbox_circuit_matches_table_fast():
-    """Fast twin of the tower provider test: the composite-field S-box
-    circuit over all 256 inputs, evaluated in plain numpy (no jit, no
-    full-cipher compile).  The slow twin pins the assembled cipher."""
-    from libjitsi_tpu.kernels.aes import _SBOX
-    from libjitsi_tpu.kernels.aes_bitsliced import (_sbox_bits,
-                                                    _sbox_bits_tower)
-
-    xs = np.arange(256, dtype=np.uint8)
-    bits = [((xs >> p) & 1).astype(np.uint8) for p in range(8)]
-    for impl in (_sbox_bits, _sbox_bits_tower):
-        out = impl(bits)
-        got = np.zeros(256, dtype=np.uint16)
-        for p in range(8):
-            got |= out[p].astype(np.uint16) << p
-        assert np.array_equal(got.astype(np.uint8), _SBOX), impl.__name__
+    _self_check()

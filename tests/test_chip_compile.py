@@ -65,9 +65,9 @@ def no_persistent_cache():
 
 @pytest.fixture
 def tower_core():
-    """The core `get_core()` picks on the chip (no `tpu` record in
-    AES_CORES.json); on this CPU it would pick `table`.  Read at trace
-    time, so set around each lowering and restored."""
+    """The core `get_core()` picks off the CPU; on this CPU it picks
+    `table`.  Read at trace time, so set around each lowering and
+    restored."""
     was = aes_mod._CORE_NAME
     aes_mod.set_core("bitsliced_tower")
     yield
@@ -179,17 +179,6 @@ def test_mixer_xla_and_pallas(one_chip, no_persistent_cache, n, f):
     _mix_jit.lower(pcm, act).compile()
     c = mix_minus_pallas.lower(pcm, act, interpret=False).compile()
     assert "tpu_custom_call" in c.as_text()    # Mosaic took the kernel
-
-
-@pytest.mark.parametrize("blocks", [128, 16384])
-def test_pallas_bitsliced_aes(one_chip, no_persistent_cache, blocks):
-    from libjitsi_tpu.kernels.aes_bitsliced import \
-        aes_encrypt_pallas_bitsliced
-    s = _on(one_chip)
-    c = aes_encrypt_pallas_bitsliced.lower(
-        s((blocks, 11, 16), jnp.uint8), s((blocks, 16), jnp.uint8),
-        interpret=False).compile()
-    assert "tpu_custom_call" in c.as_text()
 
 
 def test_affinity_tick_four_chips(topo, no_persistent_cache, tower_core):

@@ -1083,9 +1083,9 @@ def test_fixed_zrtp_seq_wraps_mod16():
 
     ep = zrtp_mod.ZrtpEndpoint(ssrc=7)
     ep._seq = 0xFFFF
-    pkt = ep._send(b"\\x00" * 12)
+    pkt = ep._send(b"\x00" * 12)
     assert ep._seq == 0          # wrapped, not 65536
-    assert pkt[2:4] == b"\\x00\\x00"
+    assert pkt[2:4] == b"\x00\x00"
 
 
 def test_fixed_header_ext_is_lint_clean_and_lookup_survives_wrap():

@@ -129,7 +129,6 @@ def test_bench_emit_final_line_is_compact_and_parseable(tmp_path):
         "bench.EXTRA['estimators_pps'] = {'pipelined_median': 2.0e9}\n"
         "bench.RESULT['value'] = round(bench._roofline("
         "'headline', 2.0e9, 632.0, 'model'), 1)\n"
-        "bench._aes_consistency_check({'xla_table': 4.0e9})\n"
         "bench.emit()\n")
     import os
 
@@ -144,13 +143,11 @@ def test_bench_emit_final_line_is_compact_and_parseable(tmp_path):
     final = json.loads(lines[-1])            # last line parses
     assert len(lines[-1]) < 2000             # sized for a tail window
     assert final["metric"] == "srtp_protect_pps_at_10k_streams"
-    # roofline capped the impossible 2.0B to <= the HBM ceiling, then
-    # the AES-core cross-check bounded it further
+    # roofline capped the impossible 2.0B to <= the HBM ceiling
     assert final["value"] <= 819e9 / 632.0 + 1
     assert final["extra"]["headline_roofline"]["roofline_capped"]
     assert final["extra"]["device_kind"] == "TPU v5 lite"
     assert final["extra"]["hbm_gbps"] == 819.0
-    assert final["extra"]["consistency_vs_aes_core"]["ok"] is False
     # full record parses too (penultimate line)
     json.loads(lines[-2])
 
