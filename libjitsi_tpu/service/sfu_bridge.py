@@ -7,7 +7,7 @@ per-receiver send chain.  Here the whole SFU tick composes the dense
 pieces: one batched MediaLoop (unprotect every sender's packets in one
 launch), the `RtpTranslator` (decrypt-once / re-encrypt-per-leg in one
 fan-out launch — grouped GCM kernel on AEAD conferences), a
-`PacketCache` serving NACK retransmissions per leg, and
+`SlabCache` serving NACK retransmissions per leg, and
 `RtcpTermination` (feedback dedupe/aggregation, min-REMB).
 
 Endpoints both send and receive: `add_endpoint(ssrc, rx_key, tx_key)`
@@ -31,7 +31,7 @@ from libjitsi_tpu.rtp import ext as rtp_ext
 from libjitsi_tpu.rtp import header as rtp_header
 from libjitsi_tpu.rtp import rtcp
 from libjitsi_tpu.service.media_stream import StreamRegistry
-from libjitsi_tpu.sfu import PacketCache, RtpTranslator
+from libjitsi_tpu.sfu import PacketCache, RtpTranslator, SlabCache
 from libjitsi_tpu.sfu import rtx as rtx_mod
 from libjitsi_tpu.sfu.recovery import RecoveryConfig, RecoveryController
 from libjitsi_tpu.sfu.rtcp_termination import RtcpTermination
@@ -189,7 +189,7 @@ class SfuBridge:
             self.tx_table = SrtpStreamTable(capacity, profile)
             self.translator = RtpTranslator(capacity=capacity,
                                             profile=profile)
-        self.cache = PacketCache()
+        self.cache = SlabCache()
         self.rtcp_term = RtcpTermination(bridge_ssrc=0x5F0BFF)
         # end-to-end loss recovery (sfu/recovery.py): uplink gap
         # detection -> upstream NACKs, budgeted NACK service, adaptive
@@ -995,27 +995,31 @@ class SfuBridge:
                      origin=None) -> None:
         if wire.batch_size == 0:
             return
-        with self.loop.tracer.span("nack_cache", rows=wire.batch_size):
+        with self.loop.tracer.span("nack_cache",
+                                   rows=wire.batch_size) as sp:
             # a just-joined leg has no latched address yet: sending to
             # 0.0.0.0:0 would EINVAL out of sendmmsg and crash the tick
             ready = self.loop.addr_port[recv] != 0
-            if not ready.any():
-                return
-            rr = np.nonzero(ready)[0]
-            wire = PacketBatch(wire.data[rr],
-                               np.asarray(wire.length)[rr],
-                               wire.stream[rr])
-            recv = recv[rr]
+            copied = not ready.all()
+            if copied:
+                if not ready.any():
+                    return
+                rr = np.nonzero(ready)[0]
+                wire = PacketBatch(wire.data[rr],
+                                   np.asarray(wire.length)[rr],
+                                   wire.stream[rr])
+                recv = recv[rr]
             # cache each leg's protected copy for NACK service, keyed
             # by (leg sid, SENDER ssrc) + original seq — seq survives
             # the fan-out, and two senders' seq ranges must never
-            # collide in one leg's cache
-            hdr = rtp_header.parse(wire)
-            copies = [wire.to_bytes(i) for i in range(wire.batch_size)]
-            self.cache.insert_batch(
-                (recv.astype(np.int64) << 32)
-                | hdr.ssrc.astype(np.int64),
-                hdr.seq, copies, now=self._now)
+            # collide in one leg's cache.  The cache keeps the tick's
+            # wire plane as it came back from the device (or the
+            # filtered copy): nothing writes to either afterwards.
+            cache = self.cache
+            evicted = cache.insert_batch(wire.data, wire.length, recv,
+                                         now=self._now)
+            sp.note(slabs=cache.slabs, live_rows=len(cache),
+                    evicted_rows=evicted, copied=int(copied))
         with self.loop.tracer.span(
                 "egress", rows=wire.batch_size,
                 bytes=int(np.asarray(wire.length).sum())):
@@ -1031,9 +1035,11 @@ class SfuBridge:
         # injection surface.  One FEC stream per (leg, sender ssrc).
         if self.recovery.fec_active():
             fec_out, fec_addr = [], []
-            for j, pkt in enumerate(copies):
+            hdr = rtp_header.parse(wire)
+            for j in range(wire.batch_size):
                 fec = self.recovery.fec_protect(int(recv[j]),
-                                                int(hdr.ssrc[j]), pkt)
+                                                int(hdr.ssrc[j]),
+                                                wire.to_bytes(j))
                 if fec is not None:
                     fec_out.append(fec)
                     fec_addr.append(int(recv[j]))

@@ -814,6 +814,15 @@ class BridgeSupervisor:
         rec = getattr(self.bridge, "recovery", None)
         if rec is not None:
             rec.register_metrics(registry)
+        if hasattr(getattr(self.bridge, "cache", None), "slabs"):
+            registry.register_scalar(
+                "recovery_rtx_cache_slabs",
+                lambda: self.bridge.cache.slabs,
+                help_="fan-out batches live in the retransmission cache")
+            registry.register_scalar(
+                "recovery_rtx_cache_resident_bytes",
+                lambda: self.bridge.cache.resident_bytes,
+                help_="memory the retransmission cache's slabs pin")
         if self.slo is not None:
             self.slo.register_metrics(registry)
         bank = getattr(self.bridge, "bank", None)

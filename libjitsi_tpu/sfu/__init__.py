@@ -1,4 +1,4 @@
-from libjitsi_tpu.sfu.cache import PacketCache  # noqa: F401
+from libjitsi_tpu.sfu.cache import PacketCache, SlabCache  # noqa: F401
 from libjitsi_tpu.sfu.rtcp_termination import RtcpTermination  # noqa: F401
 from libjitsi_tpu.sfu.rtx import (RtxReceiver, RtxSender,  # noqa: F401
                                   decapsulate_batch, encapsulate_batch)
