@@ -135,6 +135,23 @@ def protect_oracle_gcm(mk: bytes, ms: bytes, pkt: bytes,
     return pkt[:off] + AESGCM(ke).encrypt(iv, pkt[off:], pkt[:off])
 
 
+def unprotect_oracle_gcm(mk: bytes, ms: bytes, wire: bytes, index: int):
+    """Inverse of `protect_oracle_gcm`; None when the tag does not
+    verify."""
+    from cryptography.exceptions import InvalidTag
+    from cryptography.hazmat.primitives.ciphers.aead import AESGCM
+
+    ke, ks = _kdf(mk, ms, 0, len(mk)), _kdf(mk, ms, 2, 12)
+    ssrc = int.from_bytes(wire[8:12], "big")
+    iv = (int.from_bytes(ks, "big") ^ (ssrc << 48) ^ index).to_bytes(
+        12, "big")
+    off = _payload_off(wire)
+    try:
+        return wire[:off] + AESGCM(ke).decrypt(iv, wire[off:], wire[:off])
+    except InvalidTag:
+        return None
+
+
 # -- pre-JAX set-up --------------------------------------------------------
 
 def build_native() -> None:

@@ -23,7 +23,8 @@ byte order would be the backend's to choose.
 Host side: `alloc` where the rows are copied anyway, `pack`, then one
 `jax.device_put`; `Launch.fetch` and `split_out`.  Inside the jitted program: `unpack`,
 `repack`.  The AES-CM unprotect (`transform/srtp/context.py`) and the
-AES-CM fan-out (`sfu/translator.py`) stage this way.
+AES-CM fan-out (`sfu/translator.py`) stage this way.  The GCM calls
+stage an array an argument (`put_each`) and count what crossed.
 """
 
 from __future__ import annotations
@@ -67,25 +68,39 @@ def split_out(host: np.ndarray, n_words: int
     return host[:, :w], words.view("<i4")
 
 
+def put_each(*arrays) -> Tuple[list, int, int]:
+    """An argument an array, for a call that does not pack: each host
+    array crosses as it stands (its dtype is settled on the host by the
+    caller, so no `convert_element_type` program runs for it).  Returns
+    (device arrays, how many crossed, their bytes)."""
+    host = [np.ascontiguousarray(a) for a in arrays]
+    return ([jax.device_put(a) for a in host], len(host),
+            sum(int(a.nbytes) for a in host))
+
+
 class Launch:
     """A device call in flight: what crossed to the device for it, and
     how its outputs come to the host.  `outs` are the program's outputs
-    as they stand on the device (one packed plane; a mesh seam's
-    deferred scatters); `split` turns their host copies into what the
-    caller reads.  `fetch` waits, copies each output once and caches;
-    `h2d_arrays` / `h2d_bytes` / `d2h_arrays` / `d2h_bytes` count the
-    arrays that really crossed."""
+    as they stand on the device (one packed plane; a GCM call's three
+    arrays; a mesh seam's deferred scatters); `split` turns their host
+    copies into what the caller reads.  `fetch` waits, copies each
+    output once and caches; `h2d_arrays` / `h2d_bytes` / `d2h_arrays` /
+    `d2h_bytes` count the arrays that really crossed.  `counts` is what
+    else the caller's span should book for the call (the GCM calls:
+    `gm_gather_bytes`, `grouped`)."""
 
     __slots__ = ("_outs", "_split", "_host", "h2d_arrays", "h2d_bytes",
-                 "d2h_arrays", "d2h_bytes")
+                 "d2h_arrays", "d2h_bytes", "counts")
 
     def __init__(self, outs, split: Optional[Callable] = None,
-                 h2d_arrays: int = 0, h2d_bytes: int = 0):
+                 h2d_arrays: int = 0, h2d_bytes: int = 0,
+                 counts: Optional[dict] = None):
         self._outs = tuple(outs)
         self._split = split
         self._host = None
         self.h2d_arrays, self.h2d_bytes = h2d_arrays, h2d_bytes
         self.d2h_arrays = self.d2h_bytes = 0
+        self.counts = counts or {}
 
     def block_until_ready(self) -> "Launch":
         if self._host is None:

@@ -23,6 +23,9 @@ import numpy as np
 
 _R = 0xE1 << 120  # reduction polynomial bits (11100001 || 0^120)
 
+#: bytes of one key's GHASH matrix as the tables hold it (int8 [128, 128])
+GM_BYTES = 128 * 128
+
 
 def gf_mult(x: int, y: int) -> int:
     """SP 800-38D §6.3 multiplication on 128-bit ints (b0 = MSB)."""

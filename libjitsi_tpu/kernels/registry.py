@@ -19,6 +19,14 @@ Benchmarking compiles and times every provider, so it must stay off the
 media path: latency-sensitive callers (the mixer tick) call `warmup()`
 with their real shapes at setup time, exactly when the reference runs
 its startup crypto benchmark.
+
+Users: `mix_minus` (`conference/mixer.py`) and the mesh table's
+`mesh_gcm_rtp_*` (`mesh/table.py`).  `_select` hands every provider the
+SAME arguments, so a provider may not donate a buffer, and a signature
+first seen in a tick is timed in that tick: a caller whose shapes vary
+from tick to tick does not belong here.  The single-chip table's GCM
+forms left for those two reasons and are picked by a rule of the shape
+(`transform/srtp/context.py:_gcm_form_grid`).
 """
 
 from __future__ import annotations

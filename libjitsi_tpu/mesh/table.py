@@ -30,8 +30,9 @@ Profile scope: ALL four cipher modes shard.  AES-CM /
 NULL ride the two-table seam; AES-F8's second key schedule is one more
 `[S, R, 16]` tensor on the same row partition; AES-GCM shards both its
 per-row form AND the grouped-GHASH form (per-device group grids —
-picked per shape by `kernels.registry` measurement, same doctrine as
-the single-chip table).  SRTCP runs sharded on the RTCP key tables —
+picked per shape by `kernels.registry` measurement; the single-chip
+table picks by a rule of the shape, `context._gcm_form_grid`, and this
+race is the four-chip cell's to settle).  SRTCP runs sharded on the RTCP key tables —
 control traffic must not silently hop to a single-chip path.
 """
 
@@ -324,9 +325,10 @@ class _MeshSeamToken:
                 and self.geom == other.geom)
 
 
-# Measured grouped-vs-per-row choice for the MESH table, mirroring the
-# single-chip registry pattern (context.py): both providers take the
-# full argument list; per_row ignores the grid machinery.  The seam
+# Measured grouped-vs-per-row choice for the MESH table (the
+# single-chip table left the registry: `context._gcm_form_grid`): both
+# providers take the full argument list; per_row ignores the grid
+# machinery.  The seam
 # token rides in the signature, so choices are per (geometry, batch
 # shape) — measured once per deployment geometry, shared by same-shape
 # tables (warmup's scratch table pins the live table's choice).
@@ -570,8 +572,8 @@ class ShardedSrtpTable(ShardedRowsMixin, SrtpStreamTable):
         """Sharded AEAD: BOTH forms shard — per-row (key schedule +
         GHASH matrix gathers chip-local) and grouped-GHASH (per-device
         group grids, `mesh_gcm_grid`); the winner is picked per shape
-        by registry measurement, exactly like the single-chip table
-        (never a hardcoded per-row choice)."""
+        by registry measurement (never a hardcoded per-row choice; the
+        single-chip table's rule is `context._gcm_form_grid`)."""
         off_const = _uniform_off(hdr.payload_off, batch.capacity)
         data, olen = _registry.call(
             "mesh_gcm_rtp_protect", self._token(),
@@ -580,14 +582,22 @@ class ShardedSrtpTable(ShardedRowsMixin, SrtpStreamTable):
             np.asarray(iv12), off_const)
         return data, olen.astype(np.int32)
 
-    def _gcm_rtp_unprotect_call(self, stream, batch, hdr, iv12, length):
+    def _gcm_rtp_unprotect_call(self, stream, batch, hdr, iv12, length
+                                ) -> staging.Launch:
+        """As the CM seam: what goes back is a `staging.Launch` holding
+        the three deferred scatters; five arrays are routed to their
+        owning chips."""
         off_const = _uniform_off(hdr.payload_off, batch.capacity)
+        stream = np.asarray(stream, dtype=np.int64)
+        length = np.asarray(length, dtype=np.int32)
+        iv12 = np.asarray(iv12)
         data, mlen, auth_ok = _registry.call(
-            "mesh_gcm_rtp_unprotect", self._token(),
-            np.asarray(stream, dtype=np.int64), batch.data,
-            np.asarray(length, dtype=np.int32), hdr.payload_off,
-            np.asarray(iv12), off_const)
-        return data, mlen.astype(np.int32), auth_ok
+            "mesh_gcm_rtp_unprotect", self._token(), stream, batch.data,
+            length, hdr.payload_off, iv12, off_const)
+        return staging.Launch(
+            (data, mlen.astype(np.int32), auth_ok), h2d_arrays=5,
+            h2d_bytes=batch.data.nbytes + length.nbytes + iv12.nbytes
+            + 8 * batch.batch_size)
 
     def _token(self) -> _MeshSeamToken:
         tok = getattr(self, "_seam_token", None)

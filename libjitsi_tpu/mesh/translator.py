@@ -78,17 +78,21 @@ class ShardedRtpTranslator(ShardedRowsMixin, RtpTranslator):
                 int(np.asarray(a).nbytes) for a in lanes))
 
     def _gcm_fanout_call(self, recv, data, length, payload_off, iv12,
-                         capacity):
+                         capacity) -> staging.Launch:
         from libjitsi_tpu.transform.srtp.context import _uniform_off
 
         fn = self._gcm_fanout_fn(_uniform_off(payload_off, capacity))
+        lanes = [data, np.asarray(length, dtype=np.int32), payload_off,
+                 iv12]
         out, out_len = self._sharded_launch(
-            fn, self._sharded_device(), recv,
-            [data, np.asarray(length, dtype=np.int32), payload_off,
-             iv12])
-        return out, out_len.astype(np.int32)
+            fn, self._sharded_device(), recv, lanes)
+        return staging.Launch(
+            (out, out_len.astype(np.int32)), h2d_arrays=1 + len(lanes),
+            h2d_bytes=4 * len(recv) + sum(
+                int(np.asarray(a).nbytes) for a in lanes))
 
-    def _gcm_uniform_fanout_call(self, rr, pdata, plen, iv, aad_const):
+    def _gcm_uniform_fanout_call(self, rr, pdata, plen, iv, aad_const
+                                 ) -> staging.Launch:
         """Leg-partitioned full-mesh AEAD fan-out from the DEVICE-
         RESIDENT row-partitioned tables: legs route to their owning
         chips via the same owner plan as every sharded seam — no host
@@ -104,7 +108,10 @@ class ShardedRtpTranslator(ShardedRowsMixin, RtpTranslator):
             extra_args=(np.asarray(pdata), plen32))
         # leg-major [G, P, W]; the output length is structural (AEAD
         # appends a 16B tag), so no second device output to scatter
-        return out, plen32 + 16
+        return staging.Launch(
+            (out, plen32 + 16), h2d_arrays=4,
+            h2d_bytes=4 * len(rr) + sum(
+                int(np.asarray(a).nbytes) for a in (iv, pdata, plen32)))
 
     def _gcm_uniform_fn(self, off_const):
         key = ("gcm_uniform_fanout", off_const)

@@ -1036,24 +1036,21 @@ class StreamLifecycleManager:
         control traffic (NACK/RR/SR ride the same zero-recompile
         discipline as media).
 
-        The RTP warm-up runs first and alone: for a GCM table it is
-        where the registry TIMES grouped against per-row and pins the
-        winner for the process, and a race decided while other
-        compiles load the host and other launches fill the device
-        queue would pin noise.  Nothing after it is timed — the
-        fan-out variants and the SRTCP pair share no program, so they
-        compile side by side in one pool."""
+        Nothing here is timed (the GCM form is a rule of the shape,
+        `context._gcm_form_grid`), so the RTP pair, the fan-out variants
+        and the SRTCP pair share no program and compile side by side
+        in one pool.  Within a pair rx goes before tx: the second table
+        finds the programs warm."""
         rx, tx = self.bridge.rx_table, self.bridge.tx_table
         plen = self.cfg.warm_payload_len
         tr = getattr(self.bridge, "translator", None)
-        if rtp:
-            rx.warmup_rtp(rc, payload_len=plen)
-            tx.warmup_rtp(rc, payload_len=plen)
         thunks = []
+        if rtp:
+            thunks.append(lambda: (rx.warmup_rtp(rc, payload_len=plen),
+                                   tx.warmup_rtp(rc, payload_len=plen)))
         if tr is not None and hasattr(tr, "fanout_warmups"):
             thunks += tr.fanout_warmups(rc, payload_len=plen)
         if hasattr(rx, "warmup_rtcp"):
-            # rx then tx: the second table finds the programs warm
             thunks.append(lambda: (rx.warmup_rtcp(rc),
                                    tx.warmup_rtcp(rc)))
         compile_concurrently(thunks)

@@ -28,11 +28,11 @@ import numpy as np
 
 from libjitsi_tpu.core import staging
 from libjitsi_tpu.core.packet import (CLASS_HEADROOM, DEFAULT_CAPACITY,
-                                      LENGTH_CLASSES, PacketBatch,
-                                      _round_rows)
+                                      LENGTH_CLASSES, ROW_CLASSES,
+                                      PacketBatch, _round_rows)
 from libjitsi_tpu.kernels import gcm as gcm_kernel
 from libjitsi_tpu.kernels.aes import aes_encrypt_np, expand_key
-from libjitsi_tpu.kernels.ghash import ghash_matrix
+from libjitsi_tpu.kernels.ghash import GM_BYTES, ghash_matrix
 from libjitsi_tpu.kernels.sha1 import hmac_precompute
 from libjitsi_tpu.rtp import header as rtp_header
 from libjitsi_tpu.transform.srtp import kernel
@@ -52,8 +52,23 @@ def _round_width(w: int) -> int:
     return w
 
 
-def _nbytes(*arrays) -> int:
-    return sum(int(a.nbytes) for a in arrays)
+#: receivers of one list from which the leg-major GCM form is used: a
+#: list that fills the smallest row class.  A shorter one is padded to
+#: it, and the (legs x packets) grid computes several times the rows
+#: of the per-row form (7 legs x 2 packets: 256 rows against 16)
+GCM_LEG_MAJOR_MIN_LEGS = ROW_CLASSES[0]
+
+
+def _gcm_leg_major(legs: int, packets: int) -> bool:
+    """Leg-major or per-row GCM fan-out for senders that share one
+    receiver list: a pure function of the shape, like
+    `context._gcm_form_grid`.  Leg-major reads a leg's 16 KiB matrix once
+    for all its packets but pads legs AND packets to their row
+    classes: it serves from `GCM_LEG_MAJOR_MIN_LEGS` legs, where that
+    grid is at most twice the per-row form's padded rows."""
+    return (legs >= GCM_LEG_MAJOR_MIN_LEGS and packets >= 2
+            and _round_rows(legs) * _round_rows(packets)
+            <= 2 * _round_rows(legs * packets))
 
 
 def _cycle_rows(n: int) -> Optional[np.ndarray]:
@@ -96,14 +111,14 @@ def _fanout_protect_gcm(tab_rk, tab_gm, recv, data, length, aad_len, iv12,
         data, length, aad_len, rk, gm, iv12, aad_const=aad_const)
 
 
-@jax.jit
-def _fanout_packet_major(out_gp, out_len_p):
-    """Leg-major [G, P, W] -> packet-major [P, G, W], lengths broadcast
-    to [P, G].  Runs at the class-PADDED shape so the flip compiles
-    once per class combo; the raw-shape crop is host-side numpy in
-    PendingTranslate.result()."""
-    out = jnp.transpose(out_gp, (1, 0, 2))
-    return out, jnp.broadcast_to(out_len_p[:, None], out.shape[:2])
+@functools.partial(jax.jit, static_argnames=("aad_const",))
+def _fanout_protect_gcm_legs(tab_rk, tab_gm, legs, data, length, iv12,
+                             aad_const: int):
+    """P packets sealed for G legs, leg-major out [G, P, W]: one matrix
+    gathered a LEG (`gcm_kernel.gcm_protect_fanout`)."""
+    rk, gm = kernel.gather_keys(legs, tab_rk, tab_gm)
+    return gcm_kernel.gcm_protect_fanout(data, length, rk, gm, iv12,
+                                         aad_const=aad_const)
 
 
 class RtpTranslator:
@@ -134,6 +149,9 @@ class RtpTranslator:
         self._dev = None
         # routing: sender sid -> sorted receiver id array
         self._routes: Dict[int, np.ndarray] = {}
+        # the longest receiver list ever connected: what `_gcm_leg_major`
+        # can select here, hence what `fanout_warmups` warms
+        self._max_legs = 0
         # the bridge hands its loop's PipelineTracer and PhaseProfiler
         # here; a translator standing alone spans and samples nothing
         self.tracer = None
@@ -243,8 +261,9 @@ class RtpTranslator:
     def connect(self, sender_sid: int, receiver_ids: Sequence[int]) -> None:
         """Declare that `sender_sid`'s media goes to these receivers
         (reference: the translator's willWrite acceptance per target)."""
-        self._routes[sender_sid] = np.unique(
+        rr = self._routes[sender_sid] = np.unique(
             np.asarray(receiver_ids, dtype=np.int64))
+        self._max_legs = max(self._max_legs, len(rr))
 
     def disconnect(self, sender_sid: int) -> None:
         self._routes.pop(sender_sid, None)
@@ -263,6 +282,11 @@ class RtpTranslator:
         20) plus the general mixed-offset entry.  Reads the live key
         tables (row 0, key material irrelevant); outputs are garbage
         and discarded.
+
+        Under GCM the per-row form always; the leg-major form only
+        where `_gcm_leg_major` can select it for this translator: some
+        connected receiver list reaches `GCM_LEG_MAJOR_MIN_LEGS` (a
+        bridge of conferences of 8 never does, and warms none of it).
 
         Widths: the data path clips the fan-out buffer to the tick's
         largest packet's LENGTH_CLASSES bucket, so this warms the class
@@ -283,14 +307,13 @@ class RtpTranslator:
         offs.append(mixed)
 
         def one(w: int, off) -> None:
-            # block on the output: compile NOW, off-tick
+            # fetch the output: compile NOW, off-tick
             if self._gcm:
                 data = np.zeros((rows, w), dtype=np.uint8)
                 data[:, 0] = 0x80
                 iv12 = np.zeros((rows, 12), dtype=np.uint8)
-                out, _ = self._gcm_fanout_call(recv, data, length,
-                                               off, iv12, w)
-                np.asarray(out)
+                self._gcm_fanout_call(recv, data, length, off, iv12,
+                                      w).fetch()
             else:
                 plane = staging.alloc(rows, w)
                 plane[:, 0] = 0x80
@@ -298,23 +321,20 @@ class RtpTranslator:
                 self._cm_fanout_call(recv, plane, length, off, iv,
                                      idx).fetch()
 
-        def grouped(w: int, aad: int) -> None:
-            # grouped full-mesh path: legs = this bucket, packets =
-            # the smallest row class (both axes class-padded live)
+        def leg_major(w: int, aad: int) -> None:
+            # legs = this bucket, packets = the smallest row class
+            # (both axes class-padded live)
             p = _round_rows(1)
             pdata = np.zeros((p, w), dtype=np.uint8)
             plen = np.full(p, 12 + payload_len, dtype=np.int32)
             iv = np.zeros((rows, p, 12), dtype=np.uint8)
-            out_gp, out_len_p = self._gcm_uniform_fanout_call(
-                recv, pdata, plen, iv, aad)
-            out_pm, _ = _fanout_packet_major(
-                jnp.asarray(out_gp), jnp.asarray(out_len_p))
-            np.asarray(out_pm)
+            self._gcm_uniform_fanout_call(recv, pdata, plen, iv,
+                                          aad).fetch()
 
         thunks = [functools.partial(one, w, off)
                   for w in widths for off in offs]
-        if self._gcm:
-            thunks += [functools.partial(grouped, w, aad)
+        if self._gcm and self._max_legs >= GCM_LEG_MAJOR_MIN_LEGS:
+            thunks += [functools.partial(leg_major, w, aad)
                        for w in widths for aad in (12, 20)]
         return thunks
 
@@ -388,10 +408,9 @@ class RtpTranslator:
 
         pg = None
         if self._gcm:
-            out, out_len, pg = self._translate_gcm(
+            launch, pg = self._translate_gcm(
                 batch, rows, recvs, src, recv, data, length,
                 hdr, payload_off, ssrc, idx)
-            launch = staging.Launch((out, out_len))
         else:
             with span_of(tracer, "fanout_dispatch") as sp, \
                     phase_of(self.perf, "dispatch"):
@@ -467,11 +486,16 @@ class RtpTranslator:
     def _translate_gcm(self, batch, rows, recvs, src, recv, data, length,
                        hdr, payload_off, ssrc, idx):
         """AEAD fan-out: per-leg H matrices replace HMAC midstates.
+        Returns (the `staging.Launch` in flight, (packets, legs) for
+        the leg-major grid or None for flat rows).
 
-        Full-mesh fast path: when every routed sender shares one
-        receiver list and headers are uniform, the (packets x legs)
-        matrix seals via `gcm_protect_fanout` — each leg's 16 KiB GHASH
-        matrix is read once per leg, not once per output row.
+        Leg-major path: when every routed sender shares one receiver
+        list, headers are uniform and `_gcm_leg_major` says so of the
+        (legs, packets) shape, the matrix seals via
+        `gcm_protect_fanout` — each leg's 16 KiB GHASH matrix is read
+        once per leg, not once per output row.  Everything else — every
+        tick of a bridge of small conferences, whose senders' lists
+        all differ — takes the per-row path.
         Reference: RTPTranslatorImpl's cipher-agnostic per-leg
         transform (SURVEY §3.4).
         """
@@ -483,17 +507,16 @@ class RtpTranslator:
         # at the receiving legs, not in our trace).  The mesh translator
         # overrides the `_gcm_uniform_fanout_call` seam below with the
         # legs partitioned over chips — parity-tested both ways.
-        uniform = (len(recvs) > 1 and
+        uniform = (_gcm_leg_major(len(recvs[0]), len(recvs)) and
                    all(len(r) == len(recvs[0]) and np.array_equal(
                        r, recvs[0]) for r in recvs[1:])
-                   and off0.size and np.all(off0 == off0[0])
+                   and np.all(off0 == off0[0])
                    and 0 <= int(off0[0]) < batch.capacity)
         if uniform:
             with span_of(tracer, "expand") as sp:
                 rr = recvs[0]
                 p_rows = np.asarray(rows, dtype=np.int64)
-                pidx = np.asarray(idx).reshape(len(rows), len(rr))[:, 0] \
-                    if len(rr) else np.zeros(0, np.int64)
+                pidx = np.asarray(idx).reshape(len(rows), len(rr))[:, 0]
                 # class-pad BOTH grouped axes (legs and packets,
                 # cycled) plus the data width: churn varies the leg
                 # count every tick, and raw (G, P) shapes would retrace
@@ -522,23 +545,18 @@ class RtpTranslator:
                     pssrc[None, :], pidx[None, :])
                 sp.note(rows=g_real * p_real,
                         rows_padded=len(rr_p) * len(pr), width=pw)
-            with span_of(tracer, "fanout_dispatch",
-                         h2d_bytes=_nbytes(pdata, plen, iv)
-                         + 4 * len(rr_p)), \
+            with span_of(tracer, "fanout_dispatch") as sp, \
                     phase_of(perf, "dispatch"):
-                out_gp, out_len_p = self._gcm_uniform_fanout_call(
+                # the output is leg-major [G, P, W] at the class-PADDED
+                # shape; cropping to the raw (P, G) and the flip to
+                # packet-major rows (p0r0, p0r1, ...) matching
+                # `src`/`recv` are numpy work at result() time
+                launch = self._gcm_uniform_fanout_call(
                     rr_p, pdata, plen, iv, int(off0[0]))
-                # grouped output is leg-major [G, P, W]; the contract
-                # is packet-major rows (p0r0, p0r1, ...) matching
-                # `src`/`recv`.  The flip stays jitted at the
-                # class-PADDED shape (one compile per class combo);
-                # cropping to the raw (P, G) is numpy work at result()
-                # time — eager device slices here compiled per raw
-                # shape, which churn varies every tick.
-                out_pm, len_pm = _fanout_packet_major(
-                    jnp.asarray(out_gp), jnp.asarray(out_len_p))
-            return out_pm, len_pm, (p_real, g_real)
-        with span_of(tracer, "expand", rows=len(recv)) as sp:
+                sp.note(h2d_arrays=launch.h2d_arrays,
+                        h2d_bytes=launch.h2d_bytes, **launch.counts)
+            return launch, (p_real, g_real)
+        with span_of(tracer, "expand") as sp:
             rr_idx = _cycle_rows(len(recv))
             if rr_idx is None:
                 rr_idx = np.arange(len(recv))
@@ -552,39 +570,53 @@ class RtpTranslator:
             iv = gcm_kernel.srtp_gcm_iv(self._salt[recv[rr_idx]],
                                         ssrc[rr_idx], idx[rr_idx])
             plen, poff = length[rr_idx], payload_off[rr_idx]
-            sp.note(rows_padded=len(rr_idx), width=pw)
-        with span_of(tracer, "fanout_dispatch",
-                     h2d_bytes=_nbytes(pdata, plen, poff, iv)
-                     + 4 * len(rr_idx)), \
+            sp.note(rows=len(recv), rows_padded=len(rr_idx), width=pw)
+        with span_of(tracer, "fanout_dispatch") as sp, \
                 phase_of(perf, "dispatch"):
-            out, out_len = self._gcm_fanout_call(
+            launch = self._gcm_fanout_call(
                 recv[rr_idx], pdata, plen, poff, iv, pdata.shape[-1])
-        return out, out_len, None
+            sp.note(h2d_arrays=launch.h2d_arrays,
+                    h2d_bytes=launch.h2d_bytes, **launch.counts)
+        return launch, None
 
-    def _gcm_uniform_fanout_call(self, rr, pdata, plen, iv, aad_const):
-        """Full-mesh per-LEG-matrix fan-out device call: P packets
-        sealed for G legs, one GHASH matrix read per LEG — the mesh
-        translator overrides this seam with the legs partitioned over
-        chips.  Returns leg-major (out [G, P, W], out_len [P])."""
+    def _gcm_uniform_fanout_call(self, rr, pdata, plen, iv, aad_const
+                                 ) -> staging.Launch:
+        """Leg-major fan-out device call: P packets sealed for G legs,
+        one GHASH matrix read per LEG — the mesh translator overrides
+        this seam with the legs partitioned over chips.  Returns the
+        `staging.Launch` in flight, whose `fetch()` gives host
+        (leg-major out [G, P, W], out_len [P])."""
         tab_rk, tab_gm = self._device()
-        return gcm_kernel.gcm_protect_fanout(
-            jnp.asarray(pdata), jnp.asarray(plen),
-            tab_rk[jnp.asarray(rr)], tab_gm[jnp.asarray(rr)],
-            jnp.asarray(iv), aad_const=aad_const)
+        dev, n, nbytes = staging.put_each(
+            np.asarray(rr, dtype=np.int32), pdata,
+            np.asarray(plen, dtype=np.int32), iv)
+        return staging.Launch(
+            _fanout_protect_gcm_legs(tab_rk, tab_gm, *dev,
+                                     aad_const=aad_const),
+            h2d_arrays=n, h2d_bytes=nbytes,
+            counts={"gm_gather_bytes": len(rr) * GM_BYTES, "grouped": 1})
 
     def _gcm_fanout_call(self, recv, data, length, payload_off, iv12,
-                         capacity):
+                         capacity) -> staging.Launch:
         """Per-row AEAD fan-out device call — the mesh translator
         overrides exactly this seam (leg-sharded, chip-local matrix
-        gathers)."""
+        gathers).  An array an argument crosses (`staging.put_each`);
+        returns the `staging.Launch` in flight, whose `fetch()` gives
+        host (wire bytes, wire lengths)."""
         from libjitsi_tpu.transform.srtp.context import _uniform_off
 
         tab_rk, tab_gm = self._device()
-        return _fanout_protect_gcm(
-            tab_rk, tab_gm, jnp.asarray(recv, dtype=jnp.int32),
-            jnp.asarray(data), jnp.asarray(length),
-            jnp.asarray(payload_off), jnp.asarray(iv12),
-            aad_const=_uniform_off(payload_off, capacity))
+        dev, n, nbytes = staging.put_each(
+            np.asarray(recv, dtype=np.int32), data,
+            np.asarray(length, dtype=np.int32),
+            np.asarray(payload_off, dtype=np.int32), iv12)
+        return staging.Launch(
+            _fanout_protect_gcm(tab_rk, tab_gm, *dev,
+                                aad_const=_uniform_off(payload_off,
+                                                       capacity)),
+            h2d_arrays=n, h2d_bytes=nbytes,
+            counts={"gm_gather_bytes": len(recv) * GM_BYTES,
+                    "grouped": 0})
 
 
 class PendingTranslate:
@@ -604,8 +636,8 @@ class PendingTranslate:
         self._capacity = capacity
         self._tracer = tracer
         self._perf = perf
-        # (p_real, g_real) when `out` is the uniform fan-out's padded
-        # packet-major grid [P_pad, G_pad, W]; None for flat rows
+        # (p_real, g_real) when the launch is the leg-major fan-out's
+        # padded grid [G_pad, P_pad, W]; None for flat rows
         self._pg = pg
         self._done: "Tuple[PacketBatch, np.ndarray] | None" = None
 
@@ -634,12 +666,14 @@ class PendingTranslate:
             sp.note(d2h_arrays=launch.d2h_arrays,
                     d2h_bytes=launch.d2h_bytes)
             if self._pg is not None:
-                # crop the padded (P, G) grid to the real counts and
-                # flatten packet-major — numpy on the materialized
-                # buffer, so no per-raw-shape device programs
+                # crop the padded leg-major (G, P) grid to the real
+                # counts and flatten packet-major — numpy on the
+                # materialized buffer, so no per-raw-shape device
+                # programs
                 p, g = self._pg
-                arr = arr[:p, :g].reshape(p * g, arr.shape[-1])
-                lens = lens[:p, :g].reshape(-1)
+                arr = arr[:g, :p].transpose(1, 0, 2).reshape(
+                    p * g, arr.shape[-1])
+                lens = np.repeat(lens[:p], g)
             else:
                 # drop the class-padding rows (cycled copies appended
                 # by translate_async to keep the fan-out shapes on the

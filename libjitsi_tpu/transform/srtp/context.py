@@ -30,8 +30,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from libjitsi_tpu.core import staging
-from libjitsi_tpu.core.packet import (PacketBatch, _round_rows,
-                                      bucket_by_size, unbucket)
+from libjitsi_tpu.core.packet import (ROW_CLASSES, PacketBatch,
+                                      _round_rows, bucket_by_size,
+                                      unbucket)
 from libjitsi_tpu.core.rtp_math import (
     _segments,
     chain_packet_indices,
@@ -41,7 +42,8 @@ from libjitsi_tpu.core.rtp_math import (
 from libjitsi_tpu.kernels import gcm as gcm_kernel
 from libjitsi_tpu.kernels.aes import (aes_encrypt_np, expand_key,
                                       expand_keys_batch, f8_m)
-from libjitsi_tpu.kernels.ghash import ghash_matrix, ghash_matrix_batch
+from libjitsi_tpu.kernels.ghash import (GM_BYTES, ghash_matrix,
+                                        ghash_matrix_batch)
 from libjitsi_tpu.kernels.sha1 import hmac_precompute, hmac_precompute_batch
 from libjitsi_tpu.rtp import header as rtp_header
 from libjitsi_tpu.transform.srtp import kernel, replay
@@ -136,6 +138,12 @@ def _split_unprotect(host):
     auth_ok)."""
     data, words = staging.split_out(host, 2)
     return data, words[:, 0], words[:, 1].astype(bool)
+
+
+def _split_unpacked(data, mlen, auth_ok):
+    """An unpacked unprotect's three arrays back on the host -> (data,
+    media_len, auth_ok)."""
+    return data, mlen.astype(np.int32), auth_ok
 
 
 def _uniform_off(payload_off, width: int) -> "int | None":
@@ -237,12 +245,8 @@ def _gcm_grid(stream: np.ndarray):
     int64, inv_pos [B] int32), with G and P rounded up to powers of two
     so jit shapes stay cacheable — or None when the grouped path is
     structurally unusable (stream skew so heavy the padded grid would
-    more than double the GHASH work).  When a grid exists, grouped vs
-    per-row is decided by MEASUREMENT per shape signature via
-    kernels.registry (the crossover moves with batch size and
-    backend — a hardcoded constant was wrong in both directions), with
-    the usual
-    `kernels.provider.gcm_rtp_*` config override for determinism.
+    more than double the GHASH work).  Whether a grid that exists is
+    USED is `_gcm_form_grid`'s to say, from the call's shape alone.
     """
     n = len(stream)
     if n < 8:      # dispatch-dominated: nothing to win, skip the grid
@@ -266,46 +270,36 @@ def _gcm_grid(stream: np.ndarray):
     return grid, ustream, inv
 
 
-# Measured grouped-vs-per-row choice (reference pattern: crypto.Aes
-# benches providers and installs the fastest).  Both providers take the
-# grouped path's full argument list; per_row simply ignores the grid.
-# First sight of a shape signature times both (one extra compile, off
-# the steady state); `registry.force`/config pins for determinism.
-
-def _gcm_rtp_protect_grouped(tab_rk, tab_gm, stream, data, length, off,
-                             iv12, grid, us, inv, aad_const):
-    return _protect_gcm_grouped_dev(tab_rk, tab_gm, stream, data,
-                                    length, off, iv12, grid, us, inv,
-                                    aad_const=aad_const)
+#: padded rows of one call above which the grouped GHASH form is used:
+#: the largest row class a served table meets.  On the v5e the two
+#: forms are within 13 % of each other in every class up to it, and at
+#: the grids a live tick yields grouped is level or 7-9 % slower
+#: (`scripts/gcm_forms_bench.py`; PERF.md, Findings, PR 31)
+GCM_GROUPED_ABOVE_ROWS = ROW_CLASSES[-1]
 
 
-def _gcm_rtp_protect_per_row(tab_rk, tab_gm, stream, data, length, off,
-                             iv12, grid, us, inv, aad_const):
-    return _protect_gcm_dev(tab_rk, tab_gm, stream, data, length, off,
-                            iv12, aad_const=aad_const)
+def _gcm_form_grid(stream: np.ndarray):
+    """Per-row or grouped GHASH for one GCM RTP call, as the grid to
+    run it with: `_gcm_grid(stream)` for the grouped form, None for the
+    per-row one.  A pure function of the call's shape — the padded rows
+    and whether a grid exists — so equal shapes take equal forms,
+    nothing is timed, and a warm-up that drives a shape warms the
+    program a live tick of that shape runs.
 
-
-def _gcm_rtp_unprotect_grouped(tab_rk, tab_gm, stream, data, length,
-                               off, iv12, grid, us, inv, aad_const):
-    return _unprotect_gcm_grouped_dev(tab_rk, tab_gm, stream, data,
-                                      length, off, iv12, grid, us, inv,
-                                      aad_const=aad_const)
-
-
-def _gcm_rtp_unprotect_per_row(tab_rk, tab_gm, stream, data, length,
-                               off, iv12, grid, us, inv, aad_const):
-    return _unprotect_gcm_dev_call(tab_rk, tab_gm, stream, data, length,
-                                   off, iv12, aad_const=aad_const)
-
-
-from libjitsi_tpu.kernels import registry as _registry  # noqa: E402
-
-_registry.register("gcm_rtp_protect", "grouped", _gcm_rtp_protect_grouped)
-_registry.register("gcm_rtp_protect", "per_row", _gcm_rtp_protect_per_row)
-_registry.register("gcm_rtp_unprotect", "grouped",
-                   _gcm_rtp_unprotect_grouped)
-_registry.register("gcm_rtp_unprotect", "per_row",
-                   _gcm_rtp_unprotect_per_row)
+    Grouped reads each stream's 16 KiB matrix once for the stream's P
+    rows; per-row gathers a matrix a row.  Inside the row classes
+    (`ROW_CLASSES`, all a served table launches) per-row it is: one
+    program a class, whatever the streams of a tick are, where a grid's
+    (G, P) changes with them — `bucket_by_size` pads a part by cycling
+    its rows, which yields grids of 2 or 4 rows a stream and up to
+    twice the part's rows, and there grouped computes more than it
+    saves; the grid (a sort of the rows) is not even built.  Above them
+    — a bulk caller's batch, where per-row gathers 268 MB of matrices
+    for 16,384 rows — grouped, if a grid exists.  Neither the grid's
+    (G, P) nor the width moved the choice on the v5e."""
+    if len(stream) <= GCM_GROUPED_ABOVE_ROWS:
+        return None
+    return _gcm_grid(stream)
 
 
 # --- keystream-cache fast path (transform/srtp/keystream.py) ---------------
@@ -347,71 +341,6 @@ def _unprotect_gcm_cached_grouped_dev(ks_tab, ek_tab, slot, tab_gm,
     return gcm_kernel.gcm_unprotect_cached_grouped(
         data, length, ks_tab[slot], ek_tab[slot], tab_gm[ustream],
         grid_rows, inv_pos, aad_const=aad_const, packed=packed)
-
-
-# Grouped vs per-row vs grouped_packed on the cached path is measured
-# per shape signature like the stock GCM seams — the crossover is not
-# transferable from the stock measurement because the cached kernels
-# carry no AES stage.  "grouped_packed" swaps the GHASH matvec from the
-# int8 MXU matmul to packed-word AND/popcount (kernels/ghash.py): same
-# bits, opposite hardware affinity, so the registry's first-hot-call
-# race decides per backend instead of a comment.
-
-def _gcm_cached_protect_grouped(ks_tab, ek_tab, slot, tab_gm, stream,
-                                data, length, grid, us, inv, aad_const):
-    return _protect_gcm_cached_grouped_dev(
-        ks_tab, ek_tab, slot, tab_gm, stream, data, length, grid, us,
-        inv, aad_const=aad_const)
-
-
-def _gcm_cached_protect_grouped_packed(ks_tab, ek_tab, slot, tab_gm,
-                                       stream, data, length, grid, us,
-                                       inv, aad_const):
-    return _protect_gcm_cached_grouped_dev(
-        ks_tab, ek_tab, slot, tab_gm, stream, data, length, grid, us,
-        inv, aad_const=aad_const, packed=True)
-
-
-def _gcm_cached_protect_per_row(ks_tab, ek_tab, slot, tab_gm, stream,
-                                data, length, grid, us, inv, aad_const):
-    return _protect_gcm_cached_dev(ks_tab, ek_tab, slot, tab_gm, stream,
-                                   data, length, aad_const=aad_const)
-
-
-def _gcm_cached_unprotect_grouped(ks_tab, ek_tab, slot, tab_gm, stream,
-                                  data, length, grid, us, inv, aad_const):
-    return _unprotect_gcm_cached_grouped_dev(
-        ks_tab, ek_tab, slot, tab_gm, stream, data, length, grid, us,
-        inv, aad_const=aad_const)
-
-
-def _gcm_cached_unprotect_grouped_packed(ks_tab, ek_tab, slot, tab_gm,
-                                         stream, data, length, grid, us,
-                                         inv, aad_const):
-    return _unprotect_gcm_cached_grouped_dev(
-        ks_tab, ek_tab, slot, tab_gm, stream, data, length, grid, us,
-        inv, aad_const=aad_const, packed=True)
-
-
-def _gcm_cached_unprotect_per_row(ks_tab, ek_tab, slot, tab_gm, stream,
-                                  data, length, grid, us, inv, aad_const):
-    return _unprotect_gcm_cached_dev(ks_tab, ek_tab, slot, tab_gm,
-                                     stream, data, length,
-                                     aad_const=aad_const)
-
-
-_registry.register("gcm_rtp_protect_cached", "grouped",
-                   _gcm_cached_protect_grouped)
-_registry.register("gcm_rtp_protect_cached", "grouped_packed",
-                   _gcm_cached_protect_grouped_packed)
-_registry.register("gcm_rtp_protect_cached", "per_row",
-                   _gcm_cached_protect_per_row)
-_registry.register("gcm_rtp_unprotect_cached", "grouped",
-                   _gcm_cached_unprotect_grouped)
-_registry.register("gcm_rtp_unprotect_cached", "grouped_packed",
-                   _gcm_cached_unprotect_grouped_packed)
-_registry.register("gcm_rtp_unprotect_cached", "per_row",
-                   _gcm_cached_unprotect_per_row)
 
 
 class SrtpStreamTable:
@@ -724,13 +653,12 @@ class SrtpStreamTable:
     def warmup_rtp(self, batch_size: int, packets_per_stream: int = 4,
                    payload_len: int = 160) -> None:
         """Pre-compile the RTP protect/unprotect programs for the given
-        batch shape — and, for GCM, run the registry's grouped/per-row
-        measurement — OFF the media path (registry discipline: the
-        first sight of a shape otherwise times both providers inside a
-        live tick).  Runs on a THROWAWAY table of the same shape so the
-        real table's tx indices and replay windows are untouched; jit
-        caches and registry pins are process-global, so the real path
-        hits them warm."""
+        batch shape, OFF the media path.  For GCM the batch drives the
+        form that `_gcm_form_grid` picks for its shape, which is the form
+        a live tick of that shape runs: nothing is timed here.  Runs on
+        a THROWAWAY table of the same shape so the real table's tx
+        indices and replay windows are untouched; jit caches are
+        process-global, so the real path hits them warm."""
         scratch = SrtpStreamTable(self.capacity, self.profile)
         n = max(1, min(self.capacity,
                        batch_size // max(packets_per_stream, 1)))
@@ -1199,54 +1127,62 @@ class SrtpStreamTable:
         np.maximum.at(self.tx_ext, stream, idx)
         return data, length, batch.stream
 
+    def _gcm_stage(self, stream, batch, hdr, iv12, length):
+        """The stock GCM RTP calls' arguments on the device, an array
+        an argument, in the form `_gcm_form_grid` picks for the call's
+        shape.  Returns (grouped, device arguments behind the two key
+        tables, what `staging.Launch` books for them: the arrays that
+        crossed, their bytes, and the span's counts `gm_gather_bytes` —
+        a 16 KiB GHASH matrix a padded row, or a group of the grid —
+        and `grouped`)."""
+        grid = _gcm_form_grid(stream)
+        host = [np.asarray(stream, dtype=np.int32), batch.data,
+                np.asarray(length, dtype=np.int32),
+                np.asarray(hdr.payload_off, dtype=np.int32), iv12]
+        groups = len(stream)
+        if grid is not None:
+            gr, us, inv = grid
+            host += [gr, us.astype(np.int32), inv]
+            groups = len(us)
+        dev, n, nbytes = staging.put_each(*host)
+        return grid is not None, dev, {
+            "h2d_arrays": n, "h2d_bytes": nbytes,
+            "counts": {"gm_gather_bytes": groups * GM_BYTES,
+                       "grouped": int(grid is not None)}}
+
     def _gcm_rtp_protect_call(self, stream, batch, hdr, iv12):
         """AEAD-GCM RTP protect device call — like the CM seam, the
         mesh table overrides exactly this (per-row form, row-local);
-        single-chip picks grouped vs per-row by registry measurement."""
+        single-chip takes grouped or per-row as `_gcm_form_grid` says of
+        the call's shape."""
         aad_const = _uniform_off(hdr.payload_off, batch.capacity)
         tab_rk, tab_gm, _, _ = self._device()
-        grid = _gcm_grid(stream)
-        if grid is not None:
-            gr, us, inv = grid
-            # grouped vs per-row: measured per shape signature
-            return _registry.call(
-                "gcm_rtp_protect", tab_rk, tab_gm,
-                jnp.asarray(stream, dtype=jnp.int32),
-                jnp.asarray(batch.data), jnp.asarray(batch.length),
-                jnp.asarray(hdr.payload_off), jnp.asarray(iv12),
-                jnp.asarray(gr), jnp.asarray(us, dtype=jnp.int32),
-                jnp.asarray(inv), aad_const)
-        # skew: the padded grid is structurally wasteful
-        return _protect_gcm_dev(
-            tab_rk, tab_gm, jnp.asarray(stream, dtype=jnp.int32),
-            jnp.asarray(batch.data), jnp.asarray(batch.length),
-            jnp.asarray(hdr.payload_off), jnp.asarray(iv12),
-            aad_const=aad_const)
+        grouped, dev, _ = self._gcm_stage(stream, batch, hdr, iv12,
+                                          batch.length)
+        fn = _protect_gcm_grouped_dev if grouped else _protect_gcm_dev
+        return fn(tab_rk, tab_gm, *dev, aad_const=aad_const)
 
-    def _gcm_rtp_unprotect_call(self, stream, batch, hdr, iv12, length):
-        """AEAD-GCM RTP unprotect seam; returns (data, media_len,
-        auth_ok) — see _gcm_rtp_protect_call."""
+    def _gcm_rtp_unprotect_call(self, stream, batch, hdr, iv12, length
+                                ) -> staging.Launch:
+        """AEAD-GCM RTP unprotect seam — see `_gcm_rtp_protect_call`.
+        The staged packet bytes are donated (off the CPU) to the ONE
+        program the shape selects.  Returns the `staging.Launch` in
+        flight, whose `fetch()` gives host (data, media_len, auth_ok)
+        and whose counts are of the arrays that crossed."""
         aad_const = _uniform_off(hdr.payload_off, batch.capacity)
         tab_rk, tab_gm, _, _ = self._device()
-        grid = _gcm_grid(stream)
-        if grid is not None:
-            gr, us, inv = grid
-            return _registry.call(
-                "gcm_rtp_unprotect", tab_rk, tab_gm,
-                jnp.asarray(stream, dtype=jnp.int32),
-                jnp.asarray(batch.data), jnp.asarray(length),
-                jnp.asarray(hdr.payload_off), jnp.asarray(iv12),
-                jnp.asarray(gr), jnp.asarray(us, dtype=jnp.int32),
-                jnp.asarray(inv), aad_const)
-        return _unprotect_gcm_dev_call(
-            tab_rk, tab_gm, jnp.asarray(stream, dtype=jnp.int32),
-            jnp.asarray(batch.data), jnp.asarray(length),
-            jnp.asarray(hdr.payload_off), jnp.asarray(iv12),
-            aad_const=aad_const)
+        grouped, dev, staged = self._gcm_stage(stream, batch, hdr, iv12,
+                                               length)
+        fn = (_unprotect_gcm_grouped_dev if grouped
+              else _unprotect_gcm_dev_call)
+        return staging.Launch(
+            fn(tab_rk, tab_gm, *dev, aad_const=aad_const),
+            _split_unpacked, **staged)
 
     def _gcm_grid_dev(self, stream):
         """(stream_dev, grid_dev-or-None) for this batch's stream
-        pattern, memoized by the pattern bytes.  Purely positional —
+        pattern, memoized by the pattern bytes; the grid is None where
+        `_gcm_form_grid` picks the per-row form.  Purely positional —
         the grid groups row indices by equal stream values — so rekey /
         forget / move never invalidate it; only a different batch
         composition does, and those are rare tick-over-tick.  The memo
@@ -1256,7 +1192,7 @@ class SrtpStreamTable:
         hit = self._grid_memo.get(pat)
         if hit is None:
             sdev = jnp.asarray(stream, dtype=jnp.int32)
-            grid = _gcm_grid(stream)
+            grid = _gcm_form_grid(stream)
             if grid is not None:
                 gr, us, inv = grid
                 grid = (jnp.asarray(gr), jnp.asarray(us, dtype=jnp.int32),
@@ -1285,11 +1221,10 @@ class SrtpStreamTable:
         sdev, grid = self._gcm_grid_dev(stream)
         if grid is not None:
             gr, us, inv = grid
-            return _registry.call(
-                "gcm_rtp_protect_cached", ks_tab, ek_tab,
-                jnp.asarray(slot), tab_gm, sdev,
+            return _protect_gcm_cached_grouped_dev(
+                ks_tab, ek_tab, jnp.asarray(slot), tab_gm, sdev,
                 jnp.asarray(batch.data), jnp.asarray(batch.length),
-                gr, us, inv, aad_const)
+                gr, us, inv, aad_const=aad_const)
         return _protect_gcm_cached_dev(
             ks_tab, ek_tab, jnp.asarray(slot), tab_gm, sdev,
             jnp.asarray(batch.data), jnp.asarray(batch.length),
@@ -1313,11 +1248,10 @@ class SrtpStreamTable:
         sdev, grid = self._gcm_grid_dev(stream)
         if grid is not None:
             gr, us, inv = grid
-            return _registry.call(
-                "gcm_rtp_unprotect_cached", ks_tab, ek_tab,
-                jnp.asarray(slot), tab_gm, sdev,
+            return _unprotect_gcm_cached_grouped_dev(
+                ks_tab, ek_tab, jnp.asarray(slot), tab_gm, sdev,
                 jnp.asarray(batch.data), jnp.asarray(length),
-                gr, us, inv, aad_const)
+                gr, us, inv, aad_const=aad_const)
         return _unprotect_gcm_cached_dev(
             ks_tab, ek_tab, jnp.asarray(slot), tab_gm, sdev,
             jnp.asarray(batch.data), jnp.asarray(length),
@@ -1389,14 +1323,13 @@ class SrtpStreamTable:
                               h2d_bytes=plane.nbytes)
 
     def _unpacked_launch(self, out, batch, length, iv) -> staging.Launch:
-        """A GCM / F8 call's (data, media_len, auth_ok) behind the CM
-        seam's face: those calls stage data, lengths and IVs as they
-        are and stream, payload offset and ROC as a word a row each."""
-        data, mlen, auth_ok = out
+        """An F8 call's or a keystream-cache hit's (data, media_len,
+        auth_ok) behind the CM seam's face: those calls stage data,
+        lengths and IVs as they are and stream, payload offset and ROC
+        as a word a row each, booked by that formula (the stock GCM
+        call counts its arrays: `_gcm_rtp_unprotect_call`)."""
         return staging.Launch(
-            (data, mlen, auth_ok),
-            lambda d, m, a: (d, m.astype(np.int32), a),
-            h2d_arrays=6,
+            out, _split_unpacked, h2d_arrays=6,
             h2d_bytes=batch.data.nbytes + length.nbytes
             + (0 if iv is None else iv.nbytes) + 12 * batch.batch_size)
 
@@ -1530,13 +1463,13 @@ class SrtpStreamTable:
             out = (None if self._ks_cache is None
                    else self._gcm_rtp_unprotect_cached(stream, batch,
                                                        hdr, idx, length))
-            iv12 = None
             if out is None:
-                iv12 = self._gcm_rtp_iv(self._salt_rtp[stream],
-                                        hdr.ssrc, idx)
-                out = self._gcm_rtp_unprotect_call(stream, batch, hdr,
-                                                   iv12, length)
-            launch = self._unpacked_launch(out, batch, length, iv12)
+                launch = self._gcm_rtp_unprotect_call(
+                    stream, batch, hdr,
+                    self._gcm_rtp_iv(self._salt_rtp[stream], hdr.ssrc,
+                                     idx), length)
+            else:
+                launch = self._unpacked_launch(out, batch, length, None)
         elif self._f8:
             iv = self._f8_rtp_iv(hdr, v)
             launch = self._unpacked_launch(
@@ -1596,9 +1529,10 @@ class SrtpStreamTable:
                     if iv is None:
                         iv = self._gcm_rtp_iv(self._salt_rtp[stream],
                                               hdr.ssrc, idx)
-                    out = self._gcm_rtp_unprotect_call(stream, batch, hdr,
-                                                       iv, length)
-                launch = self._unpacked_launch(out, batch, length, iv)
+                    launch = self._gcm_rtp_unprotect_call(
+                        stream, batch, hdr, iv, length)
+                else:
+                    launch = self._unpacked_launch(out, batch, length, iv)
             elif self._f8:
                 launch = self._unpacked_launch(
                     self._f8_rtp_unprotect_call(stream, batch, hdr, iv, v,
@@ -1610,7 +1544,7 @@ class SrtpStreamTable:
             sp.note(h2d_arrays=launch.h2d_arrays,
                     h2d_bytes=launch.h2d_bytes,
                     d2h_arrays=launch.d2h_arrays,
-                    d2h_bytes=launch.d2h_bytes)
+                    d2h_bytes=launch.d2h_bytes, **launch.counts)
 
         with span_of(tracer, "unprotect_host"):
             srow = np.clip(stream, 0, self.capacity - 1)

@@ -11,6 +11,7 @@ slow one.
 
 from __future__ import annotations
 
+import ctypes
 import os
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Iterable, Optional
@@ -71,22 +72,48 @@ def compile_stats() -> CompileCacheStats:
     return _STATS
 
 
+#: compiles side by side at most.  HOST-SPECIFIC: sized for the 40 GiB
+#: host of the one-chip v5e machine and not derived from the memory of
+#: the host it runs on.  There the compiler holds 2.4-4.6 GB of host
+#: memory for one served program (the 4,096-row class, measured PR 31);
+#: a GCM rung of eight at once peaked at 38.5 GB beside a bridge of
+#: 10,240 endpoints and was killed, six peak at 33.6 GB and the ladder
+#: takes no longer.  A host with less memory a chip needs fewer
+#: (roughly: what the bridge leaves free / 4.6 GB); one with more
+#: gains nothing past its cores
+MAX_COMPILE_WORKERS = 6
+
+
 def compile_concurrently(thunks: Iterable[Callable[[], None]]) -> None:
     """Run independent warm-up thunks on one thread pool, at most one
-    per core, and wait for all.
+    per core and `MAX_COMPILE_WORKERS` in all, and wait for all.
 
     Each thunk's time is an XLA compile, which releases the GIL, so the
     programs of one warm-up rung compile side by side instead of one
-    after another.  Nothing that is TIMED belongs in here (see
-    `StreamLifecycleManager._warm_class`).  The first exception
-    propagates once every thunk has finished."""
+    after another.  Nothing that is TIMED belongs in here.  The first
+    exception propagates once every thunk has finished."""
     thunks = list(thunks)
     if not thunks:
         return
-    workers = min(len(thunks), os.cpu_count() or 1)
-    with ThreadPoolExecutor(max_workers=workers) as pool:
-        for fut in [pool.submit(t) for t in thunks]:
-            fut.result()
+    workers = min(len(thunks), os.cpu_count() or 1, MAX_COMPILE_WORKERS)
+    try:
+        with ThreadPoolExecutor(max_workers=workers) as pool:
+            for fut in [pool.submit(t) for t in thunks]:
+                fut.result()
+    finally:
+        _trim_heap()
+
+
+def _trim_heap() -> None:
+    """Hand the compile threads' freed memory back to the system.  A
+    TPU compile of one served program peaks at 2.4-4.6 GB of host
+    memory, a rung runs eight side by side, and glibc leaves what they
+    free in their threads' arenas: the process then carries a rung's
+    peak for good, next to everything admission allocates after it."""
+    try:
+        ctypes.CDLL("libc.so.6").malloc_trim(0)
+    except (OSError, AttributeError):    # another libc: nothing to trim
+        pass
 
 
 def enable_compile_cache() -> str:

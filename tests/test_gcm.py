@@ -214,56 +214,6 @@ def test_gcm_snapshot_restore():
     assert ok2.all()
 
 
-def test_gcm_grouped_table_path_matches_per_row():
-    """the grouped-GHASH table path (one matrix read per
-    stream per launch) must be bit-identical to the per-row path on a
-    mixed-stream batch, and round-trip through a grouped unprotect.
-    Paths are pinned via the kernels registry (the measured-choice
-    mechanism), not a batch-size constant."""
-    from libjitsi_tpu.kernels import registry
-    from libjitsi_tpu.transform.srtp import context as ctx_mod
-
-    n_streams, per = 8, 40                 # 320 rows >= grouping floor
-    rng = np.random.default_rng(5)
-    streams = np.repeat(np.arange(n_streams), per)
-    rng.shuffle(streams)
-    seqs = np.zeros(len(streams), np.int64)
-    for s in range(n_streams):
-        rows = np.nonzero(streams == s)[0]
-        seqs[rows] = 100 + np.arange(len(rows))
-    pls = [bytes(rng.integers(0, 256, int(rng.integers(8, 60)),
-                              dtype=np.uint8).tobytes())
-           for _ in streams]
-    b = rtp_header.build(pls, list(seqs), [0] * len(streams),
-                         [0x1000 + int(s) for s in streams],
-                         [96] * len(streams), stream=list(streams))
-
-    grid = ctx_mod._gcm_grid(np.asarray(streams, np.int64))
-    assert grid is not None, "uniform batch must form a grouped grid"
-
-    try:
-        registry.force("gcm_rtp_protect", "grouped")
-        tx_g = make_gcm_table(n_streams)
-        wire_g = tx_g.protect_rtp(b)
-        registry.force("gcm_rtp_protect", "per_row")
-        tx_r = make_gcm_table(n_streams)
-        wire_r = tx_r.protect_rtp(b)
-        assert np.asarray(wire_g.length).tolist() == \
-            np.asarray(wire_r.length).tolist()
-        for i in range(wire_g.batch_size):
-            assert wire_g.to_bytes(i) == wire_r.to_bytes(i), i
-        # grouped unprotect round-trips
-        registry.force("gcm_rtp_unprotect", "grouped")
-        rx = make_gcm_table(n_streams)
-        dec, ok = rx.unprotect_rtp(wire_g)
-        assert ok.all()
-        for i in range(b.batch_size):
-            assert dec.to_bytes(i) == b.to_bytes(i), i
-    finally:
-        registry.force("gcm_rtp_protect", None)
-        registry.force("gcm_rtp_unprotect", None)
-
-
 def test_gcm_grid_skew_falls_back():
     from libjitsi_tpu.transform.srtp import context as ctx_mod
 
@@ -273,7 +223,8 @@ def test_gcm_grid_skew_falls_back():
     assert ctx_mod._gcm_grid(streams) is None
     # all-distinct-streams batches skip the grid (grouped ≡ per-row
     # there); beyond these structural floors the grouped/per-row choice
-    # is the registry's measured pick, not a size constant
+    # is `_gcm_form_grid`'s, a rule of the shape (tests/test_gcm_served.py
+    # holds it, and both forms against the reference)
     assert ctx_mod._gcm_grid(np.arange(8, dtype=np.int64)) is None
     assert ctx_mod._gcm_grid(
         np.repeat(np.arange(4, dtype=np.int64), 4)) is not None

@@ -1,0 +1,424 @@
+"""The served bridge under AEAD_AES_128_GCM against the scalar RFC 7714
+reference (OpenSSL's AESGCM, `chip_smoke.py:protect_oracle_gcm` and the
+unprotect beside it), and the rule that picks the GCM form.
+
+The bridge is assembled as `benchmarks/sut.py` assembles it —
+`SfuBridge` under `BridgeSupervisor` and `StreamLifecycleManager`, every
+endpoint through `request_join`, ticked by the supervisor — at a size
+the CPU holds: 64 endpoints in 8 conferences of 8.  Clients are plain
+UDP sockets that protect and open under the reference alone.  One
+module fixture drives the traffic once, with the registry's
+`_time_once` patched to raise (nothing the bridge warms or serves may
+time providers); the tests each hold one facet of its record.
+"""
+
+import importlib.util
+import os
+import socket
+
+import numpy as np
+import pytest
+
+import libjitsi_tpu
+from libjitsi_tpu.core.packet import ROW_CLASSES, _round_rows
+from libjitsi_tpu.kernels import registry
+from libjitsi_tpu.rtp import header as rtp_header
+from libjitsi_tpu.sfu import translator as tr_mod
+from libjitsi_tpu.transform.srtp import SrtpProfile
+from libjitsi_tpu.transform.srtp import context as ctx
+from libjitsi_tpu.transform.srtp.context import SrtpStreamTable
+from libjitsi_tpu.utils.compile_cache import compile_stats
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+GCM = SrtpProfile.AEAD_AES_128_GCM
+SSRC_BASE = 0x51000000
+ROWS, CONF = 64, 8
+PT = 111
+
+
+@pytest.fixture(scope="module")
+def oracle():
+    """chip_smoke.py, loaded as tests/test_chip_smoke.py loads it."""
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(_ROOT, "chip_smoke.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _plain(rng, ssrc: int, seq: int) -> bytes:
+    hdr = (bytes([0x80, PT]) + seq.to_bytes(2, "big")
+           + (seq * 960 & 0xFFFFFFFF).to_bytes(4, "big")
+           + ssrc.to_bytes(4, "big"))
+    return hdr + rng.integers(0, 256, int(rng.integers(40, 161)),
+                              dtype=np.uint8).tobytes()
+
+
+def _keys(seed: int, n: int) -> np.ndarray:
+    """[n, 2] (client->bridge, bridge->client) of (key 16, salt 12)."""
+    return np.random.default_rng([seed, 0x6B]).integers(
+        0, 256, (n, 2, 28), dtype=np.uint8)
+
+
+def _pair(raw) -> tuple:
+    b = bytes(raw)
+    return b[:16], b[16:]
+
+
+@pytest.fixture(scope="module")
+def served(oracle):
+    from libjitsi_tpu.service import lifecycle as lifecycle_mod
+    from libjitsi_tpu.service import supervisor as supervisor_mod
+    from libjitsi_tpu.service.sfu_bridge import SfuBridge
+
+    mp = pytest.MonkeyPatch()
+
+    def no_timing(*_a, **_k):
+        raise AssertionError("kernels.registry._time_once was called")
+
+    mp.setattr(registry, "_time_once", no_timing)
+    libjitsi_tpu.stop()
+    libjitsi_tpu.init()
+    bridge = SfuBridge(libjitsi_tpu.configuration_service(), port=0,
+                       capacity=ROWS, profile=GCM, recv_window_ms=0)
+    reg = bridge.loop.metrics
+    sup = supervisor_mod.BridgeSupervisor(
+        bridge, supervisor_mod.SupervisorConfig(deadline_ms=60_000.0),
+        metrics=reg)
+    lc = lifecycle_mod.StreamLifecycleManager(
+        bridge, supervisor=sup,
+        config=lifecycle_mod.LifecycleConfig(install_batch=64,
+                                             max_pending=512),
+        metrics=reg)
+    lc.enable_placement(1)
+    keys = _keys(31, ROWS)
+    now = [1000.0]
+
+    socks = []
+    rec = {"sent": {}, "got": [], "replayed": set(), "forms": [],
+           "counts": {}}
+
+    def tick(n=1):
+        for _ in range(n):
+            now[0] += 0.02
+            sup.tick(now=now[0])
+            # the tick's span counts (drained a tick), summed
+            for stage, counts in sup.last_counts.items():
+                mine = rec["counts"].setdefault(stage, {})
+                for k, v in counts.items():
+                    mine[k] = mine.get(k, 0) + v
+            if "unprotect_wait" in sup.last_counts:
+                rec["forms"].append(
+                    (sup.last_counts["unprotect_wait"].get("grouped"),
+                     sup.last_counts.get("fanout_dispatch", {}).get(
+                         "grouped")))
+    try:
+        for i in range(ROWS):
+            ok, why = lc.request_join(SSRC_BASE + i, _pair(keys[i, 0]),
+                                      _pair(keys[i, 1]),
+                                      conference=i // CONF)
+            assert ok, why
+        while lc.admits < ROWS:
+            tick()
+            assert sup.ticks < 64, f"{lc.admits}/{ROWS} live"
+        rec["after_ladder"] = (lc.datapath_recompiles,
+                               compile_stats().compile_events)
+        for i in range(ROWS):
+            s = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+            s.bind(("127.0.0.1", 0))
+            s.setblocking(False)
+            socks.append(s)
+        rng = np.random.default_rng(32)
+        seq = np.full(ROWS, 200, dtype=np.int64)
+
+        def send(i, wire):
+            socks[i].sendto(wire, ("127.0.0.1", bridge.port))
+
+        def fresh(i):
+            s = int(seq[i])
+            seq[i] += 1
+            plain = _plain(rng, SSRC_BASE + i, s)
+            rec["sent"][(SSRC_BASE + i, s)] = plain
+            return oracle.protect_oracle_gcm(*_pair(keys[i, 0]), plain, s)
+
+        def drain(into):
+            for r, s in enumerate(socks):
+                while True:
+                    try:
+                        pkt = s.recv(2048)
+                    except BlockingIOError:
+                        break
+                    if len(pkt) >= 12 and (pkt[1] & 0x7F) == PT:
+                        into.append((r, pkt))
+
+        # latch every address: what these first packets reach is not
+        # judged (a leg without an address yet gets nothing)
+        for i in range(ROWS):
+            send(i, fresh(i))
+        tick(3)
+        drain([])
+        for k in list(rec["sent"]):
+            del rec["sent"][k]
+        events0 = compile_stats().compile_events
+        wires = {}
+        # 20 served rounds: a seeded set of senders, one or two packets
+        # each, so that rows, width and grid change from tick to tick
+        for _round in range(20):
+            n_tx = int(rng.integers(3, 28))
+            for i in rng.choice(ROWS, n_tx, replace=False):
+                for _ in range(int(rng.integers(1, 3))):
+                    w = fresh(int(i))
+                    wires[(int(i), int(seq[i]) - 1)] = w
+                    send(int(i), w)
+            tick(2)
+            drain(rec["got"])
+        # replay: packets the bridge has already forwarded, sent again
+        again = []
+        for (i, s), w in list(wires.items())[:12]:
+            rec["replayed"].add((SSRC_BASE + i, s))
+            send(i, w)
+        tick(3)
+        drain(again)
+        rec["replay_deliveries"] = again
+        rec["at_end"] = (lc.datapath_recompiles,
+                         compile_stats().compile_events - events0)
+        rec["keys"] = keys
+        rec["health"] = sup.health()
+        yield rec
+    finally:
+        mp.undo()
+        for s in socks:
+            s.close()
+        bridge.close()
+        libjitsi_tpu.stop()
+
+
+def _sender_seq(pkt: bytes):
+    return (int.from_bytes(pkt[8:12], "big"),
+            int.from_bytes(pkt[2:4], "big"))
+
+
+def test_served_deliveries_open_under_the_receivers_own_key(served,
+                                                            oracle):
+    """Every delivery opens with the scalar reference under the
+    RECEIVER's own bridge->client key to the sender's plaintext: fixed
+    header past the X bit (the bridge stamps abs-send-time) and the
+    whole payload."""
+    assert len(served["got"]) > 1000
+    for r, wire in served["got"]:
+        ssrc, seq = _sender_seq(wire)
+        plain = oracle.unprotect_oracle_gcm(
+            *_pair(served["keys"][r, 1]), wire, seq)
+        assert plain is not None, (r, hex(ssrc), seq)
+        sent = served["sent"][(ssrc, seq)]
+        off = oracle._payload_off(plain)
+        assert plain[1:12] == sent[1:12] and plain[off:] == sent[12:]
+        assert plain[0] & 0xEF == sent[0]
+        # and under no other endpoint's key
+        other = (r + 1) % ROWS
+        assert oracle.unprotect_oracle_gcm(
+            *_pair(served["keys"][other, 1]), wire, seq) is None
+
+
+def test_served_deliveries_stay_in_their_conference(served):
+    """Every packet reaches exactly the 7 others of its conference:
+    none leaves it, none returns to its sender, none arrives twice."""
+    reached = {}
+    for r, wire in served["got"]:
+        ssrc, seq = _sender_seq(wire)
+        tx = ssrc - SSRC_BASE
+        assert tx // CONF == r // CONF and tx != r
+        reached.setdefault((ssrc, seq), []).append(r)
+    assert set(reached) == set(served["sent"])
+    for (ssrc, _seq), rs in reached.items():
+        tx = ssrc - SSRC_BASE
+        conf = range(tx // CONF * CONF, (tx // CONF + 1) * CONF)
+        assert sorted(rs) == [r for r in conf if r != tx]
+
+
+def test_served_replay_is_not_forwarded(served):
+    assert served["replayed"]
+    assert served["replay_deliveries"] == []
+    assert served["counts"]["unprotect_wait"]["rows"] > 0
+
+
+def test_served_zero_datapath_recompiles_after_the_ladder(served):
+    """Nothing compiles on the data path: the ladder warmed the one
+    form the rule can select at every shape the rounds drove."""
+    assert served["after_ladder"][0] == 0
+    assert served["at_end"] == (0, 0)
+    h = served["health"]
+    assert not h["shed"] and not h["quarantined"] and not h["level"]
+
+
+def test_served_path_never_times_providers(served):
+    """The ladder and 20 served ticks ran with `_time_once` raising; the
+    single-chip GCM ops are not in the timed registry at all; and every
+    served launch took the per-row form (all inside the row classes)."""
+    assert not [op for op in registry.report() if op.startswith("gcm_")]
+    assert len(served["forms"]) >= 20
+    assert all(f in ((0, 0), (0, None)) for f in served["forms"])
+    c = served["counts"]
+    assert c["unprotect_wait"]["gm_gather_bytes"] \
+        == c["unprotect_wait"]["rows_padded"] * ctx.GM_BYTES
+    assert c["fanout_dispatch"]["gm_gather_bytes"] \
+        == c["expand"]["rows_padded"] * tr_mod.GM_BYTES
+    assert c["expand"]["rows"] <= c["expand"]["rows_padded"]
+    # summed over the launches: five arrays in each, three / two back
+    u, f = c["unprotect_wait"], c["fanout_dispatch"]
+    assert u["h2d_arrays"] % 5 == 0 and f["h2d_arrays"] % 5 == 0
+    assert u["d2h_arrays"] * 5 == u["h2d_arrays"] * 3
+    assert c["fanout_d2h"]["d2h_arrays"] * 5 == f["h2d_arrays"] * 2
+    assert u["h2d_bytes"] == u["rows_padded"] * (4 + 224 + 4 + 4 + 12)
+
+
+# ------------------------------------------------------------- the rule
+
+def _streams(rows: int, per: int, seed: int) -> np.ndarray:
+    rng = np.random.default_rng(seed)
+    s = np.repeat(rng.choice(1 << 20, rows // per, replace=False), per)
+    rng.shuffle(s)
+    return s.astype(np.int64)
+
+
+@pytest.mark.parametrize("rows", ROW_CLASSES + (8192, 16384))
+@pytest.mark.parametrize("per", (2, 4, 16))
+def test_equal_shapes_give_equal_forms(rows, per):
+    """The form is a function of the shape alone: two batches of one
+    shape and different streams take the same form; inside the row
+    classes that is per-row, above them grouped."""
+    a, b = _streams(rows, per, 1), _streams(rows, per, 2)
+    ga, gb = ctx._gcm_form_grid(a), ctx._gcm_form_grid(b)
+    assert (ga is None) == (gb is None) == (rows <= ROW_CLASSES[-1])
+    if ga is not None:
+        assert ga[0].shape == gb[0].shape == ctx._gcm_grid(a)[0].shape
+    # no grid, no grouped form, whatever the rows
+    assert ctx._gcm_form_grid(np.arange(rows, dtype=np.int64)) is None
+
+
+@pytest.mark.parametrize("legs,packets,want", [
+    (7, 2, False), (7, 16, False), (15, 64, False), (16, 1, False),
+    (16, 2, False), (16, 16, True), (64, 16, True), (256, 16, True),
+    (4096, 16, True), (17, 2, False)])
+def test_leg_major_rule_is_a_function_of_the_shape(legs, packets, want):
+    assert tr_mod._gcm_leg_major(legs, packets) is want
+    if want:
+        assert (_round_rows(legs) * _round_rows(packets)
+                <= 2 * _round_rows(legs * packets))
+
+
+# ------------------------------- both forms against the reference (c)
+
+def _table(n: int, seed: int):
+    rng = np.random.default_rng(seed)
+    mk = rng.integers(0, 256, (n, 16), dtype=np.uint8)
+    ms = rng.integers(0, 256, (n, 12), dtype=np.uint8)
+    t = SrtpStreamTable(n, GCM)
+    t.add_streams(np.arange(n), mk, ms)
+    return t, mk, ms
+
+
+def _batch(rows: int, per: int, seed: int, mixed: bool):
+    """`rows` packets, `per` a stream, shuffled; payloads 8-60 bytes;
+    `mixed`: a CSRC on every third packet, so payload offsets differ."""
+    rng = np.random.default_rng(seed)
+    n = rows // per
+    streams = np.repeat(np.arange(n), per)
+    rng.shuffle(streams)
+    seqs = np.zeros(rows, np.int64)
+    for s in range(n):
+        at = np.nonzero(streams == s)[0]
+        seqs[at] = 100 + np.arange(len(at))
+    pls = [rng.integers(0, 256, int(rng.integers(8, 61)),
+                        dtype=np.uint8).tobytes() for _ in range(rows)]
+    csrcs = [[7] if mixed and i % 3 == 0 else [] for i in range(rows)]
+    b = rtp_header.build(pls, seqs.tolist(), [0] * rows,
+                         (0x1000 + streams).tolist(), [96] * rows,
+                         csrcs=csrcs, stream=streams.tolist())
+    return b, streams, seqs
+
+
+@pytest.mark.parametrize("rows,per,mixed", [
+    (16, 4, False), (64, 4, False), (256, 4, True), (320, 40, False),
+    (1024, 4, False), (4096, 4, False), (8192, 4, False)],
+    ids=lambda v: str(v))
+def test_rule_selected_form_matches_the_reference(rows, per, mixed,
+                                                  oracle):
+    """At every shape the rule can select — per-row in each row class,
+    grouped above them — the table's protect is byte-equal to the scalar
+    reference under each stream's key and its unprotect gives the
+    plaintext back; and where a grid exists the OTHER form's program is
+    byte-equal too, so the rule moves time and never bytes."""
+    import jax.numpy as jnp
+
+    n = rows // per
+    b, streams, seqs = _batch(rows, per, rows + per, mixed)
+    tx, mk, ms = _table(n, 3)
+    wire = tx.protect_rtp(b)
+    step = max(1, rows // 256)           # the reference is scalar
+    for i in range(0, rows, step):
+        s = int(streams[i])
+        assert wire.to_bytes(i) == oracle.protect_oracle_gcm(
+            bytes(mk[s]), bytes(ms[s]), b.to_bytes(i), int(seqs[i])), i
+    rx, _, _ = _table(n, 3)
+    dec, ok = rx.unprotect_rtp(wire)
+    assert ok.all()
+    for i in range(0, rows, step):
+        assert dec.to_bytes(i) == b.to_bytes(i), i
+    # the two programs at this batch's own shape
+    pad = _round_rows(rows)
+    grid = ctx._gcm_grid(np.resize(streams, pad).astype(np.int64))
+    if grid is None or pad != rows or mixed:
+        return
+    hdr = rtp_header.parse(b)
+    fresh, _, _ = _table(n, 3)
+    tab_rk, tab_gm, _, _ = fresh._device()
+    iv12 = fresh._gcm_rtp_iv(fresh._salt_rtp[streams], hdr.ssrc, seqs)
+    data = np.zeros((rows, 224), np.uint8)
+    data[:, :b.capacity] = b.data[:, :224]
+    args = (tab_rk, tab_gm, jnp.asarray(streams, dtype=jnp.int32),
+            jnp.asarray(data), jnp.asarray(b.length),
+            jnp.asarray(hdr.payload_off, dtype=jnp.int32),
+            jnp.asarray(iv12))
+    gr, us, inv = grid
+    out_r, len_r = ctx._protect_gcm_dev(*args, aad_const=12)
+    out_g, len_g = ctx._protect_gcm_grouped_dev(
+        *args, jnp.asarray(gr), jnp.asarray(us, dtype=jnp.int32),
+        jnp.asarray(inv), aad_const=12)
+    assert np.array_equal(np.asarray(len_r), np.asarray(len_g))
+    assert np.array_equal(np.asarray(out_r), np.asarray(out_g))
+    for i in range(0, rows, step):
+        n_i = int(np.asarray(len_r)[i])
+        assert np.asarray(out_r)[i, :n_i].tobytes() == wire.to_bytes(i)
+
+
+@pytest.mark.parametrize("legs,packets", [(7, 3), (16, 16), (20, 2)])
+def test_fanout_forms_match_the_reference(legs, packets, oracle):
+    """Senders that share one receiver list: whichever fan-out form
+    `_gcm_leg_major` picks for (legs, packets), every output row opens
+    under its receiver's key to the sender's packet."""
+    rng = np.random.default_rng(legs * 100 + packets)
+    t = tr_mod.RtpTranslator(64, GCM)
+    keys = [(rng.integers(0, 256, 16, dtype=np.uint8).tobytes(),
+             rng.integers(0, 256, 12, dtype=np.uint8).tobytes())
+            for _ in range(legs)]
+    for r, (k, s) in enumerate(keys):
+        t.add_receiver(r, k, s)
+    senders = list(range(40, 40 + packets))
+    for sid in senders:
+        t.connect(sid, range(legs))
+    pls = [rng.integers(0, 256, int(rng.integers(40, 161)),
+                        dtype=np.uint8).tobytes() for _ in senders]
+    seqs = [500 + i for i in range(packets)]
+    b = rtp_header.build(pls, seqs, [0] * packets,
+                         [0x2000 + s for s in senders], [96] * packets,
+                         stream=senders)
+    pend = t.translate_async(b, np.asarray(seqs, dtype=np.int64))
+    assert (pend._pg is not None) is tr_mod._gcm_leg_major(legs, packets)
+    wire, recv = pend.result()
+    assert wire.batch_size == legs * packets
+    for j in range(wire.batch_size):
+        p, r = divmod(j, legs)
+        assert int(recv[j]) == r
+        assert wire.to_bytes(j) == oracle.protect_oracle_gcm(
+            *keys[r], b.to_bytes(p), seqs[p]), j

@@ -282,28 +282,30 @@ def test_warmups_fire_only_at_bucket_boundaries():
     assert lc._warm_bucket == 16
 
 
-def test_rtp_warmup_is_alone_and_the_rest_side_by_side(monkeypatch):
-    """The RTP warm-up holds the registry's TIMED provider race (GCM):
-    nothing else may compile beside it.  The fan-out variants and the
-    SRTCP pair are not timed and run together, after it."""
+def test_rtp_warmup_runs_beside_the_rest_rx_before_tx(monkeypatch):
+    """Nothing in a rung is timed (the GCM form is a rule of the shape),
+    so the RTP pair compiles in the pool with the fan-out variants and
+    the SRTCP pair: all four overlap.  Within the RTP pair rx goes
+    first and tx finds its programs warm."""
     import threading
 
     monkeypatch.setattr(os, "cpu_count", lambda: 4)   # pool: 1 per core
     lc, bridge = _lc(capacity=64, min_bucket=4)
     log, lock = [], threading.Lock()
-    together = threading.Barrier(3, timeout=30)   # 2 fan-outs + SRTCP
+    # rx RTP + 2 fan-outs + SRTCP: passes only if all four overlap
+    together = threading.Barrier(4, timeout=30)
 
     def span(name, meet):
         def run(*_a, **_k):
             with lock:
                 log.append(("in", name))
             if meet:
-                together.wait()    # passes only if all three overlap
+                together.wait()
             with lock:
                 log.append(("out", name))
         return run
 
-    bridge.rx_table.warmup_rtp = span("rx_rtp", False)
+    bridge.rx_table.warmup_rtp = span("rx_rtp", True)
     bridge.tx_table.warmup_rtp = span("tx_rtp", False)
     bridge.rx_table.warmup_rtcp = span("rtcp", True)
     bridge.tx_table.warmup_rtcp = lambda *_a, **_k: None
@@ -311,12 +313,12 @@ def test_rtp_warmup_is_alone_and_the_rest_side_by_side(monkeypatch):
         fanout_warmups=lambda rc, payload_len: [span("fan0", True),
                                                 span("fan1", True)])
     lc._warm_class(16, rtp=True)
-    assert log[:4] == [("in", "rx_rtp"), ("out", "rx_rtp"),
-                       ("in", "tx_rtp"), ("out", "tx_rtp")]
-    assert sorted(log[4:7]) == [("in", "fan0"), ("in", "fan1"),
-                                ("in", "rtcp")]
+    assert sorted(log[:4]) == [("in", "fan0"), ("in", "fan1"),
+                               ("in", "rtcp"), ("in", "rx_rtp")]
+    assert log.index(("out", "rx_rtp")) < log.index(("in", "tx_rtp"))
+    assert ("out", "tx_rtp") in log
     log.clear()
-    together.reset()
+    together = threading.Barrier(3, timeout=30)
     lc._warm_class(64, rtp=False)              # listener rows: no RTP
     assert {n for _, n in log} == {"fan0", "fan1", "rtcp"}
 

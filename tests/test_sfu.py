@@ -226,16 +226,18 @@ def _gcm_fanout_roundtrip(routes):
 
 @pytest.mark.slow
 def test_gcm_fanout_full_mesh_grouped_path():
-    """Uniform routes take the grouped (per-leg H matrix) kernel; every
-    leg must still open the AEAD against its own session keys."""
+    """Uniform routes (three legs: under `_gcm_leg_major`'s floor, so
+    the per-row form; tests/test_gcm_served.py drives the leg-major one
+    from 16 legs): every leg must open the AEAD against its own session
+    keys."""
     _gcm_fanout_roundtrip({0: [1, 2, 3]})
 
 
 @pytest.mark.slow
 def test_gcm_fanout_general_path_matches_grouped():
-    """Non-uniform routes fall back to the per-row gather path; the
-    ciphertext for a shared (packet, receiver) pair must be identical
-    to the grouped path's (same keys, same IVs => same AEAD output)."""
+    """Two senders with different routes against one sender's uniform
+    route: the ciphertext for a shared (packet, receiver) pair must be
+    identical (same keys, same IVs => same AEAD output)."""
     from libjitsi_tpu.transform.srtp import SrtpProfile
 
     prof = SrtpProfile.AEAD_AES_128_GCM
