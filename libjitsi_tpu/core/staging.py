@@ -22,9 +22,17 @@ byte order would be the backend's to choose.
 
 Host side: `alloc` where the rows are copied anyway, `pack`, then one
 `jax.device_put`; `Launch.fetch` and `split_out`.  Inside the jitted program: `unpack`,
-`repack`.  The AES-CM unprotect (`transform/srtp/context.py`) and the
-AES-CM fan-out (`sfu/translator.py`) stage this way.  The GCM calls
-stage an array an argument (`put_each`) and count what crossed.
+`repack`.  The two programs a served tick launches stage this way
+under AES-CM and under AES-GCM alike: the RTP unprotect
+(`transform/srtp/context.py`; GCM in its per-row form) and the per-row
+fan-out (`sfu/translator.py`).  GCM has no ROC word (three words, the
+fourth 0) and its 12-byte IV rides in the first 12 IV columns.
+
+What keeps an array an argument (`put_each`, which counts what
+crossed): the grouped GCM form above the row classes (its grid arrays
+have another shape than the rows), the leg-major GCM fan-out,
+`protect_rtp`, AES-F8, a keystream-cache hit, SRTCP, and the mesh
+seams, which route an array per lane to its owning chip.
 """
 
 from __future__ import annotations
@@ -49,14 +57,17 @@ def alloc(rows: int, width: int) -> np.ndarray:
 
 
 def pack(plane: np.ndarray, words: Sequence, iv) -> None:
-    """Write the per-row words (each `[rows]`, any integer dtype, taken
-    modulo 2**32) and the 16-byte IVs behind the packet bytes."""
+    """Write up to WORDS per-row words (each `[rows]`, any integer
+    dtype, taken modulo 2**32) and the IVs (`[rows, 16]`, or GCM's
+    `[rows, 12]`) behind the packet bytes.  Columns of a word or an IV
+    byte not given keep what `alloc` left there: zero."""
     rows, w = plane.shape[0], plane.shape[1] - TAIL
-    side = np.empty((rows, WORDS), dtype="<u4")
+    side = np.empty((rows, len(words)), dtype="<u4")
     for k, word in enumerate(words):
         side[:, k] = word
-    plane[:, w:w + 4 * WORDS] = side.view(np.uint8)
-    plane[:, w + 4 * WORDS:] = iv
+    plane[:, w:w + 4 * len(words)] = side.view(np.uint8)
+    at = w + 4 * WORDS
+    plane[:, at:at + np.shape(iv)[1]] = iv
 
 
 def split_out(host: np.ndarray, n_words: int
@@ -81,8 +92,9 @@ def put_each(*arrays) -> Tuple[list, int, int]:
 class Launch:
     """A device call in flight: what crossed to the device for it, and
     how its outputs come to the host.  `outs` are the program's outputs
-    as they stand on the device (one packed plane; a GCM call's three
-    arrays; a mesh seam's deferred scatters); `split` turns their host
+    as they stand on the device (one packed plane; the three arrays of
+    a grouped GCM, an F8 or a cache-hit unprotect; a mesh seam's
+    deferred scatters); `split` turns their host
     copies into what the caller reads.  `fetch` waits, copies each
     output once and caches; `h2d_arrays` / `h2d_bytes` / `d2h_arrays` /
     `d2h_bytes` count the arrays that really crossed.  `counts` is what

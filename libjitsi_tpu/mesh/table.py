@@ -584,9 +584,11 @@ class ShardedSrtpTable(ShardedRowsMixin, SrtpStreamTable):
 
     def _gcm_rtp_unprotect_call(self, stream, batch, hdr, iv12, length
                                 ) -> staging.Launch:
-        """As the CM seam: what goes back is a `staging.Launch` holding
-        the three deferred scatters; five arrays are routed to their
-        owning chips."""
+        """As the CM seam: the part comes with a staging plane under
+        GCM too (`batch.data` is its leading columns) and nothing is
+        packed; what goes back is a `staging.Launch` holding the three
+        deferred scatters; five arrays are routed to their owning
+        chips."""
         off_const = _uniform_off(hdr.payload_off, batch.capacity)
         stream = np.asarray(stream, dtype=np.int64)
         length = np.asarray(length, dtype=np.int32)

@@ -77,11 +77,15 @@ class ShardedRtpTranslator(ShardedRowsMixin, RtpTranslator):
             h2d_bytes=4 * len(recv) + sum(
                 int(np.asarray(a).nbytes) for a in lanes))
 
-    def _gcm_fanout_call(self, recv, data, length, payload_off, iv12,
-                         capacity) -> staging.Launch:
+    def _gcm_fanout_call(self, recv, plane, length, payload_off, iv12
+                         ) -> staging.Launch:
+        """As `_cm_fanout_call` above: the packet bytes are `plane`'s
+        leading columns and nothing is packed."""
         from libjitsi_tpu.transform.srtp.context import _uniform_off
 
-        fn = self._gcm_fanout_fn(_uniform_off(payload_off, capacity))
+        data = plane[:, :plane.shape[-1] - staging.TAIL]
+        fn = self._gcm_fanout_fn(_uniform_off(payload_off,
+                                              data.shape[-1]))
         lanes = [data, np.asarray(length, dtype=np.int32), payload_off,
                  iv12]
         out, out_len = self._sharded_launch(

@@ -103,12 +103,19 @@ def _split_fanout(host):
     return data, words[:, 0]
 
 
-@functools.partial(jax.jit, static_argnames=("aad_const",), donate_argnums=(3,))
-def _fanout_protect_gcm(tab_rk, tab_gm, recv, data, length, aad_len, iv12,
-                        aad_const=None):
-    rk, gm = kernel.gather_keys(recv, tab_rk, tab_gm)
-    return gcm_kernel.gcm_protect(
-        data, length, aad_len, rk, gm, iv12, aad_const=aad_const)
+@functools.partial(jax.jit, static_argnames=("aad_const",),
+                   donate_argnums=(2,))
+def _fanout_protect_gcm(tab_rk, tab_gm, plane, aad_const=None):
+    """The per-row GCM fan-out on one packed plane (core/staging.py):
+    words receiver, length, payload offset (GCM has no ROC word), the
+    12-byte IV in the first 12 IV columns; out word the wire length.
+    The plane that comes back has the donated plane's shape."""
+    data, w, iv = staging.unpack(plane)
+    rk, gm = kernel.gather_keys(staging.as_i32(w[:, 0]), tab_rk, tab_gm)
+    out, out_len = gcm_kernel.gcm_protect(
+        data, staging.as_i32(w[:, 1]), staging.as_i32(w[:, 2]), rk, gm,
+        iv[:, :12], aad_const=aad_const)
+    return staging.repack(out, out_len)
 
 
 @functools.partial(jax.jit, static_argnames=("aad_const",))
@@ -308,15 +315,13 @@ class RtpTranslator:
 
         def one(w: int, off) -> None:
             # fetch the output: compile NOW, off-tick
+            plane = staging.alloc(rows, w)
+            plane[:, 0] = 0x80
             if self._gcm:
-                data = np.zeros((rows, w), dtype=np.uint8)
-                data[:, 0] = 0x80
                 iv12 = np.zeros((rows, 12), dtype=np.uint8)
-                self._gcm_fanout_call(recv, data, length, off, iv12,
-                                      w).fetch()
+                self._gcm_fanout_call(recv, plane, length, off,
+                                      iv12).fetch()
             else:
-                plane = staging.alloc(rows, w)
-                plane[:, 0] = 0x80
                 iv = np.zeros((rows, 16), dtype=np.uint8)
                 self._cm_fanout_call(recv, plane, length, off, iv,
                                      idx).fetch()
@@ -565,8 +570,8 @@ class RtpTranslator:
             pw = _round_width(int(np.max(length, initial=12))
                               + self.policy.auth_tag_len)
             cw = min(pw, data.shape[-1])
-            pdata = np.zeros((len(rr_idx), pw), dtype=np.uint8)
-            pdata[:, :cw] = data[rr_idx][:, :cw]
+            plane = staging.alloc(len(rr_idx), pw)
+            plane[:, :cw] = data[rr_idx][:, :cw]
             iv = gcm_kernel.srtp_gcm_iv(self._salt[recv[rr_idx]],
                                         ssrc[rr_idx], idx[rr_idx])
             plen, poff = length[rr_idx], payload_off[rr_idx]
@@ -574,7 +579,7 @@ class RtpTranslator:
         with span_of(tracer, "fanout_dispatch") as sp, \
                 phase_of(perf, "dispatch"):
             launch = self._gcm_fanout_call(
-                recv[rr_idx], pdata, plen, poff, iv, pdata.shape[-1])
+                recv[rr_idx], plane, plen, poff, iv)
             sp.note(h2d_arrays=launch.h2d_arrays,
                     h2d_bytes=launch.h2d_bytes, **launch.counts)
         return launch, None
@@ -596,25 +601,28 @@ class RtpTranslator:
             h2d_arrays=n, h2d_bytes=nbytes,
             counts={"gm_gather_bytes": len(rr) * GM_BYTES, "grouped": 1})
 
-    def _gcm_fanout_call(self, recv, data, length, payload_off, iv12,
-                         capacity) -> staging.Launch:
+    def _gcm_fanout_call(self, recv, plane, length, payload_off, iv12
+                         ) -> staging.Launch:
         """Per-row AEAD fan-out device call — the mesh translator
         overrides exactly this seam (leg-sharded, chip-local matrix
-        gathers).  An array an argument crosses (`staging.put_each`);
-        returns the `staging.Launch` in flight, whose `fetch()` gives
-        host (wire bytes, wire lengths)."""
+        gathers).
+
+        As `_cm_fanout_call`: `plane` is `staging.alloc(rows, width)`
+        with the packet bytes in its first `width` columns; receiver,
+        length, payload offset and the 12-byte IV are packed behind
+        them here, so ONE array goes to the device and one plane comes
+        back.  Returns the `staging.Launch` in flight, whose `fetch()`
+        gives host (wire bytes `[rows, width]`, wire lengths)."""
         from libjitsi_tpu.transform.srtp.context import _uniform_off
 
         tab_rk, tab_gm = self._device()
-        dev, n, nbytes = staging.put_each(
-            np.asarray(recv, dtype=np.int32), data,
-            np.asarray(length, dtype=np.int32),
-            np.asarray(payload_off, dtype=np.int32), iv12)
+        staging.pack(plane, (recv, length, payload_off), iv12)
+        out = _fanout_protect_gcm(
+            tab_rk, tab_gm, jax.device_put(plane),
+            aad_const=_uniform_off(payload_off,
+                                   plane.shape[-1] - staging.TAIL))
         return staging.Launch(
-            _fanout_protect_gcm(tab_rk, tab_gm, *dev,
-                                aad_const=_uniform_off(payload_off,
-                                                       capacity)),
-            h2d_arrays=n, h2d_bytes=nbytes,
+            (out,), _split_fanout, h2d_arrays=1, h2d_bytes=plane.nbytes,
             counts={"gm_gather_bytes": len(recv) * GM_BYTES,
                     "grouped": 0})
 

@@ -198,26 +198,43 @@ def _protect_gcm_dev(tab_rk, tab_gm, stream, data, length, aad_len, iv12,
         data, length, aad_len, rk, gm, iv12, aad_const=aad_const)
 
 
-def _unprotect_gcm_impl(tab_rk, tab_gm, stream, data, length, aad_len, iv12,
-                        aad_const=None):
-    rk, gm = kernel.gather_keys(stream, tab_rk, tab_gm)
-    return gcm_kernel.gcm_unprotect(
-        data, length, aad_len, rk, gm, iv12, aad_const=aad_const)
+def _unprotect_gcm_impl(tab_rk, tab_gm, plane, aad_const=None):
+    """The per-row GCM RTP unprotect on one packed plane
+    (core/staging.py): words stream, length, payload offset (GCM has no
+    ROC word), the 12-byte IV in the first 12 IV columns; out words
+    media length, auth verdict.  The arithmetic between is
+    `gcm_kernel.gcm_unprotect`'s."""
+    data, w, iv = staging.unpack(plane)
+    rk, gm = kernel.gather_keys(staging.as_i32(w[:, 0]), tab_rk, tab_gm)
+    out, mlen, auth_ok = gcm_kernel.gcm_unprotect(
+        data, staging.as_i32(w[:, 1]), staging.as_i32(w[:, 2]), rk, gm,
+        iv[:, :12], aad_const=aad_const)
+    return staging.repack(out, mlen, auth_ok)
 
 
 _unprotect_gcm_dev = jax.jit(
     _unprotect_gcm_impl, static_argnames=("aad_const",))
 
-# donated twin — see _unprotect_rtp_dev_donated
+# donated twin — see _unprotect_rtp_packed_donated
 _unprotect_gcm_dev_donated = jax.jit(
     _unprotect_gcm_impl, static_argnames=("aad_const",),
-    donate_argnums=(3,))
+    donate_argnums=(2,))
 
 
 def _unprotect_gcm_dev_call(*args, **kwargs):
     fn = (_unprotect_gcm_dev_donated if _donate_ingest()
           else _unprotect_gcm_dev)
     return fn(*args, **kwargs)
+
+
+@functools.partial(jax.jit, static_argnames=("aad_const",))
+def _open_gcm_dev(tab_rk, tab_gm, stream, data, length, aad_len, iv12,
+                  aad_const=None):
+    """The per-row GCM open with an array an argument: SRTCP's
+    (`_gcm_rtcp_open_call`), as `_protect_gcm_dev` is its seal."""
+    rk, gm = kernel.gather_keys(stream, tab_rk, tab_gm)
+    return gcm_kernel.gcm_unprotect(
+        data, length, aad_len, rk, gm, iv12, aad_const=aad_const)
 
 
 @functools.partial(jax.jit, static_argnames=("aad_const",))
@@ -1127,15 +1144,15 @@ class SrtpStreamTable:
         np.maximum.at(self.tx_ext, stream, idx)
         return data, length, batch.stream
 
-    def _gcm_stage(self, stream, batch, hdr, iv12, length):
-        """The stock GCM RTP calls' arguments on the device, an array
-        an argument, in the form `_gcm_form_grid` picks for the call's
-        shape.  Returns (grouped, device arguments behind the two key
+    def _gcm_stage(self, stream, batch, hdr, iv12, length, grid):
+        """The arguments of a GCM RTP call that does not pack, on the
+        device, an array an argument: `protect_rtp` in either form, and
+        the grouped unprotect, whose `grid` arrays have another shape
+        than the rows.  Returns (device arguments behind the two key
         tables, what `staging.Launch` books for them: the arrays that
         crossed, their bytes, and the span's counts `gm_gather_bytes` —
         a 16 KiB GHASH matrix a padded row, or a group of the grid —
         and `grouped`)."""
-        grid = _gcm_form_grid(stream)
         host = [np.asarray(stream, dtype=np.int32), batch.data,
                 np.asarray(length, dtype=np.int32),
                 np.asarray(hdr.payload_off, dtype=np.int32), iv12]
@@ -1145,7 +1162,7 @@ class SrtpStreamTable:
             host += [gr, us.astype(np.int32), inv]
             groups = len(us)
         dev, n, nbytes = staging.put_each(*host)
-        return grid is not None, dev, {
+        return dev, {
             "h2d_arrays": n, "h2d_bytes": nbytes,
             "counts": {"gm_gather_bytes": groups * GM_BYTES,
                        "grouped": int(grid is not None)}}
@@ -1157,26 +1174,42 @@ class SrtpStreamTable:
         the call's shape."""
         aad_const = _uniform_off(hdr.payload_off, batch.capacity)
         tab_rk, tab_gm, _, _ = self._device()
-        grouped, dev, _ = self._gcm_stage(stream, batch, hdr, iv12,
-                                          batch.length)
-        fn = _protect_gcm_grouped_dev if grouped else _protect_gcm_dev
+        grid = _gcm_form_grid(stream)
+        dev, _ = self._gcm_stage(stream, batch, hdr, iv12, batch.length,
+                                 grid)
+        fn = _protect_gcm_dev if grid is None else _protect_gcm_grouped_dev
         return fn(tab_rk, tab_gm, *dev, aad_const=aad_const)
 
     def _gcm_rtp_unprotect_call(self, stream, batch, hdr, iv12, length
                                 ) -> staging.Launch:
         """AEAD-GCM RTP unprotect seam — see `_gcm_rtp_protect_call`.
-        The staged packet bytes are donated (off the CPU) to the ONE
-        program the shape selects.  Returns the `staging.Launch` in
+
+        The per-row form (all a served table launches) takes the part
+        as the CM seam does: stream, length, payload offset and the
+        12-byte IV are packed into `batch.plane` behind the packet
+        bytes, ONE array goes to the device, donated off the CPU, and
+        one plane comes back.  The grouped form stages an array an
+        argument (`_gcm_stage`).  Returns the `staging.Launch` in
         flight, whose `fetch()` gives host (data, media_len, auth_ok)
         and whose counts are of the arrays that crossed."""
         aad_const = _uniform_off(hdr.payload_off, batch.capacity)
         tab_rk, tab_gm, _, _ = self._device()
-        grouped, dev, staged = self._gcm_stage(stream, batch, hdr, iv12,
-                                               length)
-        fn = (_unprotect_gcm_grouped_dev if grouped
-              else _unprotect_gcm_dev_call)
+        grid = _gcm_form_grid(stream)
+        if grid is None:
+            plane = batch.plane
+            staging.pack(plane, (stream, length, hdr.payload_off), iv12)
+            out = _unprotect_gcm_dev_call(
+                tab_rk, tab_gm, jax.device_put(plane), aad_const=aad_const)
+            return staging.Launch(
+                (out,), _split_unprotect, h2d_arrays=1,
+                h2d_bytes=plane.nbytes,
+                counts={"gm_gather_bytes": len(stream) * GM_BYTES,
+                        "grouped": 0})
+        dev, staged = self._gcm_stage(stream, batch, hdr, iv12, length,
+                                      grid)
         return staging.Launch(
-            fn(tab_rk, tab_gm, *dev, aad_const=aad_const),
+            _unprotect_gcm_grouped_dev(tab_rk, tab_gm, *dev,
+                                       aad_const=aad_const),
             _split_unpacked, **staged)
 
     def _gcm_grid_dev(self, stream):
@@ -1324,10 +1357,11 @@ class SrtpStreamTable:
 
     def _unpacked_launch(self, out, batch, length, iv) -> staging.Launch:
         """An F8 call's or a keystream-cache hit's (data, media_len,
-        auth_ok) behind the CM seam's face: those calls stage data,
+        auth_ok) behind the packed seams' face: those calls stage data,
         lengths and IVs as they are and stream, payload offset and ROC
-        as a word a row each, booked by that formula (the stock GCM
-        call counts its arrays: `_gcm_rtp_unprotect_call`)."""
+        as a word a row each, booked by that formula.  (The stock CM
+        and per-row GCM calls pack one plane; the grouped GCM call
+        counts its arrays: `_gcm_rtp_unprotect_call`.)"""
         return staging.Launch(
             out, _split_unpacked, h2d_arrays=6,
             h2d_bytes=batch.data.nbytes + length.nbytes
@@ -1335,8 +1369,9 @@ class SrtpStreamTable:
 
     def _part_tail(self) -> int:
         """Room `bucket_by_size` leaves behind an unprotect part's
-        bytes: the CM call packs its arguments there."""
-        return 0 if self._gcm or self._f8 else staging.TAIL
+        bytes: the CM and the per-row GCM call pack their arguments
+        there."""
+        return 0 if self._f8 else staging.TAIL
 
     def unprotect_rtp(self, batch: PacketBatch, return_index: bool = False):
         """Auth-check, replay-check and decrypt incoming RTP.
@@ -1666,7 +1701,7 @@ class SrtpStreamTable:
         returns (data, media_len, auth_ok)."""
         tab_rk, tab_aux = self._device()[2], self._device()[3]
         n = len(klen)
-        return _unprotect_gcm_dev(
+        return _open_gcm_dev(
             tab_rk, tab_aux, jnp.asarray(stream, dtype=jnp.int32),
             jnp.asarray(kin), jnp.asarray(klen, dtype=jnp.int32),
             jnp.asarray(np.full(n, 12, np.int32)), jnp.asarray(iv12),

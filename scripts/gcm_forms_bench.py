@@ -12,7 +12,11 @@ class: the per-row program and the grouped one at the grids that
 stream four packets, the grouped form's best case, no padding);
 (rows/2, 4) and (rows, 2) are what `bucket_by_size`'s cycling makes of a
 live tick whose packets come from distinct streams (3/8 and 3/4 of the
-class real, the grid padded to twice the rows).  Arguments are staged
+class real, the grid padded to twice the rows).  The per-row unprotect
+and the per-row fan-out are the served programs, each on one packed
+plane (`core/staging.py`; the fan-out without its donation, so that one
+staged plane serves every launch); the grouped and leg-major forms and
+`protect_rtp` take an array an argument.  Arguments are staged
 once, the programs compile side by side, then each runs alone:
 milliseconds a launch over `ITERS` back-to-back launches, the queue
 drained once at the end.  One JSON line last; the table also goes to
@@ -38,6 +42,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from libjitsi_tpu.core import staging  # noqa: E402
 from libjitsi_tpu.core.packet import _round_rows  # noqa: E402
 from libjitsi_tpu.transform.srtp import context as ctx  # noqa: E402
 from libjitsi_tpu.transform.srtp.policy import SrtpProfile  # noqa: E402
@@ -64,16 +69,32 @@ def cases(rows: int):
     yield "live_3of4", cycled(3 * rows // 4)
 
 
+def packed(tab_rk, tab_gm, stream, data, length, off, iv):
+    """The arguments of a packed per-row program: the two key tables
+    and one staged plane."""
+    plane = staging.alloc(len(stream), WIDTH)
+    plane[:, :WIDTH] = data
+    staging.pack(plane, (stream, length, off), iv)
+    return tab_rk, tab_gm, jax.device_put(plane)
+
+
+def dev(*arrays):
+    return tuple(jnp.asarray(a) for a in arrays)
+
+
 def part2_jobs(tab_rk, tab_gm, rng, tiny: bool):
     from libjitsi_tpu.sfu import translator as tr
 
+    # the served per-row fan-out donates its plane: time its undonated
+    # twin, so that one staged plane serves every launch
+    fanout_per_row = jax.jit(tr._fanout_protect_gcm.__wrapped__,
+                             static_argnames=("aad_const",))
+
     def args(rows, aad):
-        return (jnp.asarray(rng.integers(0, 256, (rows, WIDTH),
-                                         dtype=np.uint8)),
-                jnp.asarray(rng.integers(68, 189, rows, dtype=np.int32)),
-                jnp.full(rows, aad, dtype=jnp.int32),
-                jnp.asarray(rng.integers(0, 256, (rows, 12),
-                                         dtype=np.uint8)))
+        return (rng.integers(0, 256, (rows, WIDTH), dtype=np.uint8),
+                rng.integers(68, 189, rows, dtype=np.int32),
+                np.full(rows, aad, dtype=np.int32),
+                rng.integers(0, 256, (rows, 12), dtype=np.uint8))
 
     jobs = []
     shapes = ([(256, 8), (256, 16)] if tiny else
@@ -83,11 +104,14 @@ def part2_jobs(tab_rk, tab_gm, rng, tiny: bool):
         stream = np.repeat(rng.choice(CAPACITY, rows // p, replace=False),
                            p)
         gr, us, inv = ctx._gcm_grid(stream.astype(np.int64))
+        host = args(rows, AAD)
         base = (tab_rk, tab_gm, jnp.asarray(stream, dtype=jnp.int32),
-                *args(rows, AAD))
+                *dev(*host))
         if rows > 4096:
             jobs.append((rows, "any", "per_row", "unprotect",
-                         ctx._unprotect_gcm_dev, base, None, AAD))
+                         ctx._unprotect_gcm_dev,
+                         packed(tab_rk, tab_gm, stream, *host), None,
+                         AAD))
         jobs.append((rows, f"p{p}", "grouped", "unprotect",
                      ctx._unprotect_gcm_grouped_dev,
                      base + (jnp.asarray(gr),
@@ -98,21 +122,19 @@ def part2_jobs(tab_rk, tab_gm, rng, tiny: bool):
         rows = legs * pk
         data, length, off, iv = args(rows, 20)
         jobs.append((rows, f"legs{legs}x{pk}", "per_row", "fanout",
-                     ctx._protect_gcm_dev,
-                     (tab_rk, tab_gm,
-                      jnp.asarray(np.tile(rr, pk), dtype=jnp.int32),
-                      data, length, off, iv), None, 20))
+                     fanout_per_row,
+                     packed(tab_rk, tab_gm, np.tile(rr, pk), data, length,
+                            off, iv), None, 20))
         jobs.append((rows, f"legs{legs}x{pk}", "leg_major", "fanout",
                      tr._fanout_protect_gcm_legs,
                      (tab_rk, tab_gm, jnp.asarray(rr, dtype=jnp.int32),
-                      data[:pk], length[:pk],
-                      iv.reshape(legs, pk, 12)), (legs, pk), 20))
+                      *dev(data[:pk], length[:pk],
+                           iv.reshape(legs, pk, 12))), (legs, pk), 20))
     # 7 legs x 2 packets, a small conference's single-sender tick: 16
     # per-row rows against the (16, 16) leg-major grid above
-    data, length, off, iv = args(16, 20)
-    jobs.append((16, "legs7x2", "per_row", "fanout", ctx._protect_gcm_dev,
-                 (tab_rk, tab_gm, jnp.arange(16, dtype=jnp.int32), data,
-                  length, off, iv), None, 20))
+    jobs.append((16, "legs7x2", "per_row", "fanout", fanout_per_row,
+                 packed(tab_rk, tab_gm, np.arange(16), *args(16, 20)),
+                 None, 20))
     return jobs
 
 
@@ -122,8 +144,8 @@ def main() -> int:
     tiny = [a for a in sys.argv[1:] if a.isdigit()]
     if tiny:                            # rehearsal off the chip
         CAPACITY, ROWS, ITERS = int(tiny[0]), (64, 256), 2
-    dev = jax.devices()[0]
-    print(f"device {dev.platform} {dev.device_kind}", flush=True)
+    chip = jax.devices()[0]
+    print(f"device {chip.platform} {chip.device_kind}", flush=True)
     table = ctx.SrtpStreamTable(CAPACITY, SrtpProfile.AEAD_AES_128_GCM)
     rng = np.random.default_rng(1)
     table.add_streams(np.arange(CAPACITY),
@@ -135,15 +157,15 @@ def main() -> int:
         jobs = part2_jobs(tab_rk, tab_gm, rng, bool(tiny))
     for rows in () if part2 else ROWS:
         assert _round_rows(rows) == rows
-        data = jnp.asarray(rng.integers(0, 256, (rows, WIDTH),
-                                        dtype=np.uint8))
-        length = jnp.asarray(rng.integers(68, 189, rows, dtype=np.int32))
-        off = jnp.full(rows, AAD, dtype=jnp.int32)
-        iv = jnp.asarray(rng.integers(0, 256, (rows, 12), dtype=np.uint8))
+        host = (rng.integers(0, 256, (rows, WIDTH), dtype=np.uint8),
+                rng.integers(68, 189, rows, dtype=np.int32),
+                np.full(rows, AAD, dtype=np.int32),
+                rng.integers(0, 256, (rows, 12), dtype=np.uint8))
+        hdev = dev(*host)
         for label, stream in cases(rows):
-            sdev = jnp.asarray(stream, dtype=jnp.int32)
             grid = ctx._gcm_grid(stream.astype(np.int64))
-            base = (tab_rk, tab_gm, sdev, data, length, off, iv)
+            base = (tab_rk, tab_gm, jnp.asarray(stream, dtype=jnp.int32),
+                    *hdev)
             for op, per_row, grouped in (
                     ("unprotect", ctx._unprotect_gcm_dev,
                      ctx._unprotect_gcm_grouped_dev),
@@ -152,7 +174,9 @@ def main() -> int:
                 if op == "protect" and rows not in (256, ROWS[-1]):
                     continue
                 if label == "warm_p4":      # per-row: one program a class
-                    jobs.append((rows, "any", "per_row", op, per_row, base,
+                    jobs.append((rows, "any", "per_row", op, per_row,
+                                 packed(tab_rk, tab_gm, stream, *host)
+                                 if op == "unprotect" else base,
                                  None, AAD))
                 if grid is None:
                     print(f"no grid: {rows} {label}", flush=True)
@@ -188,7 +212,7 @@ def main() -> int:
     with open("chiprun_out/gcm_forms%s.json" % ("_part2" if part2 else ""),
               "w") as f:
         json.dump(out, f, indent=1)
-    print(json.dumps({"device": dev.device_kind, "width": WIDTH,
+    print(json.dumps({"device": chip.device_kind, "width": WIDTH,
                       "table_rows": CAPACITY, "results": out}))
     return 0
 

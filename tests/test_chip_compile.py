@@ -155,10 +155,11 @@ def test_gcm_grouped_protect_served_shape(one_chip, no_persistent_cache,
 
 
 # The two GCM programs the served tick makes (per-row GHASH, the form
-# `context._gcm_form_grid` picks inside the row classes), an array an
-# argument: the fan-out at the top warmed row class with the bridge's
-# abs-send-time header (AAD 20), the unprotect at the class 584 uplink
-# packets pad to.  Each donates its packet bytes.
+# `context._gcm_form_grid` picks inside the row classes), each on one
+# packed plane `[rows, WIDTH + TAIL]` (core/staging.py): the fan-out at
+# the top warmed row class with the bridge's abs-send-time header (AAD
+# 20), the unprotect at the class 584 uplink packets pad to.  Each
+# donates its plane.
 @pytest.mark.parametrize("module,fn_name,rows,aad", [
     ("libjitsi_tpu.sfu.translator", "_fanout_protect_gcm", 4096, 20),
     ("libjitsi_tpu.transform.srtp.context", "_unprotect_gcm_dev_donated",
@@ -168,12 +169,13 @@ def test_gcm_per_row_served_shape(one_chip, no_persistent_cache,
                                   tower_core, module, fn_name, rows, aad):
     import importlib
 
+    from libjitsi_tpu.core import staging
+
     s = _on(one_chip)
     c = getattr(importlib.import_module(module), fn_name).lower(
         s((CAP, 11, 16), jnp.uint8), s((CAP, 128, 128), jnp.int8),
-        s((rows,), jnp.int32), s((rows, WIDTH), jnp.uint8),
-        s((rows,), jnp.int32), s((rows,), jnp.int32),
-        s((rows, 12), jnp.uint8), aad_const=aad).compile()
+        s((rows, WIDTH + staging.TAIL), jnp.uint8),
+        aad_const=aad).compile()
     _fits(c)
     assert "input_output_alias" in c.as_text()    # the donation took
 

@@ -20,7 +20,9 @@ import numpy as np
 import pytest
 
 import libjitsi_tpu
-from libjitsi_tpu.core.packet import ROW_CLASSES, _round_rows
+from libjitsi_tpu.core import staging
+from libjitsi_tpu.core.packet import (ROW_CLASSES, PacketBatch,
+                                      _round_rows)
 from libjitsi_tpu.kernels import registry
 from libjitsi_tpu.rtp import header as rtp_header
 from libjitsi_tpu.sfu import translator as tr_mod
@@ -28,6 +30,7 @@ from libjitsi_tpu.transform.srtp import SrtpProfile
 from libjitsi_tpu.transform.srtp import context as ctx
 from libjitsi_tpu.transform.srtp.context import SrtpStreamTable
 from libjitsi_tpu.utils.compile_cache import compile_stats
+from libjitsi_tpu.utils.tracing import PipelineTracer
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 GCM = SrtpProfile.AEAD_AES_128_GCM
@@ -96,7 +99,7 @@ def served(oracle):
 
     socks = []
     rec = {"sent": {}, "got": [], "replayed": set(), "forms": [],
-           "counts": {}}
+           "counts": {}, "arrays": []}
 
     def tick(n=1):
         for _ in range(n):
@@ -108,6 +111,14 @@ def served(oracle):
                 for k, v in counts.items():
                     mine[k] = mine.get(k, 0) + v
             if "unprotect_wait" in sup.last_counts:
+                lc_ = sup.last_counts
+                rec["arrays"].append(tuple(
+                    lc_.get(stage, {}).get(key)
+                    for stage, key in (
+                        ("unprotect_wait", "h2d_arrays"),
+                        ("unprotect_wait", "d2h_arrays"),
+                        ("fanout_dispatch", "h2d_arrays"),
+                        ("fanout_d2h", "d2h_arrays"))))
                 rec["forms"].append(
                     (sup.last_counts["unprotect_wait"].get("grouped"),
                      sup.last_counts.get("fanout_dispatch", {}).get(
@@ -264,12 +275,15 @@ def test_served_path_never_times_providers(served):
     assert c["fanout_dispatch"]["gm_gather_bytes"] \
         == c["expand"]["rows_padded"] * tr_mod.GM_BYTES
     assert c["expand"]["rows"] <= c["expand"]["rows_padded"]
-    # summed over the launches: five arrays in each, three / two back
+    # every tick: one packed plane in and one back for each launch
+    # (one size class, so one unprotect launch a tick)
+    assert all(a in ((1, 1, 1, 1), (1, 1, None, None))
+               for a in served["arrays"]), served["arrays"]
     u, f = c["unprotect_wait"], c["fanout_dispatch"]
-    assert u["h2d_arrays"] % 5 == 0 and f["h2d_arrays"] % 5 == 0
-    assert u["d2h_arrays"] * 5 == u["h2d_arrays"] * 3
-    assert c["fanout_d2h"]["d2h_arrays"] * 5 == f["h2d_arrays"] * 2
-    assert u["h2d_bytes"] == u["rows_padded"] * (4 + 224 + 4 + 4 + 12)
+    plane = 224 + staging.TAIL
+    assert u["h2d_bytes"] == u["d2h_bytes"] == u["rows_padded"] * plane
+    assert f["h2d_bytes"] == c["fanout_d2h"]["d2h_bytes"] \
+        == c["expand"]["rows_padded"] * plane
 
 
 # ------------------------------------------------------------- the rule
@@ -361,10 +375,22 @@ def test_rule_selected_form_matches_the_reference(rows, per, mixed,
         assert wire.to_bytes(i) == oracle.protect_oracle_gcm(
             bytes(mk[s]), bytes(ms[s]), b.to_bytes(i), int(seqs[i])), i
     rx, _, _ = _table(n, 3)
+    rx.tracer = tracer = PipelineTracer(annotate=False)
     dec, ok = rx.unprotect_rtp(wire)
     assert ok.all()
     for i in range(0, rows, step):
         assert dec.to_bytes(i) == b.to_bytes(i), i
+    # per-row packs one plane each way; the grouped form above the row
+    # classes still stages an array an argument (five and the grid's
+    # three in, three back)
+    tracer.take_ledger()
+    c = tracer.last_counts["unprotect_wait"]
+    grouped = rows > ROW_CLASSES[-1]
+    assert c["grouped"] == int(grouped)
+    assert (c["h2d_arrays"], c["d2h_arrays"]) == ((8, 3) if grouped
+                                                  else (1, 1))
+    assert grouped or c["gm_gather_bytes"] \
+        == c["rows_padded"] * ctx.GM_BYTES
     # the two programs at this batch's own shape
     pad = _round_rows(rows)
     grid = ctx._gcm_grid(np.resize(streams, pad).astype(np.int64))
@@ -422,3 +448,208 @@ def test_fanout_forms_match_the_reference(legs, packets, oracle):
         assert int(recv[j]) == r
         assert wire.to_bytes(j) == oracle.protect_oracle_gcm(
             *keys[r], b.to_bytes(p), seqs[p]), j
+
+
+# --------------------- the packed per-row programs (core/staging.py)
+
+def _plain_rows(rows: int, width: int, offsets: str, seed: int):
+    """`rows` RTP packets in a `[rows, width]` buffer with room for the
+    tag: payload offsets all 12 (`uniform`), 12 or 16 by a CSRC
+    (`per-row`), or as `per-row` with the offsets of rows 3 and 7
+    forged past the packet (`forged`: what a hostile ext_words field
+    makes the header parse say)."""
+    rng = np.random.default_rng([rows, width, seed])
+    per = 4
+    streams = np.repeat(np.arange(rows // per), per)
+    rng.shuffle(streams)
+    seqs = 100 + np.arange(rows)
+    room = width - 16 - 16
+    pls = [rng.integers(0, 256, int(rng.integers(8, room - 11)),
+                        dtype=np.uint8).tobytes() for _ in range(rows)]
+    csrcs = [[7] if offsets != "uniform" and i % 3 == 0 else []
+             for i in range(rows)]
+    b = rtp_header.build(pls, seqs.tolist(), [0] * rows,
+                         (0x1000 + streams).tolist(), [96] * rows,
+                         csrcs=csrcs, stream=streams.tolist())
+    data = np.zeros((rows, width), np.uint8)
+    take = min(width, b.capacity)
+    data[:, :take] = b.data[:, :take]
+    off = np.asarray(rtp_header.parse(b).payload_off, dtype=np.int32)
+    if offsets == "forged":
+        off = off.copy()
+        off[3], off[7] = 4000, width + 5
+    return b, data, np.asarray(b.length, np.int32), off, streams, seqs
+
+
+@pytest.mark.parametrize("rows,width,offsets", [
+    (64, 192, "uniform"), (64, 224, "per-row"), (256, 224, "forged"),
+    (256, 192, "per-row"), (1024, 224, "uniform"),
+    (1024, 192, "forged"), (4096, 224, "per-row")],
+    ids=lambda v: str(v))
+def test_packed_per_row_programs_match_the_unpacked_reference(
+        rows, width, offsets, oracle):
+    """The packed per-row fan-out and unprotect programs against the
+    scalar reference and against the same arithmetic with an array an
+    argument (`_protect_gcm_dev`, `_open_gcm_dev`): every output byte,
+    length and verdict, with a tag that does not verify among them."""
+    import jax
+    import jax.numpy as jnp
+
+    n = rows // 4
+    b, data, length, off, streams, seqs = _plain_rows(rows, width,
+                                                      offsets, 5)
+    t, mk, ms = _table(n, 9)
+    tab_rk, tab_gm, _, _ = t._device()
+    hdr = rtp_header.parse(b)
+    iv12 = t._gcm_rtp_iv(t._salt_rtp[streams], hdr.ssrc, seqs)
+    aad = ctx._uniform_off(off, width)
+    assert (aad is None) == (offsets != "uniform")
+    unpacked = (jnp.asarray(streams, dtype=jnp.int32), jnp.asarray(data),
+                jnp.asarray(length), jnp.asarray(off), jnp.asarray(iv12))
+    ref, ref_len = map(np.asarray, ctx._protect_gcm_dev(
+        tab_rk, tab_gm, *unpacked, aad_const=aad))
+    forged = {3, 7} if offsets == "forged" else set()
+    for i in range(0, rows, max(1, rows // 64)):
+        if i not in forged:
+            s = int(streams[i])
+            assert ref[i, :ref_len[i]].tobytes() \
+                == oracle.protect_oracle_gcm(
+                    bytes(mk[s]), bytes(ms[s]), b.to_bytes(i),
+                    int(seqs[i])), i
+    # the packed fan-out: one plane in, one of its shape back
+    plane = staging.alloc(rows, width)
+    plane[:, :width] = data
+    staging.pack(plane, (streams, length, off), iv12)
+    back = np.asarray(tr_mod._fanout_protect_gcm(
+        tab_rk, tab_gm, jax.device_put(plane), aad_const=aad))
+    assert back.shape == plane.shape and back.dtype == np.uint8
+    out, out_len = tr_mod._split_fanout(back)
+    np.testing.assert_array_equal(out_len, ref_len)
+    np.testing.assert_array_equal(out, ref)
+    # the packed unprotect of that wire, one tag bit flipped
+    wire = ref.copy()
+    wire[5, ref_len[5] - 1] ^= 0x01
+    want = [np.asarray(a) for a in ctx._open_gcm_dev(
+        tab_rk, tab_gm, unpacked[0], jnp.asarray(wire),
+        jnp.asarray(ref_len), unpacked[3], unpacked[4], aad_const=aad)]
+    plane = staging.alloc(rows, width)
+    plane[:, :width] = wire
+    staging.pack(plane, (streams, ref_len, off), iv12)
+    dec, mlen, auth_ok = ctx._split_unprotect(np.asarray(
+        ctx._unprotect_gcm_dev(tab_rk, tab_gm, jax.device_put(plane),
+                               aad_const=aad)))
+    np.testing.assert_array_equal(dec, want[0])
+    np.testing.assert_array_equal(mlen, want[1])
+    np.testing.assert_array_equal(auth_ok, want[2])
+    # forged offsets read 12 bytes too few or too many as AAD: dead
+    assert not auth_ok[5] and auth_ok.sum() == rows - 1 - len(forged)
+    good = np.nonzero(auth_ok)[0]
+    np.testing.assert_array_equal(mlen[good], length[good])
+    for i in good[:: max(1, rows // 64)]:
+        assert dec[i, :mlen[i]].tobytes() == b.to_bytes(int(i)), i
+
+
+def test_packed_gcm_unprotect_one_array_each_way(warmed_launch_guard):
+    """With the tracer on, a warmed per-row GCM unprotect sends ONE
+    array to the device and copies one back, compiles nothing, starts
+    no `convert_element_type` program, and still books what the GHASH
+    gathers read; a row whose tag does not verify keeps its bytes."""
+    n = 4
+    tx, _, _ = _table(n, 11)
+    rx, _, _ = _table(n, 11)
+    rx.tracer = tracer = PipelineTracer(annotate=False)
+
+    def wire(seq0, mixed):
+        pls = [bytes([i]) * (20 + 10 * (i % 3)) for i in range(12)]
+        return tx.protect_rtp(rtp_header.build(
+            pls, [seq0 + i // n for i in range(12)], [0] * 12,
+            [0x1000 + i % n for i in range(12)], [96] * 12,
+            csrcs=[[7] if mixed and i % 3 == 0 else []
+                   for i in range(12)],
+            stream=[i % n for i in range(12)]))
+
+    for mixed in (False, True):
+        first, second = wire(10 + 20 * mixed, mixed), \
+            wire(20 + 20 * mixed, mixed)
+        second.data[5, second.length[5] - 3] ^= 0x10
+        before = second.to_bytes(5)
+        _, ok = rx.unprotect_rtp(first)           # warms the program
+        assert ok.all()
+        tracer.take_ledger()
+        with warmed_launch_guard():
+            out, ok = rx.unprotect_rtp(second)
+        assert not ok[5] and ok.sum() == 11
+        assert out.to_bytes(5) == before
+        tracer.take_ledger()
+        c = tracer.last_counts["unprotect_wait"]
+        assert c["h2d_arrays"] == 1 and c["d2h_arrays"] == 1
+        assert c["rows"] == 12 and c["rows_padded"] == 16
+        assert c["h2d_bytes"] == c["d2h_bytes"] \
+            == 16 * (192 + 32 + staging.TAIL)
+        assert c["gm_gather_bytes"] == 16 * ctx.GM_BYTES
+        assert c["grouped"] == 0
+    assert rx.auth_fail.sum() == 2
+
+
+def test_packed_gcm_unprotect_async_matches_sync():
+    """`unprotect_rtp_async` goes through the same packed seam."""
+    n = 4
+    tx, _, _ = _table(n, 12)
+    t_sync, _, _ = _table(n, 12)
+    t_async, _, _ = _table(n, 12)
+    pls = [bytes([i]) * (20 + 30 * (i % 3)) for i in range(12)]
+    wire = tx.protect_rtp(rtp_header.build(
+        pls, [300 + i // n for i in range(12)], [0] * 12,
+        [0x1000 + i % n for i in range(12)], [96] * 12,
+        stream=[i % n for i in range(12)]))
+    bad = wire.copy()
+    bad.data[5, 14] ^= 0x40
+    want, want_ok = t_sync.unprotect_rtp(bad)
+    got, ok = t_async.unprotect_rtp_async(bad).block_until_ready() \
+        .result()
+    np.testing.assert_array_equal(ok, want_ok)
+    assert not ok[5] and ok.sum() == 11
+    for i in range(12):
+        assert got.to_bytes(i) == want.to_bytes(i), i
+    np.testing.assert_array_equal(t_async.rx_max, t_sync.rx_max)
+
+
+def test_packed_gcm_fanout_one_array_each_way(warmed_launch_guard,
+                                              oracle):
+    """With the tracer on, a warmed per-row GCM fan-out sends ONE array
+    to the device and copies one back, compiles nothing and starts no
+    `convert_element_type` program; every row opens under its leg."""
+    rng = np.random.default_rng(77)
+    t = tr_mod.RtpTranslator(8, GCM)
+    keys = {r: (rng.integers(0, 256, 16, dtype=np.uint8).tobytes(),
+                rng.integers(0, 256, 12, dtype=np.uint8).tobytes())
+            for r in (1, 2, 3)}
+    for r, (k, s) in keys.items():
+        t.add_receiver(r, k, s)
+    t.connect(0, [1, 2, 3])
+    t.tracer = tracer = PipelineTracer(annotate=False)
+
+    def batch(seq0):
+        pls = [bytes([seq0 + i & 0xFF]) * (40 + 9 * i) for i in range(4)]
+        return rtp_header.build(pls, [seq0 + i for i in range(4)],
+                                [0] * 4, [0x2000] * 4, [96] * 4,
+                                stream=[0] * 4)
+
+    t.translate(batch(1000), np.arange(1000, 1004))      # warms
+    tracer.take_ledger()
+    b = batch(1004)
+    with warmed_launch_guard():
+        out, recv = t.translate(b, np.arange(1004, 1008))
+    assert out.batch_size == 12
+    for j in range(12):
+        assert out.to_bytes(j) == oracle.protect_oracle_gcm(
+            *keys[int(recv[j])], b.to_bytes(j // 3), 1004 + j // 3), j
+    tracer.take_ledger()
+    counts = tracer.last_counts
+    plane = 16 * (192 + 32 + staging.TAIL)    # 12 rows padded to 16
+    assert counts["fanout_dispatch"] == {
+        "h2d_arrays": 1, "h2d_bytes": plane,
+        "gm_gather_bytes": 16 * tr_mod.GM_BYTES, "grouped": 0}
+    assert counts["fanout_d2h"] == {"d2h_arrays": 1, "d2h_bytes": plane}
+    assert counts["expand"] == {"rows": 12, "rows_padded": 16,
+                                "width": 224}
