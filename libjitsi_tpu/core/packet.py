@@ -138,7 +138,7 @@ def _round_rows(n: int) -> int:
 def bucket_by_size(batch: "PacketBatch",
                    length_classes=LENGTH_CLASSES,
                    headroom: int = CLASS_HEADROOM,
-                   tail: int = 0):
+                   tail: int = 0, pad_rows: bool = True):
     """Split a batch into width/row-class sub-batches.
 
     Returns a list of (orig_rows, sub_batch, n_real): `orig_rows` are the
@@ -156,6 +156,10 @@ def bucket_by_size(batch: "PacketBatch",
     view) of `sub_batch.plane`, and a device seam packs its per-row
     arguments into the rest (core/staging.py) instead of staging them
     as arrays of their own — the rows are copied once either way.
+
+    `pad_rows` False leaves each sub-batch at its real rows: a table on
+    a device mesh pads once, per chip, where it routes the rows to
+    their owners (mesh/table.py `_OwnerPlan`).
     """
     ln = np.asarray(batch.length)
     out = []
@@ -169,7 +173,7 @@ def bucket_by_size(batch: "PacketBatch",
             continue
         cap = cls + headroom
         n_real = len(rows)
-        n_pad = _round_rows(n_real)
+        n_pad = _round_rows(n_real) if pad_rows else n_real
         idx = np.resize(rows, n_pad)     # pads cycle the real rows
         plane = np.zeros((n_pad, cap + tail), dtype=np.uint8)
         take = min(cap, batch.capacity)

@@ -32,7 +32,8 @@ What keeps an array an argument (`put_each`, which counts what
 crossed): the grouped GCM form above the row classes (its grid arrays
 have another shape than the rows), the leg-major GCM fan-out,
 `protect_rtp`, AES-F8, a keystream-cache hit, SRTCP, and the mesh
-seams, which route an array per lane to its owning chip.
+seams, which route an array per lane to its owning chip
+(mesh/table.py `_sharded_call`, counted the same way).
 """
 
 from __future__ import annotations
@@ -94,25 +95,30 @@ class Launch:
     how its outputs come to the host.  `outs` are the program's outputs
     as they stand on the device (one packed plane; the three arrays of
     a grouped GCM, an F8 or a cache-hit unprotect; a mesh seam's
-    deferred scatters); `split` turns their host
+    outputs in lane layout, a block a chip); `split` turns their host
     copies into what the caller reads.  `fetch` waits, copies each
     output once and caches; `h2d_arrays` / `h2d_bytes` / `d2h_arrays` /
     `d2h_bytes` count the arrays that really crossed.  `counts` is what
     else the caller's span should book for the call (the GCM calls:
-    `gm_gather_bytes`, `grouped`)."""
+    `gm_gather_bytes`, `grouped`; a mesh call: `shards`, `lanes`,
+    `rows_hottest_shard`, `affine`), `d2h_counts` what the span round
+    the copy back should, where that is a span of its own (the
+    fan-out's `fanout_d2h`: a mesh call's four again)."""
 
     __slots__ = ("_outs", "_split", "_host", "h2d_arrays", "h2d_bytes",
-                 "d2h_arrays", "d2h_bytes", "counts")
+                 "d2h_arrays", "d2h_bytes", "counts", "d2h_counts")
 
     def __init__(self, outs, split: Optional[Callable] = None,
                  h2d_arrays: int = 0, h2d_bytes: int = 0,
-                 counts: Optional[dict] = None):
+                 counts: Optional[dict] = None,
+                 d2h_counts: Optional[dict] = None):
         self._outs = tuple(outs)
         self._split = split
         self._host = None
         self.h2d_arrays, self.h2d_bytes = h2d_arrays, h2d_bytes
         self.d2h_arrays = self.d2h_bytes = 0
         self.counts = counts or {}
+        self.d2h_counts = d2h_counts or {}
 
     def block_until_ready(self) -> "Launch":
         if self._host is None:

@@ -11,10 +11,14 @@ partitioned over a `jax.sharding.Mesh`:
   on the row axis — device d owns rows [d*S/n, (d+1)*S/n); nothing is
   replicated;
 - each batch is grouped by owning device on the host (the control plane
-  already knows every packet's row), padded per device to a power-of-two
-  lane count, and the crypto runs under `shard_map` with ZERO
-  collectives: a packet's key material is chip-local by construction —
-  stream-data-parallelism exactly as SURVEY §2.7 prescribes;
+  already knows every packet's row), padded per device to the row class
+  (`core/packet.py:ROW_CLASSES`) the hottest device's rows need, and
+  the crypto runs under `shard_map` with ZERO collectives: a packet's
+  key material is chip-local by construction — stream-data-parallelism
+  exactly as SURVEY §2.7 prescribes.  The plan is handed the REAL
+  rows (`_pads_rows` False: the host plane above pads nothing), so a
+  mesh launch is padded once and its lanes come from the five classes
+  the lifecycle ladder warms;
 - results stay DEVICE-RESIDENT in lane layout until materialized: the
   scatter back to wire order is deferred (`_LazyArray`), so
   `protect_rtp_async` keeps its launch-overlap contract in mesh mode
@@ -49,10 +53,26 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from libjitsi_tpu.mesh.compat import shard_map
 
 from libjitsi_tpu.core import staging
+from libjitsi_tpu.core.packet import (CLASS_HEADROOM, LENGTH_CLASSES,
+                                      ROW_CLASSES, _round_rows)
 from libjitsi_tpu.kernels import registry as _registry
 from libjitsi_tpu.transform.srtp import kernel
 from libjitsi_tpu.transform.srtp.context import SrtpStreamTable, _uniform_off
 from libjitsi_tpu.transform.srtp.policy import Cipher, SrtpProfile
+from libjitsi_tpu.utils.tracing import span_of
+
+#: the shard_map programs of a mesh, shared by every table and
+#: translator on it: rx table, tx table and the warm ladder's scratch
+#: tables launch the same programs, so a scratch table's warm-up warms
+#: the live table's (a jit object a table would each compile its own)
+_MESH_PROGRAMS: Dict[Mesh, Dict[Tuple, "jax.stages.Wrapped"]] = {}
+
+
+def _named(fn, name: str):
+    """`fn` under the name its program gets in the trace, the compile
+    log and the lowered module (`jit_<name>`)."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 class _LazyArray:
@@ -108,6 +128,11 @@ class ShardedRowsMixin:
     `_dev`-invalidation mirror, and the sharded device cache (one entry
     per named table group — "rtp", "rtcp")."""
 
+    #: the host plane above the seams pads no rows (`bucket_by_size`,
+    #: `_rtcp_row_pad`, `_cycle_rows`): `_OwnerPlan` pads the lanes a
+    #: chip to the row class of the hottest chip, once
+    _pads_rows = False
+
     def _init_sharding(self, mesh: Mesh, capacity: int) -> None:
         n_dev = int(mesh.devices.size)
         if capacity % n_dev:
@@ -121,7 +146,12 @@ class ShardedRowsMixin:
         self.n_dev = n_dev
         self.rows_per = capacity // n_dev
         self._sh_dev: Dict[str, Tuple] = {}
-        self._sh_fns: Dict[Tuple, "jax.stages.Wrapped"] = {}
+        self._sh_fns = _MESH_PROGRAMS.setdefault(mesh, {})
+        # times the key tables were placed on the mesh (a re-keying
+        # drops the copies; the next launch places them again)
+        self.placements = 0
+        # rows of the last call each shard owned (`mesh_rows_per_shard`)
+        self.shard_rows = np.zeros(n_dev, dtype=np.int64)
 
     # the parent classes use `self._dev = None` as their invalidation
     # signal (every key mutator sets it); mirror that onto the sharded
@@ -148,46 +178,101 @@ class ShardedRowsMixin:
             got = tuple(jax.device_put(t, spec)
                         for t in self._sharded_tables(group))
             self._sh_dev[group] = got
+            self.placements += 1
             if hasattr(self, "_aliased"):
                 # the table's COW discipline repoints masters before
                 # in-place mutation when this is set
                 self._aliased = True
         return got
 
-    def _sharded_launch(self, fn, tabs, ids, lane_args, extra_args=(),
-                        plan=None):
+    def _sharded_call(self, fn, tabs, ids, lane_args, extra_args=(),
+                      plan=None):
         """Plan/gather/dispatch shared by EVERY sharded seam (table
         CM/F8/GCM/SRTCP, translator fan-outs): route rows to their
-        owning chips, run `fn` under shard_map, and return one
-        `_LazyArray` per output — the scatter back to wire order is
-        DEFERRED until materialization, keeping the async contract.
-        `lane_args` are per-row arrays (1-D like length/off/roc or
-        N-D like data/iv) routed through the plan; `extra_args` are
-        already device-wide arrays passed through as-is (grouped-GCM
-        grids, fan-out packet blocks).  Callers that pre-built the
-        plan (to derive grids from it) pass it via `plan`.
-        """
+        owning chips (`owner_plan`: the plan and the lane gathers),
+        put each lane array on the mesh a block a chip, and run `fn`
+        under shard_map.  `lane_args` are per-row arrays (1-D like
+        length/off/roc or N-D like data/iv) routed through the plan;
+        `extra_args` are already device-wide arrays passed through
+        as-is (grouped-GCM grids, fan-out packet blocks).  Callers that
+        pre-built the plan (to derive grids from it) pass it via
+        `plan`.  Returns (outputs in lane layout `[n_dev, per(, W)]`,
+        still on the device; the plan; arrays that crossed; their
+        bytes)."""
         ids = np.asarray(ids, dtype=np.int64)
-        if plan is None:
-            plan = _OwnerPlan(ids, self.capacity, self.rows_per,
-                              self.n_dev)
-        local = local_rows(plan, ids, self.capacity, self.rows_per,
-                           self.n_dev)
-        if plan.affine:
-            # identity routing: lane gather is a reshape, and the
-            # output scatter is skipped entirely (inv=None)
-            outs = fn(*tabs, jnp.asarray(local),
-                      *(jnp.asarray(np.asarray(a).reshape(
-                            plan.slot.shape[0], plan.per,
-                            *np.asarray(a).shape[1:]))
-                        for a in lane_args),
-                      *(jnp.asarray(e) for e in extra_args))
-            return tuple(_LazyArray(o, None) for o in outs)
-        outs = fn(*tabs, jnp.asarray(local),
-                  *(jnp.asarray(np.asarray(a)[plan.slot])
-                    for a in lane_args),
-                  *(jnp.asarray(e) for e in extra_args))
-        return tuple(_LazyArray(o, plan.inv) for o in outs)
+        with span_of(getattr(self, "tracer", None), "owner_plan",
+                     rows=len(ids), shards=self.n_dev):
+            if plan is None:
+                plan = _OwnerPlan(ids, self.capacity, self.rows_per,
+                                  self.n_dev)
+            lanes = [local_rows(plan, ids, self.capacity, self.rows_per,
+                                self.n_dev)]
+            for a in lane_args:
+                a = np.asarray(a)
+                # identity routing: the lane gather is a reshape
+                lanes.append(a.reshape(self.n_dev, plan.per, *a.shape[1:])
+                             if plan.affine else a[plan.slot])
+        self.shard_rows = plan.counts
+        dev, n, nbytes = self._put_lanes(lanes)
+        outs = fn(*tabs, *dev, *(jnp.asarray(e) for e in extra_args))
+        return outs, plan, n, nbytes
+
+    def _put_lanes(self, lanes):
+        """`staging.put_each` for lane arrays `[n_dev, per, ...]`: each
+        crosses once, a block to the chip that owns it, in the dtype
+        the program takes (64-bit words are cut to 32 on the host, as
+        JAX would on the device).  Returns (device arrays, how many
+        crossed, their bytes)."""
+        host = []
+        for a in lanes:
+            if a.dtype.itemsize == 8:
+                a = a.astype(np.uint32 if a.dtype.kind == "u"
+                             else np.int32)
+            host.append(np.ascontiguousarray(a))
+        dev = [jax.device_put(a, NamedSharding(
+            self.mesh, P(self._axes, *([None] * (a.ndim - 1)))))
+            for a in host]
+        return dev, len(host), sum(int(a.nbytes) for a in host)
+
+    def _sharded_launch(self, fn, tabs, ids, lane_args, extra_args=(),
+                        plan=None):
+        """`_sharded_call` with one `_LazyArray` per output — the
+        scatter back to wire order is DEFERRED until materialization,
+        keeping the async contract (the protect, F8, GCM and SRTCP
+        seams; the served CM unprotect and fan-out hand their outputs
+        to a `staging.Launch`, `_mesh_launch`)."""
+        outs, plan, _n, _nbytes = self._sharded_call(
+            fn, tabs, ids, lane_args, extra_args, plan)
+        inv = None if plan.affine else plan.inv   # affine: wire order
+        return tuple(_LazyArray(o, inv) for o in outs)
+
+    def _mesh_launch(self, outs, plan: "_OwnerPlan", h2d_arrays: int,
+                     h2d_bytes: int, dtypes) -> staging.Launch:
+        """A `staging.Launch` over a sharded call's outputs: `fetch()`
+        copies each lane-layout output back once (counted as it
+        crosses) and scatters it to wire order (`mesh_scatter`), cast to
+        `dtypes` (None: as it came).  Its counts are the plan's:
+        `shards`, `lanes` a chip, `rows_hottest_shard`, `affine`."""
+        n = len(plan.inv)
+        inv = None if plan.affine else plan.inv
+        tracer = getattr(self, "tracer", None)
+
+        def scatter(*host):
+            with span_of(tracer, "mesh_scatter", rows=n):
+                out = []
+                for a, dt in zip(host, dtypes):
+                    a = a.reshape(-1, *a.shape[2:])
+                    if inv is not None:   # None: affine plan, wire order
+                        a = a[inv]
+                    out.append(a if dt is None else a.astype(dt))
+                return tuple(out)
+
+        counts = {"shards": self.n_dev, "lanes": plan.per,
+                  "rows_hottest_shard": int(plan.counts.max()),
+                  "affine": int(plan.affine)}
+        return staging.Launch(outs, scatter, h2d_arrays=h2d_arrays,
+                              h2d_bytes=h2d_bytes, counts=counts,
+                              d2h_counts=counts)
 
 
 def local_rows(plan: "_OwnerPlan", ids: np.ndarray, capacity: int,
@@ -207,11 +292,19 @@ class _OwnerPlan:
     """Host-side routing of one batch onto the row partition: `slot`
     [n_dev, per] gathers batch rows into per-device lanes (pads repeat a
     real row — crypto on device is stateless, pads are dropped at
-    scatter); `inv` [B] maps each original row to its flat lane.
+    scatter); `inv` [B] maps each original row to its flat lane;
+    `counts` [n_dev] are the rows each device owns.
+
+    `per`, the lanes a chip, is the ROW CLASS (`core/packet.py`
+    `ROW_CLASSES`, multiples of the largest beyond it) that the
+    hottest device's rows need: the one rule of the mesh's shape space,
+    so a mesh launch takes the five lane counts the lifecycle ladder
+    warms whatever the tick's skew, and, handed the real rows, is
+    padded once.
     Fully vectorized — no Python loop over devices (the loop showed
     at 64k-batch x 8-device shapes)."""
 
-    __slots__ = ("slot", "inv", "per", "affine")
+    __slots__ = ("slot", "inv", "per", "affine", "counts")
 
     def __init__(self, stream: np.ndarray, capacity: int, rows_per: int,
                  n_dev: int):
@@ -220,27 +313,24 @@ class _OwnerPlan:
         owner = s // rows_per
         # Affine fast path (conference-affinity placement's steady
         # state, mesh/placement.py): the batch already arrives
-        # shard-major with equal per-shard counts — rows are drawn from
-        # contiguous per-shard ranges, so no argsort, no scattered
-        # writes, and crucially NO pad-lane skew (random routing pads
-        # every device to the hottest device's pow2 lane count, which
-        # is where the mesh's 2x-slowdown came from).  Identity
+        # shard-major with equal per-shard counts that fill a row
+        # class — rows are drawn from contiguous per-shard ranges, so no
+        # argsort, no scattered writes and no pad lane.  Identity
         # routing: slot is a reshape, inv is arange.
         cnt = n // n_dev if n_dev else 0
         self.affine = bool(
-            n and cnt >= 4 and n == cnt * n_dev
-            and (cnt & (cnt - 1)) == 0
+            n and n == cnt * n_dev and cnt == _round_rows(cnt)
             and np.array_equal(owner,
                                np.repeat(np.arange(n_dev), cnt)))
         if self.affine:
             self.per = cnt
+            self.counts = np.full(n_dev, cnt, dtype=np.int64)
             self.slot = np.arange(n, dtype=np.int64).reshape(n_dev, cnt)
             self.inv = np.arange(n, dtype=np.int64)
             return
         order = np.argsort(owner, kind="stable")
-        counts = np.bincount(owner, minlength=n_dev)
-        top = int(counts.max()) if n else 1
-        self.per = per = 1 << max(int(top - 1).bit_length(), 2)
+        self.counts = counts = np.bincount(owner, minlength=n_dev)
+        self.per = per = _round_rows(int(counts.max()) if n else 1)
         starts = np.concatenate(([0], np.cumsum(counts)))
         dev_sorted = owner[order]
         lane = np.arange(n, dtype=np.int64) - starts[dev_sorted]
@@ -402,87 +492,39 @@ class ShardedSrtpTable(ShardedRowsMixin, SrtpStreamTable):
         t._load_state(snap)
         return t
 
-    def warmup(self, max_batch: int, off_const=12,
-               capacities=(224, 544)) -> None:
-        """Pre-compile the shard_map ladders so live ticks never absorb
-        an XLA compile (the same discipline as AudioMixer's setup-time
-        warmup): lane counts are power-of-two padded and bounded by the
-        BATCH size (worst-case skew parks a whole batch on one chip),
-        so the pow2 ladder up to `max_batch` covers every lane shape a
-        batch that size can produce — per payload offset AND per
-        bucketing capacity class (the defaults are `bucket_by_size`'s
-        LENGTH_CLASSES + CLASS_HEADROOM; batches in the terminal
-        full-width class, like rare offsets, still compile lazily).
-        Covers the RTP ops, the SRTCP programs (sharded since round
-        5 — RTCP batches are not size-bucketed, so only the listed
-        capacities pre-compile), and for GCM the registry's
-        grouped/per-row measurement (advisor r5: the measurement
-        compiles both providers and times 12 launches — that must
-        happen here, ON THIS table, not on the first live batch).
-        Called by ConferenceBridge.warmup(); standalone deployments
-        call it before going live."""
-        tabs = self._sharded_device("rtp")
-        rtcp_tabs = self._sharded_device("rtcp")
-        gcm = self._gcm
-        encrypt = self.policy.cipher != Cipher.NULL
-        tag = self.policy.auth_tag_len
-        if gcm:
-            ops = ("gcm_protect", "gcm_unprotect")
-        elif self._f8:
-            ops = ("f8_protect", "f8_unprotect")
-        else:
-            ops = ("protect", "unprotect")
-        for cap in capacities:
-            lanes = 4
-            top = max(4, max_batch)
-            while True:
-                for op in ops:
-                    fn = self._shard_fn(op, tag, encrypt, off_const)
-                    shape = (self.n_dev, lanes)
-                    args = list(tabs)
-                    args += [jnp.zeros(shape, jnp.int32),
-                             jnp.zeros(shape + (cap,), jnp.uint8),
-                             jnp.full(shape, 64, jnp.int32),
-                             jnp.full(shape, off_const, jnp.int32)]
-                    if gcm:
-                        args.append(jnp.zeros(shape + (12,), jnp.uint8))
-                    else:
-                        args += [jnp.zeros(shape + (16,), jnp.uint8),
-                                 jnp.zeros(shape, jnp.uint32)]
-                    jax.block_until_ready(fn(*args))
-                if not gcm and lanes <= 256:
-                    # SRTCP ladder (the GCM SRTCP seam reuses the RTP
-                    # gcm programs above — same _shard_fn cache key).
-                    # Capped at 256 lanes: control traffic is low-rate,
-                    # and every ladder rung is a fresh compile.
-                    self._warmup_rtcp(rtcp_tabs, cap, lanes, tag,
-                                      encrypt)
-                if lanes >= top:
-                    break
-                lanes *= 2
-        if gcm:
-            self._warmup_gcm_registry(max_batch, capacities)
+    def _scratch(self) -> "ShardedSrtpTable":
+        """The warm ladder's throwaway table: sharded over this table's
+        mesh, so `warmup_rtp` / `warmup_rtcp` (the base class's, run as
+        they are) compile the shard_map programs this table launches,
+        at the lanes the owner plan gives their batches."""
+        return ShardedSrtpTable(self.capacity, self.mesh, self.profile)
 
-    def _warmup_rtcp(self, rtcp_tabs, cap: int, lanes: int, tag: int,
-                     encrypt: bool) -> None:
-        shape = (self.n_dev, lanes)
-        p_fn = self._shard_fn(
-            "rtcp_f8_protect" if self._f8 else "rtcp_protect", tag,
-            encrypt, None)
-        jax.block_until_ready(p_fn(
-            *rtcp_tabs, jnp.zeros(shape, jnp.int32),
-            jnp.zeros(shape + (cap,), jnp.uint8),
-            jnp.full(shape, 64, jnp.int32),
-            jnp.zeros(shape + (16,), jnp.uint8),
-            jnp.zeros(shape, jnp.int32)))
-        u_fn = self._shard_fn(
-            "rtcp_f8_unprotect" if self._f8 else "rtcp_unprotect", tag,
-            encrypt, None)
-        jax.block_until_ready(u_fn(
-            *rtcp_tabs, jnp.zeros(shape, jnp.int32),
-            jnp.zeros(shape + (cap,), jnp.uint8),
-            jnp.full(shape, 64, jnp.int32),
-            jnp.zeros(shape + (16,), jnp.uint8)))
+    def warmup(self, max_batch: int, payload_lens=(160, 480)) -> None:
+        """Pre-compile the shard_map programs so live ticks never absorb
+        an XLA compile (the same discipline as AudioMixer's setup-time
+        warmup), for a deployment that runs no lifecycle ladder: the
+        ladder's own steps (`warmup_rtp`, `warmup_rtcp`) for every row
+        class up to the one holding `max_batch` (worst-case skew parks
+        a whole batch on one chip, and the plan's lanes are row
+        classes), per bucketing width class (`payload_lens`: one packet
+        size inside each of `bucket_by_size`'s first two; batches in
+        the terminal full-width class, like rare offsets, still compile
+        lazily), and for GCM the registry's grouped/per-row measurement
+        (advisor r5: the measurement compiles both providers and times
+        12 launches — that must happen here, ON THIS table, not on the
+        first live batch).  Called by ConferenceBridge.warmup();
+        standalone deployments call it before going live."""
+        top = _round_rows(max(1, max_batch))
+        for rc in ROW_CLASSES:
+            if rc > top:
+                break
+            for plen in payload_lens:
+                self.warmup_rtp(rc, payload_len=plen)
+            self.warmup_rtcp(rc)
+        if self._gcm:
+            self._warmup_gcm_registry(
+                max_batch, tuple(c + CLASS_HEADROOM
+                                 for c in LENGTH_CLASSES[:2]))
 
     def _warmup_gcm_registry(self, max_batch: int, capacities) -> None:
         """Drive THIS table's GCM registry seams with synthetic args so
@@ -492,8 +534,6 @@ class ShardedSrtpTable(ShardedRowsMixin, SrtpStreamTable):
         geometry token but leave the live table's jit closures cold —
         advisor r5).  Pure dispatch: these seams touch no host crypto
         state (replay/tx planes live in the callers above them)."""
-        from libjitsi_tpu.core.packet import ROW_CLASSES
-
         rng = np.random.default_rng(0)
         n = max(1, min(self.capacity, 64))
         for cap in capacities:
@@ -517,11 +557,14 @@ class ShardedSrtpTable(ShardedRowsMixin, SrtpStreamTable):
 
     # ------------------------------------------------------- sharded seams
     def _run_sharded(self, op: str, stream, batch, hdr, length,
-                     tail_args):
+                     tail_args, launch=None):
+        """One RTP program over a part; `launch`: `_sharded_launch`
+        (deferred scatters) unless the seam builds a `staging.Launch`
+        from `_sharded_call`'s outputs itself."""
         off_const = _uniform_off(hdr.payload_off, batch.capacity)
         fn = self._shard_fn(op, self.policy.auth_tag_len,
                             self.policy.cipher != Cipher.NULL, off_const)
-        return self._sharded_launch(
+        return (launch or self._sharded_launch)(
             fn, self._sharded_device("rtp"), stream,
             [batch.data, np.asarray(length, dtype=np.int32),
              hdr.payload_off, *tail_args])
@@ -543,15 +586,14 @@ class ShardedSrtpTable(ShardedRowsMixin, SrtpStreamTable):
         leading columns) and what goes back is a `staging.Launch` whose
         `fetch()` gives host (data, media_len, auth_ok).  The sharded
         call packs nothing: its arguments are routed to their owning
-        chips one array each, and the launch holds the three deferred
-        scatters."""
-        roc = self._roc32(v)
-        data, mlen, auth_ok = self._run_sharded(
-            "unprotect", stream, batch, hdr, length, [iv, roc])
-        return staging.Launch(
-            (data, mlen.astype(np.int32), auth_ok), h2d_arrays=6,
-            h2d_bytes=batch.data.nbytes + iv.nbytes + roc.nbytes
-            + 12 * batch.batch_size)
+        chips one array each (six cross, counted as they do), and the
+        launch holds the three outputs in lane layout until `fetch`
+        scatters them back."""
+        outs, plan, n, nbytes = self._run_sharded(
+            "unprotect", stream, batch, hdr, length,
+            [iv, self._roc32(v)], launch=self._sharded_call)
+        return self._mesh_launch(outs, plan, n, nbytes,
+                                 (None, np.int32, None))
 
     # ------------------------------------------------------------------ F8
     def _f8_rtp_protect_call(self, stream, batch, hdr, iv, v):
@@ -695,7 +737,8 @@ class ShardedSrtpTable(ShardedRowsMixin, SrtpStreamTable):
         else:
             fn = self._build_rtp_fn(op, tag_len, encrypt, f8, off_const,
                                     row3, lanes)
-        # setdefault: concurrent warm-ups of one key share ONE jit
+        # setdefault: concurrent warm-ups of one key, and every table
+        # of the mesh, share ONE jit (`_MESH_PROGRAMS`)
         return self._sh_fns.setdefault(key, fn)
 
     def _build_rtp_fn(self, op, tag_len, encrypt, f8, off_const, row3,
@@ -723,7 +766,8 @@ class ShardedSrtpTable(ShardedRowsMixin, SrtpStreamTable):
                         lanes)
         n_out = 2 if "unprotect" not in op else 3
         return jax.jit(shard_map(
-            _run, mesh=self.mesh, in_specs=in_specs,
+            _named(_run, f"mesh_{op}_rtp"), mesh=self.mesh,
+            in_specs=in_specs,
             out_specs=(row3, lanes) if n_out == 2
             else (row3, lanes, lanes), check_vma=False))
 
@@ -758,7 +802,8 @@ class ShardedSrtpTable(ShardedRowsMixin, SrtpStreamTable):
 
             in_specs = (row3, row3, lanes, row3, lanes, lanes, row3)
         return jax.jit(shard_map(
-            _run, mesh=self.mesh, in_specs=in_specs,
+            _named(_run, f"mesh_{op}"), mesh=self.mesh,
+            in_specs=in_specs,
             out_specs=(row3, lanes, lanes) if unprot else (row3, lanes),
             check_vma=False))
 
@@ -803,5 +848,5 @@ class ShardedSrtpTable(ShardedRowsMixin, SrtpStreamTable):
                 in_specs = (row3, row3, lanes, row3, lanes, row3, lanes)
             out_specs = (row3, lanes)
         return jax.jit(shard_map(
-            _run, mesh=self.mesh, in_specs=in_specs,
-            out_specs=out_specs, check_vma=False))
+            _named(_run, f"mesh_{op}"), mesh=self.mesh,
+            in_specs=in_specs, out_specs=out_specs, check_vma=False))

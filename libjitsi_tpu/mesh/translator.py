@@ -26,7 +26,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from libjitsi_tpu.core import staging
 from libjitsi_tpu.mesh.compat import shard_map
 
-from libjitsi_tpu.mesh.table import ShardedRowsMixin
+from libjitsi_tpu.mesh.table import ShardedRowsMixin, _named
 from libjitsi_tpu.sfu.translator import RtpTranslator
 from libjitsi_tpu.transform.srtp import kernel
 from libjitsi_tpu.transform.srtp.policy import Cipher, SrtpProfile
@@ -55,27 +55,36 @@ class ShardedRtpTranslator(ShardedRowsMixin, RtpTranslator):
     def _sharded_tables(self, group: str = "rtp"):
         return self._rk, (self._gm if self._gcm else self._mid)
 
+    def fanout_warmups(self, rows: int, payload_len: int = 160):
+        """`RtpTranslator.fanout_warmups` on a throwaway translator of
+        this class on this mesh: the thunks launch the shard_map
+        programs this translator launches (shared a mesh), leave its
+        placed tables alone and, run side by side off the tick thread,
+        open no span of its tracer."""
+        scratch = ShardedRtpTranslator(self.capacity, self.mesh,
+                                       self.profile)
+        scratch._max_legs = self._max_legs
+        return RtpTranslator.fanout_warmups(scratch, rows, payload_len)
+
     def _cm_fanout_call(self, recv, plane, length, payload_off, iv, idx
                         ) -> staging.Launch:
         """The seam's contract is `RtpTranslator._cm_fanout_call`'s:
         `plane` holds the packet bytes in its leading columns, and what
         comes back is a `staging.Launch` whose `fetch()` gives host
         (wire bytes, wire lengths).  The sharded call packs nothing: its
-        arguments are routed to their owning chips one array each, and
-        the launch holds the two deferred scatters."""
+        arguments are routed to their owning chips one array each (six
+        cross, counted as they do), and the launch holds the two
+        outputs in lane layout until `fetch` scatters them back."""
         from libjitsi_tpu.transform.srtp.context import _uniform_off
 
         data = plane[:, :plane.shape[-1] - staging.TAIL]
         roc = ((np.asarray(idx) >> 16) & 0xFFFFFFFF).astype(np.uint32)
-        lanes = [data, np.asarray(length, dtype=np.int32), payload_off, iv,
-                 roc]
-        out, out_len = self._sharded_launch(
+        outs, plan, n, nbytes = self._sharded_call(
             self._fanout_fn(_uniform_off(payload_off, data.shape[-1])),
-            self._sharded_device(), recv, lanes)
-        return staging.Launch(
-            (out, out_len.astype(np.int32)), h2d_arrays=1 + len(lanes),
-            h2d_bytes=4 * len(recv) + sum(
-                int(np.asarray(a).nbytes) for a in lanes))
+            self._sharded_device(), recv,
+            [data, np.asarray(length, dtype=np.int32), payload_off, iv,
+             roc])
+        return self._mesh_launch(outs, plan, n, nbytes, (None, np.int32))
 
     def _gcm_fanout_call(self, recv, plane, length, payload_off, iv12
                          ) -> staging.Launch:
@@ -86,14 +95,11 @@ class ShardedRtpTranslator(ShardedRowsMixin, RtpTranslator):
         data = plane[:, :plane.shape[-1] - staging.TAIL]
         fn = self._gcm_fanout_fn(_uniform_off(payload_off,
                                               data.shape[-1]))
-        lanes = [data, np.asarray(length, dtype=np.int32), payload_off,
-                 iv12]
-        out, out_len = self._sharded_launch(
-            fn, self._sharded_device(), recv, lanes)
-        return staging.Launch(
-            (out, out_len.astype(np.int32)), h2d_arrays=1 + len(lanes),
-            h2d_bytes=4 * len(recv) + sum(
-                int(np.asarray(a).nbytes) for a in lanes))
+        outs, plan, n, nbytes = self._sharded_call(
+            fn, self._sharded_device(), recv,
+            [data, np.asarray(length, dtype=np.int32), payload_off,
+             iv12])
+        return self._mesh_launch(outs, plan, n, nbytes, (None, np.int32))
 
     def _gcm_uniform_fanout_call(self, rr, pdata, plen, iv, aad_const
                                  ) -> staging.Launch:
@@ -133,13 +139,14 @@ class ShardedRtpTranslator(ShardedRowsMixin, RtpTranslator):
         row3 = P(self._axes, None, None)
         lanes = P(self._axes, None)
         fn = jax.jit(shard_map(
-            _run, mesh=self.mesh,
+            _named(_run, "mesh_fanout_protect_gcm_legs"), mesh=self.mesh,
             in_specs=(row3, row3, lanes,
                       P(self._axes, None, None, None),
                       P(None, None), P(None)),
             out_specs=(P(self._axes, None, None, None),),
             check_vma=False))
-        # setdefault: concurrent warm-ups of one key share ONE jit
+        # setdefault: concurrent warm-ups of one key, and every
+        # translator of the mesh, share ONE jit
         return self._sh_fns.setdefault(key, fn)
 
     def _gcm_fanout_fn(self, off_const):
@@ -158,10 +165,11 @@ class ShardedRtpTranslator(ShardedRowsMixin, RtpTranslator):
         row3 = P(self._axes, None, None)
         lanes = P(self._axes, None)
         fn = jax.jit(shard_map(
-            _run, mesh=self.mesh,
+            _named(_run, "mesh_fanout_protect_gcm"), mesh=self.mesh,
             in_specs=(row3, row3, lanes, row3, lanes, lanes, row3),
             out_specs=(row3, lanes), check_vma=False))
-        # setdefault: concurrent warm-ups of one key share ONE jit
+        # setdefault: concurrent warm-ups of one key, and every
+        # translator of the mesh, share ONE jit
         return self._sh_fns.setdefault(key, fn)
 
     def _fanout_fn(self, off_const=None):
@@ -183,9 +191,10 @@ class ShardedRtpTranslator(ShardedRowsMixin, RtpTranslator):
         row3 = P(self._axes, None, None)
         lanes = P(self._axes, None)
         fn = jax.jit(shard_map(
-            _run, mesh=self.mesh,
+            _named(_run, "mesh_fanout_protect"), mesh=self.mesh,
             in_specs=(row3, row3, lanes, row3, lanes, lanes, row3,
                       lanes),
             out_specs=(row3, lanes), check_vma=False))
-        # setdefault: concurrent warm-ups of one key share ONE jit
+        # setdefault: concurrent warm-ups of one key, and every
+        # translator of the mesh, share ONE jit
         return self._sh_fns.setdefault(key, fn)

@@ -152,6 +152,11 @@ class ConferenceBridge:
         _log.info("participant_join", sid=sid, ssrc=ssrc)
         return sid
 
+    def has_ssrc(self, ssrc: int) -> bool:
+        """Whether a participant has joined under this SSRC (what
+        admission asks of any bridge)."""
+        return (ssrc & 0xFFFFFFFF) in self._ssrc_of.values()
+
     def _register_media(self, ssrc: int,
                         codec: Optional[FrameCodec]) -> int:
         """Crypto-independent join half: row, demux, bank/mixer/speaker."""
@@ -161,7 +166,7 @@ class ConferenceBridge:
             raise ValueError(
                 f"codec ptime {codec.frame_samples * 1000.0 / codec.sample_rate:.1f} ms "
                 f"!= bridge ptime {self.ptime_ms} ms")
-        if ssrc in [s for s in self._ssrc_of.values()]:
+        if self.has_ssrc(ssrc):
             # silently remapping would mute the existing participant
             raise ValueError(f"ssrc {ssrc:#x} already joined")
         sid = self.registry.alloc(self)

@@ -57,6 +57,8 @@ from collections import deque
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
+import numpy as np
+
 from libjitsi_tpu.core.packet import ROW_CLASSES
 from libjitsi_tpu.utils.compile_cache import (compile_concurrently,
                                                compile_stats)
@@ -98,6 +100,13 @@ class LifecycleConfig:
     # nominal between-ticks cadence used to turn a backlog depth into
     # the retry-after hint attached to handshake_backlog refusals
     handshake_retry_tick_s: float = 0.02
+    # ------------------------------------------------ sharded tables
+    # what the deployment states of the bridge it hands the manager:
+    # its key tables are row-sharded this many ways (0: not stated).
+    # Picks nothing: the manager refuses a bridge whose tables are
+    # sharded otherwise, and a placement whose shards are not the
+    # tables' (a conference could then straddle a chip unseen)
+    table_shards: int = 0
 
 
 class HandshakeQueue:
@@ -235,6 +244,12 @@ class StreamLifecycleManager:
         self.bridge = bridge
         self.supervisor = supervisor
         self.cfg = config or LifecycleConfig()
+        mesh = getattr(bridge, "_mesh", None)
+        shards = 1 if mesh is None else int(mesh.devices.size)
+        if self.cfg.table_shards and self.cfg.table_shards != shards:
+            raise ValueError(
+                f"the configuration states {self.cfg.table_shards} table "
+                f"shards, the bridge's tables have {shards}")
         if flight is None:
             flight = (supervisor.flight if supervisor is not None
                       else getattr(bridge, "flight", None))
@@ -313,6 +328,9 @@ class StreamLifecycleManager:
         barrier.  `n_shards` must divide the registry capacity (shard
         ranges are contiguous row blocks)."""
         from libjitsi_tpu.mesh.placement import ConferencePlacer
+        if self.cfg.table_shards and n_shards != self.cfg.table_shards:
+            raise ValueError(f"{n_shards} placement shards over "
+                             f"{self.cfg.table_shards} table shards")
         capacity = self.bridge.registry.capacity
         if capacity % n_shards:
             raise ValueError(f"capacity {capacity} not divisible by "
@@ -471,10 +489,9 @@ class StreamLifecycleManager:
         (video tracks and direct add_endpoint also draw from it);
         placement only constrains WHERE a conference's rows may live."""
         lo = shard * self._rows_per_shard
-        hi = lo + self._rows_per_shard
-        avail = sorted(s for s in self.bridge.registry._free
-                       if lo <= s < hi)
-        return avail[:k]
+        free = np.fromiter(self.bridge.registry._free, dtype=np.int64)
+        free = free[(free >= lo) & (free < lo + self._rows_per_shard)]
+        return np.sort(free)[:k].tolist()
 
     # ------------------------------------------------------- admission
 
@@ -482,8 +499,7 @@ class StreamLifecycleManager:
         return self.supervisor.ticks if self.supervisor is not None else 0
 
     def _admission_reason(self, ssrc: int) -> Optional[str]:
-        if (ssrc in self.bridge._ssrc_of.values()
-                or ssrc in self._queued_ssrcs):
+        if self.bridge.has_ssrc(ssrc) or ssrc in self._queued_ssrcs:
             return "duplicate"
         if len(self._join_q) + len(self._staged) >= self.cfg.max_pending:
             return "backlog"
@@ -619,8 +635,7 @@ class StreamLifecycleManager:
                 "bridge has no DTLS association table; use request_join")
         ssrc = int(ssrc) & 0xFFFFFFFF
         reason: Optional[str] = None
-        if (ssrc in self.bridge._ssrc_of.values()
-                or ssrc in self._queued_ssrcs):
+        if self.bridge.has_ssrc(ssrc) or ssrc in self._queued_ssrcs:
             reason = "duplicate"
         elif self.bridge.registry.free_slots <= len(self._join_q):
             reason = "capacity"

@@ -290,10 +290,18 @@ class SfuBridge:
             obj.tracer, obj.perf = self.loop.tracer, self.loop.perf
 
     # ---------------------------------------------------------- endpoints
+    def has_ssrc(self, ssrc: int) -> bool:
+        """Whether an endpoint has joined under this SSRC: one look in
+        the registry's demux map (every join maps its SSRC there), not
+        a walk over the live endpoints — admission asks once a join."""
+        ssrc &= 0xFFFFFFFF
+        sid = self.registry._ssrc_to_sid.get(ssrc)
+        return sid is not None and self._ssrc_of.get(sid) == ssrc
+
     def add_endpoint(self, ssrc: int, rx_key: Tuple[bytes, bytes],
                      tx_key: Tuple[bytes, bytes],
                      name: Optional[str] = None) -> int:
-        if ssrc in self._ssrc_of.values():
+        if self.has_ssrc(ssrc):
             raise ValueError(f"ssrc {ssrc:#x} already joined")
         self._quiesce_fanout()
         sid = self.registry.alloc(self)
@@ -328,7 +336,7 @@ class SfuBridge:
         are dropped rather than guessed onto the wrong row).
         Reference: DtlsControlImpl started by MediaStream.start
         (SURVEY §3.5)."""
-        if ssrc in self._ssrc_of.values():
+        if self.has_ssrc(ssrc):
             raise ValueError(f"ssrc {ssrc:#x} already joined")
         sid = self.registry.alloc(self)
         self.registry.map_ssrc(ssrc, sid)
@@ -473,7 +481,7 @@ class SfuBridge:
         if not specs:
             return []
         for ssrc, _rx, _tx, _name in specs:
-            if ssrc in self._ssrc_of.values():
+            if self.has_ssrc(ssrc):
                 raise ValueError(f"ssrc {ssrc:#x} already joined")
         self._quiesce_fanout()
         if sids is None:
@@ -498,9 +506,8 @@ class SfuBridge:
                            for _, _, tx, _ in specs])
         self.rx_table.add_streams(arr, rx_mks, rx_mss)
         self.tx_table.add_streams(arr, tx_mks, tx_mss)
-        self.translator.add_receivers(
-            sids, [tx[0] for _, _, tx, _ in specs],
-            [tx[1] for _, _, tx, _ in specs])
+        # a leg's fan-out keys are its tx keys: derived once a wave
+        self.translator.adopt_receivers(arr, self.tx_table)
         for sid, (ssrc, rx, tx, name) in zip(sids, specs):
             self.registry.map_ssrc(ssrc, sid)
             self._ssrc_of[sid] = ssrc & 0xFFFFFFFF
@@ -515,12 +522,15 @@ class SfuBridge:
 
     def commit_endpoints(self, sids) -> None:
         """Between-ticks commit barrier: flip staged rows live — one
-        route rebuild for the whole batch, held media replayed through
-        the normal receive path, video receivers attached."""
+        route rebuild for the whole batch, of the conferences the batch
+        joins and no other (a wave's cost does not grow with the
+        bridge), held media replayed through the normal receive path,
+        video receivers attached."""
         sids = [int(s) for s in sids if int(s) in self._staged]
         if not sids:
             return
         self._quiesce_fanout()
+        touched = {self._conf_of.get(sid, -1) for sid in sids}
         for sid in sids:
             self._staged.discard(sid)
             conf = self._conf_of.get(sid)
@@ -530,7 +540,7 @@ class SfuBridge:
                 # barrier later)
                 self.loop.set_fanout_only(
                     sid, sid not in self._bcast_speakers[conf])
-        self._rebuild_routes()
+        self._rebuild_routes(touched)
         for sid in sids:
             for track in set(self._video.values()):
                 self._attach_video_receiver(track, sid)
@@ -869,13 +879,23 @@ class SfuBridge:
             return True
         return False
 
-    def _rebuild_routes(self) -> None:
+    def _rebuild_routes(self, conferences=None) -> None:
         """Full mesh: every sender forwards to every OTHER endpoint.
         DTLS-pending rows have no leg keys yet and stay out of the mesh
         until their install completes; staged rows (lifecycle admit in
-        flight) stay out until their commit barrier."""
-        sids = [s for s in sorted(self._ssrc_of)
-                if s not in self._dtls.pending and s not in self._staged]
+        flight) stay out until their commit barrier.
+
+        `conferences`: rebuild these conferences' routes alone (ids as
+        `_conf_of` has them, -1 the rows without one): a route depends
+        on nothing outside its sender's conference, so a commit of a
+        few joins reconnects those conferences' senders and not every
+        live one.  None: all."""
+        conf_of = self._conf_of
+        only = conferences if conf_of else None
+        sids = sorted(
+            s for s in self._ssrc_of
+            if s not in self._dtls.pending and s not in self._staged
+            and (only is None or conf_of.get(s, -1) in only))
         if self._conf_of:
             # conference-scoped mesh: a sender fans out only within its
             # conference (rows without an id share the -1 group)

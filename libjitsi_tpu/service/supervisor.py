@@ -781,6 +781,13 @@ class BridgeSupervisor:
                 "srtp_replay_reject",
                 lambda: self.bridge.rx_table.replay_reject,
                 help_="SRTP replay-window rejections", kind="counter")
+        if table is not None and hasattr(table, "shard_rows"):
+            registry.register_multi(
+                "mesh_rows_per_shard",
+                lambda: [({"shard": str(d)}, float(n)) for d, n in
+                         enumerate(self.bridge.rx_table.shard_rows)],
+                help_="rows of the last tick's unprotect call that "
+                      "each mesh shard owned")
         if hasattr(self.bridge, "forwarded"):
             # denominator of the residual-loss SLO: packets the bridge
             # actually forwarded downstream

@@ -137,6 +137,11 @@ class RtpTranslator:
     routing table says which receivers get which sender's media.
     """
 
+    #: the per-row fan-out's rows are cycled up to their row class
+    #: (`_cycle_rows`) here, in `expand`.  The mesh translator says
+    #: False: its owner plan pads the lanes a chip, once
+    _pads_rows = True
+
     def __init__(self, capacity: int = 1024,
                  profile: SrtpProfile = SrtpProfile.AES_CM_128_HMAC_SHA1_80):
         self.profile = profile
@@ -216,6 +221,27 @@ class RtpTranslator:
             self._mid[rids] = hmac_precompute_batch(ksb.rtp_auth)
         self._salt[rids, : p.salt_len] = ksb.rtp_salt
         self._salt[rids, p.salt_len:] = 0
+        self.active[rids] = True
+        self._dev = None
+
+    def adopt_receivers(self, rids, table) -> None:
+        """`add_receivers` for legs whose keys an SRTP table holds
+        already: rows `rids` of `table` (an `SrtpStreamTable` of this
+        profile keyed with the legs' master keys, the bridge's tx
+        table) carry the session key schedule, the leg constant and the
+        salt this translator would derive again from the same master
+        keys, so a staged wave derives them once."""
+        rids = np.asarray(rids, dtype=np.int64)
+        if len(rids) == 0:
+            return
+        if table.profile != self.profile:
+            raise ValueError("table of another profile")
+        self._rk[rids] = table._rk_rtp[rids]
+        if self._gcm:
+            self._gm[rids] = table._gm_rtp[rids]
+        else:
+            self._mid[rids] = table._mid_rtp[rids]
+        self._salt[rids] = table._salt_rtp[rids]
         self.active[rids] = True
         self._dev = None
 
@@ -421,7 +447,7 @@ class RtpTranslator:
                     phase_of(self.perf, "dispatch"):
                 launch = self._cm_fanout_call(*cm)
                 sp.note(h2d_arrays=launch.h2d_arrays,
-                        h2d_bytes=launch.h2d_bytes)
+                        h2d_bytes=launch.h2d_bytes, **launch.counts)
         return PendingTranslate(launch, recv, batch.capacity, pg=pg,
                                 tracer=tracer, perf=self.perf)
 
@@ -442,7 +468,7 @@ class RtpTranslator:
         # changes every tick, so raw (packets x receivers) shapes
         # would retrace the fan-out jit unboundedly — bucketing
         # keeps the compiled-shape space at LENGTH x ROW classes
-        rr_idx = _cycle_rows(len(recv))
+        rr_idx = _cycle_rows(len(recv)) if self._pads_rows else None
         if rr_idx is None:
             rr_idx = np.arange(len(recv))
         # width clips to the tick's largest packet's class, not the
@@ -562,7 +588,7 @@ class RtpTranslator:
                         h2d_bytes=launch.h2d_bytes, **launch.counts)
             return launch, (p_real, g_real)
         with span_of(tracer, "expand") as sp:
-            rr_idx = _cycle_rows(len(recv))
+            rr_idx = _cycle_rows(len(recv)) if self._pads_rows else None
             if rr_idx is None:
                 rr_idx = np.arange(len(recv))
             # width clips to the largest packet's class (see the CM
@@ -672,7 +698,7 @@ class PendingTranslate:
             arr, lens = launch.fetch()
             lens = np.asarray(lens, dtype=np.int32)
             sp.note(d2h_arrays=launch.d2h_arrays,
-                    d2h_bytes=launch.d2h_bytes)
+                    d2h_bytes=launch.d2h_bytes, **launch.d2h_counts)
             if self._pg is not None:
                 # crop the padded leg-major (G, P) grid to the real
                 # counts and flatten packet-major — numpy on the

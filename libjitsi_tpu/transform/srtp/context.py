@@ -363,6 +363,11 @@ def _unprotect_gcm_cached_grouped_dev(ks_tab, ek_tab, slot, tab_gm,
 class SrtpStreamTable:
     """Batched crypto contexts for up to `capacity` streams of one profile."""
 
+    #: a call's rows are padded to their row class (`ROW_CLASSES`) here,
+    #: before the device seam.  The mesh table says False: its owner
+    #: plan pads the lanes a chip, once (mesh/table.py)
+    _pads_rows = True
+
     def __init__(self, capacity: int = 1024,
                  profile: SrtpProfile = SrtpProfile.AES_CM_128_HMAC_SHA1_80):
         self.profile = profile
@@ -667,16 +672,28 @@ class SrtpStreamTable:
         if self._ks_cache is not None:
             self._ks_cache.forget(sid)
 
+    def _rtcp_pad(self, n: int):
+        """`_rtcp_row_pad` where this table pads rows, else None."""
+        return _rtcp_row_pad(n) if self._pads_rows else None
+
+    def _scratch(self) -> "SrtpStreamTable":
+        """An empty table that launches the programs this one launches:
+        same class, capacity (the key tables' rows are part of every
+        program's signature) and profile."""
+        return SrtpStreamTable(self.capacity, self.profile)
+
     def warmup_rtp(self, batch_size: int, packets_per_stream: int = 4,
                    payload_len: int = 160) -> None:
         """Pre-compile the RTP protect/unprotect programs for the given
         batch shape, OFF the media path.  For GCM the batch drives the
         form that `_gcm_form_grid` picks for its shape, which is the form
         a live tick of that shape runs: nothing is timed here.  Runs on
-        a THROWAWAY table of the same shape so the real table's tx
-        indices and replay windows are untouched; jit caches are
-        process-global, so the real path hits them warm."""
-        scratch = SrtpStreamTable(self.capacity, self.profile)
+        a THROWAWAY table of this table's class and shape
+        (`_scratch`: a mesh table's is sharded over its mesh, so what
+        is warmed is what it launches) so the real table's tx indices
+        and replay windows are untouched; jit caches are process-global,
+        so the real path hits them warm."""
+        scratch = self._scratch()
         n = max(1, min(self.capacity,
                        batch_size // max(packets_per_stream, 1)))
         rng = np.random.default_rng(0)
@@ -717,7 +734,7 @@ class SrtpStreamTable:
                                   [96] * batch_size,
                                   stream=streams.tolist())
             wire2 = scratch.protect_rtp(b2)
-            scratch_rx = SrtpStreamTable(self.capacity, self.profile)
+            scratch_rx = self._scratch()
             scratch_rx.add_streams(sids, mks, mss)
             crx = scratch_rx.enable_keystream_cache(**cw)
             crx.prime(sids, ssrcs, start=1 + pp)
@@ -729,7 +746,7 @@ class SrtpStreamTable:
         zero-recompile discipline as media (the per-tick RTCP count is
         row-class padded, so one warm per class covers every count in
         it).  Scratch table, same rationale as `warmup_rtp`."""
-        scratch = SrtpStreamTable(self.capacity, self.profile)
+        scratch = self._scratch()
         scratch.add_stream(0, b"\x00" * self.policy.enc_key_len,
                            b"\x00" * self.policy.salt_len)
         # minimal valid compound: one empty receiver report (PT 201)
@@ -1074,7 +1091,7 @@ class SrtpStreamTable:
                 out, _, _ = self._merge_row_results(batch.batch_size, done)
                 return out
             self._apply_epochs(stream0, r, rtcp=False)
-        parts = bucket_by_size(batch)
+        parts = bucket_by_size(batch, pad_rows=self._pads_rows)
         done = [(rows, self._protect_rtp_direct(part), n)
                 for rows, part, n in parts]
         out, _ = unbucket(done, batch.batch_size, batch.capacity)
@@ -1099,7 +1116,7 @@ class SrtpStreamTable:
         if self._kdr_active(stream0):
             return PendingProtect([], 0, batch.capacity,
                                   done=self.protect_rtp(batch))
-        parts = bucket_by_size(batch)
+        parts = bucket_by_size(batch, pad_rows=self._pads_rows)
         pend = [(rows, self._protect_rtp_dispatch(part), n)
                 for rows, part, n in parts]
         return PendingProtect(pend, batch.batch_size, batch.capacity)
@@ -1417,7 +1434,8 @@ class SrtpStreamTable:
         # leaves are entered once per size class and sum)
         with span_of(self.tracer, "unprotect_host",
                      rows=batch.batch_size):
-            parts = bucket_by_size(batch, tail=self._part_tail())
+            parts = bucket_by_size(batch, tail=self._part_tail(),
+                                   pad_rows=self._pads_rows)
         done, masks = [], []
         idx_parts = []
         for rows, part, n in parts:
@@ -1473,7 +1491,8 @@ class SrtpStreamTable:
             done = self.unprotect_rtp(batch, return_index)
             return PendingUnprotect(self, [], batch, return_index,
                                     done=done)
-        parts = bucket_by_size(batch, tail=self._part_tail())
+        parts = bucket_by_size(batch, tail=self._part_tail(),
+                                   pad_rows=self._pads_rows)
         pend = [(rows, self._unprotect_rtp_dispatch(part), n)
                 for rows, part, n in parts]
         p = PendingUnprotect(self, pend, batch, return_index)
@@ -1643,7 +1662,7 @@ class SrtpStreamTable:
             iv = self._cm_iv(self._salt_rtcp[stream], ssrc, index)
             enc_flag, f8 = encrypting, False
         n = batch.batch_size
-        pad = _rtcp_row_pad(n)
+        pad = self._rtcp_pad(n)
         if pad is None:
             data, length = self._rtcp_protect_call(
                 stream, batch, iv, index_word, enc_flag, f8=f8)
@@ -1730,7 +1749,7 @@ class SrtpStreamTable:
         kin = np.where(sel, shifted, kin).astype(np.uint8)
 
         iv12 = self._gcm_rtcp_iv(self._salt_rtcp[stream], ssrc, index)
-        pad = _rtcp_row_pad(n)
+        pad = self._rtcp_pad(n)
         if pad is None:
             out, out_len = self._gcm_rtcp_seal_call(stream, kin,
                                                     12 + plen, iv12)
@@ -1796,7 +1815,7 @@ class SrtpStreamTable:
                 iv = self._cm_iv(self._salt_rtcp[stream], ssrc, index)
                 enc_flag, f8 = p.cipher != Cipher.NULL, False
             n = batch.batch_size
-            pad = _rtcp_row_pad(n)
+            pad = self._rtcp_pad(n)
             if pad is None:
                 data, mlen, auth_ok, _e, _idx = self._rtcp_unprotect_call(
                     stream, batch, iv, length, enc_flag, f8=f8)
@@ -1844,7 +1863,7 @@ class SrtpStreamTable:
         kin = np.where(sel, shifted, kin).astype(np.uint8)
 
         iv12 = self._gcm_rtcp_iv(self._salt_rtcp[stream], ssrc, index)
-        pad = _rtcp_row_pad(n)
+        pad = self._rtcp_pad(n)
         if pad is None:
             dec, _, auth_ok = self._gcm_rtcp_open_call(
                 stream, kin, 12 + ctlen + 16, iv12)

@@ -236,3 +236,43 @@ def test_affinity_tick_four_chips(topo, no_persistent_cache, tower_core):
     for sh in jax.tree_util.tree_leaves(c.output_shardings):
         assert len(sh.device_set) == 4
     _fits(c)
+
+
+# The two programs a tick of the bridge on a device mesh launches
+# (`SfuBridge(mesh=4)`: mesh/table.py, mesh/translator.py), over the
+# 2x2 host with the 40,960-row tables of `audio-sfu-cm-40k-mesh4`
+# row-sharded 10,240 a chip.  Tier 1 asks at the 256-lane class; the
+# fan-out at the top class, 4,096 lanes a chip, is `slow`.
+@pytest.mark.parametrize("what,lanes", [
+    ("unprotect", 256), ("fanout", 256),
+    pytest.param("fanout", 4096, marks=pytest.mark.slow),
+])
+def test_mesh_served_programs_four_chips(topo, no_persistent_cache,
+                                         tower_core, what, lanes):
+    from libjitsi_tpu.mesh import ShardedRtpTranslator, ShardedSrtpTable
+    from libjitsi_tpu.mesh.sharded import AXIS
+
+    mesh = Mesh(np.asarray(topo.devices), (AXIS,))
+    cap = 40960
+
+    def s(shape, dtype):           # leading axis over the mesh
+        spec = P(AXIS, *([None] * (len(shape) - 1)))
+        return _on(NamedSharding(mesh, spec))(shape, dtype)
+
+    fn = (ShardedSrtpTable(cap, mesh)._shard_fn("unprotect", 10, True, 12)
+          if what == "unprotect"
+          else ShardedRtpTranslator(cap, mesh)._fanout_fn(20))
+    assert fn.__name__ == {"unprotect": "mesh_unprotect_rtp",
+                           "fanout": "mesh_fanout_protect"}[what]
+    c = fn.lower(
+        s((cap, 11, 16), jnp.uint8), s((cap, 2, 5), jnp.uint32),
+        s((4, lanes), jnp.int32), s((4, lanes, WIDTH), jnp.uint8),
+        s((4, lanes), jnp.int32), s((4, lanes), jnp.int32),
+        s((4, lanes, 16), jnp.uint8), s((4, lanes), jnp.uint32)).compile()
+    text = c.as_text()
+    for coll in ("all-reduce", "all-gather", "all-to-all",
+                 "collective-permute"):
+        assert coll not in text, f"{coll} in a shard-local program"
+    for sh in jax.tree_util.tree_leaves(c.output_shardings):
+        assert len(sh.device_set) == 4
+    _fits(c)

@@ -67,6 +67,9 @@ class LcBridge:
 
         self.registry = _Reg()
 
+    def has_ssrc(self, ssrc):
+        return ssrc in self._ssrc_of.values()
+
     def stage_endpoints(self, specs):
         sids = []
         for ssrc, rx, tx, _name in specs:
@@ -102,6 +105,32 @@ def _lc(capacity=8, supervisor=None, **cfg):
     lc = StreamLifecycleManager(bridge, supervisor=supervisor,
                                 config=LifecycleConfig(**cfg))
     return lc, bridge
+
+
+@pytest.mark.parametrize("stated, devices, placement, refused", [
+    (0, 0, 4, None), (0, 4, 2, None), (4, 4, 4, None), (1, 0, 1, None),
+    (4, 0, 4, "the bridge's tables have 1"),
+    (1, 4, 1, "the bridge's tables have 4"),
+    (4, 4, 2, "2 placement shards over 4 table shards")])
+def test_stated_table_shards_are_held_to_the_bridge(stated, devices,
+                                                    placement, refused):
+    """`LifecycleConfig.table_shards` picks nothing: a deployment that
+    states its bridge's table shards gets a manager that refuses a
+    bridge, or a placement, sharded otherwise; unstated, as before."""
+    bridge = LcBridge(capacity=8)
+    bridge.registry.capacity = 8
+    if devices:
+        bridge._mesh = types.SimpleNamespace(
+            devices=np.zeros((devices, 1), dtype=object))
+    cfg = LifecycleConfig(table_shards=stated)
+    if refused is None:
+        lc = StreamLifecycleManager(bridge, config=cfg)
+        lc.enable_placement(placement)
+        assert lc.placer.n_shards == placement
+        return
+    with pytest.raises(ValueError, match=refused):
+        StreamLifecycleManager(bridge, config=cfg).enable_placement(
+            placement)
 
 
 def _all_events(flight):
@@ -708,3 +737,110 @@ def test_cascade_soak_invariants():
     assert report["orphans_adopted"] >= 1
     assert report["refusals"].get("trunk_down", 0) > 0
     assert report["conf_bridge_home"] == 1
+
+
+# ------------------------------------- admission grows with the table
+
+def _admitted(install_batch: int, n: int = 96, mesh=None):
+    """An SfuBridge with `n` endpoints in conferences of 8 admitted
+    through `request_join` in waves of `install_batch` (ladder off:
+    admission is host work), placement over four shards."""
+    libjitsi_tpu.stop()
+    libjitsi_tpu.init()
+    kwargs = {} if mesh is None else {"mesh": mesh}
+    bridge = SfuBridge(libjitsi_tpu.configuration_service(), port=0,
+                       capacity=128, recv_window_ms=0, **kwargs)
+    sup = BridgeSupervisor(bridge, SupervisorConfig(deadline_ms=60_000.0))
+    lc = StreamLifecycleManager(
+        bridge, supervisor=sup,
+        config=LifecycleConfig(install_batch=install_batch,
+                               max_pending=1024))
+    lc._warm_bucket = 1 << 30
+    lc.enable_placement(4)
+    rng = np.random.default_rng(5)
+    keys = rng.integers(0, 256, (n, 2, 30), dtype=np.uint8)
+    for i in range(n):
+        k = [(bytes(keys[i, j, :16]), bytes(keys[i, j, 16:]))
+             for j in (0, 1)]
+        ok, why = lc.request_join(0x7000 + i, k[0], k[1],
+                                  conference=i // 8)
+        assert ok, why
+    connects, inner = [0], bridge.translator.connect
+
+    def connect(sid, rr):
+        connects[0] += 1
+        inner(sid, rr)
+
+    bridge.translator.connect = connect
+    waves = 0
+    while lc.admits < n:
+        sup.tick(now=1000.0 + 0.02 * sup.ticks)
+        waves += 1
+        if mesh is not None:
+            # what a launch does first: a wave's re-keying dropped the
+            # placed copies, however many times it set `_dev = None`
+            bridge.rx_table._sharded_device("rtp")
+            bridge.translator._sharded_device()
+        assert waves < 4 * n
+    # a wave is staged in one tick and goes live in the next
+    return bridge, lc, waves - 1, connects[0]
+
+
+def _state(bridge, lc):
+    rx, tx, tr = bridge.rx_table, bridge.tx_table, bridge.translator
+    return {
+        "rx": (rx._rk_rtp, rx._mid_rtp, rx._salt_rtp, rx._rk_rtcp,
+               rx._mid_rtcp, rx._salt_rtcp, rx.active),
+        "tx": (tx._rk_rtp, tx._mid_rtp, tx._salt_rtp, tx._rk_rtcp,
+               tx._mid_rtcp, tx._salt_rtcp, tx.active),
+        "legs": (tr._rk, tr._mid, tr._salt, tr.active),
+        "ssrc_of": dict(bridge._ssrc_of),
+        "conf_of": dict(bridge._conf_of),
+        "routes": {s: rr.tolist() for s, rr in tr._routes.items()},
+        "placement": {c: lc.placer.shard_of(c) for c in range(12)},
+    }
+
+
+@pytest.mark.parametrize("install_batch", [64, 512])
+def test_waves_leave_what_single_joins_leave(install_batch):
+    """Admission in waves of 64 or of 512 leaves byte-equal key tables,
+    leg tables, SSRC map, routes and placement to one join a wave; and
+    a commit reconnects the conferences it touched, not every live
+    sender (the route rebuilds of a bridge grow with the bridge, not
+    with its square)."""
+    b1, lc1, waves1, connects1 = _admitted(1)
+    want = _state(b1, lc1)
+    b1.close()
+    bw, lcw, waves, connects = _admitted(install_batch)
+    got = _state(bw, lcw)
+    bw.close()
+    assert waves1 == 96 and waves == -(-96 // install_batch)
+    for name in ("rx", "tx", "legs"):
+        for a, b in zip(want[name], got[name]):
+            np.testing.assert_array_equal(a, b)
+    for name in ("ssrc_of", "conf_of", "routes", "placement"):
+        assert want[name] == got[name], name
+    assert len(got["routes"]) == 96
+    assert all(len(rr) == 7 for rr in got["routes"].values())
+    # a wave reconnects at most the conferences it touches: one join a
+    # wave reconnects its conference's members so far (1 + ... + 8 a
+    # conference), a wave of whole conferences each sender once
+    assert connects1 == 12 * 36
+    assert connects == 96
+
+
+def test_a_sharded_table_is_placed_at_most_once_a_wave():
+    import jax
+
+    from libjitsi_tpu.mesh import make_media_mesh
+
+    mesh = make_media_mesh(jax.devices()[:4])
+    bridge, lc, waves, _connects = _admitted(32, mesh=mesh)
+    assert waves == 3
+    assert bridge.rx_table.placements == waves
+    assert bridge.translator.placements == waves
+    # no conference straddles a shard
+    for conf in range(12):
+        rows = [s for s, c in bridge._conf_of.items() if c == conf]
+        assert len({s // 32 for s in rows}) == 1 and len(rows) == 8
+    bridge.close()

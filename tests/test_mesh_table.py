@@ -420,3 +420,115 @@ def test_sharded_table_kdr_rekey_parity():
         for i in range(4):
             assert w_sh.to_bytes(i) == w_pl.to_bytes(i), (start, i)
     assert sh._epoch_rtp[3] == pl._epoch_rtp[3] >= 1
+
+
+# ------------------------------------------------ the warm ladder's shapes
+
+@pytest.mark.parametrize("n_dev", [2, 4, 8])
+def test_owner_plan_lanes_are_row_classes(n_dev):
+    """The lanes a chip are the row class of the hottest shard's REAL
+    rows, whatever the skew: the one-chip ladder's five rungs are every
+    lane count a mesh launch can take (multiples of the largest beyond
+    it), and the plan routes every row to its owner and back."""
+    from libjitsi_tpu.core.packet import ROW_CLASSES, _round_rows
+    from libjitsi_tpu.mesh.table import _OwnerPlan, local_rows
+
+    rng = np.random.default_rng(n_dev)
+    cap = 1024 * n_dev
+    rows_per = cap // n_dev
+    for _ in range(200):
+        n = int(rng.choice([1, 3, 15, 16, 17, 64, 400, 1023, 4088]))
+        hot = rng.random() < 0.5          # half the draws: one hot shard
+        ids = (rng.integers(0, rows_per, n) if hot
+               else rng.integers(0, cap, n))
+        plan = _OwnerPlan(ids, cap, rows_per, n_dev)
+        top = int(np.bincount(ids // rows_per, minlength=n_dev).max())
+        assert plan.per in ROW_CLASSES and plan.per == _round_rows(top)
+        assert plan.slot.shape == (n_dev, plan.per)
+        assert int(plan.counts.max()) == top
+        # every row reaches the chip that owns it, and comes back
+        np.testing.assert_array_equal(plan.slot.reshape(-1)[plan.inv],
+                                      np.arange(n))
+        local = local_rows(plan, ids, cap, rows_per, n_dev)
+        owner = plan.inv // plan.per
+        np.testing.assert_array_equal(
+            owner * rows_per + local.reshape(-1)[plan.inv], ids)
+    # shard-major, equal, class-filling batches route by identity
+    ids = np.repeat(np.arange(n_dev) * rows_per, 16) + np.tile(
+        np.arange(16), n_dev)
+    plan = _OwnerPlan(ids, cap, rows_per, n_dev)
+    assert plan.affine and plan.per == 16
+    assert not _OwnerPlan(ids[:-1], cap, rows_per, n_dev).affine
+    # past the largest class: multiples of it
+    big = _OwnerPlan(np.zeros(5000, np.int64), cap, rows_per, n_dev)
+    assert big.per == 2 * ROW_CLASSES[-1]
+
+
+def _compiled_names(caplog):
+    """Program names in JAX's compile log (`jax.log_compiles`)."""
+    import re
+
+    return set(re.findall(r"Compiling jit\(([\w.<>-]+)\) ", caplog.text))
+
+
+@pytest.mark.parametrize("what", ["table", "translator"])
+def test_warmups_on_sharded_objects_compile_the_mesh_programs(what,
+                                                              caplog):
+    """`warmup_rtp` / `warmup_rtcp` / `fanout_warmups` on a sharded
+    object run on a scratch object of ITS class on ITS mesh: the
+    programs in the compile log are the `mesh_*` ones the object
+    launches, none of the one-chip ones, and the live object's state
+    and placed tables are left alone."""
+    import logging
+
+    import jax
+
+    from libjitsi_tpu.mesh import ShardedRtpTranslator
+
+    mesh = make_media_mesh(jax.devices()[:4])
+    cap = 40            # no other test's: the programs compile here
+    caplog.set_level(logging.WARNING)
+    with jax.log_compiles(True):
+        if what == "table":
+            tab = ShardedSrtpTable(cap, mesh)
+            tab.add_stream(3, b"\x01" * 16, b"\x02" * 14)
+            before = (tab.tx_ext.copy(), tab.rx_max.copy(),
+                      tab.placements)
+            tab.warmup_rtp(16)
+            tab.warmup_rtcp(16)
+            assert isinstance(tab._scratch(), ShardedSrtpTable)
+            assert tab._scratch().mesh is mesh
+            np.testing.assert_array_equal(tab.tx_ext, before[0])
+            np.testing.assert_array_equal(tab.rx_max, before[1])
+            assert tab.placements == before[2] == 0
+            want = {"mesh_protect_rtp", "mesh_unprotect_rtp",
+                    "mesh_rtcp_protect", "mesh_rtcp_unprotect"}
+        else:
+            tr = ShardedRtpTranslator(cap, mesh)
+            thunks = tr.fanout_warmups(16)
+            assert len(thunks) == 6       # 2 widths x 3 offset forms
+            for t in thunks[:3]:          # one width: three programs
+                t()
+            assert tr.placements == 0 and tr._sh_dev == {}
+            want = {"mesh_fanout_protect"}
+    names = _compiled_names(caplog)
+    assert want <= names, names
+    one_chip = {"_fanout_protect", "_protect_rtp_dev",
+                "_unprotect_rtp_packed_impl", "_unprotect_rtp_impl",
+                "_protect_rtcp_dev", "_unprotect_rtcp_dev"}
+    assert not names & one_chip, names
+
+
+def test_tables_of_one_mesh_share_their_programs():
+    """rx table, tx table and every scratch table of a mesh launch the
+    same jit objects, so a scratch table's warm-up warms the live
+    tables (a table a jit of its own compiled everything again)."""
+    import jax
+
+    mesh = make_media_mesh(jax.devices()[:4])
+    a, b = ShardedSrtpTable(CAP, mesh), ShardedSrtpTable(CAP, mesh)
+    assert a._shard_fn("unprotect", 10, True, 12) is \
+        b._shard_fn("unprotect", 10, True, 12)
+    assert a._scratch()._sh_fns is a._sh_fns
+    other = ShardedSrtpTable(CAP, make_media_mesh(jax.devices()[:2]))
+    assert other._sh_fns is not a._sh_fns
