@@ -26,14 +26,18 @@ Host side: `alloc` where the rows are copied anyway, `pack`, then one
 under AES-CM and under AES-GCM alike: the RTP unprotect
 (`transform/srtp/context.py`; GCM in its per-row form) and the per-row
 fan-out (`sfu/translator.py`).  GCM has no ROC word (three words, the
-fourth 0) and its 12-byte IV rides in the first 12 IV columns.
+fourth 0) and its 12-byte IV rides in the first 12 IV columns.  On a
+device mesh the two CM programs stage the same plane gathered into
+lanes, `[chips, lanes, width + TAIL]`, a block a chip each way, with
+the chip-local key row as word 0 (mesh/table.py `_packed_call`).
 
 What keeps an array an argument (`put_each`, which counts what
 crossed): the grouped GCM form above the row classes (its grid arrays
 have another shape than the rows), the leg-major GCM fan-out,
 `protect_rtp`, AES-F8, a keystream-cache hit, SRTCP, and the mesh
-seams, which route an array per lane to its owning chip
-(mesh/table.py `_sharded_call`, counted the same way).
+seams no cell runs (CM protect, F8, GCM, SRTCP, the GCM fan-outs),
+which route an array per lane to its owning chip (mesh/table.py
+`_sharded_call`, counted the same way).
 """
 
 from __future__ import annotations
@@ -93,10 +97,11 @@ def put_each(*arrays) -> Tuple[list, int, int]:
 class Launch:
     """A device call in flight: what crossed to the device for it, and
     how its outputs come to the host.  `outs` are the program's outputs
-    as they stand on the device (one packed plane; the three arrays of
-    a grouped GCM, an F8 or a cache-hit unprotect; a mesh seam's
-    outputs in lane layout, a block a chip); `split` turns their host
-    copies into what the caller reads.  `fetch` waits, copies each
+    as they stand on the device (one packed plane, on a mesh in lane
+    layout, a block a chip; the three arrays of a grouped GCM, an F8
+    or a cache-hit unprotect; an unpacked mesh seam's outputs in lane
+    layout); `split` turns their host copies into what the caller
+    reads.  `fetch` waits, copies each
     output once and caches; `h2d_arrays` / `h2d_bytes` / `d2h_arrays` /
     `d2h_bytes` count the arrays that really crossed.  `counts` is what
     else the caller's span should book for the call (the GCM calls:

@@ -57,7 +57,9 @@ from libjitsi_tpu.core.packet import (CLASS_HEADROOM, LENGTH_CLASSES,
                                       ROW_CLASSES, _round_rows)
 from libjitsi_tpu.kernels import registry as _registry
 from libjitsi_tpu.transform.srtp import kernel
-from libjitsi_tpu.transform.srtp.context import SrtpStreamTable, _uniform_off
+from libjitsi_tpu.transform.srtp.context import (SrtpStreamTable,
+                                                 _split_unprotect,
+                                                 _uniform_off)
 from libjitsi_tpu.transform.srtp.policy import Cipher, SrtpProfile
 from libjitsi_tpu.utils.tracing import span_of
 
@@ -187,8 +189,10 @@ class ShardedRowsMixin:
 
     def _sharded_call(self, fn, tabs, ids, lane_args, extra_args=(),
                       plan=None):
-        """Plan/gather/dispatch shared by EVERY sharded seam (table
-        CM/F8/GCM/SRTCP, translator fan-outs): route rows to their
+        """Plan/gather/dispatch shared by every sharded seam that
+        keeps an array an argument (table CM protect/F8/GCM/SRTCP, the
+        translator's GCM fan-outs; the served CM unprotect and fan-out
+        pack one plane, `_packed_call`): route rows to their
         owning chips (`owner_plan`: the plan and the lane gathers),
         put each lane array on the mesh a block a chip, and run `fn`
         under shard_map.  `lane_args` are per-row arrays (1-D like
@@ -239,8 +243,8 @@ class ShardedRowsMixin:
         """`_sharded_call` with one `_LazyArray` per output — the
         scatter back to wire order is DEFERRED until materialization,
         keeping the async contract (the protect, F8, GCM and SRTCP
-        seams; the served CM unprotect and fan-out hand their outputs
-        to a `staging.Launch`, `_mesh_launch`)."""
+        seams; the served CM unprotect and fan-out hand one packed
+        plane to a `staging.Launch`, `_packed_call`)."""
         outs, plan, _n, _nbytes = self._sharded_call(
             fn, tabs, ids, lane_args, extra_args, plan)
         inv = None if plan.affine else plan.inv   # affine: wire order
@@ -267,12 +271,93 @@ class ShardedRowsMixin:
                     out.append(a if dt is None else a.astype(dt))
                 return tuple(out)
 
-        counts = {"shards": self.n_dev, "lanes": plan.per,
-                  "rows_hottest_shard": int(plan.counts.max()),
-                  "affine": int(plan.affine)}
+        counts = self._plan_counts(plan)
         return staging.Launch(outs, scatter, h2d_arrays=h2d_arrays,
                               h2d_bytes=h2d_bytes, counts=counts,
                               d2h_counts=counts)
+
+    def _plan_counts(self, plan: "_OwnerPlan") -> dict:
+        """What a mesh call's spans book of its plan."""
+        return {"shards": self.n_dev, "lanes": plan.per,
+                "rows_hottest_shard": int(plan.counts.max()),
+                "affine": int(plan.affine)}
+
+    def _packed_call(self, fn, plane: np.ndarray, ids, words, iv,
+                     split) -> staging.Launch:
+        """The served CM seams (RTP unprotect, fan-out): ONE packed
+        `uint8` lane plane to the mesh and one back (core/staging.py),
+        a block a chip each way.
+
+        `plane` is the seam's staging plane in wire order, the packet
+        bytes in its leading columns.  Word 0 is the CHIP-LOCAL key row
+        (`ids` clipped into the table, modulo the rows a chip), `words`
+        are the seam's other three (length, payload offset, ROC).  The
+        plan gathers the packed plane into lanes (`owner_plan`; a
+        reshape when affine; a pad lane repeats a real row, whose local
+        word is in range wherever it lands), one `jax.device_put` puts
+        it on the mesh, and `fn` (`_packed_fn`) gives one plane of the
+        same shape back.  `fetch()` copies it once, takes it to wire
+        order (`mesh_scatter`) and ends in `split`, the one-chip
+        seam's."""
+        ids = np.asarray(ids, dtype=np.int64)
+        n = len(ids)
+        tracer = getattr(self, "tracer", None)
+        local = np.clip(ids, 0, self.capacity - 1) % self.rows_per
+        staging.pack(plane, (local, *words), iv)
+        with span_of(tracer, "owner_plan", rows=n, shards=self.n_dev):
+            plan = _OwnerPlan(ids, self.capacity, self.rows_per,
+                              self.n_dev)
+            # identity routing: the lane gather is a reshape
+            lanes = (plane.reshape(self.n_dev, plan.per, plane.shape[-1])
+                     if plan.affine else plane[plan.slot])
+        self.shard_rows = plan.counts
+        out = fn(*self._sharded_device("rtp"), jax.device_put(
+            lanes, NamedSharding(self.mesh, P(self._axes, None, None))))
+        inv = None if plan.affine else plan.inv
+
+        def scatter(host):
+            with span_of(tracer, "mesh_scatter", rows=n):
+                host = host.reshape(-1, host.shape[-1])
+                if inv is not None:   # None: affine plan, wire order
+                    host = host[inv]
+            return split(host)
+
+        counts = self._plan_counts(plan)
+        return staging.Launch((out,), scatter, h2d_arrays=1,
+                              h2d_bytes=lanes.nbytes, counts=counts,
+                              d2h_counts=counts)
+
+    def _packed_fn(self, key: Tuple, name: str, kfn, tag_len: int,
+                   encrypt: bool, off_const):
+        """The shard_map program of a packed seam, shared a mesh under
+        `key` and named `name` in the trace: the one-chip packed
+        program's body (`context._unprotect_rtp_packed_impl`,
+        `sfu.translator._fanout_protect`) over this chip's lane block
+        and this chip's shard of the key tables.  `kfn` is
+        `kernel.srtp_unprotect` or `kernel.srtp_protect`; whatever it
+        returns beside the bytes rides back as the plane's words."""
+        fn = self._sh_fns.get(key)
+        if fn is not None:
+            return fn
+
+        def _run(tab_rk, tab_mid, plane):
+            # per-shard leading axis is 1 (this chip's lane block)
+            data, w, iv = staging.unpack(plane[0])
+            rk, mid = kernel.gather_keys(staging.as_i32(w[:, 0]), tab_rk,
+                                         tab_mid)
+            out = kfn(data, staging.as_i32(w[:, 1]),
+                      staging.as_i32(w[:, 2]), rk, iv, mid, w[:, 3],
+                      tag_len, encrypt, payload_off_const=off_const)
+            return staging.repack(*out)[None]
+
+        row3 = P(self._axes, None, None)
+        fn = jax.jit(shard_map(
+            _named(_run, name), mesh=self.mesh,
+            in_specs=(row3, row3, row3), out_specs=row3,
+            check_vma=False))
+        # setdefault: concurrent warm-ups of one key, and every table
+        # and translator of the mesh, share ONE jit (`_MESH_PROGRAMS`)
+        return self._sh_fns.setdefault(key, fn)
 
 
 def local_rows(plan: "_OwnerPlan", ids: np.ndarray, capacity: int,
@@ -557,14 +642,13 @@ class ShardedSrtpTable(ShardedRowsMixin, SrtpStreamTable):
 
     # ------------------------------------------------------- sharded seams
     def _run_sharded(self, op: str, stream, batch, hdr, length,
-                     tail_args, launch=None):
-        """One RTP program over a part; `launch`: `_sharded_launch`
-        (deferred scatters) unless the seam builds a `staging.Launch`
-        from `_sharded_call`'s outputs itself."""
+                     tail_args):
+        """One unpacked RTP program over a part (`_sharded_launch`:
+        deferred scatters)."""
         off_const = _uniform_off(hdr.payload_off, batch.capacity)
         fn = self._shard_fn(op, self.policy.auth_tag_len,
                             self.policy.cipher != Cipher.NULL, off_const)
-        return (launch or self._sharded_launch)(
+        return self._sharded_launch(
             fn, self._sharded_device("rtp"), stream,
             [batch.data, np.asarray(length, dtype=np.int32),
              hdr.payload_off, *tail_args])
@@ -583,17 +667,19 @@ class ShardedSrtpTable(ShardedRowsMixin, SrtpStreamTable):
                                ) -> staging.Launch:
         """The seam's contract is `SrtpStreamTable`'s: the part comes
         with a staging plane (`batch.plane`; `batch.data` is its
-        leading columns) and what goes back is a `staging.Launch` whose
-        `fetch()` gives host (data, media_len, auth_ok).  The sharded
-        call packs nothing: its arguments are routed to their owning
-        chips one array each (six cross, counted as they do), and the
-        launch holds the three outputs in lane layout until `fetch`
-        scatters them back."""
-        outs, plan, n, nbytes = self._run_sharded(
-            "unprotect", stream, batch, hdr, length,
-            [iv, self._roc32(v)], launch=self._sharded_call)
-        return self._mesh_launch(outs, plan, n, nbytes,
-                                 (None, np.int32, None))
+        leading columns), into which chip-local row, length, payload
+        offset, ROC (`v` mod 2**32) and IV are packed; ONE lane plane
+        goes to the mesh, a block a chip, and one comes back
+        (`_packed_call`).  Returns the `staging.Launch` in flight, whose
+        `fetch()` gives host (data, media_len, auth_ok)."""
+        fn = self._shard_fn(
+            "unprotect", self.policy.auth_tag_len,
+            self.policy.cipher != Cipher.NULL,
+            _uniform_off(hdr.payload_off, batch.capacity))
+        return self._packed_call(
+            fn, batch.plane, stream,
+            (length, hdr.payload_off, v & 0xFFFFFFFF), iv,
+            _split_unprotect)
 
     # ------------------------------------------------------------------ F8
     def _f8_rtp_protect_call(self, stream, batch, hdr, iv, v):
@@ -729,6 +815,11 @@ class ShardedSrtpTable(ShardedRowsMixin, SrtpStreamTable):
         row3 = P(self._axes, None, None)
         lanes = P(self._axes, None)
         f8 = op.startswith("f8_") or op.startswith("rtcp_f8_")
+        if op == "unprotect":
+            # the served CM unprotect: one packed plane each way
+            return self._packed_fn(key, "mesh_unprotect_rtp",
+                                   kernel.srtp_unprotect, tag_len,
+                                   encrypt, off_const)
         if op.startswith("gcm_"):
             fn = self._build_gcm_fn(op, off_const, row3, lanes)
         elif op.startswith("rtcp_"):
@@ -743,9 +834,12 @@ class ShardedSrtpTable(ShardedRowsMixin, SrtpStreamTable):
 
     def _build_rtp_fn(self, op, tag_len, encrypt, f8, off_const, row3,
                       lanes):
-        kfn = kernel.srtp_protect if op.endswith("protect") and not \
-            op.endswith("unprotect") else kernel.srtp_unprotect
+        """The unpacked RTP programs: the CM `protect`, and F8 both
+        ways (the CM unprotect is `_packed_fn`'s)."""
+        unprot = op.endswith("unprotect")
         if f8:
+            kfn = kernel.srtp_unprotect if unprot else kernel.srtp_protect
+
             def _run(tab_rk, tab_mid, tab_f8, local, data, length, off,
                      iv, roc):
                 out = kfn(data[0], length[0], off[0], tab_rk[local[0]],
@@ -758,18 +852,18 @@ class ShardedSrtpTable(ShardedRowsMixin, SrtpStreamTable):
         else:
             def _run(tab_rk, tab_mid, local, data, length, off, iv, roc):
                 # per-shard leading axis is 1 (this chip's lane block)
-                out = kfn(data[0], length[0], off[0], tab_rk[local[0]],
-                          iv[0], tab_mid[local[0]], roc[0], tag_len,
-                          encrypt, payload_off_const=off_const)
+                out = kernel.srtp_protect(
+                    data[0], length[0], off[0], tab_rk[local[0]], iv[0],
+                    tab_mid[local[0]], roc[0], tag_len, encrypt,
+                    payload_off_const=off_const)
                 return tuple(o[None] for o in out)
             in_specs = (row3, row3, lanes, row3, lanes, lanes, row3,
                         lanes)
-        n_out = 2 if "unprotect" not in op else 3
         return jax.jit(shard_map(
             _named(_run, f"mesh_{op}_rtp"), mesh=self.mesh,
             in_specs=in_specs,
-            out_specs=(row3, lanes) if n_out == 2
-            else (row3, lanes, lanes), check_vma=False))
+            out_specs=(row3, lanes, lanes) if unprot else (row3, lanes),
+            check_vma=False))
 
     def _build_gcm_fn(self, op, off_const, row3, lanes):
         from libjitsi_tpu.kernels import gcm as gcm_kernel

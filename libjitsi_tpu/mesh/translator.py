@@ -27,7 +27,7 @@ from libjitsi_tpu.core import staging
 from libjitsi_tpu.mesh.compat import shard_map
 
 from libjitsi_tpu.mesh.table import ShardedRowsMixin, _named
-from libjitsi_tpu.sfu.translator import RtpTranslator
+from libjitsi_tpu.sfu.translator import RtpTranslator, _split_fanout
 from libjitsi_tpu.transform.srtp import kernel
 from libjitsi_tpu.transform.srtp.policy import Cipher, SrtpProfile
 
@@ -36,7 +36,8 @@ class ShardedRtpTranslator(ShardedRowsMixin, RtpTranslator):
     """`RtpTranslator` whose re-encrypt fan-out runs sharded by leg.
 
     `translate_async` keeps its overlap contract in mesh mode: the
-    sharded seams return deferred-scatter results (`_LazyArray`), so
+    sharded seams return a `staging.Launch` whose outputs stay on the
+    mesh in lane layout (the CM fan-out: one packed plane), so
     `PendingTranslate` holds device-resident lane buffers until
     `.result()` — SfuBridge composes mesh with pipelined ticks.
     """
@@ -69,27 +70,29 @@ class ShardedRtpTranslator(ShardedRowsMixin, RtpTranslator):
     def _cm_fanout_call(self, recv, plane, length, payload_off, iv, idx
                         ) -> staging.Launch:
         """The seam's contract is `RtpTranslator._cm_fanout_call`'s:
-        `plane` holds the packet bytes in its leading columns, and what
-        comes back is a `staging.Launch` whose `fetch()` gives host
-        (wire bytes, wire lengths).  The sharded call packs nothing: its
-        arguments are routed to their owning chips one array each (six
-        cross, counted as they do), and the launch holds the two
-        outputs in lane layout until `fetch` scatters them back."""
+        `plane` holds the packet bytes in its leading columns, and
+        chip-local receiver row, length, payload offset, ROC
+        (`idx >> 16` mod 2**32) and IV are packed behind them; ONE lane
+        plane goes to the mesh, a block a chip, and one comes back
+        (`ShardedRowsMixin._packed_call`).  Returns the
+        `staging.Launch` in flight, whose `fetch()` gives host (wire
+        bytes, wire lengths)."""
         from libjitsi_tpu.transform.srtp.context import _uniform_off
 
-        data = plane[:, :plane.shape[-1] - staging.TAIL]
-        roc = ((np.asarray(idx) >> 16) & 0xFFFFFFFF).astype(np.uint32)
-        outs, plan, n, nbytes = self._sharded_call(
-            self._fanout_fn(_uniform_off(payload_off, data.shape[-1])),
-            self._sharded_device(), recv,
-            [data, np.asarray(length, dtype=np.int32), payload_off, iv,
-             roc])
-        return self._mesh_launch(outs, plan, n, nbytes, (None, np.int32))
+        fn = self._fanout_fn(_uniform_off(
+            payload_off, plane.shape[-1] - staging.TAIL))
+        return self._packed_call(
+            fn, plane, recv,
+            (length, payload_off, (np.asarray(idx) >> 16) & 0xFFFFFFFF),
+            iv, _split_fanout)
 
     def _gcm_fanout_call(self, recv, plane, length, payload_off, iv12
                          ) -> staging.Launch:
-        """As `_cm_fanout_call` above: the packet bytes are `plane`'s
-        leading columns and nothing is packed."""
+        """The GCM twin keeps an array an argument: the packet bytes
+        are `plane`'s leading columns and nothing is packed; five lane
+        arrays are routed to their owning chips, counted as they cross,
+        and the launch holds the two outputs in lane layout until
+        `fetch` scatters them back (`_mesh_launch`)."""
         from libjitsi_tpu.transform.srtp.context import _uniform_off
 
         data = plane[:, :plane.shape[-1] - staging.TAIL]
@@ -173,28 +176,10 @@ class ShardedRtpTranslator(ShardedRowsMixin, RtpTranslator):
         return self._sh_fns.setdefault(key, fn)
 
     def _fanout_fn(self, off_const=None):
-        key = ("fanout", self.policy.auth_tag_len,
-               self.policy.cipher != Cipher.NULL, off_const)
-        fn = self._sh_fns.get(key)
-        if fn is not None:
-            return fn
+        """The packed CM fan-out program of this mesh."""
         tag_len = self.policy.auth_tag_len
         encrypt = self.policy.cipher != Cipher.NULL
-
-        def _run(tab_rk, tab_mid, local, data, length, off, iv, roc):
-            out = kernel.srtp_protect(
-                data[0], length[0], off[0], tab_rk[local[0]], iv[0],
-                tab_mid[local[0]], roc[0], tag_len, encrypt,
-                payload_off_const=off_const)
-            return tuple(o[None] for o in out)
-
-        row3 = P(self._axes, None, None)
-        lanes = P(self._axes, None)
-        fn = jax.jit(shard_map(
-            _named(_run, "mesh_fanout_protect"), mesh=self.mesh,
-            in_specs=(row3, row3, lanes, row3, lanes, lanes, row3,
-                      lanes),
-            out_specs=(row3, lanes), check_vma=False))
-        # setdefault: concurrent warm-ups of one key, and every
-        # translator of the mesh, share ONE jit
-        return self._sh_fns.setdefault(key, fn)
+        return self._packed_fn(
+            ("fanout", tag_len, encrypt, off_const),
+            "mesh_fanout_protect", kernel.srtp_protect, tag_len, encrypt,
+            off_const)

@@ -249,6 +249,7 @@ def test_affinity_tick_four_chips(topo, no_persistent_cache, tower_core):
 ])
 def test_mesh_served_programs_four_chips(topo, no_persistent_cache,
                                          tower_core, what, lanes):
+    from libjitsi_tpu.core import staging
     from libjitsi_tpu.mesh import ShardedRtpTranslator, ShardedSrtpTable
     from libjitsi_tpu.mesh.sharded import AXIS
 
@@ -264,11 +265,10 @@ def test_mesh_served_programs_four_chips(topo, no_persistent_cache,
           else ShardedRtpTranslator(cap, mesh)._fanout_fn(20))
     assert fn.__name__ == {"unprotect": "mesh_unprotect_rtp",
                            "fanout": "mesh_fanout_protect"}[what]
+    # one packed lane plane (core/staging.py) beside the tables
     c = fn.lower(
         s((cap, 11, 16), jnp.uint8), s((cap, 2, 5), jnp.uint32),
-        s((4, lanes), jnp.int32), s((4, lanes, WIDTH), jnp.uint8),
-        s((4, lanes), jnp.int32), s((4, lanes), jnp.int32),
-        s((4, lanes, 16), jnp.uint8), s((4, lanes), jnp.uint32)).compile()
+        s((4, lanes, WIDTH + staging.TAIL), jnp.uint8)).compile()
     text = c.as_text()
     for coll in ("all-reduce", "all-gather", "all-to-all",
                  "collective-permute"):

@@ -22,6 +22,7 @@ import numpy as np
 import pytest
 
 import libjitsi_tpu
+from libjitsi_tpu.core import staging
 from libjitsi_tpu.core.packet import ROW_CLASSES
 from libjitsi_tpu.mesh import (ShardedRtpTranslator, ShardedSrtpTable,
                                make_media_mesh)
@@ -287,13 +288,13 @@ def test_mesh_spans_book_the_plan_and_what_crossed(served, stage, arrays):
         assert c["shards"] == SHARDS and c["lanes"] in ROW_CLASSES
         assert 0 < c["rows_hottest_shard"] <= c["lanes"]
         assert c["affine"] in (0, 1)
-        # six lane arrays in (local rows, data, length, offset, IV,
-        # ROC), three or two back: counted as they cross
-        want = {"h2d_arrays": 6,
-                "d2h_arrays": 3 if stage == "unprotect_wait" else 2}
+        # ONE packed lane plane each way (core/staging.py), a block a
+        # chip: counted as it crosses, its bytes the lane plane's (40
+        # to 160 byte payloads: the 224-byte width class both ways)
         for k in arrays:
-            assert c[k] == want[k]
-            assert c[k.replace("arrays", "bytes")] > 0
+            assert c[k] == 1
+            assert c[k.replace("arrays", "bytes")] == \
+                SHARDS * c["lanes"] * (224 + staging.TAIL)
         assert counts["owner_plan"] == {"rows": counts["owner_plan"][
             "rows"], "shards": 2 * SHARDS}
         assert counts["mesh_scatter"]["rows"] == \
@@ -309,3 +310,39 @@ def test_rows_per_shard_are_on_the_metrics_page(served):
     for d in range(SHARDS):
         assert f'libjitsi_tpu_mesh_rows_per_shard{{shard="{d}"}}' in text
     assert "mesh_rows_per_shard" not in served["one"]["metrics"]
+
+
+@pytest.mark.parametrize("case,want", [
+    ("served", 4.0), ("an_array_an_argument", 17.0), ("no_stats", None),
+    ("untraced", None)])
+def test_staged_arrays_reader_sums_what_the_spans_book(served,
+                                                       monkeypatch, case,
+                                                       want):
+    """`benchmarks/layers/mesh_staged_arrays_per_tick.paced.py` over a
+    slice whose staging spans carry this bridge's own counts reads 4;
+    over the counts an array an argument booked, 17; nothing where the
+    spans carry no such stat or the run was not traced."""
+    bench = os.path.join(_ROOT, "benchmarks")
+    monkeypatch.syspath_prepend(bench)
+    import xstats
+
+    spec = importlib.util.spec_from_file_location(
+        "layer_staged", os.path.join(
+            bench, "layers", "mesh_staged_arrays_per_tick.paced.py"))
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    stages = ("unprotect_wait", "fanout_dispatch", "fanout_d2h")
+    if case == "served":
+        ticks = [{s: c[s] for s in stages}
+                 for c in served["mesh"]["counts"]]
+    else:
+        old = {"unprotect_wait": {"h2d_arrays": 6, "d2h_arrays": 3},
+               "fanout_dispatch": {"h2d_arrays": 6},
+               "fanout_d2h": {"d2h_arrays": 2}}
+        ticks = [{s: ({"rows": 9} if case == "no_stats" else old[s])
+                  for s in stages}] * 3
+    host = [("stage:" + s, 0, 1, dict(c, tick=t))
+            for t, tick in enumerate(ticks) for s, c in tick.items()]
+    monkeypatch.setattr(xstats, "load", lambda _p: {"host": host})
+    ctx = {"trace": None if case == "untraced" else {"xplane": "x"}}
+    assert len(ticks) >= 3 and reader.read(ctx) == want

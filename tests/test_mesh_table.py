@@ -532,3 +532,170 @@ def test_tables_of_one_mesh_share_their_programs():
     assert a._scratch()._sh_fns is a._sh_fns
     other = ShardedSrtpTable(CAP, make_media_mesh(jax.devices()[:2]))
     assert other._sh_fns is not a._sh_fns
+
+
+# ------------------------------------- the packed mesh seams (one plane)
+
+def _ids_for(case: str, rows_per: int) -> np.ndarray:
+    """Key rows of one batch over four shards of `rows_per` rows."""
+    rng = np.random.default_rng(7)
+    if case == "skewed":             # 3 / 30 / 2 / 5 rows a shard
+        return rng.permutation(np.concatenate([
+            d * rows_per + rng.integers(0, rows_per, n)
+            for d, n in enumerate((3, 30, 2, 5))]))
+    if case == "two_empty_shards":   # the mesh cell's 1/0/0/8 x 7 rows
+        return np.concatenate([rng.integers(0, rows_per, 7),
+                               3 * rows_per + rng.integers(0, rows_per,
+                                                           56)])
+    if case == "affine":             # shard-major, a row class a shard
+        return np.concatenate([d * rows_per + rng.integers(0, rows_per,
+                                                           16)
+                               for d in range(4)])
+    return np.array([2 * rows_per + 5])          # one row
+
+
+@pytest.mark.parametrize("case", ["skewed", "two_empty_shards", "affine",
+                                  "one_row"])
+def test_lane_plane_there_and_back_is_the_identity(case):
+    """`pack` -> lane gather -> the mesh -> inverse gather ->
+    `split_out` hands every real row back where it was: its bytes, its
+    three words and its IV, with word 0 the chip-local key row; one
+    array crosses each way and its bytes are the lane plane's."""
+    import jax
+
+    from libjitsi_tpu.core import staging
+    from libjitsi_tpu.core.packet import _round_rows
+
+    mesh = make_media_mesh(jax.devices()[:4])
+    tab = ShardedSrtpTable(64, mesh)
+    ids = _ids_for(case, tab.rows_per)
+    n, width = len(ids), 224
+    rng = np.random.default_rng(len(ids))
+    plane = staging.alloc(n, width)
+    plane[:, :width] = rng.integers(0, 256, (n, width), dtype=np.uint8)
+    words = [rng.integers(0, 1 << 32, n, dtype=np.int64) for _ in range(3)]
+    iv = rng.integers(0, 256, (n, 16), dtype=np.uint8)
+    sent = plane[:, :width].copy()
+
+    launch = tab._packed_call(
+        lambda _rk, _mid, lanes: lanes, plane, ids, words, iv,
+        lambda host: (staging.split_out(host, staging.WORDS),
+                      host[:, -staging.IV_BYTES:]))
+    (data, got), iv_back = launch.fetch()
+    np.testing.assert_array_equal(data, sent)
+    np.testing.assert_array_equal(got[:, 0], ids % tab.rows_per)
+    for k, w in enumerate(words):
+        np.testing.assert_array_equal(got[:, 1 + k].view(np.uint32), w)
+    np.testing.assert_array_equal(iv_back, iv)
+    hottest = int(np.bincount(ids // tab.rows_per, minlength=4).max())
+    lanes = _round_rows(hottest)
+    assert launch.counts == {"shards": 4, "lanes": lanes,
+                             "rows_hottest_shard": hottest,
+                             "affine": int(case == "affine")}
+    assert launch.h2d_arrays == launch.d2h_arrays == 1
+    assert launch.h2d_bytes == launch.d2h_bytes \
+        == 4 * lanes * (width + staging.TAIL)
+    np.testing.assert_array_equal(
+        tab.shard_rows, np.bincount(ids // tab.rows_per, minlength=4))
+
+
+def _spy(obj, seam: str) -> list:
+    """Keep every `staging.Launch` that `obj`'s seam hands back."""
+    kept, real = [], getattr(obj, seam)
+
+    def call(*args, **kwargs):
+        kept.append(real(*args, **kwargs))
+        return kept[-1]
+
+    setattr(obj, seam, call)
+    return kept
+
+
+def _same_launch_outputs(mesh_launch, one_launch) -> int:
+    """A mesh seam's `fetch()` against the one-chip seam's, over the
+    mesh's rows (the one-chip host plane pads its rows behind them):
+    same arrays, same dtypes.  Returns the rows compared."""
+    got, want = mesh_launch.fetch(), one_launch.fetch()
+    assert len(got) == len(want)
+    n = len(got[0])
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape[1:] == w.shape[1:]
+        np.testing.assert_array_equal(g, w[:n])
+    return n
+
+
+@pytest.mark.parametrize("payload,width", [(100, 224), (400, 544)])
+def test_packed_mesh_unprotect_equals_the_one_chip_table(payload, width):
+    """The packed mesh unprotect hands back what the one-chip packed
+    unprotect does, byte for byte: data, media lengths and verdicts,
+    a flipped tag bit refused by both."""
+    import jax
+
+    mesh = make_media_mesh(jax.devices()[:4])
+    rng = np.random.default_rng(payload)
+    mks = rng.integers(0, 256, (CAP, 16), dtype=np.uint8)
+    mss = rng.integers(0, 256, (CAP, 14), dtype=np.uint8)
+    tx = SrtpStreamTable(CAP)
+    sh, one = ShardedSrtpTable(CAP, mesh), SrtpStreamTable(CAP)
+    for t in (tx, sh, one):
+        t.add_streams(np.arange(CAP), mks, mss)
+    wire = tx.protect_rtp(_batch(rng, 21, 500, sizes=(payload,)))
+    bad = 5
+    wire.data[bad, int(wire.length[bad]) - 1] ^= 0x04
+    spies = [_spy(t, "_cm_rtp_unprotect_call") for t in (sh, one)]
+    oks = []
+    for t in (sh, one):
+        copy = type(wire)(wire.data.copy(), wire.length.copy(),
+                          wire.stream.copy())
+        _out, ok = t.unprotect_rtp(copy)
+        oks.append(np.asarray(ok))
+    np.testing.assert_array_equal(oks[0], oks[1])
+    assert not oks[0][bad] and oks[0].sum() == 20
+    (m,), (o,) = spies
+    assert _same_launch_outputs(m, o) == 21
+    data, mlen, auth_ok = m.fetch()
+    assert data.shape == (21, width) and mlen.dtype == np.int32
+    assert auth_ok.dtype == bool and auth_ok.sum() == 20
+    assert (m.h2d_arrays, m.d2h_arrays) == (1, 1)
+
+
+@pytest.mark.parametrize("payload,width", [(40, 224), (400, 544)])
+def test_packed_mesh_fanout_equals_the_one_chip_translator(payload,
+                                                           width):
+    """The packed mesh fan-out hands back what the one-chip packed
+    fan-out does, byte for byte: wire bytes and wire lengths."""
+    import jax
+
+    from libjitsi_tpu.core.packet import PacketBatch
+    from libjitsi_tpu.mesh import ShardedRtpTranslator
+    from libjitsi_tpu.sfu.translator import RtpTranslator
+
+    mesh = make_media_mesh(jax.devices()[:4])
+    rng = np.random.default_rng(payload)
+    keys = rng.integers(0, 256, (CAP, 30), dtype=np.uint8)
+    pair = (ShardedRtpTranslator(CAP, mesh), RtpTranslator(CAP))
+    for tr in pair:
+        for r in range(CAP):
+            tr.add_receiver(r, keys[r, :16].tobytes(),
+                            keys[r, 16:].tobytes())
+        tr.connect(0, list(range(1, CAP)))      # every shard has legs
+    pls = [rng.integers(0, 256, payload, dtype=np.uint8).tobytes()
+           for _ in range(3)]
+    spies = [_spy(tr, "_cm_fanout_call") for tr in pair]
+    outs = []
+    for tr in pair:
+        b = rtp_header.build(pls, [900 + i for i in range(3)], [0] * 3,
+                             [0x4321] * 3, [96] * 3, stream=[0] * 3)
+        wide = PacketBatch.empty(b.batch_size, b.capacity + 32)
+        wide.data[:, :b.capacity] = b.data
+        wide.length[:] = b.length
+        wide.stream[:] = b.stream
+        out, recv = tr.translate(wide, np.arange(900, 903))
+        outs.append({(int(recv[i]), i): out.to_bytes(i)
+                     for i in range(out.batch_size)})
+    assert outs[0] == outs[1] and len(outs[0]) == 3 * (CAP - 1)
+    (m,), (o,) = spies
+    assert _same_launch_outputs(m, o) == 3 * (CAP - 1)
+    data, lens = m.fetch()
+    assert data.shape == (45, width) and lens.dtype == np.int32
+    assert (m.h2d_arrays, m.d2h_arrays) == (1, 1)
