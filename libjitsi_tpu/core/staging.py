@@ -21,8 +21,9 @@ shifts in the program: no width-changing `bitcast_convert_type`, whose
 byte order would be the backend's to choose.
 
 Host side: `alloc` where the rows are copied anyway, `pack`, then one
-`jax.device_put`; `Launch.fetch` and `split_out`.  Inside the jitted program: `unpack`,
-`repack`.  The two programs a served tick launches stage this way
+`put` (the `jax.device_put`, a span of its own inside the call's
+`dispatch`); `Launch.fetch` and `split_out`.  Inside the jitted
+program: `unpack`, `repack`.  The two programs a served tick launches stage this way
 under AES-CM and under AES-GCM alike: the RTP unprotect
 (`transform/srtp/context.py`; GCM in its per-row form) and the per-row
 fan-out (`sfu/translator.py`).  GCM has no ROC word (three words, the
@@ -42,15 +43,21 @@ which route an array per lane to its owning chip (mesh/table.py
 
 from __future__ import annotations
 
+import threading
 from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from libjitsi_tpu.utils.tracing import span_of
+
 WORDS = 4                       # per-row 32-bit words in, and room out
 IV_BYTES = 16
 TAIL = 4 * WORDS + IV_BYTES     # 32: 224 -> 256, 544 -> 576, 1536 -> 1568
+
+# (tracer, "<seam>_put") of the `dispatch` open on this thread
+_seam = threading.local()
 
 
 # ---- host ----------------------------------------------------------------
@@ -84,14 +91,56 @@ def split_out(host: np.ndarray, n_words: int
     return host[:, :w], words.view("<i4")
 
 
-def put_each(*arrays) -> Tuple[list, int, int]:
-    """An argument an array, for a call that does not pack: each host
-    array crosses as it stands (its dtype is settled on the host by the
-    caller, so no `convert_element_type` program runs for it).  Returns
+class dispatch:
+    """`with staging.dispatch(tracer, "fanout") as sp:`: the span
+    `<seam>_dispatch` of one device call of a tick (seam `unprotect` or
+    `fanout`), from `pack` to the jit call's return.  While it is open,
+    a `put` / `put_each` on THIS thread books `<seam>_put` as its
+    child; one on a thread inside no `dispatch` (a warm-up in the
+    compile pool, a table standing alone) opens no span, so the
+    tracer's lock-free tree stays the tick thread's."""
+
+    __slots__ = ("_span", "_here", "_prev")
+
+    def __init__(self, tracer, seam: str, **counts):
+        self._span = span_of(tracer, seam + "_dispatch", **counts)
+        self._here = (tracer, seam + "_put")
+
+    def __enter__(self):
+        self._prev = getattr(_seam, "at", None)
+        _seam.at = self._here
+        return self._span.__enter__()
+
+    def __exit__(self, *exc) -> None:
+        _seam.at = self._prev
+        self._span.__exit__(*exc)
+
+
+def put_each(arrays, sharding=None) -> Tuple[list, int, int]:
+    """THE host-to-device copy of a device call, and the one place a
+    served call reaches `jax.device_put`: every array of `arrays`
+    crosses as it stands (its dtype is settled on the host by the
+    caller, so no `convert_element_type` program runs for it), to the
+    default device, or onto a mesh a block a chip where `sharding`
+    gives an array its `NamedSharding`.  Inside a `dispatch` the copies
+    lie in ONE span `<seam>_put`, booked with what crossed.  Returns
     (device arrays, how many crossed, their bytes)."""
     host = [np.ascontiguousarray(a) for a in arrays]
-    return ([jax.device_put(a) for a in host], len(host),
-            sum(int(a.nbytes) for a in host))
+    n, nbytes = len(host), sum(int(a.nbytes) for a in host)
+    tracer, stage = getattr(_seam, "at", None) or (None, "")
+    with span_of(tracer, stage, h2d_arrays=n, h2d_bytes=nbytes):
+        dev = [jax.device_put(a, None if sharding is None else sharding(a))
+               for a in host]
+    return dev, n, nbytes
+
+
+def put(plane: np.ndarray, sharding=None):
+    """`put_each` of one packed plane (on a mesh: one lane plane, a
+    block a chip under the `NamedSharding` `sharding`); returns the
+    device array."""
+    (dev,), _n, _nbytes = put_each(
+        (plane,), None if sharding is None else lambda _a: sharding)
+    return dev
 
 
 class Launch:
@@ -107,8 +156,8 @@ class Launch:
     else the caller's span should book for the call (the GCM calls:
     `gm_gather_bytes`, `grouped`; a mesh call: `shards`, `lanes`,
     `rows_hottest_shard`, `affine`), `d2h_counts` what the span round
-    the copy back should, where that is a span of its own (the
-    fan-out's `fanout_d2h`: a mesh call's four again)."""
+    the copy back should (`unprotect_d2h`, `fanout_d2h`: a mesh call's
+    four again)."""
 
     __slots__ = ("_outs", "_split", "_host", "h2d_arrays", "h2d_bytes",
                  "d2h_arrays", "d2h_bytes", "counts", "d2h_counts")
@@ -124,6 +173,18 @@ class Launch:
         self.d2h_arrays = self.d2h_bytes = 0
         self.counts = counts or {}
         self.d2h_counts = d2h_counts or {}
+
+    def copy_back_async(self) -> "Launch":
+        """Ask for the outputs' host copies now: each starts when the
+        program ends, not when the thread that waits for it has woken
+        (what `np.asarray` of an output still in flight does first).
+        An output that is no device array (a mesh seam's deferred
+        scatter, a length known on the host) is passed over, as
+        `jax.block_until_ready` passes it over."""
+        for o in self._outs:
+            if isinstance(o, jax.Array):
+                o.copy_to_host_async()
+        return self
 
     def block_until_ready(self) -> "Launch":
         if self._host is None:

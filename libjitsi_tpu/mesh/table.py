@@ -222,7 +222,7 @@ class ShardedRowsMixin:
         return outs, plan, n, nbytes
 
     def _put_lanes(self, lanes):
-        """`staging.put_each` for lane arrays `[n_dev, per, ...]`: each
+        """`staging.put_each` of lane arrays `[n_dev, per, ...]`: each
         crosses once, a block to the chip that owns it, in the dtype
         the program takes (64-bit words are cut to 32 on the host, as
         JAX would on the device).  Returns (device arrays, how many
@@ -232,11 +232,9 @@ class ShardedRowsMixin:
             if a.dtype.itemsize == 8:
                 a = a.astype(np.uint32 if a.dtype.kind == "u"
                              else np.int32)
-            host.append(np.ascontiguousarray(a))
-        dev = [jax.device_put(a, NamedSharding(
+            host.append(a)
+        return staging.put_each(host, lambda a: NamedSharding(
             self.mesh, P(self._axes, *([None] * (a.ndim - 1)))))
-            for a in host]
-        return dev, len(host), sum(int(a.nbytes) for a in host)
 
     def _sharded_launch(self, fn, tabs, ids, lane_args, extra_args=(),
                         plan=None):
@@ -294,11 +292,11 @@ class ShardedRowsMixin:
         are the seam's other three (length, payload offset, ROC).  The
         plan gathers the packed plane into lanes (`owner_plan`; a
         reshape when affine; a pad lane repeats a real row, whose local
-        word is in range wherever it lands), one `jax.device_put` puts
-        it on the mesh, and `fn` (`_packed_fn`) gives one plane of the
-        same shape back.  `fetch()` copies it once, takes it to wire
-        order (`mesh_scatter`) and ends in `split`, the one-chip
-        seam's."""
+        word is in range wherever it lands), one `staging.put` puts it
+        on the mesh (`<seam>_put`, a block a chip), and `fn`
+        (`_packed_fn`) gives one plane of the same shape back.
+        `fetch()` copies it once, takes it to wire order
+        (`mesh_scatter`) and ends in `split`, the one-chip seam's."""
         ids = np.asarray(ids, dtype=np.int64)
         n = len(ids)
         tracer = getattr(self, "tracer", None)
@@ -311,7 +309,7 @@ class ShardedRowsMixin:
             lanes = (plane.reshape(self.n_dev, plan.per, plane.shape[-1])
                      if plan.affine else plane[plan.slot])
         self.shard_rows = plan.counts
-        out = fn(*self._sharded_device("rtp"), jax.device_put(
+        out = fn(*self._sharded_device("rtp"), staging.put(
             lanes, NamedSharding(self.mesh, P(self._axes, None, None))))
         inv = None if plan.affine else plan.inv
 

@@ -443,7 +443,7 @@ class RtpTranslator:
                 batch, rows, recvs, src, recv, data, length,
                 hdr, payload_off, ssrc, idx)
         else:
-            with span_of(tracer, "fanout_dispatch") as sp, \
+            with staging.dispatch(tracer, "fanout") as sp, \
                     phase_of(self.perf, "dispatch"):
                 launch = self._cm_fanout_call(*cm)
                 sp.note(h2d_arrays=launch.h2d_arrays,
@@ -505,7 +505,7 @@ class RtpTranslator:
         staging.pack(plane, (recv, length, payload_off,
                              (idx >> 16) & 0xFFFFFFFF), iv)
         out = _fanout_protect(
-            tab_rk, tab_mid, jax.device_put(plane),
+            tab_rk, tab_mid, staging.put(plane),
             self.policy.auth_tag_len, self.policy.cipher != Cipher.NULL,
             off_const=_uniform_off(payload_off,
                                    plane.shape[-1] - staging.TAIL))
@@ -576,7 +576,7 @@ class RtpTranslator:
                     pssrc[None, :], pidx[None, :])
                 sp.note(rows=g_real * p_real,
                         rows_padded=len(rr_p) * len(pr), width=pw)
-            with span_of(tracer, "fanout_dispatch") as sp, \
+            with staging.dispatch(tracer, "fanout") as sp, \
                     phase_of(perf, "dispatch"):
                 # the output is leg-major [G, P, W] at the class-PADDED
                 # shape; cropping to the raw (P, G) and the flip to
@@ -602,7 +602,7 @@ class RtpTranslator:
                                         ssrc[rr_idx], idx[rr_idx])
             plen, poff = length[rr_idx], payload_off[rr_idx]
             sp.note(rows=len(recv), rows_padded=len(rr_idx), width=pw)
-        with span_of(tracer, "fanout_dispatch") as sp, \
+        with staging.dispatch(tracer, "fanout") as sp, \
                 phase_of(perf, "dispatch"):
             launch = self._gcm_fanout_call(
                 recv[rr_idx], plane, plen, poff, iv)
@@ -619,8 +619,8 @@ class RtpTranslator:
         (leg-major out [G, P, W], out_len [P])."""
         tab_rk, tab_gm = self._device()
         dev, n, nbytes = staging.put_each(
-            np.asarray(rr, dtype=np.int32), pdata,
-            np.asarray(plen, dtype=np.int32), iv)
+            (np.asarray(rr, dtype=np.int32), pdata,
+             np.asarray(plen, dtype=np.int32), iv))
         return staging.Launch(
             _fanout_protect_gcm_legs(tab_rk, tab_gm, *dev,
                                      aad_const=aad_const),
@@ -644,7 +644,7 @@ class RtpTranslator:
         tab_rk, tab_gm = self._device()
         staging.pack(plane, (recv, length, payload_off), iv12)
         out = _fanout_protect_gcm(
-            tab_rk, tab_gm, jax.device_put(plane),
+            tab_rk, tab_gm, staging.put(plane),
             aad_const=_uniform_off(payload_off,
                                    plane.shape[-1] - staging.TAIL))
         return staging.Launch(

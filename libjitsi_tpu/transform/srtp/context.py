@@ -1178,7 +1178,7 @@ class SrtpStreamTable:
             gr, us, inv = grid
             host += [gr, us.astype(np.int32), inv]
             groups = len(us)
-        dev, n, nbytes = staging.put_each(*host)
+        dev, n, nbytes = staging.put_each(host)
         return dev, {
             "h2d_arrays": n, "h2d_bytes": nbytes,
             "counts": {"gm_gather_bytes": groups * GM_BYTES,
@@ -1216,7 +1216,7 @@ class SrtpStreamTable:
             plane = batch.plane
             staging.pack(plane, (stream, length, hdr.payload_off), iv12)
             out = _unprotect_gcm_dev_call(
-                tab_rk, tab_gm, jax.device_put(plane), aad_const=aad_const)
+                tab_rk, tab_gm, staging.put(plane), aad_const=aad_const)
             return staging.Launch(
                 (out,), _split_unprotect, h2d_arrays=1,
                 h2d_bytes=plane.nbytes,
@@ -1366,7 +1366,7 @@ class SrtpStreamTable:
                              v & 0xFFFFFFFF), iv)
         fn = (_unprotect_rtp_packed_donated if _donate_ingest()
               else _unprotect_rtp_packed)
-        out = fn(tab_rk, tab_mid, jax.device_put(plane), p.auth_tag_len,
+        out = fn(tab_rk, tab_mid, staging.put(plane), p.auth_tag_len,
                  p.cipher != Cipher.NULL,
                  off_const=_uniform_off(hdr.payload_off, batch.capacity))
         return staging.Launch((out,), _split_unprotect, h2d_arrays=1,
@@ -1570,31 +1570,46 @@ class SrtpStreamTable:
                 iv = self._cm_iv(self._salt_rtp[stream], hdr.ssrc, idx)
 
         # from the staging of the arguments to the outputs as host
-        # arrays: what the tick thread waits on the device for
+        # arrays: what the tick thread spends round the device call, in
+        # the four phases every seam books (`unprotect_put` opens
+        # inside the call: core/staging.py)
         with span_of(tracer, "unprotect_wait",
                      rows=batch.batch_size if n_real is None else n_real,
                      rows_padded=batch.batch_size) as sp, \
                 phase_of(self.perf, "device_compute"):
-            if self._gcm:
-                out = (None if self._ks_cache is None
-                       else self._gcm_rtp_unprotect_cached(
-                           stream, batch, hdr, idx, length))
-                if out is None:
-                    if iv is None:
-                        iv = self._gcm_rtp_iv(self._salt_rtp[stream],
-                                              hdr.ssrc, idx)
-                    launch = self._gcm_rtp_unprotect_call(
-                        stream, batch, hdr, iv, length)
+            with staging.dispatch(tracer, "unprotect"):
+                if self._gcm:
+                    out = (None if self._ks_cache is None
+                           else self._gcm_rtp_unprotect_cached(
+                               stream, batch, hdr, idx, length))
+                    if out is None:
+                        if iv is None:
+                            iv = self._gcm_rtp_iv(self._salt_rtp[stream],
+                                                  hdr.ssrc, idx)
+                        launch = self._gcm_rtp_unprotect_call(
+                            stream, batch, hdr, iv, length)
+                    else:
+                        launch = self._unpacked_launch(out, batch, length,
+                                                       iv)
+                elif self._f8:
+                    launch = self._unpacked_launch(
+                        self._f8_rtp_unprotect_call(stream, batch, hdr, iv,
+                                                    v, length),
+                        batch, length, iv)
                 else:
-                    launch = self._unpacked_launch(out, batch, length, iv)
-            elif self._f8:
-                launch = self._unpacked_launch(
-                    self._f8_rtp_unprotect_call(stream, batch, hdr, iv, v,
-                                                length), batch, length, iv)
-            else:
-                launch = self._cm_rtp_unprotect_call(
-                    stream, batch, hdr, iv, v, length)
-            data, mlen, auth_ok = launch.fetch()
+                    launch = self._cm_rtp_unprotect_call(
+                        stream, batch, hdr, iv, v, length)
+            with span_of(tracer, "unprotect_block"):
+                # this seam fetched with no wait before it (until PR
+                # 37), and `np.asarray` of an output in flight asks for
+                # the copy first: kept, or the copy back would start
+                # only once this thread had woken (0.18 ms a call on
+                # the v5e, PERF.md, PR 37)
+                launch.copy_back_async().block_until_ready()
+            with span_of(tracer, "unprotect_d2h") as d2h:
+                data, mlen, auth_ok = launch.fetch()
+                d2h.note(d2h_arrays=launch.d2h_arrays,
+                         d2h_bytes=launch.d2h_bytes, **launch.d2h_counts)
             sp.note(h2d_arrays=launch.h2d_arrays,
                     h2d_bytes=launch.h2d_bytes,
                     d2h_arrays=launch.d2h_arrays,

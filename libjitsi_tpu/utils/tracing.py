@@ -46,18 +46,31 @@ except Exception:                       # pragma: no cover - jax present
 
 #: the leaves of an SfuBridge tick, in the order they run: spans with
 #: no child (but `gc`, which opens wherever a collection lands).  They
-#: tile the tick: what lies between them is in no span, and the
-#: benchmark's `tick_unspanned_pct` watches that it stays small
-LEAF_STAGES = ("ingress", "demux", "unprotect_host", "unprotect_wait",
-               "parse", "recovery", "bwe", "abs_send_time", "route",
-               "expand", "fanout_dispatch", "fanout_wait", "fanout_d2h",
-               "nack_cache", "egress", "supervise", "gc")
+#: tile the tick: what lies between them is in no leaf (a container's
+#: own lines, or no span at all), and the benchmark's
+#: `tick_unspanned_pct` watches that it stays small.  Each device call
+#: of the tick (seam S in `unprotect`, `fanout`) books the same four
+#: host phases: `S_put` (the `jax.device_put` alone, core/staging.py),
+#: the rest of `S_dispatch` (pack and the jit call), the wait for the
+#: program (`unprotect_block`, `fanout_wait`) and `S_d2h` (the copy
+#: back and the split)
+LEAF_STAGES = ("ingress", "demux", "unprotect_host", "unprotect_put",
+               "unprotect_block", "unprotect_d2h", "parse", "recovery",
+               "bwe", "abs_send_time", "route", "expand", "fanout_put",
+               "fanout_wait", "fanout_d2h", "nack_cache", "egress",
+               "supervise", "gc")
+
+#: the spans round them: `unprotect_wait` holds `unprotect_dispatch`,
+#: `unprotect_block` and `unprotect_d2h` (its counts are the whole
+#: call's, as they were when it was a leaf), `S_dispatch` holds `S_put`
+CONTAINER_STAGES = ("reverse_chain", "unprotect", "unprotect_wait",
+                    "unprotect_dispatch", "forward_chain",
+                    "fanout_dispatch")
 
 #: canonical stage names (a tracer accepts any string; these are the
 #: ones the dashboards are generated from): the leaves, the containers
 #: round them and the mixer bridge's stages
-STAGES = LEAF_STAGES + ("reverse_chain", "unprotect", "forward_chain",
-                        "decode", "mixer")
+STAGES = LEAF_STAGES + CONTAINER_STAGES + ("decode", "mixer")
 
 
 class _NullSpan:

@@ -286,6 +286,20 @@ def test_served_path_never_times_providers(served):
         == c["expand"]["rows_padded"] * plane
 
 
+@pytest.mark.parametrize("leaf,parent,keys", [
+    ("unprotect_put", "unprotect_wait", ("h2d_arrays", "h2d_bytes")),
+    ("unprotect_d2h", "unprotect_wait", ("d2h_arrays", "d2h_bytes")),
+    ("fanout_put", "fanout_dispatch", ("h2d_arrays", "h2d_bytes"))])
+def test_served_seam_leaves_book_what_their_calls_book(served, leaf,
+                                                       parent, keys):
+    """Under GCM as under CM the put is spanned once, where it happens
+    (core/staging.py), and books the arrays and bytes its call books;
+    the unprotect's copy back likewise."""
+    c = served["counts"]
+    assert c[leaf] == {k: c[parent][k] for k in keys}
+    assert c[leaf][keys[0]] > 0
+
+
 # ------------------------------------------------------------- the rule
 
 def _streams(rows: int, per: int, seed: int) -> np.ndarray:
@@ -389,6 +403,12 @@ def test_rule_selected_form_matches_the_reference(rows, per, mixed,
     assert c["grouped"] == int(grouped)
     assert (c["h2d_arrays"], c["d2h_arrays"]) == ((8, 3) if grouped
                                                   else (1, 1))
+    # ONE `unprotect_put` a call, with the count of its arrays
+    # (`staging.put_each` under the grouped form), and one copy back
+    put, back = (tracer.last_counts[k] for k in ("unprotect_put",
+                                                 "unprotect_d2h"))
+    assert put == {k: c[k] for k in ("h2d_arrays", "h2d_bytes")}
+    assert back == {k: c[k] for k in ("d2h_arrays", "d2h_bytes")}
     assert grouped or c["gm_gather_bytes"] \
         == c["rows_padded"] * ctx.GM_BYTES
     # the two programs at this batch's own shape

@@ -305,6 +305,34 @@ def test_mesh_spans_book_the_plan_and_what_crossed(served, stage, arrays):
         assert "lanes" not in counts["unprotect_wait"]
 
 
+@pytest.mark.parametrize("bridge", ["mesh", "one"])
+@pytest.mark.parametrize("leaf,parent,keys", [
+    ("unprotect_put", "unprotect_wait", ("h2d_arrays", "h2d_bytes")),
+    ("unprotect_d2h", "unprotect_wait", ("d2h_arrays", "d2h_bytes")),
+    ("fanout_put", "fanout_dispatch", ("h2d_arrays", "h2d_bytes"))])
+def test_seam_leaves_book_what_their_calls_book(served, bridge, leaf,
+                                                parent, keys):
+    """On the mesh as on one chip the put is spanned once, where it
+    happens (core/staging.py), and books the arrays and bytes its call
+    books; the unprotect's copy back likewise (under a mesh with the
+    plan's four beside them, as `fanout_d2h` has them)."""
+    ticks = served[bridge]["counts"]
+    assert ticks
+    for counts in ticks:
+        got = counts[leaf]
+        assert {k: got[k] for k in keys} == \
+            {k: counts[parent][k] for k in keys}
+        assert got[keys[0]] == 1
+        mesh_keys = {"shards", "lanes", "rows_hottest_shard", "affine"}
+        if leaf.endswith("_put"):
+            assert set(got) == set(keys)
+        else:
+            assert set(got) - set(keys) == (
+                mesh_keys if bridge == "mesh" else set())
+        for stage in ("unprotect_dispatch", "unprotect_block"):
+            assert stage not in counts      # they carry no counts
+
+
 def test_rows_per_shard_are_on_the_metrics_page(served):
     text = served["mesh"]["metrics"]
     for d in range(SHARDS):

@@ -422,12 +422,24 @@ def test_sfu_tick_emits_every_leaf_and_self_times_tile_it(
     try:
         send.until_forwarded()
         led, self_led = sup.last_ledger, sup.last_self_ledger
-        containers = {"reverse_chain", "unprotect", "forward_chain"}
+        containers = set(tracing.CONTAINER_STAGES)
         assert set(led) - containers == set(tracing.LEAF_STAGES) - {"gc"}
+        assert set(led) >= containers
         assert set(tracing.STAGES) >= set(led)
         # a leaf has no child: both ledgers agree on it
         for stage in set(led) - containers:
             assert self_led[stage] == led[stage], stage
+        # the device seam: `unprotect_wait` is a container now, tiled
+        # by its dispatch, block and copy-back (its own lines are a
+        # few span entries); each dispatch holds its put
+        assert self_led["unprotect_wait"] < 0.2 * led["unprotect_wait"]
+        assert led["unprotect_wait"] >= (
+            led["unprotect_dispatch"] + led["unprotect_block"]
+            + led["unprotect_d2h"]) >= 0.8 * led["unprotect_wait"]
+        for seam in ("unprotect", "fanout"):
+            assert 0.0 < led[seam + "_put"] < led[seam + "_dispatch"]
+            assert self_led[seam + "_dispatch"] == pytest.approx(
+                led[seam + "_dispatch"] - led[seam + "_put"])
         assert sum(self_led.values()) == pytest.approx(
             led["ingress"] + led["demux"] + led["reverse_chain"]
             + led["supervise"])
@@ -440,6 +452,14 @@ def test_sfu_tick_emits_every_leaf_and_self_times_tile_it(
         assert counts["expand"]["rows_padded"] >= 6
         assert counts["fanout_dispatch"]["h2d_bytes"] > 0
         assert counts["fanout_d2h"]["d2h_bytes"] > 0
+        # a put books what its call books, where it happens
+        assert counts["unprotect_put"] == {
+            k: counts["unprotect_wait"][k]
+            for k in ("h2d_arrays", "h2d_bytes")}
+        assert counts["unprotect_d2h"] == {
+            k: counts["unprotect_wait"][k]
+            for k in ("d2h_arrays", "d2h_bytes")}
+        assert counts["fanout_put"] == counts["fanout_dispatch"]
         assert counts["nack_cache"]["rows"] == 6
         assert counts["egress"]["rows"] == 6
         assert counts["egress"]["bytes"] > 6 * 12

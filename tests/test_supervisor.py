@@ -214,6 +214,45 @@ def test_sfu_overrun_in_forward_chain_escalates_on_self_time(
     assert sup.health()["last_ledger"] == led
 
 
+@pytest.mark.parametrize("slow_call, stage", [
+    ("block_until_ready", "unprotect_block"), ("fetch", "unprotect_d2h")])
+def test_sfu_overrun_inside_the_device_seam_escalates_as_it_did(
+        sfu_with_traffic, monkeypatch, slow_call, stage):
+    """The seam's spans are grandchildren of `reverse_chain`: a tick
+    lost waiting for the unprotect names the leaf it was lost in
+    (`unprotect_wait`, now a container, kept none of it), neither
+    `forward_chain` nor `ingress` gains self time, and the ladder takes
+    the rung it took when `unprotect_wait` was the leaf: the wall
+    ladder's first."""
+    import time
+
+    from libjitsi_tpu.core import staging
+
+    sfu, sup, send = sfu_with_traffic
+    send.until_forwarded()                  # shapes compiled, warm
+    real = getattr(staging.Launch, slow_call)
+    slept = []
+
+    def slow(self):
+        if not slept:                       # the tick's first call: the
+            slept.append(1)                 # unprotect's
+            time.sleep(1.0)
+        return real(self)
+
+    sup.cfg.overload_after = 1
+    sup.watchdog.deadline_s = 0.5
+    monkeypatch.setattr(staging.Launch, slow_call, slow)
+    send.until_forwarded()
+    (ev,) = _escalations(sup)
+    led, self_led = sup.last_ledger, sup.last_self_ledger
+    assert ev["stage"] == stage and ev["stage_s"] >= 1.0
+    assert ev["stage_s"] == pytest.approx(self_led[stage])
+    assert ev["rung"] == sup.LADDER[0] == "recv_window"
+    assert led["unprotect_wait"] >= led[stage] >= 1.0
+    assert self_led["unprotect_wait"] < 0.05
+    assert self_led["forward_chain"] < 0.05 and self_led["ingress"] < 0.05
+
+
 def test_stage_skew_ingress_shrinks_recv_window_and_unwinds_lifo():
     ledger = {"ingress": 0.008, "forward_chain": 0.001,
               "egress": 0.001}
