@@ -557,6 +557,7 @@ def phase_c(rows: int, seed: int, on_chip: bool, latch_ticks: int = 6,
             tick_s.append(sup.last_tick_s)
         for _ in range(2):                # flush anything in flight
             tick()
+            bridge.flush_egress()         # ... the egress worker's too
             for cl in clients:
                 cl.drain(by_conf[cl.conf])
 

@@ -756,15 +756,18 @@ class MediaLoop:
         return self.note_journey_at(self.journey_origin(), n, sids=sids)
 
     def note_journey_at(self, origin: Tuple[int, Optional[float]],
-                        n: int, sids=None) -> Optional[float]:
-        """Observe `n` packets leaving now against an ingress origin.
+                        n: int, sids=None,
+                        at: Optional[float] = None) -> Optional[float]:
+        """Observe `n` packets leaving now (or at `at`, a
+        `time.perf_counter()` instant: the egress worker's end stamp of
+        a burst reaped later) against an ingress origin.
         A journey that overflows the top histogram bucket marks the
         shipped streams priority in the flight recorder, so the next
         header sample keeps their burst tail (adaptive hdr sampling)."""
         trace, t0 = origin
         if n <= 0 or t0 is None:
             return None
-        dt = time.perf_counter() - t0
+        dt = (time.perf_counter() if at is None else at) - t0
         tail = self.journey_hist.observe_same(
             dt, int(n), exemplar={"trace_id": str(trace)})
         if tail and self.flight is not None and sids is not None:

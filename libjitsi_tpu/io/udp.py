@@ -15,7 +15,7 @@ import os
 import socket
 import struct
 import subprocess
-from typing import Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -60,6 +60,18 @@ def _load():
         ctypes.c_int, ctypes.c_void_p, ctypes.c_int, ctypes.c_void_p,
         ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
         ctypes.c_int]
+    # the egress worker (one thread a socket, started by the first
+    # udp_send_async, joined in udp_close)
+    lib.udp_send_async.restype = ctypes.c_int64
+    lib.udp_send_async.argtypes = [
+        ctypes.c_int, ctypes.c_void_p, ctypes.c_int, ctypes.c_void_p,
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int, ctypes.c_void_p]
+    lib.udp_send_reap.restype = ctypes.c_int
+    lib.udp_send_reap.argtypes = [
+        ctypes.c_int, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_void_p, ctypes.c_int]
+    lib.udp_send_flush.restype = ctypes.c_int
+    lib.udp_send_flush.argtypes = [ctypes.c_int]
     lib.udp_enable_timestamps.restype = ctypes.c_int
     lib.udp_enable_timestamps.argtypes = [ctypes.c_int]
     lib.udp_recv_batch_ts.restype = ctypes.c_int
@@ -78,11 +90,6 @@ def _load():
     lib.udp_uring_recv.restype = ctypes.c_int
     lib.udp_uring_recv.argtypes = [
         ctypes.c_void_p, ctypes.c_int, ctypes.c_int, ctypes.c_void_p]
-    lib.udp_uring_send_idx.restype = ctypes.c_int
-    lib.udp_uring_send_idx.argtypes = [
-        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int,
-        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-        ctypes.c_void_p, ctypes.c_int]
     lib.udp_uring_stat.restype = ctypes.c_long
     lib.udp_uring_stat.argtypes = [ctypes.c_void_p, ctypes.c_int]
     lib.udp_uring_destroy.restype = None
@@ -176,6 +183,30 @@ def u32_to_ip(v: int) -> str:
     return socket.inet_ntoa(struct.pack("!I", v & 0xFFFFFFFF))
 
 
+#: jobs the native egress worker lets wait behind the one in flight
+#: before a hand-over blocks (`kEgressQueueJobs`, native/udp_engine.cpp)
+MAX_QUEUED_JOBS = 4
+
+
+class SendJob(NamedTuple):
+    """Receipt of `UdpEngine.send_batch_async`."""
+
+    id: int
+    rows: int
+    #: an earlier job was still queued or in flight at the hand-over:
+    #: the worker, not the caller, is the pace
+    behind: bool
+
+
+class SendDone(NamedTuple):
+    """One completion from `UdpEngine.reap`."""
+
+    id: int
+    sent: int        # datagrams sent, or -errno
+    t0: float        # `time.perf_counter()` seconds: the send began
+    t1: float        # ... and ended
+
+
 class UdpEngine:
     """One batched UDP socket (rtcp-mux style single port per engine).
 
@@ -191,15 +222,9 @@ class UdpEngine:
                  engine_mode: str = "auto"):
         if engine_mode not in ("auto", "io_uring", "recvmmsg"):
             raise ValueError(f"engine_mode: {engine_mode!r}")
-        # egress stays on sendmmsg even in ring mode unless opted in:
-        # measured on this class of box, one sendmmsg beats N SENDMSG
-        # SQEs (~127 vs ~226 us per 64-pkt burst — the kernel's
-        # per-SQE sendmsg path repays per-op async bookkeeping the
-        # batch syscall never touches), while ring INGEST holds even on
-        # loopback and sheds the per-window syscall entirely on real
-        # NICs where softirq context fills the armed chain
-        self.uring_egress = bool(
-            os.environ.get("LIBJITSI_TPU_URING_EGRESS", ""))
+        # egress is sendmmsg in every mode (one sendmmsg beats N SENDMSG
+        # SQEs: ~127 vs ~226 us per 64-pkt burst); the ring is INGEST
+        # only
         lib = _load()
         self.capacity = capacity
         #: live batching knob — recv windows honor the CURRENT value
@@ -237,6 +262,11 @@ class UdpEngine:
         #: native call); the io_uring engine's own enter count adds in
         #: via the `syscall_enters` property
         self._py_enters = 0
+        # asynchronous sends handed to the native egress worker and not
+        # reaped yet: job id -> the arrays the worker reads (the plane
+        # is NOT copied, so the reference is what keeps it alive)
+        self._jobs: Dict[int, tuple] = {}
+        self._reap_out = None        # scratch of `reap`, made on first use
         self._u = None  # io_uring engine handle (None => recvmmsg)
         self._uring_arena: Optional[_Arena] = None
         # mode resolution: "auto" follows the probe (env pin or the
@@ -455,43 +485,115 @@ class UdpEngine:
         return batch, a.sip[lo:hi], a.sport[lo:hi], a.ats[lo:hi]
 
     @staticmethod
-    def _c_u8(arr: np.ndarray) -> np.ndarray:
-        # no-op when already contiguous uint8 (numpy returns the same
-        # object) — only non-contiguous callers pay a materialization
-        if arr.dtype == np.uint8 and arr.flags["C_CONTIGUOUS"]:
+    def _rows_u8(arr: np.ndarray) -> np.ndarray:
+        # a uint8 matrix whose ROWS are contiguous goes to the C ABI as
+        # it is, under its own row stride: a column slice of a wider
+        # plane (the fan-out's `[rows, :width]` of the plane the device
+        # returned) is not copied.  Only other layouts are materialized
+        if (arr.dtype == np.uint8 and arr.ndim == 2
+                and arr.strides[1] == 1 and arr.strides[0] >= arr.shape[1]):
             return arr
         return np.ascontiguousarray(arr, dtype=np.uint8)  # jitlint: disable=hotpath-alloc
 
-    def send_batch(self, batch: PacketBatch, dst_ip, dst_port) -> int:
-        """Send all rows; dst_ip (u32 or dotted str) / dst_port broadcast."""
+    def _stage_send(self, batch: PacketBatch, dst_ip, dst_port):
+        """(data, lens, ips, ports) as the C ABI takes them, for all
+        rows of `batch`; dst_ip (u32 or dotted str) / dst_port
+        broadcast."""
         n = batch.batch_size
-        if n == 0:
-            return 0
         if isinstance(dst_ip, str):
             dst_ip = ip_to_u32(dst_ip)
         ips = np.broadcast_to(np.asarray(dst_ip, dtype=np.uint32), (n,))
         ports = np.broadcast_to(np.asarray(dst_port, dtype=np.uint16), (n,))
-        data = self._c_u8(batch.data)
+        data = self._rows_u8(batch.data)
         # O(n) metadata staging for the C ABI (int32/u32/u16 arrays),
         # not O(n*capacity) payload bytes
         lens = np.ascontiguousarray(  # jitlint: disable=hotpath-alloc
             batch.length, dtype=np.int32)
         ips = np.ascontiguousarray(ips)  # jitlint: disable=hotpath-alloc
         ports = np.ascontiguousarray(ports)  # jitlint: disable=hotpath-alloc
-        if self._u is not None and self.uring_egress:
-            # NULL idx = identity: all rows, gather egress via the ring
-            sent = _load().udp_uring_send_idx(
-                self._u, data.ctypes.data, data.shape[1],
-                lens.ctypes.data, ips.ctypes.data, ports.ctypes.data,
-                None, n)
-        else:
-            self._py_enters += 1
-            sent = _load().udp_send_batch(
-                self._fd, data.ctypes.data, data.shape[1],
-                lens.ctypes.data, ips.ctypes.data, ports.ctypes.data, n)
+        return data, lens, ips, ports
+
+    def send_batch(self, batch: PacketBatch, dst_ip, dst_port) -> int:
+        """Send all rows; dst_ip (u32 or dotted str) / dst_port broadcast.
+
+        Synchronous: runs inline on the caller when nothing is queued
+        on the egress worker, and behind whatever is (the native call
+        waits for the worker to fall idle first), so a leg never sees
+        this datagram before one handed over earlier."""
+        n = batch.batch_size
+        if n == 0:
+            return 0
+        data, lens, ips, ports = self._stage_send(batch, dst_ip, dst_port)
+        self._py_enters += 1
+        sent = _load().udp_send_batch(
+            self._fd, data.ctypes.data, data.strides[0],
+            lens.ctypes.data, ips.ctypes.data, ports.ctypes.data, n)
         if sent < 0:
             raise OSError(-sent, os.strerror(-sent))
         return sent
+
+    def send_batch_async(self, batch: PacketBatch, dst_ip, dst_port
+                         ) -> Optional["SendJob"]:
+        """Hand all rows to the socket's egress worker (one native
+        thread, started by the first call) and return at once: the
+        `sendmmsg` runs there, in hand-over order, while the caller
+        goes on.  Returns the job (None for an empty batch); its result
+        comes from `reap()`.
+
+        The caller's promise: `batch.data` is not written to until the
+        job is reaped (the plane is not copied; the engine holds the
+        references).  A caller that reuses its buffer (an arena view,
+        scratch) uses `send_batch`.  Waits only when `MAX_QUEUED_JOBS`
+        are already queued: overload shows as the caller's time, not as
+        memory."""
+        n = batch.batch_size
+        if n == 0:
+            return None
+        arrays = self._stage_send(batch, dst_ip, dst_port)
+        data, lens, ips, ports = arrays
+        behind = ctypes.c_int(0)
+        self._py_enters += 1
+        job = _load().udp_send_async(
+            self._fd, data.ctypes.data, data.strides[0], lens.ctypes.data,
+            ips.ctypes.data, ports.ctypes.data, n, ctypes.byref(behind))
+        if job < 0:
+            raise OSError(-job, os.strerror(-job))
+        self._jobs[job] = arrays
+        return SendJob(int(job), n, bool(behind.value))
+
+    def reap(self) -> List["SendDone"]:
+        """Completions of asynchronous sends so far, oldest first; never
+        blocks.  Each job comes back once, and its arrays are released
+        here.  `sent` is the datagrams sent or `-errno`; `t0` / `t1`
+        are `time.perf_counter()` seconds at which the worker began and
+        ended the job's `sendmmsg`."""
+        if not self._jobs:
+            return []
+        out = self._reap_out
+        if out is None:
+            k = MAX_QUEUED_JOBS + 2
+            out = self._reap_out = (
+                np.zeros(k, dtype=np.int64), np.zeros(k, dtype=np.int32),
+                np.zeros(k, dtype=np.int64), np.zeros(k, dtype=np.int64))
+        ids, sent, t0, t1 = out
+        done: List[SendDone] = []
+        lib = _load()
+        while True:
+            k = lib.udp_send_reap(self._fd, ids.ctypes.data,
+                                  sent.ctypes.data, t0.ctypes.data,
+                                  t1.ctypes.data, len(ids))
+            for i in range(k):
+                self._jobs.pop(int(ids[i]), None)
+                done.append(SendDone(int(ids[i]), int(sent[i]),
+                                     int(t0[i]) * 1e-9, int(t1[i]) * 1e-9))
+            if k < len(ids):
+                return done
+
+    def flush(self) -> None:
+        """Wait until every asynchronous send handed over has been sent
+        (their completions stay to be reaped)."""
+        if self._jobs:
+            _load().udp_send_flush(self._fd)
 
     def send_rows(self, batch: PacketBatch, rows, dst_ip, dst_port) -> int:
         """Gather-send selected rows in ONE multi-destination sendmmsg.
@@ -499,8 +601,8 @@ class UdpEngine:
         `rows` indexes into `batch`; `dst_ip`/`dst_port` are scalars or
         per-selected-row arrays (in `rows` order).  The native iovec
         gather IS the row selection — the host never materializes a
-        contiguous copy of the egress subset.  Falls back to the copy
-        path when the loaded engine predates `udp_send_batch_idx`."""
+        contiguous copy of the egress subset.  Synchronous, and ordered
+        behind the egress worker as `send_batch` is."""
         rows = np.asarray(rows, dtype=np.int32)
         n = int(rows.shape[0])
         if n == 0:
@@ -523,22 +625,22 @@ class UdpEngine:
         ports = np.ascontiguousarray(np.broadcast_to(  # jitlint: disable=hotpath-alloc
             np.asarray(dst_port, dtype=np.uint16), (n,)))
         idx = np.ascontiguousarray(rows)  # jitlint: disable=hotpath-alloc
-        if self._u is not None and self.uring_egress:
-            sent = lib.udp_uring_send_idx(
-                self._u, data.ctypes.data, data.shape[1],
-                lens.ctypes.data, ips.ctypes.data, ports.ctypes.data,
-                idx.ctypes.data, n)
-        else:
-            self._py_enters += 1
-            sent = lib.udp_send_batch_idx(
-                self._fd, data.ctypes.data, data.shape[1],
-                lens.ctypes.data, ips.ctypes.data, ports.ctypes.data,
-                idx.ctypes.data, n)
+        self._py_enters += 1
+        sent = lib.udp_send_batch_idx(
+            self._fd, data.ctypes.data, data.shape[1],
+            lens.ctypes.data, ips.ctypes.data, ports.ctypes.data,
+            idx.ctypes.data, n)
         if sent < 0:
             raise OSError(-sent, os.strerror(-sent))
         return sent
 
     def close(self) -> None:
+        """Send what was handed over, join the egress worker, close the
+        socket.  A second call is a no-op."""
+        # the worker still reads the jobs' arrays: everything out
+        # first, then the references may go
+        self.flush()
+        self.reap()
         if self._u is not None:
             # cancels any armed recvs and drains before the arenas can
             # be collected — MUST precede closing the socket fd

@@ -194,6 +194,7 @@ def run_sfu_once(cfg, mesh, capacity: int, rounds: int = 3,
                                sfu.port)
             for _ in range(12):
                 sfu.tick(now=now)
+            sfu.flush_egress()      # the fan-out leaves on a worker
             for j, (_ssrc, _prot, eng) in enumerate(eps):
                 back, _, _ = eng.recv_batch(timeout_ms=2)
                 if back.batch_size:

@@ -47,18 +47,18 @@ build_oracle() {
 
 case "${1:-}" in
   tsan)
-    link libudp_engine_tsan.so -O1 -g -Wall $URING_FLAGS \
+    link libudp_engine_tsan.so -O1 -g -Wall -pthread $URING_FLAGS \
         -fsanitize=thread -shared -fPIC udp_engine.cpp
     echo "built $(pwd)/libudp_engine_tsan.so" ;;
   asan)
-    link libudp_engine_asan.so -O1 -g -Wall $URING_FLAGS \
+    link libudp_engine_asan.so -O1 -g -Wall -pthread $URING_FLAGS \
         -fsanitize=address -shared -fPIC udp_engine.cpp
     echo "built $(pwd)/libudp_engine_asan.so" ;;
   oracle)
     build_oracle
     echo "built $(pwd)/libcrypto_oracle.so" ;;
   *)
-    link libudp_engine.so -O2 -Wall $URING_FLAGS -shared -fPIC \
+    link libudp_engine.so -O2 -Wall -pthread $URING_FLAGS -shared -fPIC \
         udp_engine.cpp
     # oracle is best-effort here: a box without libcrypto.so.3 still
     # gets the UDP engine (tests needing the oracle build it

@@ -12,16 +12,177 @@
 // Exposed as a plain C ABI for ctypes (no pybind11 in this image).
 
 #include <arpa/inet.h>
+#include <atomic>
 #include <cerrno>
+#include <condition_variable>
+#include <csignal>
 #include <cstdint>
 #include <cstring>
 #include <ctime>
+#include <deque>
+#include <mutex>
 #include <netinet/in.h>
+#include <new>
 #include <poll.h>
 #include <sys/socket.h>
 #include <sys/types.h>
+#include <system_error>
+#include <thread>
 #include <unistd.h>
+#include <unordered_map>
 #include <vector>
+
+// ===========================================================================
+// Egress worker: the fan-out's sendmmsg off the caller's thread.
+//
+// One worker thread a socket, started by the socket's first
+// udp_send_async and joined in udp_close.  A caller hands over a burst
+// (the same arguments udp_send_batch takes) and goes on; the worker
+// builds the mmsghdrs, runs the same sendmmsg loop and publishes a
+// completion: job id, `sent` or -errno, and CLOCK_MONOTONIC stamps of
+// the send's start and end (Python's time.perf_counter clock).
+//
+// ORDER is the contract.  One worker, FIFO; and a synchronous send on a
+// socket that has a worker first waits until the worker has nothing
+// queued or in flight, so a leg never sees a later datagram before an
+// earlier one.  The queue is bounded: a full queue makes the hand-over
+// wait, so overload shows as the caller's time and not as memory.
+//
+// The caller owns every array of a job (plane, lengths, addresses) and
+// keeps it alive and unwritten until the job's completion is reaped;
+// nothing is copied.  A socket has one sending caller at a time, as it
+// always had (the fd itself is not guarded against a close during a
+// send either).  No Python object is touched on the worker.
+
+namespace {
+
+//: jobs that may wait behind the one in flight before the hand-over
+//: blocks.  A constant, not a setting: a tick hands over one burst, so
+//: more than a few queued means the worker is the pace
+constexpr int kEgressQueueJobs = 4;
+
+int64_t monotonic_ns() {
+  timespec ts{};
+  clock_gettime(CLOCK_MONOTONIC, &ts);
+  return static_cast<int64_t>(ts.tv_sec) * 1000000000LL + ts.tv_nsec;
+}
+
+// the sendmmsg loop both paths run: scratch is the calling thread's own
+int send_burst(int fd, const uint8_t *buf, int capacity,
+               const int32_t *lengths, const uint32_t *dst_ip,
+               const uint16_t *dst_port, const int32_t *idx, int n) {
+  thread_local std::vector<mmsghdr> hdrs;
+  thread_local std::vector<iovec> iovs;
+  thread_local std::vector<sockaddr_in> addrs;
+  if (static_cast<int>(hdrs.size()) < n) {
+    hdrs.resize(n);
+    iovs.resize(n);
+    addrs.resize(n);
+  }
+  for (int i = 0; i < n; i++) {
+    int row = idx ? idx[i] : i;
+    iovs[i].iov_base = const_cast<uint8_t *>(buf) +
+                       static_cast<size_t>(row) * capacity;
+    iovs[i].iov_len = lengths[i];
+    addrs[i] = sockaddr_in{};
+    addrs[i].sin_family = AF_INET;
+    addrs[i].sin_port = htons(dst_port[i]);
+    addrs[i].sin_addr.s_addr = htonl(dst_ip[i]);
+    std::memset(&hdrs[i], 0, sizeof(mmsghdr));
+    hdrs[i].msg_hdr.msg_iov = &iovs[i];
+    hdrs[i].msg_hdr.msg_iovlen = 1;
+    hdrs[i].msg_hdr.msg_name = &addrs[i];
+    hdrs[i].msg_hdr.msg_namelen = sizeof(sockaddr_in);
+  }
+  int sent = 0;
+  while (sent < n) {
+    int r = sendmmsg(fd, hdrs.data() + sent, n - sent, 0);
+    if (r < 0) {
+      if (errno == EAGAIN || errno == EWOULDBLOCK) break;
+      return -errno;
+    }
+    sent += r;
+  }
+  return sent;
+}
+
+struct EgressJob {
+  int64_t id;
+  const uint8_t *buf;
+  int capacity;
+  const int32_t *lengths;
+  const uint32_t *dst_ip;
+  const uint16_t *dst_port;
+  int n;
+};
+
+struct EgressDone {
+  int64_t id;
+  int sent;  // datagrams sent, or -errno
+  int64_t t0_ns, t1_ns;
+};
+
+struct EgressWorker {
+  int fd = -1;
+  std::mutex mu;
+  std::condition_variable work;  // the worker waits here for a job
+  std::condition_variable room;  // callers wait here for room / idle
+  std::deque<EgressJob> queue;   // handed over, not yet taken
+  std::deque<EgressDone> done;   // completed, not yet reaped
+  bool busy = false;             // a job is in the worker's hands
+  bool stop = false;
+  int64_t next_id = 1;
+  std::thread thread;
+
+  void run() {
+    // the process's signals are the interpreter's: none is taken here,
+    // so no send of the worker ends in EINTR
+    sigset_t all;
+    sigfillset(&all);
+    pthread_sigmask(SIG_BLOCK, &all, nullptr);
+    std::unique_lock<std::mutex> lk(mu);
+    for (;;) {
+      work.wait(lk, [this] { return stop || !queue.empty(); });
+      if (queue.empty()) return;  // stop, and nothing left to send
+      EgressJob job = queue.front();
+      queue.pop_front();
+      busy = true;
+      room.notify_all();
+      lk.unlock();
+      int64_t t0 = monotonic_ns();
+      int sent = send_burst(fd, job.buf, job.capacity, job.lengths,
+                            job.dst_ip, job.dst_port, nullptr, job.n);
+      int64_t t1 = monotonic_ns();
+      lk.lock();
+      done.push_back(EgressDone{job.id, sent, t0, t1});
+      busy = false;
+      room.notify_all();
+    }
+  }
+
+  bool idle() const { return queue.empty() && !busy; }
+};
+
+std::mutex g_egress_mu;
+std::unordered_map<int, EgressWorker *> g_egress;  // fd -> its worker
+// sockets with a worker: the synchronous send looks no table up while
+// this is zero (every engine that never sent asynchronously)
+std::atomic<int> g_egress_count{0};
+
+EgressWorker *egress_of(int fd) {
+  if (g_egress_count.load(std::memory_order_acquire) == 0) return nullptr;
+  std::lock_guard<std::mutex> g(g_egress_mu);
+  auto it = g_egress.find(fd);
+  return it == g_egress.end() ? nullptr : it->second;
+}
+
+// the order barrier of the synchronous calls, and udp_send_flush
+void egress_wait_idle(EgressWorker *w) {
+  std::unique_lock<std::mutex> lk(w->mu);
+  w->room.wait(lk, [w] { return w->idle(); });
+}
+
+}  // namespace
 
 extern "C" {
 
@@ -49,7 +210,31 @@ int udp_create(const char *bind_ip, uint16_t port, int reuseport,
   return fd;
 }
 
-int udp_close(int fd) { return close(fd); }
+// Close the socket.  Where it has an egress worker: everything handed
+// over is sent first, then the worker is joined (completions nobody
+// reaped go with it).
+int udp_close(int fd) {
+  EgressWorker *w = nullptr;
+  if (g_egress_count.load(std::memory_order_acquire) != 0) {
+    std::lock_guard<std::mutex> g(g_egress_mu);
+    auto it = g_egress.find(fd);
+    if (it != g_egress.end()) {
+      w = it->second;
+      g_egress.erase(it);
+      g_egress_count.fetch_sub(1, std::memory_order_release);
+    }
+  }
+  if (w) {
+    {
+      std::lock_guard<std::mutex> g(w->mu);
+      w->stop = true;
+    }
+    w->work.notify_all();
+    w->thread.join();
+    delete w;
+  }
+  return close(fd);
+}
 
 // Enable kernel receive timestamps (SO_TIMESTAMPNS).  The BWE
 // inter-arrival filters (GCC) react to sub-millisecond queueing-delay
@@ -195,39 +380,10 @@ int udp_send_batch_idx(int fd, const uint8_t *buf, int capacity,
                        const int32_t *lengths, const uint32_t *dst_ip,
                        const uint16_t *dst_port, const int32_t *idx,
                        int n) {
-  thread_local std::vector<mmsghdr> hdrs;
-  thread_local std::vector<iovec> iovs;
-  thread_local std::vector<sockaddr_in> addrs;
-  if (static_cast<int>(hdrs.size()) < n) {
-    hdrs.resize(n);
-    iovs.resize(n);
-    addrs.resize(n);
-  }
-  for (int i = 0; i < n; i++) {
-    int row = idx ? idx[i] : i;
-    iovs[i].iov_base = const_cast<uint8_t *>(buf) +
-                       static_cast<size_t>(row) * capacity;
-    iovs[i].iov_len = lengths[i];
-    addrs[i] = sockaddr_in{};
-    addrs[i].sin_family = AF_INET;
-    addrs[i].sin_port = htons(dst_port[i]);
-    addrs[i].sin_addr.s_addr = htonl(dst_ip[i]);
-    std::memset(&hdrs[i], 0, sizeof(mmsghdr));
-    hdrs[i].msg_hdr.msg_iov = &iovs[i];
-    hdrs[i].msg_hdr.msg_iovlen = 1;
-    hdrs[i].msg_hdr.msg_name = &addrs[i];
-    hdrs[i].msg_hdr.msg_namelen = sizeof(sockaddr_in);
-  }
-  int sent = 0;
-  while (sent < n) {
-    int r = sendmmsg(fd, hdrs.data() + sent, n - sent, 0);
-    if (r < 0) {
-      if (errno == EAGAIN || errno == EWOULDBLOCK) break;
-      return -errno;
-    }
-    sent += r;
-  }
-  return sent;
+  // behind whatever was handed to the socket's worker, inline when
+  // nothing was (and always where the socket has no worker)
+  if (EgressWorker *w = egress_of(fd)) egress_wait_idle(w);
+  return send_burst(fd, buf, capacity, lengths, dst_ip, dst_port, idx, n);
 }
 
 // Batched send via sendmmsg from the same row-major layout.
@@ -237,6 +393,77 @@ int udp_send_batch(int fd, const uint8_t *buf, int capacity,
                    const uint16_t *dst_port, int n) {
   return udp_send_batch_idx(fd, buf, capacity, lengths, dst_ip, dst_port,
                             nullptr, n);
+}
+
+// Hand rows 0..n-1 to the socket's egress worker and return at once:
+// a job id > 0, or -errno (the worker could not be started).  Waits
+// only while kEgressQueueJobs jobs are already queued.  `behind` (may
+// be nullptr) is set to 1 when a job handed over earlier had not
+// completed yet, else 0.  The arrays must stay alive and unwritten
+// until the job's completion is reaped.
+int64_t udp_send_async(int fd, const uint8_t *buf, int capacity,
+                       const int32_t *lengths, const uint32_t *dst_ip,
+                       const uint16_t *dst_port, int n, int *behind) {
+  EgressWorker *w = egress_of(fd);
+  if (!w) {
+    std::lock_guard<std::mutex> g(g_egress_mu);
+    auto it = g_egress.find(fd);
+    if (it != g_egress.end()) {
+      w = it->second;
+    } else {
+      w = new (std::nothrow) EgressWorker();
+      if (!w) return -ENOMEM;
+      w->fd = fd;
+      try {
+        w->thread = std::thread(&EgressWorker::run, w);
+      } catch (const std::system_error &e) {
+        delete w;
+        return -(e.code().value() > 0 ? e.code().value() : EAGAIN);
+      }
+      g_egress[fd] = w;
+      g_egress_count.fetch_add(1, std::memory_order_release);
+    }
+  }
+  std::unique_lock<std::mutex> lk(w->mu);
+  if (behind) *behind = w->idle() ? 0 : 1;
+  w->room.wait(lk, [w] {
+    return static_cast<int>(w->queue.size()) < kEgressQueueJobs;
+  });
+  int64_t id = w->next_id++;
+  w->queue.push_back(EgressJob{id, buf, capacity, lengths, dst_ip,
+                               dst_port, n});
+  lk.unlock();
+  w->work.notify_one();
+  return id;
+}
+
+// Completions so far, oldest first, at most `max`; never blocks.
+// Writes job id, `sent` or -errno, and the CLOCK_MONOTONIC ns at which
+// the worker began and ended the job's sendmmsg loop.  Each completion
+// is returned once.  Returns how many were written.
+int udp_send_reap(int fd, int64_t *ids, int32_t *sent, int64_t *t0_ns,
+                  int64_t *t1_ns, int max) {
+  EgressWorker *w = egress_of(fd);
+  if (!w) return 0;
+  std::lock_guard<std::mutex> g(w->mu);
+  int k = 0;
+  while (k < max && !w->done.empty()) {
+    const EgressDone &d = w->done.front();
+    ids[k] = d.id;
+    sent[k] = d.sent;
+    t0_ns[k] = d.t0_ns;
+    t1_ns[k] = d.t1_ns;
+    w->done.pop_front();
+    k++;
+  }
+  return k;
+}
+
+// Wait until everything handed over has been sent (completions stay
+// to be reaped).  Returns 0.
+int udp_send_flush(int fd) {
+  if (EgressWorker *w = egress_of(fd)) egress_wait_idle(w);
+  return 0;
 }
 
 }  // extern "C"
@@ -264,7 +491,8 @@ int udp_send_batch(int fd, const uint8_t *buf, int capacity,
 // Delivery is CONTIGUOUS-PREFIX: completions can land out of row order
 // (rarely, under load), so a drain hands back only the completed prefix
 // [delivered, first-hole) and later calls pick up the rest.  Egress
-// multiplexes SENDMSG SQEs on the same CQ, tagged in user_data.
+// is not the ring's: every send is a sendmmsg (above), the fan-out's
+// on the socket's egress worker.
 //
 // Built only when the kernel UAPI header is present; otherwise every
 // entry point is an ENOSYS stub so one .so serves both worlds and the
@@ -274,7 +502,6 @@ int udp_send_batch(int fd, const uint8_t *buf, int capacity,
 
 #include <linux/io_uring.h>
 #include <linux/time_types.h>
-#include <new>
 #include <sys/mman.h>
 #include <sys/syscall.h>
 
@@ -296,7 +523,7 @@ int sys_uring_enter(int fd, unsigned to_submit, unsigned min_complete,
                                   min_complete, flags, arg, argsz));
 }
 
-constexpr uint64_t kSendTag = 1ULL << 62;  // user_data: send vs recv row
+constexpr uint64_t kCancelTag = 1ULL << 62;  // user_data: not a recv row
 
 struct UringEngine {
   int sock_fd = -1;
@@ -459,9 +686,9 @@ void arm_rows(UringEngine *u) {
 // drain the CQ ring-side (no syscall).  Recv completions mark their
 // row done and stash metadata into the arena arrays; failed recvs
 // (e.g. ECONNREFUSED surfacing a prior send's ICMP error) re-arm the
-// row.  Send completions (kSendTag) bump *send_done.  Returns number
+// row.  The teardown's cancel op (kCancelTag) is no row.  Returns number
 // of completions consumed.
-int reap(UringEngine *u, int *send_done, int *send_errs) {
+int reap(UringEngine *u) {
   int n = 0;
   int64_t fallback = 0;
   unsigned head = *u->cq_head;
@@ -473,10 +700,7 @@ int reap(UringEngine *u, int *send_done, int *send_errs) {
     int res = cqe->res;
     head++;
     n++;
-    if (ud & kSendTag) {
-      if (send_done) (*send_done)++;
-      if (res < 0 && send_errs) (*send_errs)++;
-    } else {
+    if (!(ud & kCancelTag)) {
       int row = static_cast<int>(ud);
       u->inflight--;
       if (res < 0) {
@@ -617,7 +841,7 @@ int udp_uring_arm(void *h, uint8_t *buf, int rows, int capacity,
                   int64_t *arrival_ns) {
   auto *u = static_cast<UringEngine *>(h);
   if (!u || rows <= 0) return -EINVAL;
-  reap(u, nullptr, nullptr);
+  reap(u);
   if (u->inflight > 0) return -EBUSY;
   if (static_cast<unsigned>(rows) > u->sq_entries) rows = u->sq_entries;
   u->buf = buf;
@@ -651,13 +875,13 @@ int udp_uring_recv(void *h, int max_pkts, int timeout_ms,
   auto *u = static_cast<UringEngine *>(h);
   if (!u || !u->buf) return -EINVAL;
   if (u->delivered >= u->rows) return URING_ARENA_EXHAUSTED;
-  reap(u, nullptr, nullptr);
+  reap(u);
   arm_rows(u);
   if (u->sq_pending) uring_submit(u, false, 0);
   if (!u->completed[u->delivered] && timeout_ms > 0) {
     int r = uring_submit(u, true, timeout_ms);
     if (r < 0) return r;
-    reap(u, nullptr, nullptr);
+    reap(u);
   }
   int lo = u->delivered;
   int hi = lo;
@@ -667,75 +891,6 @@ int udp_uring_recv(void *h, int max_pkts, int timeout_ms,
   u->delivered = hi;
   *start_row = lo;
   return hi - lo;
-}
-
-// Row-indexed gather send, ring edition: one SENDMSG SQE per packet
-// submitted in SQ-sized chunks, waiting each chunk's completions so
-// the per-op msghdr slots can be reused.  Same contract as
-// udp_send_batch_idx.  Returns packets sent or -errno.
-int udp_uring_send_idx(void *h, const uint8_t *buf, int capacity,
-                       const int32_t *lengths, const uint32_t *dst_ip,
-                       const uint16_t *dst_port, const int32_t *idx,
-                       int n) {
-  auto *u = static_cast<UringEngine *>(h);
-  if (!u) return -EINVAL;
-  thread_local std::vector<msghdr> smh;
-  thread_local std::vector<iovec> siov;
-  thread_local std::vector<sockaddr_in> saddr;
-  int done = 0;
-  int errs = 0;
-  int sent_at = 0;
-  while (sent_at < n) {
-    reap(u, &done, &errs);
-    unsigned room = sq_room(u);
-    if (room == 0) {
-      int r = uring_submit(u, true, -1);
-      if (r < 0) return r;
-      continue;
-    }
-    int chunk = n - sent_at < static_cast<int>(room)
-                    ? n - sent_at
-                    : static_cast<int>(room);
-    if (static_cast<int>(smh.size()) < chunk) {
-      smh.resize(chunk);
-      siov.resize(chunk);
-      saddr.resize(chunk);
-    }
-    for (int i = 0; i < chunk; i++) {
-      int k = sent_at + i;
-      int row = idx ? idx[k] : k;
-      siov[i].iov_base = const_cast<uint8_t *>(buf) +
-                         static_cast<size_t>(row) * capacity;
-      siov[i].iov_len = lengths[k];
-      saddr[i] = sockaddr_in{};
-      saddr[i].sin_family = AF_INET;
-      saddr[i].sin_port = htons(dst_port[k]);
-      saddr[i].sin_addr.s_addr = htonl(dst_ip[k]);
-      std::memset(&smh[i], 0, sizeof(msghdr));
-      smh[i].msg_iov = &siov[i];
-      smh[i].msg_iovlen = 1;
-      smh[i].msg_name = &saddr[i];
-      smh[i].msg_namelen = sizeof(sockaddr_in);
-      io_uring_sqe *sqe = stage_sqe(u);
-      sqe->opcode = IORING_OP_SENDMSG;
-      sqe->fd = u->sock_fd;
-      sqe->addr = reinterpret_cast<uint64_t>(&smh[i]);
-      sqe->user_data = kSendTag | static_cast<uint64_t>(k);
-    }
-    int target = done + chunk;
-    int r = uring_submit(u, false, 0);
-    if (r < 0) return r;
-    // the chunk's msghdr slots are reused next iteration: wait for
-    // every completion of THIS chunk before building the next
-    while (done < target) {
-      reap(u, &done, &errs);
-      if (done >= target) break;
-      r = uring_submit(u, true, -1);
-      if (r < 0) return r;
-    }
-    sent_at += chunk;
-  }
-  return n - errs;
 }
 
 // Telemetry: 0 = io_uring_enter syscalls, 1 = completions reaped
@@ -766,13 +921,13 @@ void udp_uring_destroy(void *h) {
     io_uring_sqe *sqe = stage_sqe(u);
     sqe->opcode = IORING_OP_ASYNC_CANCEL;
     sqe->cancel_flags = IORING_ASYNC_CANCEL_ANY;
-    sqe->user_data = kSendTag | 1;
+    sqe->user_data = kCancelTag | 1;
     uring_submit(u, false, 0);
     for (int i = 0; i < 64 && u->inflight > 0; i++) {
-      reap(u, nullptr, nullptr);
+      reap(u);
       if (u->inflight > 0 && uring_submit(u, true, 50) < 0) break;
     }
-    reap(u, nullptr, nullptr);
+    reap(u);
     if (u->inflight > 0) {
       close(u->ring_fd);  // leak u: kernel may still reference mh[]
       return;
@@ -805,12 +960,6 @@ int udp_uring_arm(void *, uint8_t *, int, int, int32_t *, uint32_t *,
 }
 
 int udp_uring_recv(void *, int, int, int32_t *) { return -ENOSYS; }
-
-int udp_uring_send_idx(void *, const uint8_t *, int, const int32_t *,
-                       const uint32_t *, const uint16_t *, const int32_t *,
-                       int) {
-  return -ENOSYS;
-}
 
 long udp_uring_stat(void *, int) { return -ENOSYS; }
 

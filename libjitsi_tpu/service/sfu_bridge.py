@@ -18,6 +18,7 @@ forwards to everyone else).
 
 from __future__ import annotations
 
+import os
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -233,7 +234,13 @@ class SfuBridge:
         # media queues on the hold mask, and the route mesh excludes
         # them until commit_endpoints flips them live between ticks
         self._staged: set = set()
+        #: datagrams the fan-out has SENT: counted when a burst's
+        #: completion is reaped (the tick after its hand-over), so it
+        #: trails the hand-overs until `flush_egress()`
         self.forwarded = 0
+        # fan-out bursts handed to the engine's egress worker and not
+        # reaped yet: job id -> (journey origin, receiver sids)
+        self._egress_jobs: Dict[int, tuple] = {}
         self.retransmitted = 0
         # overload degradation (set by BridgeSupervisor): suppress the
         # RTCP feedback fan-out while media forwarding keeps flowing
@@ -1040,15 +1047,20 @@ class SfuBridge:
                                          now=self._now)
             sp.note(slabs=cache.slabs, live_rows=len(cache),
                     evicted_rows=evicted, copied=int(copied))
+        # the hand-over: the sendmmsg runs on the engine's egress
+        # worker while the tick goes on.  The plane is final (the cache
+        # above keeps the same one) and the addresses are copies, which
+        # is what lets this call site, and no other of the file, send
+        # asynchronously.  `_reap_egress` books the outcome next tick
         with self.loop.tracer.span(
                 "egress", rows=wire.batch_size,
-                bytes=int(np.asarray(wire.length).sum())):
-            sent = self.loop.engine.send_batch(
+                bytes=int(np.asarray(wire.length).sum())) as sp:
+            job = self.loop.engine.send_batch_async(
                 wire, self.loop.addr_ip[recv], self.loop.addr_port[recv])
-            self.loop.note_journey_at(
-                origin if origin is not None
-                else self.loop.journey_origin(), sent, sids=recv)
-        self.forwarded += sent
+            sp.note(queued=1, behind=int(job.behind))
+        self._egress_jobs[job.id] = (
+            origin if origin is not None else self.loop.journey_origin(),
+            recv)
         # adaptive FEC over the PROTECTED per-leg copies: XOR of SRTP
         # ciphertexts is opaque, and a recovered packet still passes the
         # receiver's normal SRTP auth — FEC adds redundancy, never an
@@ -1222,9 +1234,39 @@ class SfuBridge:
                 self.loop.addr_port[arow])
         return sent
 
+    def _reap_egress(self) -> None:
+        """Book the fan-out bursts the egress worker has completed:
+        `forwarded`, the journey (measured to the worker's END stamp,
+        not to now) and the send's own duration as `egress_send`.  A
+        failed send raises here as the synchronous call raised in its
+        own tick; a short one counts what was sent.  Never waits."""
+        failed = None
+        for done in self.loop.engine.reap():
+            origin, recv = self._egress_jobs.pop(done.id)
+            if done.sent < 0:
+                failed = failed or done
+                continue
+            self.forwarded += done.sent
+            self.loop.note_journey_at(origin, done.sent, sids=recv,
+                                      at=done.t1)
+            self.loop.tracer.book("egress_send", done.t1 - done.t0,
+                                  rows=done.sent)
+        if failed is not None:
+            raise OSError(-failed.sent, os.strerror(-failed.sent))
+
+    def flush_egress(self) -> None:
+        """Wait until every fan-out burst handed over has left, and
+        book it.  For shutdown and for tests that read a client socket
+        right after a tick; the tick itself never calls it (that would
+        be the synchronous send again)."""
+        self.loop.engine.flush()
+        self._reap_egress()
+
     def tick(self, now: Optional[float] = None) -> dict:
         self._now = time.time() if now is None else now
         self._media_ran = False
+        if self._egress_jobs:
+            self._reap_egress()
         rx = self.loop.tick()
         if self._pending_fanout and not self._media_ran:
             # no media drove _on_media this tick: flush here instead
@@ -1355,7 +1397,10 @@ class SfuBridge:
         return bridge
 
     def close(self) -> None:
-        if self._pending_fanout:
-            self._flush_fanout()     # the last tick's media still ships
-        for eng in self.loop.rings:
-            eng.close()
+        try:
+            if self._pending_fanout:
+                self._flush_fanout()  # the last tick's media still ships
+            self.flush_egress()
+        finally:
+            for eng in self.loop.rings:
+                eng.close()

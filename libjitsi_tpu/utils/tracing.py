@@ -67,10 +67,19 @@ CONTAINER_STAGES = ("reverse_chain", "unprotect", "unprotect_wait",
                     "unprotect_dispatch", "forward_chain",
                     "fanout_dispatch")
 
+#: stages that are NOT the tick thread's time: booked with
+#: `PipelineTracer.book` from a duration measured elsewhere.
+#: `egress_send` is the egress worker's `sendmmsg` of one fan-out burst
+#: (io/udp.py:send_batch_async), booked by the tick that reaps it; the
+#: tick's own `egress` leaf is the hand-over alone.  In no leaf sum and
+#: not in the self ledger the overload ladder steers on
+OFF_TICK_STAGES = ("egress_send",)
+
 #: canonical stage names (a tracer accepts any string; these are the
 #: ones the dashboards are generated from): the leaves, the containers
-#: round them and the mixer bridge's stages
-STAGES = LEAF_STAGES + CONTAINER_STAGES + ("decode", "mixer")
+#: round them, what runs beside the tick and the mixer bridge's stages
+STAGES = (LEAF_STAGES + CONTAINER_STAGES + OFF_TICK_STAGES
+          + ("decode", "mixer"))
 
 
 class _NullSpan:
@@ -204,6 +213,21 @@ class PipelineTracer:
 
     def span(self, stage: str, **counts) -> _StageSpan:
         return _StageSpan(self, stage, counts)
+
+    def book(self, stage: str, seconds: float, **counts) -> None:
+        """Book a duration that was measured elsewhere (a worker
+        thread's, by its own stamps) under `stage` in the tick under
+        way: the stage's ring, the inclusive ledger and the counts.
+        It opens no span and has no parent, so it enters neither the
+        self ledger (it is not the tick thread's time: the ladder must
+        not steer on it) nor the profiler's trace."""
+        self._sink(stage)[0].record(seconds)
+        led = self._ledger
+        led[stage] = led.get(stage, 0.0) + seconds
+        if counts:
+            mine = self._counts.setdefault(stage, {})
+            for k, v in counts.items():
+                mine[k] = mine.get(k, 0) + v
 
     def tick_root(self, tick: int, **counts):
         """Root of one tick's tree: an annotation `<prefix>:tick` with

@@ -267,6 +267,7 @@ def run_soak(duration_s: float = 30.0, ramp_s: float = 6.0,
         for p in plist:
             p.send_media(1)
         sup.tick(now=now)
+        bridge.flush_egress()    # the fan-out leaves on a worker
         now += dt
         for p in plist:
             p.drain(0.0)
@@ -297,6 +298,7 @@ def run_soak(duration_s: float = 30.0, ramp_s: float = 6.0,
                 if speaking[i]:
                     p.send_media(2)
         sup.tick(now=now)
+        bridge.flush_egress()
         for p in plist:
             p.drain(drop_rate)
         if t % 2 == 1:
@@ -355,6 +357,7 @@ def run_soak(duration_s: float = 30.0, ramp_s: float = 6.0,
                     lc.request_leave(ssrc=ssrc)
                     alive.remove(ssrc)
         sup.tick(now=now)
+        bridge.flush_egress()
         for p in plist:
             p.drain(0.0 if in_settle else drop_rate)
         if t % 2 == 1:
@@ -880,6 +883,7 @@ def run_reconnect_soak(n_clients: int = 1000, dt: float = 0.02,
     def _tick1():
         nonlocal now
         sup.tick(now=now)
+        bridge.flush_egress()
         now += dt
 
     prime_got = _dtls_echo(clients[0], clients[1], _tick1, seq0=3000)
@@ -992,6 +996,7 @@ def run_reconnect_soak(n_clients: int = 1000, dt: float = 0.02,
     def _tick2():
         nonlocal now
         sup2.tick(now=now)
+        sup2.bridge.flush_egress()
         now += dt
 
     all_live = all(c.state == "live" for c in clients)
@@ -1165,12 +1170,15 @@ def run_cascade_soak(dt: float = 0.01, n_senders: int = 3,
             supA.tick(now=now)
             supB.tick(now=now)
             now += dt
+        bA.flush_egress()
+        bB.flush_egress()
 
     def tick_b(k=1):
         nonlocal now
         for _ in range(k):
             supB.tick(now=now)
             now += dt
+        bB.flush_egress()
 
     senders, receivers = [], []
     for k in range(n_senders):

@@ -66,6 +66,58 @@ def warmed_launch_guard(monkeypatch):
     return guard
 
 
+class EgressTap:
+    """Sits on one engine's `send_batch_async`: records every burst as
+    it is handed over (`handed`: (destination port, datagram) in row
+    order, which is the order one `sendmmsg` sends them in) and, while
+    `synchronous` is set, sends it with the synchronous `send_batch`
+    instead of the egress worker, behind the same hand-over / reap
+    interface: the fan-out as it was before the worker, to compare
+    with."""
+
+    def __init__(self, engine):
+        self.engine = engine
+        self.synchronous = False
+        self.handed = []
+        self.reaped = []            # every completion `reap` returned
+        self._async, self._reap = engine.send_batch_async, engine.reap
+        self._done = []
+        engine.send_batch_async, engine.reap = self._send, self._reap_all
+
+    def _send(self, batch, dst_ip, dst_port):
+        import time
+
+        import numpy as np
+
+        from libjitsi_tpu.io.udp import SendDone, SendJob
+
+        n = batch.batch_size
+        ports = np.broadcast_to(np.asarray(dst_port), (n,))
+        self.handed += [(int(ports[i]), batch.to_bytes(i))
+                        for i in range(n)]
+        if not self.synchronous:
+            return self._async(batch, dst_ip, dst_port)
+        t0 = time.perf_counter()
+        sent = self.engine.send_batch(batch, dst_ip, dst_port)
+        job = SendJob(-1 - len(self.reaped) - len(self._done), n, False)
+        self._done.append(SendDone(job.id, sent, t0, time.perf_counter()))
+        return job
+
+    def _reap_all(self):
+        done, self._done = self._reap() + self._done, []
+        self.reaped += done
+        return done
+
+    def undo(self):
+        del self.engine.send_batch_async, self.engine.reap
+
+
+@pytest.fixture(scope="session")
+def egress_tap():
+    """`egress_tap(bridge) -> EgressTap` on the bridge's engine."""
+    return lambda bridge: EgressTap(bridge.loop.engine)
+
+
 @pytest.fixture
 def sfu_with_traffic():
     """`(sfu, sup, send)`: an SfuBridge of three keyed endpoints behind
@@ -115,6 +167,10 @@ def sfu_with_traffic():
             time.sleep(0.01)
             before = sfu.forwarded
             sup.tick(now=50.0)
+            # `forwarded` counts at the reap: have the tick's own burst
+            # out and booked, so that the tick that fanned out is the
+            # one the caller reads the ledgers of
+            sfu.flush_egress()
             if sfu.forwarded > before:
                 return
         raise AssertionError("the bridge never forwarded")

@@ -69,7 +69,7 @@ def _pair(raw) -> tuple:
 
 
 @pytest.fixture(scope="module")
-def served(oracle):
+def served(oracle, egress_tap):
     from libjitsi_tpu.service import lifecycle as lifecycle_mod
     from libjitsi_tpu.service import supervisor as supervisor_mod
     from libjitsi_tpu.service.sfu_bridge import SfuBridge
@@ -94,6 +94,7 @@ def served(oracle):
                                              max_pending=512),
         metrics=reg)
     lc.enable_placement(1)
+    tap = egress_tap(bridge)
     keys = _keys(31, ROWS)
     now = [1000.0]
 
@@ -123,6 +124,9 @@ def served(oracle):
                     (sup.last_counts["unprotect_wait"].get("grouped"),
                      sup.last_counts.get("fanout_dispatch", {}).get(
                          "grouped")))
+        # the fan-out's datagrams leave on the engine's egress worker:
+        # have them out before a caller reads a client socket
+        bridge.flush_egress()
     try:
         for i in range(ROWS):
             ok, why = lc.request_join(SSRC_BASE + i, _pair(keys[i, 0]),
@@ -170,11 +174,17 @@ def served(oracle):
         drain([])
         for k in list(rec["sent"]):
             del rec["sent"][k]
+        tap.handed.clear()
+        forwarded0 = bridge.forwarded
         events0 = compile_stats().compile_events
         wires = {}
         # 20 served rounds: a seeded set of senders, one or two packets
         # each, so that rows, width and grid change from tick to tick
         for _round in range(20):
+            # odd rounds send the fan-out with the synchronous call
+            # (the path before the egress worker), even ones hand it
+            # to the worker: the tests below judge both alike
+            tap.synchronous = bool(_round % 2)
             n_tx = int(rng.integers(3, 28))
             for i in rng.choice(ROWS, n_tx, replace=False):
                 for _ in range(int(rng.integers(1, 3))):
@@ -183,6 +193,11 @@ def served(oracle):
                     send(int(i), w)
             tick(2)
             drain(rec["got"])
+        tap.synchronous = False
+        rec["forwarded"] = bridge.forwarded - forwarded0
+        rec["handed"] = list(tap.handed)
+        rec["ports"] = [s.getsockname()[1] for s in socks]
+        rec["job_ids"] = [d.id for d in tap.reaped]
         # replay: packets the bridge has already forwarded, sent again
         again = []
         for (i, s), w in list(wires.items())[:12]:
@@ -245,6 +260,21 @@ def test_served_deliveries_stay_in_their_conference(served):
         tx = ssrc - SSRC_BASE
         conf = range(tx // CONF * CONF, (tx // CONF + 1) * CONF)
         assert sorted(rs) == [r for r in conf if r != tx]
+
+
+def test_worker_delivers_in_order_what_the_synchronous_call_delivers(
+        served):
+    """Half the served rounds handed their fan-out to the egress
+    worker, half sent it with the synchronous call: under both every
+    receiver got exactly the datagrams handed over for its port, in
+    hand-over order, and `forwarded` counts them all."""
+    ids = served["job_ids"]
+    assert sum(j > 0 for j in ids) >= 10 and sum(j < 0 for j in ids) >= 10
+    assert served["forwarded"] == len(served["handed"]) \
+        == len(served["got"])
+    for r, port in enumerate(served["ports"]):
+        assert [p for rr, p in served["got"] if rr == r] == \
+            [p for to, p in served["handed"] if to == port]
 
 
 def test_served_replay_is_not_forwarded(served):

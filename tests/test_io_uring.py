@@ -276,26 +276,6 @@ def test_uring_steady_state_recv_is_zero_syscall():
 
 
 @ring_only
-def test_uring_gather_egress_roundtrip(monkeypatch):
-    """Linked-SQE gather egress (opt-in via LIBJITSI_TPU_URING_EGRESS)
-    delivers the same bytes sendmmsg would."""
-    monkeypatch.setenv("LIBJITSI_TPU_URING_EGRESS", "1")
-    tx = UdpEngine(port=0, engine_mode="io_uring")
-    rx = UdpEngine(port=0, max_batch=16)
-    try:
-        assert tx.uring_egress
-        sent = [bytes([0x70 + i]) * (25 + i) for i in range(6)]
-        _send(tx, rx, sent)
-        got, toks = _drain_views(rx, len(sent))
-        assert got == sent
-        for t in toks:
-            rx.release_arena(t)
-    finally:
-        tx.close()
-        rx.close()
-
-
-@ring_only
 def test_uring_arena_exhaustion_rearms_across_boundary():
     """Delivering more packets than one arena holds forces the
     EXHAUSTED -> re-arm path; nothing is lost at the boundary and the

@@ -483,6 +483,7 @@ def _run_recovery_e2e(rounds, per_round, seed=7):
 
     def drain(now):
         nonlocal dropped
+        sfu.flush_egress()     # the fan-out leaves on the egress worker
         for _ in range(6):
             for ssrc, seq, is_rtcp, pkt in recv.recv_wire():
                 if is_rtcp:

@@ -91,8 +91,10 @@ def _rounds(rng):
     return out
 
 
-def _serve(oracle, mesh, ladder: bool) -> dict:
-    """Admit ROWS endpoints and drive the seeded rounds; the record."""
+def _serve(oracle, mesh, ladder: bool, tap_of, synchronous: bool) -> dict:
+    """Admit ROWS endpoints and drive the seeded rounds; the record.
+    `synchronous`: the fan-out through the synchronous `send_batch`
+    (conftest's `EgressTap`) instead of the engine's egress worker."""
     from libjitsi_tpu.service import lifecycle as lifecycle_mod
     from libjitsi_tpu.service import supervisor as supervisor_mod
     from libjitsi_tpu.service.sfu_bridge import SfuBridge
@@ -119,6 +121,8 @@ def _serve(oracle, mesh, ladder: bool) -> dict:
         # the twin is judged on its bytes alone: it compiles as it goes
         lc._warm_bucket = 1 << 30
     lc.enable_placement(SHARDS)
+    tap = tap_of(bridge)
+    tap.synchronous = synchronous
     keys = _keys(41, ROWS)
     now = [1000.0]
     rec = {"sent": {}, "got": [], "counts": [], "lanes": {
@@ -136,6 +140,9 @@ def _serve(oracle, mesh, ladder: bool) -> dict:
                     lanes = counts.get(stage, {}).get("lanes")
                     if lanes is not None:
                         rec["lanes"][stage].add(int(lanes))
+        # the fan-out's datagrams leave on the engine's egress worker:
+        # have them out before a caller reads a client socket
+        bridge.flush_egress()
 
     socks = []
     try:
@@ -182,6 +189,8 @@ def _serve(oracle, mesh, ladder: bool) -> dict:
         drain([])
         rec["sent"].clear()
         rec["counts"].clear()
+        tap.handed.clear()
+        forwarded0 = bridge.forwarded
         for lanes in rec["lanes"].values():
             lanes.clear()
         events0 = compile_stats().compile_events
@@ -200,6 +209,10 @@ def _serve(oracle, mesh, ladder: bool) -> dict:
         rec["classes"] = (type(bridge.rx_table), type(bridge.translator))
         rec["placements"] = (bridge.rx_table.placements
                              if mesh is not None else None)
+        rec["forwarded"] = bridge.forwarded - forwarded0
+        rec["handed"] = tap.handed
+        rec["ports"] = [s.getsockname()[1] for s in socks]
+        rec["job_ids"] = [d.id for d in tap.reaped]
         return rec
     finally:
         for s in socks:
@@ -208,10 +221,12 @@ def _serve(oracle, mesh, ladder: bool) -> dict:
 
 
 @pytest.fixture(scope="module")
-def served(oracle):
+def served(oracle, egress_tap):
+    # the mesh bridge sends through the egress worker, its one-chip twin
+    # through the synchronous call: their egress is compared below
     mesh = make_media_mesh(__import__("jax").devices()[:SHARDS])
-    return {"mesh": _serve(oracle, mesh, ladder=True),
-            "one": _serve(oracle, None, ladder=False)}
+    return {"mesh": _serve(oracle, mesh, True, egress_tap, False),
+            "one": _serve(oracle, None, False, egress_tap, True)}
 
 
 def _by_key(rec):
@@ -253,6 +268,25 @@ def test_egress_is_byte_equal_to_the_one_chip_bridge(served):
     mesh, one = _by_key(served["mesh"]), _by_key(served["one"])
     assert mesh.keys() == one.keys() and len(mesh) > 1000
     assert all(mesh[k] == one[k] for k in mesh)
+
+
+def test_worker_delivers_in_order_what_the_synchronous_call_delivers(
+        served):
+    """The mesh bridge hands its fan-out to the egress worker, the
+    one-chip twin sends it with the synchronous call: every receiver
+    gets the same datagrams in the same order from both, and from each
+    exactly what was handed over for its port, burst after burst."""
+    mesh, one = served["mesh"], served["one"]
+    assert mesh["job_ids"] and all(j > 0 for j in mesh["job_ids"])
+    assert one["job_ids"] and all(j < 0 for j in one["job_ids"])
+    for rec in (mesh, one):
+        assert rec["forwarded"] == len(rec["handed"]) == len(rec["got"])
+        for r, port in enumerate(rec["ports"]):
+            assert [p for rr, p in rec["got"] if rr == r] == \
+                [p for to, p in rec["handed"] if to == port]
+    for r in range(ROWS):
+        assert [p for rr, p in mesh["got"] if rr == r] == \
+            [p for rr, p in one["got"] if rr == r]
 
 
 def test_no_conference_straddles_a_shard(served):

@@ -483,6 +483,7 @@ def test_sfu_warm_tick_is_two_launches_one_array_each_way(
     before = sfu.forwarded
     with warmed_launch_guard():
         sup.tick(now=50.0)
+    sfu.flush_egress()               # `forwarded` counts at the reap
     assert sfu.forwarded == before + 6
     counts = sup.last_counts
     up, down = counts["unprotect_wait"], counts["fanout_d2h"]

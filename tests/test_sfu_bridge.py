@@ -90,6 +90,7 @@ def test_sfu_fanout_and_nack_over_udp():
             e.send_media()
         for _ in range(20):
             sfu.tick(now=50.0 + rnd * 0.02)
+        sfu.flush_egress()
         for e in eps:
             for _ in range(4):
                 e.drain()
@@ -194,6 +195,7 @@ def test_sfu_bwe_congestion_drives_remb_down_and_back_up():
             for _ in range(10):
                 sfu.tick(now=t)
             sfu.emit_feedback(now=t)
+            sfu.flush_egress()
             got = sender.drain_rembs()
             if got:
                 rembs.append(got[-1])
@@ -272,6 +274,7 @@ def test_sfu_dtls_keyed_endpoint_e2e():
     eng.send_batch(tx.protect_rtp(b), "127.0.0.1", sfu.port)
     for _ in range(20):
         sfu.tick(now=80.1)
+    sfu.flush_egress()
     for _ in range(4):
         recv.drain()
     got = b"".join(recv.got.values())
@@ -475,6 +478,7 @@ def test_sfu_pipelined_fanout_delivers_everything():
             e.send_media()
         for _ in range(24):       # extra ticks: flush rides tick N+1
             sfu.tick(now=70.0 + rnd * 0.02)
+        sfu.flush_egress()
         for e in eps:
             for _ in range(4):
                 e.drain()
@@ -762,6 +766,7 @@ def test_sfu_bridge_snapshot_resume_mid_conference():
             e.send_media()
         for _ in range(16):
             sfu.tick(now=40.0 + rnd * 0.02)
+        sfu.flush_egress()
         for e in eps:
             e.drain()
     assert sfu.forwarded > 0
@@ -781,6 +786,7 @@ def test_sfu_bridge_snapshot_resume_mid_conference():
             e.send_media()              # SRTP counters CONTINUE
         for _ in range(16):
             sfu2.tick(now=41.0 + rnd * 0.02)
+        sfu2.flush_egress()
         for e in eps:
             for _ in range(3):
                 e.drain()
@@ -803,5 +809,173 @@ def test_sfu_bridge_snapshot_resume_mid_conference():
                              sfu2.port)
     for _ in range(10):
         sfu2.tick(now=41.2)
+    sfu2.flush_egress()
     assert sfu2.forwarded == rx_before, "replayed old seq re-forwarded"
     sfu2.close()
+
+
+# ------------------------------------------------- the egress worker
+
+def _served_rounds(egress_tap, synchronous, pipelined, rounds=4):
+    """Three endpoints, `rounds` rounds of four packets each: what
+    every endpoint's socket received, raw and in arrival order, with
+    the fan-out sent by the egress worker or (`synchronous`) by the
+    synchronous call it replaced."""
+    libjitsi_tpu.stop()
+    libjitsi_tpu.init()
+    sfu = SfuBridge(libjitsi_tpu.configuration_service(), port=0,
+                    capacity=8, recv_window_ms=0, pipelined=pipelined)
+    tap = egress_tap(sfu)
+    tap.synchronous = synchronous
+    eps = [_Endpoint(0x100 + 7 * k, sfu.port) for k in range(3)]
+    try:
+        for e in eps:
+            sfu.add_endpoint(e.ssrc, e.rx_key, e.tx_key)
+        got = {e.ssrc: [] for e in eps}
+        for rnd in range(rounds):
+            for e in eps:
+                e.send_media()
+            for _ in range(3):
+                sfu.tick(now=50.0 + rnd * 0.02)
+            sfu.flush_egress()
+            for e in eps:
+                back, _, _ = e.engine.recv_batch(timeout_ms=0)
+                got[e.ssrc] += [back.to_bytes(i)
+                                for i in range(back.batch_size)]
+        assert not sfu._egress_jobs and not sfu.loop.engine._jobs
+        return {"got": got, "forwarded": sfu.forwarded,
+                "handed": tap.handed, "jobs": [d.id for d in tap.reaped],
+                "ports": {e.ssrc: e.engine.port for e in eps}}
+    finally:
+        sfu.close()
+        for e in eps:
+            e.engine.close()
+
+
+@pytest.mark.parametrize("pipelined", (False, True),
+                         ids=("inline", "pipelined"))
+def test_worker_round_delivers_what_the_synchronous_round_delivers(
+        egress_tap, pipelined):
+    """The same served rounds through the egress worker and through the
+    synchronous `send_batch`: every receiver gets the same datagrams,
+    byte for byte and in the same order, which is the order they were
+    handed over in; `forwarded` after `flush_egress()` is the sends."""
+    worker = _served_rounds(egress_tap, False, pipelined)
+    sync = _served_rounds(egress_tap, True, pipelined)
+    assert worker["jobs"] and all(j > 0 for j in worker["jobs"])
+    assert sync["jobs"] and all(j < 0 for j in sync["jobs"])
+    # rounds after the address latch forward 2 x 4 packets a receiver
+    assert all(len(v) >= 3 * 8 for v in worker["got"].values())
+    assert worker["got"] == sync["got"]
+    for rec in (worker, sync):
+        assert rec["forwarded"] == len(rec["handed"]) \
+            == sum(len(v) for v in rec["got"].values())
+        for ssrc, port in rec["ports"].items():
+            assert rec["got"][ssrc] == [p for to, p in rec["handed"]
+                                        if to == port]
+
+
+def test_journey_is_measured_to_the_workers_end_stamp(sfu_with_traffic,
+                                                      egress_tap):
+    """The journey histogram takes a burst's latency from its arrival
+    to the END of the worker's send, however much later the reap is."""
+    import time
+
+    sfu, sup, send = sfu_with_traffic
+    send.until_forwarded()
+    tap = egress_tap(sfu)
+    hist = sfu.loop.journey_hist
+    send()
+    time.sleep(0.01)
+    sum0, count0 = hist.sum, hist.count
+    sup.tick(now=50.0)
+    ((job, (origin, recv)),) = sfu._egress_jobs.items()
+    assert (hist.sum, hist.count) == (sum0, count0)   # nothing booked yet
+    time.sleep(0.05)                     # the reap comes late ...
+    t_reap = time.perf_counter()
+    sfu.flush_egress()
+    (done,) = tap.reaped
+    assert done.id == job and done.sent == len(recv) == 6
+    # ... and the journey does not grow with it
+    assert hist.count == count0 + 6
+    assert hist.sum - sum0 == pytest.approx(6 * (done.t1 - origin[1]))
+    assert origin[1] < done.t0 <= done.t1 < t_reap - 0.04
+
+
+def test_egress_send_is_booked_by_the_reaping_tick_and_is_no_leaf(
+        sfu_with_traffic):
+    """`egress` is the hand-over (`queued`, `behind`); the send's own
+    time is `egress_send`, booked by the tick that reaps the job: in
+    the inclusive ledger and the counts, in no leaf, not in the self
+    ledger the ladder steers on."""
+    import time
+
+    from libjitsi_tpu.utils import tracing
+
+    sfu, sup, send = sfu_with_traffic
+    send.until_forwarded()
+    sup.tick(now=50.0)               # drains what that flush booked
+    send()
+    time.sleep(0.01)
+    before = sfu.forwarded
+    sup.tick(now=50.0)               # the hand-over
+    led, counts = sup.last_ledger, sup.last_counts
+    assert counts["egress"] == {"rows": 6, "bytes": counts["egress"][
+        "bytes"], "queued": 1, "behind": 0}
+    assert "egress_send" not in led and sfu.forwarded == before
+    assert len(sfu._egress_jobs) == 1
+    sfu.loop.engine.flush()          # sent, not reaped
+    assert sfu.forwarded == before
+    sup.tick(now=50.0)               # an idle tick: the reap
+    led, counts = sup.last_ledger, sup.last_counts
+    assert sfu.forwarded == before + 6 and not sfu._egress_jobs
+    assert 0.0 < led["egress_send"] < 0.05
+    assert counts["egress_send"] == {"rows": 6}
+    assert "egress" not in led       # nothing was handed over here
+    assert "egress_send" not in sup.last_self_ledger
+    assert "egress_send" in tracing.OFF_TICK_STAGES
+    assert "egress_send" not in (tracing.LEAF_STAGES
+                                 + tracing.CONTAINER_STAGES)
+    assert sfu.loop.metrics.timing("stage_egress_send").count >= 1
+
+
+@pytest.mark.parametrize("outcome", ("behind", "short", "failed"))
+def test_reap_books_what_the_worker_reports(sfu_with_traffic, outcome):
+    """`behind` rides on the hand-over's span; a short send counts what
+    was sent and a failed one raises `OSError` out of the tick that
+    reaps it, as the synchronous call raised out of its own."""
+    import errno
+    import time
+
+    from libjitsi_tpu.io.udp import SendJob
+
+    sfu, sup, send = sfu_with_traffic
+    send.until_forwarded()
+    sup.tick(now=50.0)
+    eng = sfu.loop.engine
+    hand, reap = eng.send_batch_async, eng.reap
+    if outcome == "behind":
+        eng.send_batch_async = lambda *a: SendJob(
+            *hand(*a)[:2], behind=True)
+    else:
+        sent = 4 if outcome == "short" else -errno.EINVAL
+        eng.reap = lambda: [d._replace(sent=sent) for d in reap()]
+    try:
+        send()
+        time.sleep(0.01)
+        before = sfu.forwarded
+        sup.tick(now=50.0)
+        assert sup.last_counts["egress"]["behind"] == (
+            1 if outcome == "behind" else 0)
+        if outcome == "failed":
+            with pytest.raises(OSError) as exc:
+                sfu.flush_egress()
+            assert exc.value.errno == errno.EINVAL
+            assert sfu.forwarded == before and not sfu._egress_jobs
+        else:
+            sfu.flush_egress()
+            assert sfu.forwarded == before + (
+                4 if outcome == "short" else 6)
+    finally:
+        vars(eng).pop("reap", None)
+        vars(eng).pop("send_batch_async", None)

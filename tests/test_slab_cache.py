@@ -373,8 +373,12 @@ _SPAN = _Span()
 
 
 class _Engine:
-    def send_batch(self, batch, ip, port):
-        return batch.batch_size
+    """The hand-over alone: nothing is sent and nothing completes."""
+
+    def send_batch_async(self, batch, ip, port):
+        from libjitsi_tpu.io.udp import SendJob
+
+        return SendJob(1, batch.batch_size, False)
 
 
 @pytest.mark.parametrize("rows", (1024,))
@@ -394,6 +398,7 @@ def test_emit_fanout_creates_no_per_row_objects(rows):
         note_journey_at=lambda *a, **k: None)
     me = types.SimpleNamespace(
         loop=loop, cache=SlabCache(), _now=1.0, forwarded=0, flight=None,
+        _egress_jobs={},
         recovery=types.SimpleNamespace(fec_active=lambda: False))
 
     def one():
