@@ -142,7 +142,11 @@ class Reflector(threading.Thread):
 def check_loadgen() -> None:
     config = {"profile": "AES_CM_128_HMAC_SHA1_80", "capacity": 64,
               "conference_sizes": [8]}
-    traffic = {"payload": {"min": 40, "max": 160}, "sample_target": 64}
+    # 3 of a conference's 8 speak; the 5 others reach the reflector
+    # with one packet in the first period and then only receive
+    speakers = 3
+    traffic = {"payload": {"min": 40, "max": 160}, "sample_target": 64,
+               "speakers_per_conference": speakers}
     plan = loadgen.make_plan(config, traffic, seed=3, n_active=2,
                              duration_s=3.0)
     sched = loadgen.build_schedule(plan)
@@ -168,8 +172,9 @@ def check_loadgen() -> None:
     refl.stop = True
     refl.join()
     res = loadgen.analyze(plan, sched, got, t0, w0, w1, int(0.5e9))
-    n_sock = len(eps)
-    want_offered = n_sock * window_slots * (plan["conf_size"] - 1)
+    cs = plan["conf_size"]
+    n_speak = len(plan["active"]) * speakers
+    want_offered = n_speak * window_slots * (cs - 1)
     if res["offered"] != want_offered:
         fail(f"offered {res['offered']} != {want_offered}")
     want_lost = len(drop) * (plan["conf_size"] - 1)
@@ -178,6 +183,18 @@ def check_loadgen() -> None:
     if res["foreign"] or res["duplicates"] or res["unknown"]:
         fail(f"foreign/duplicates/unknown {res['foreign']}/"
              f"{res['duplicates']}/{res['unknown']}")
+    # the listeners' packets went out and what was reflected of them
+    # matched the schedule (`unknown` 0 above); every listener received
+    listening = np.nonzero(np.arange(len(eps)) % cs >= speakers)[0]
+    listeners = eps[listening]
+    from_listeners = int(np.isin(
+        got["recs"]["ssrc"].astype(np.int64) - loadgen.SSRC_BASE,
+        listeners).sum())
+    if not from_listeners:
+        fail("no delivery of a listener's packet: they were not sent")
+    quiet = set(listening.tolist()) - set(got["recs"]["rx"].tolist())
+    if quiet:
+        fail(f"listeners {sorted(quiet)} received nothing")
     # due time, not send time: each delivery's latency is at least its
     # source packet's recorded lateness, and within 20 ms of it (a
     # Python reflector on shared cores)
@@ -203,7 +220,8 @@ def check_loadgen() -> None:
     if res["lat_p50_ms"] is None or not 0 < res["lat_p50_ms"] < 20:
         fail(f"latency p50 {res['lat_p50_ms']}")
     print(f"check: loadgen ok: offered {res['offered']}, lost "
-          f"{res['lost']} (= known gaps), {checked} deliveries within "
+          f"{res['lost']} (= known gaps), {from_listeners} deliveries of "
+          f"the {len(listeners)} listeners' packets, {checked} within "
           f"[lateness, +20 ms] of their due time, p50 "
           f"{res['lat_p50_ms']:.3f} ms, sender late p99 "
           f"{res['late_p99_ms']:.3f} ms, samples {len(got['samples'])}")

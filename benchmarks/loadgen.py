@@ -4,7 +4,11 @@ One general generator, driven by a traffic file's parameters: which
 conferences are active, who speaks in them, the packet schedule (a
 fixed period per speaking endpoint, phases spread over the period,
 optional talk spurts, bursts, loss and reorder on the client legs) and
-the payload sizes.  Nothing here knows a cell's name.
+the payload sizes.  Every member of an active conference, speaking or
+not, sends one packet in the plan's first period: the bridge learns a
+leg's address from that leg's own packets alone, and a member that
+never reached it would receive nothing.  Nothing here knows a cell's
+name.
 
 Three kinds of process use this module, none of which imports JAX:
 
@@ -48,6 +52,8 @@ import numpy as np
 SSRC_BASE = 0x10000
 FIRST_INDEX = 1000
 RTP_PT = 96
+# faults the generator plays (the harness plays `bridge-bitflip`)
+CLIENT_FAULTS = ("client-key-bit", "no-latch")
 TS_PER_PACKET = 960           # Opus at 48 kHz, 20 ms (RFC 7587)
 SO_TIMESTAMPNS = 35           # asm-generic/socket.h (the _OLD value)
 SO_RXQ_OVFL = 40
@@ -141,6 +147,17 @@ def build_schedule(plan: dict) -> dict:
     also the RTP sequence number: windows stay under 2**16), `due_ns`
     (offset from the window's t0).  Also `due_of` [sockets, slots]: due
     offset of (socket, index - first_index), -1 where nothing is sent.
+
+    Every socket sends index `first_index` inside the first period, at
+    a phase of its own: it stands for what ICE and DTLS do before media
+    (the generator has neither) and is how the bridge learns the leg's
+    address.  A speaker goes on from there; one that a `talk_spurt`
+    starts "off" then keeps silent until its first spurt; a member
+    outside `speakers_per_conference` (a listener) sends nothing more.
+    The fault `no-latch` withholds the listeners' packets.  The draws
+    keep their order and the listeners' phases come last, so a plan in
+    which every member speaks and no spurt is set gives the schedule it
+    always gave.
     """
     rng = np.random.default_rng([plan["seed"], 0x7363686564])
     cs, sp = plan["conf_size"], plan["speakers"]
@@ -172,6 +189,7 @@ def build_schedule(plan: dict) -> dict:
                     row[a:b] = True
                 t, state = t + d, not state
             on[i] = row
+        on[:, 0] = True
     index = plan["first_index"] + np.cumsum(on, axis=1) - 1
     due = phase[:, None] + np.arange(slots, dtype=np.int64)[None, :] \
         * period
@@ -183,18 +201,27 @@ def build_schedule(plan: dict) -> dict:
     sent = on.copy()
     if plan["client_loss_pct"] > 0:
         sent &= rng.random((e, slots)) >= plan["client_loss_pct"] / 100
+        sent[:, 0] = True       # ICE and DTLS retransmit; media does not
     if int(index.max(initial=0)) >= 1 << 16:
         raise ValueError("window would wrap the 16-bit sequence space")
     rr, kk = np.nonzero(sent)
     order = np.argsort(due[rr, kk], kind="stable")
     rr, kk = rr[order], kk[order]
+    sock, idx, due_ns = spk[rr], index[rr, kk], due[rr, kk]
+    lis = np.nonzero(~speaking)[0]
+    if len(lis) and plan["fault"] != "no-latch":
+        lphase = (rng.permutation(len(lis)).astype(np.int64) * period
+                  // len(lis))
+        sock = np.concatenate([sock, lis])
+        idx = np.concatenate([idx, np.full(len(lis), plan["first_index"])])
+        due_ns = np.concatenate([due_ns, lphase])
+        order = np.argsort(due_ns, kind="stable")
+        sock, idx, due_ns = sock[order], idx[order], due_ns[order]
     due_of = np.full((n_sock, slots), -1, dtype=np.int64)
-    due_of[spk[rr], index[rr, kk] - plan["first_index"]] = due[rr, kk]
-    return {"sock": spk[rr].astype(np.int32),
-            "index": index[rr, kk].astype(np.int64),
-            "due_ns": due[rr, kk].astype(np.int64),
-            "due_of": due_of, "period_ns": period,
-            "speaking_sockets": spk}
+    due_of[sock, idx - plan["first_index"]] = due_ns
+    return {"sock": sock.astype(np.int32), "index": idx.astype(np.int64),
+            "due_ns": due_ns.astype(np.int64),
+            "due_of": due_of, "period_ns": period}
 
 
 def payload_of(plan_seed: int, spec: dict, ssrc: int, index: int) -> bytes:
@@ -449,6 +476,8 @@ class Generator:
         self.sender_out = os.path.join(workdir, "late.npy")
         self.sender = spawn("sender", fds, {
             "plan": plan, "fds": fds, "out": self.sender_out})
+        # the sender child builds the same schedule from the same plan
+        self.sched = build_schedule(plan)
         sched_n = self.expected_events()
         cs = plan["conf_size"]
         deliveries = sched_n * (cs - 1)
@@ -474,9 +503,9 @@ class Generator:
         self.ready_info = None
 
     def expected_events(self) -> int:
-        p = self.plan
-        slots = int(np.ceil(p["duration_s"] * 1e3 / p["period_ms"]))
-        return len(p["active"]) * p["speakers"] * slots
+        """Packets the plan really sends (spurts, listeners and client
+        loss counted): the receivers' sampling rate follows from it."""
+        return len(self.sched["due_ns"])
 
     def wait_ready(self) -> dict:
         for p in self.receivers:

@@ -13,7 +13,8 @@ with `--trace 1`); everything else goes on earlier lines or under
 run exits non-zero and prints no result.
 
 Beyond the driver's four arguments (see README.md):
-  --fault client-key-bit | bridge-bitflip   a run that must say false
+  --fault client-key-bit | bridge-bitflip | no-latch
+                        a run that must say false
   --sweep A1,A2,...     knee sweep: one set-up, one window per A
   --seeds s1,s2,...     one set-up, one window per traffic seed
   --faults f1,f2,...    control windows appended to a --seeds run
@@ -151,6 +152,7 @@ def drive_window(system, gen, timing, seconds: float, trace_dir):
 
     sup, loop, stats = system.sup, system.loop, system.stats
     now_ns = time.time_ns
+    members = system.member_sids(loadgen.plan_endpoints(gen.plan))
     t0 = gen.go(system.port)
     # ---- lead-in: addresses latch on the first packets, every shape
     # the traffic drives runs once, and compile stats must stand still
@@ -167,6 +169,7 @@ def drive_window(system, gen, timing, seconds: float, trace_dir):
         if t > t0 + timing["lead_max_s"] * 1e9:
             say("lead-in: compile stats never stood still")
             break
+    unlatched = system.unlatched(members)
     w0 = now_ns()
     w1 = w0 + int(seconds * 1e9)
     gen.until(w1 + (0 if trace_dir else int(0.3e9)))
@@ -231,6 +234,7 @@ def drive_window(system, gen, timing, seconds: float, trace_dir):
         for k, v in led.items():
             stages.setdefault(k, np.zeros(len(ledgers)))[i] = v
     return {"t0": t0, "w0": w0, "w1": w1, "gc_pauses": gc_pauses,
+            "unlatched_members": unlatched,
             "counters": {k: c1[k] - c0[k] for k in c0},
             "after": system.counters(),
             "ticks": {"tick_s": np.asarray(tick_s), "stage": stages,
@@ -263,10 +267,14 @@ def reduce_window_trace(trace_dir, trace_slice, out_dir):
     return red
 
 
-def judge(traffic, win, client, sample) -> bool:
-    """Every number `correct` rests on, beside its limit."""
+def judge(traffic, win, client, sample) -> list:
+    """Every number `correct` rests on, beside its limit: a list of
+    `(name, number, "==" or ">=", limit, passed)`."""
     c, after = win["counters"], win["after"]
     checks = [
+        # members of the plan's conferences whose address the bridge
+        # had not learnt when the window opened: they receive nothing
+        ("unlatched_members", win["unlatched_members"], "==", 0),
         ("sample_opened", sample["checked"], ">=",
          traffic.get("sample_min", 4096)),
         ("sample_bad_tag", sample["bad_tag"], "==", 0),
@@ -283,16 +291,21 @@ def judge(traffic, win, client, sample) -> bool:
         for k in ("shed", "quarantined", "level", "quarantine_total",
                   "refused"):
             checks.append((f"supervisor_{k}", after[k], "==", 0))
-    ok = True
+    out = []
     for name, got, op, lim in checks:
-        good = got >= lim if op == ">=" else got == lim
-        ok &= bool(good)
-        say(f"check {name}: {got} (limit {op} {lim})"
+        good = bool(got >= lim if op == ">=" else got == lim)
+        out.append((name, int(got), op, int(lim), good))
+        say(check_line(out[-1]))
+    return out
+
+
+def check_line(check) -> str:
+    name, got, op, lim, good = check
+    return (f"check {name}: {got} (limit {op} {lim})"
             + ("" if good else "  <-- FAILS"))
-    return ok
 
 
-def log_window(seconds, win, client, sample) -> None:
+def log_window(seconds, win, client, sample, fanout) -> None:
     """What a reader of the run's log wants beside the result line."""
     t = win["ticks"]
     busy = t["rx"] > 0
@@ -331,6 +344,18 @@ def log_window(seconds, win, client, sample) -> None:
             f"{np.median(t['tick_s'][quarter == q]) * 1e3:.1f} / "
             f"{np.mean(t['rx'][quarter == q]):.0f}"
             for q in range(4) if (quarter == q).any()))
+    if busy.any():
+        # against the program's row classes as they are today (64, 256,
+        # 1,024, 4,096): a log line, no metric reads it
+        rows = t["rx"][busy] * fanout
+        say("packets a tick (ticks with packets): " + " ".join(
+            f"p{q} {np.percentile(t['rx'][busy], q):.0f}"
+            for q in (50, 90, 95, 99, 100))
+            + f"; fan-out rows a tick (x {fanout} receivers), share of "
+            "ticks: " + " ".join(
+                f"{lo + 1}-{hi}: {100 * ((rows > lo) & (rows <= hi)).mean():.2f}%"
+                for lo, hi in ((0, 64), (64, 256), (256, 1024),
+                               (1024, 4096), (4096, 1 << 30))))
     gen2 = [p for g, p in win["gc_pauses"] if g == 2]
     say(f"interpreter gc in the window: {len(win['gc_pauses'])} "
         f"collections {sum(p for _g, p in win['gc_pauses']):.3f}s, of "
@@ -349,8 +374,8 @@ def run_window(system, config, traffic, peaks, gen, plan, sched, seconds,
     client = loadgen.analyze(plan, sched, got, win["t0"], win["w0"],
                              win["w1"], int(timing["grace_s"] * 1e9))
     sample = loadgen.verify_sample(plan, got)
-    log_window(seconds, win, client, sample)
-    correct = judge(traffic, win, client, sample)
+    log_window(seconds, win, client, sample, system.fanout)
+    checks = judge(traffic, win, client, sample)
     red = None
     if trace:
         red = reduce_window_trace(trace_dir, win["trace_slice"], out_dir)
@@ -371,7 +396,8 @@ def run_window(system, config, traffic, peaks, gen, plan, sched, seconds,
         attempted, failed = client["offered"], client["lost"]
     failed += sample["bad_tag"] + sample["bad_bytes"]
     return {"ctx": ctx, "win": win, "client": client, "sample": sample,
-            "correct": correct, "attempted": int(attempted),
+            "checks": checks, "correct": all(c[4] for c in checks),
+            "attempted": int(attempted),
             "failed": int(failed), "seconds": seconds}
 
 
@@ -402,8 +428,9 @@ def e2e_values(names, res, setup_s, bench) -> dict:
         # cell holds the same rate to a bound of its own
         if n.split(".")[0] == "delivered_pps":
             have[n] = c["delivered_in_window"] / res["seconds"]
-        # `added_latency_p<q>_ms`: that percentile over all deliveries
-        m = re.fullmatch(r"added_latency_p(\d+)_ms", n)
+        # `added_latency_p<q>_ms[.<suffix>]`: that percentile over all
+        # deliveries
+        m = re.fullmatch(r"added_latency_p(\d+)_ms(\..+)?", n)
         if m and len(c["latency_ns"]):
             have[n] = float(np.percentile(c["latency_ns"],
                                           int(m.group(1)))) / 1e6
@@ -472,7 +499,7 @@ def main() -> int:
             config, traffic, seed, a,
             timing["lead_max_s"] + seconds + timing["grace_s"] + 1.0,
             first_index=loadgen.FIRST_INDEX + k * INDEX_STRIDE,
-            fault="client-key-bit" if fault == "client-key-bit" else "",
+            fault=fault if fault in loadgen.CLIENT_FAULTS else "",
             sample_over_s=timing["lead_min_s"] + seconds)
         # keys are installed once, from the first window's seed
         plan["key_seed"] = windows[0][0]
@@ -504,12 +531,15 @@ def main() -> int:
         results = []
         for k in range(len(windows)):
             if k:
+                # a chained window starts as a process does: the last
+                # window's sockets are closed, so what it latched is
+                # stale (and a port may now be another endpoint's)
+                system.forget_addresses()
                 plan, gen = spawn(k)
             t = time.perf_counter()
             info = gen.wait_ready()
             if k == 0:
                 split["generator_wait"] = time.perf_counter() - t
-            sched = loadgen.build_schedule(plan)
             say(f"generator ready: {info['packets']} packets protected "
                 f"in {info['protect_s']:.1f}s (waited "
                 f"{time.perf_counter() - t:.1f}s); window {k}: seed "
@@ -518,7 +548,8 @@ def main() -> int:
             mend = (system.break_fanout()
                     if windows[k][2] == "bridge-bitflip" else None)
             res = run_window(system, config, traffic, peaks, gen, plan,
-                             sched, seconds, bool(args.trace), out_dir)
+                             gen.sched, seconds, bool(args.trace),
+                             out_dir)
             gen = None
             if mend:
                 mend()
@@ -583,7 +614,15 @@ def main() -> int:
         line["breakdown"] = res["ctx"]["trace"]["breakdown"]
     if len(results) > 1:
         line["windows"] = [{"correct": r["correct"]} for r in results]
+    # every number compared beside its limit, last on the line and last
+    # on stderr (of the first window that failed, else of the first)
+    shown = next((r for r in results if not r["correct"]), res)
+    line["checks"] = {name: {"value": got, "limit": f"{op} {lim}"}
+                      for name, got, op, lim, _good in shown["checks"]}
     print(json.dumps(line), flush=True)
+    for check in shown["checks"]:
+        print(check_line(check), file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

@@ -142,6 +142,25 @@ class System:
             "inbound_dropped": loop.inbound_dropped_total,
         }
 
+    def member_sids(self, rows) -> np.ndarray:
+        """The bridge's stream ids of the endpoints `rows` (= ssrc -
+        SSRC_BASE): what its address table is indexed by."""
+        sid_of = {ssrc: sid for sid, ssrc in self.bridge._ssrc_of.items()}
+        return np.array([sid_of[SSRC_BASE + int(r)] for r in rows],
+                        dtype=np.int64)
+
+    def unlatched(self, sids) -> int:
+        """How many of these streams the bridge knows no address for
+        (it learns a leg's address from that leg's own packets): what
+        it forwards to them goes nowhere."""
+        return int((self.loop.addr_port[sids] == 0).sum())
+
+    def forget_addresses(self) -> None:
+        """Between chained windows: every leg unlatched, as in a new
+        process."""
+        self.loop.addr_ip[:] = 0
+        self.loop.addr_port[:] = 0
+
     def break_fanout(self, share: float = 0.02):
         """Fault `bridge-bitflip`: the timed path broken underneath —
         one payload bit flipped in a share of the fan-out rows where
