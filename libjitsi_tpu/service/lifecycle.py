@@ -72,7 +72,7 @@ _log = get_logger("lifecycle")
 ADMIT_REASONS = ("capacity", "backlog", "duplicate", "fast_burn",
                  "stalled", "shedding", "host_bound", "shard_burn",
                  "hop_burn", "handshake_backlog", "trunk_down",
-                 "trunk_backlog", "capacity_forecast")
+                 "trunk_backlog", "capacity_forecast", "conference_full")
 
 
 @dataclass
@@ -107,6 +107,14 @@ class LifecycleConfig:
     # sharded otherwise, and a placement whose shards are not the
     # tables' (a conference could then straddle a chip unseen)
     table_shards: int = 0
+    # --------------------------------------------------- the room cap
+    # the most members a conference admits (prosody's
+    # `muc_max_occupants`; 0: not stated).  An admission rule: with
+    # placement enabled `request_join` refuses the member past it
+    # `conference_full`; nothing on the data path reads it.  A declared
+    # broadcast conference is not held to it (its listeners are what it
+    # is for)
+    max_conference_size: int = 0
 
 
 class HandshakeQueue:
@@ -542,6 +550,9 @@ class StreamLifecycleManager:
         conf = self._conf_key(ssrc, conference)
         shard = self.placer.shard_of(conf)
         if shard is not None:
+            cap = self.cfg.max_conference_size
+            if cap and self.placer.size_of(conf) >= cap:
+                return conf, "conference_full"
             if self.supervisor is not None:
                 ok, r = self.supervisor.admission_decision(shard=shard)
                 if not ok and r in ("shard_burn", "capacity_forecast"):
@@ -1038,11 +1049,22 @@ class StreamLifecycleManager:
         for rc in want:
             self._warm_class(rc, rtp=True)
             self._warm_rows.add(rc)
+        self._bound_fanout()
         self.flight.record("bucket_warm", tick=self.ticks(),
                            bucket=bucket, rows=sorted(self._warm_rows))
         _log.info("bucket_warm", bucket=bucket,
                   row_classes=sorted(self._warm_rows))
         self._warm_bucket = bucket
+
+    def _bound_fanout(self) -> None:
+        """The translator cuts a tick's fan-out rows into launches of
+        at most the largest row class warmed here (`launch_rows`;
+        `ROW_CLASSES[-1]` once the ladder is whole): a backlog tick of
+        a small population stays inside what its ladder compiled too."""
+        tr = getattr(self.bridge, "translator", None)
+        warmed = self._warm_rows | self._warm_lrows
+        if warmed and hasattr(tr, "launch_rows"):
+            tr.launch_rows = max(warmed)
 
     def _warm_class(self, rc: int, rtp: bool) -> None:
         """Compile every program one row class can drive: uplink RTP
@@ -1091,6 +1113,7 @@ class StreamLifecycleManager:
         for rc in want:
             self._warm_class(rc, rtp=False)
             self._warm_lrows.add(rc)
+        self._bound_fanout()
         self.flight.record("listener_bucket_warm", tick=self.ticks(),
                            bucket=bucket,
                            rows=sorted(self._warm_lrows))

@@ -985,21 +985,31 @@ class SfuBridge:
                                   sub.stream[keep])
                 idx_sel = idx_sel[keep]
         # the translator books route / expand / fanout_dispatch inside
-        # `translate_async` and fanout_wait / fanout_d2h inside
-        # `result()`, each with the phase it is (the route loop and the
-        # expansion are host_python, the residual)
+        # `translate_async` and fanout_wait / fanout_d2h where a launch
+        # is waited for, each with the phase it is (the route loop and
+        # the expansion are host_python, the residual)
+        tr = self.translator
         if self.pipelined:
             with tracer.span("forward_chain"):
                 # dispatch carries its ingress origin: the flush lands
                 # on a LATER tick, and the journey must charge the
                 # pipelining delay to the tick the packets arrived on
-                pend = self.translator.translate_async(sub, idx_sel)
+                pend = tr.translate_async(sub, idx_sel)
                 self._pending_fanout.append(
                     (pend, self.loop.journey_origin()))
             return None
         with tracer.span("forward_chain"):
-            wire, recv = self.translator.translate(sub, idx_sel)
-        self._emit_fanout(wire, recv)
+            if tr.single_launch(sub.batch_size):
+                # one launch whatever the routes: the call whole
+                parts, several = [tr.translate(sub, idx_sel)], False
+            else:
+                # the rows may outgrow the largest warmed class: every
+                # launch is dispatched here; each is waited for, cached
+                # and handed over in turn below, so the egress worker
+                # sends launch 1 while launch 2 is on the device
+                pend = tr.translate_async(sub, idx_sel)
+                parts, several = pend.each(), pend.launches > 1
+        self._emit_launches(parts, several)
         return None
 
     def _quiesce_fanout(self) -> None:
@@ -1016,14 +1026,27 @@ class SfuBridge:
     def _flush_fanout(self) -> None:
         pending, self._pending_fanout = self._pending_fanout, []
         for pend, origin in pending:
-            self._emit_fanout(*pend.result(), origin=origin)
+            self._emit_launches(pend.each(), pend.launches > 1, origin)
+
+    def _emit_launches(self, parts, several: bool, origin=None) -> None:
+        """`_emit_fanout` each (wire, recv) of `parts`, a tick's
+        launches in row order: the egress worker is FIFO, so every
+        socket sees the order one launch would have given it.  Where
+        the tick has `several` the spans say which launch they belong
+        to (`launch`)."""
+        for k, (wire, recv) in enumerate(parts):
+            self._emit_fanout(wire, recv, origin,
+                              {"launch": k} if several else None)
 
     def _emit_fanout(self, wire: PacketBatch, recv: np.ndarray,
-                     origin=None) -> None:
+                     origin=None, nth=None) -> None:
+        """Cache, then hand over, the rows of ONE fan-out launch (`nth`:
+        what its spans book beside their own counts)."""
         if wire.batch_size == 0:
             return
-        with self.loop.tracer.span("nack_cache",
-                                   rows=wire.batch_size) as sp:
+        nth = nth or {}
+        with self.loop.tracer.span("nack_cache", rows=wire.batch_size,
+                                   **nth) as sp:
             # a just-joined leg has no latched address yet: sending to
             # 0.0.0.0:0 would EINVAL out of sendmmsg and crash the tick
             ready = self.loop.addr_port[recv] != 0
@@ -1053,7 +1076,7 @@ class SfuBridge:
         # is what lets this call site, and no other of the file, send
         # asynchronously.  `_reap_egress` books the outcome next tick
         with self.loop.tracer.span(
-                "egress", rows=wire.batch_size,
+                "egress", rows=wire.batch_size, **nth,
                 bytes=int(np.asarray(wire.length).sum())) as sp:
             job = self.loop.engine.send_batch_async(
                 wire, self.loop.addr_ip[recv], self.loop.addr_port[recv])

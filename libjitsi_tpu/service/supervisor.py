@@ -794,6 +794,20 @@ class BridgeSupervisor:
             registry.register_scalar(
                 "bridge_forwarded", lambda: self.bridge.forwarded,
                 help_="packets forwarded to receivers", kind="counter")
+        if hasattr(getattr(self.bridge, "translator", None),
+                   "fanout_launches"):
+            registry.register_scalar(
+                "fanout_launches_total",
+                lambda: self.bridge.translator.fanout_launches,
+                help_="device calls the fan-out has made (one a tick "
+                      "inside the largest warmed row class)",
+                kind="counter")
+            registry.register_scalar(
+                "fanout_split_ticks_total",
+                lambda: self.bridge.translator.fanout_split_ticks,
+                help_="ticks whose fan-out rows outgrew the largest "
+                      "warmed row class and went out in several launches",
+                kind="counter")
         if hasattr(self.bridge, "_video"):
             # simulcast/SVC forwarders are per-receiver objects; export
             # the fleet-wide sums (drift rule: every bumped counter is

@@ -164,6 +164,17 @@ class RtpTranslator:
         # the longest receiver list ever connected: what `_gcm_leg_major`
         # can select here, hence what `fanout_warmups` warms
         self._max_legs = 0
+        #: rows of the largest per-row fan-out launch, a row class: a
+        #: tick with more rows is cut into several launches
+        #: (`translate_async`).  The lifecycle's warm ladder lowers it
+        #: to the largest class it has compiled while that is not yet
+        #: the top one (`_bound_fanout`); no option sets it
+        self.launch_rows = ROW_CLASSES[-1]
+        # device calls the fan-out has made, and ticks it cut in two or
+        # more (/metrics: `fanout_launches_total`,
+        # `fanout_split_ticks_total`)
+        self.fanout_launches = 0
+        self.fanout_split_ticks = 0
         # the bridge hands its loop's PipelineTracer and PhaseProfiler
         # here; a translator standing alone spans and samples nothing
         self.tracer = None
@@ -393,11 +404,28 @@ class RtpTranslator:
         pend = self.translate_async(batch, index)
         return pend.result()
 
+    def single_launch(self, packets: int) -> bool:
+        """True where `packets` packets fan out in ONE launch whatever
+        their routes: even at the longest receiver list ever connected
+        their rows fit `launch_rows`.  Such a tick is `translate`'s,
+        whole; a longer one comes back a launch at a time
+        (`translate_async(...).each()`)."""
+        return packets * self._max_legs <= self.launch_rows
+
     def translate_async(self, batch: PacketBatch, index: np.ndarray
                         ) -> "PendingTranslate":
-        """Dispatch-only `translate`: the fan-out launch is enqueued,
-        results materialize on `.result()` — the SFU's pipelined tick
-        overlaps the launch with its next recv window."""
+        """Dispatch-only `translate`: the fan-out is enqueued, results
+        materialize on `.result()` (or a launch at a time on `.each()`)
+        — the SFU's pipelined tick overlaps the launch with its next
+        recv window.
+
+        The (packet, receiver) rows are cut into launches of at most
+        `launch_rows` rows, the largest row class the warm ladder
+        compiles: whatever the conference size and the backlog, no
+        launch has a shape the ladder did not warm.  A tick inside one
+        class is one launch, as it always was.  Every launch of a tick
+        is dispatched before the first is waited for, and they come
+        back in row order."""
         tracer = self.tracer
         stream = np.asarray(batch.stream, dtype=np.int64)
         index = np.asarray(index, dtype=np.int64)
@@ -412,49 +440,90 @@ class RtpTranslator:
                 rows.append(i)
                 recvs.append(rr)
         if not rows:
-            return PendingTranslate(None, np.zeros(0, np.int64),
-                                    batch.capacity)
+            return PendingTranslate([], batch.capacity)
         with span_of(tracer, "expand") as sp:
             counts = np.array([len(r) for r in recvs])
             src = np.repeat(np.array(rows, dtype=np.int64), counts)
             recv = np.concatenate(recvs)
             if not np.all(self.active[recv]):
                 raise KeyError("route to receiver without installed keys")
-
-            data = batch.data[src]
+            # per-row vectors over the whole tick; the packet bytes are
+            # gathered a launch at a time (`_expand_rows`), so a tick
+            # that found a backlog builds no [rows, capacity]
+            # intermediate
             length = np.asarray(batch.length, dtype=np.int32)[src]
             hdr = rtp_header.parse(batch)
-            payload_off = hdr.payload_off[src]
-            ssrc = hdr.ssrc[src]
             idx = index[src]
-            if int(np.max(length, initial=0)) + self.policy.auth_tag_len \
-                    > batch.capacity:
+            longest = int(np.max(length, initial=12)) \
+                + self.policy.auth_tag_len
+            if longest > batch.capacity:
                 raise ValueError(
                     "fan-out rows need tag headroom in capacity")
-            if not self._gcm:
-                cm = self._expand_cm(recv, data, length, payload_off,
-                                     ssrc, idx)
-                sp.note(rows=len(recv), rows_padded=len(cm[0]),
-                        width=cm[1].shape[-1] - staging.TAIL)
-
-        pg = None
-        if self._gcm:
-            launch, pg = self._translate_gcm(
-                batch, rows, recvs, src, recv, data, length,
-                hdr, payload_off, ssrc, idx)
-        else:
-            with staging.dispatch(tracer, "fanout") as sp, \
+            off0 = self._gcm_legs(batch, rows, recvs, hdr) \
+                if self._gcm else None
+            if off0 is None:
+                # width clips to the tick's largest packet's class, not
+                # the wire buffer: voice riding full-MTU rx buffers
+                # would pay ~7x keystream over every leg
+                pw = _round_width(longest)
+                rowv = (src, recv, length, hdr.payload_off[src],
+                        hdr.ssrc[src], idx)
+                top = self.launch_rows
+                cuts = [(a, min(a + top, len(recv)))
+                        for a in range(0, len(recv), top)]
+                sp.note(rows=len(recv), width=pw, launches=len(cuts),
+                        legs_max=int(counts.max()), rows_padded=sum(
+                            _round_rows(b - a) if self._pads_rows
+                            else b - a for a, b in cuts))
+                args = self._expand_rows(batch, rowv, pw, *cuts[0])
+        if off0 is not None:
+            self.fanout_launches += 1
+            return PendingTranslate(
+                [self._translate_gcm_legs(batch, rows, recvs[0], hdr,
+                                          idx, off0) + (recv, {})],
+                batch.capacity, tracer=tracer, perf=self.perf)
+        call = self._gcm_fanout_call if self._gcm else self._cm_fanout_call
+        parts = []
+        for k, (a, b) in enumerate(cuts):
+            # a launch of a tick that has several says which it is, on
+            # each span it books (`launch`: 0, 1, ...); a tick's only
+            # launch books what it always did
+            nth = {"launch": k} if len(cuts) > 1 else {}
+            if k:
+                with span_of(tracer, "expand"):
+                    args = self._expand_rows(batch, rowv, pw, a, b)
+            with staging.dispatch(tracer, "fanout", **nth) as sp, \
                     phase_of(self.perf, "dispatch"):
-                launch = self._cm_fanout_call(*cm)
+                launch = call(*args)
                 sp.note(h2d_arrays=launch.h2d_arrays,
                         h2d_bytes=launch.h2d_bytes, **launch.counts)
-        return PendingTranslate(launch, recv, batch.capacity, pg=pg,
-                                tracer=tracer, perf=self.perf)
+            parts.append((launch, None, recv[a:b], nth))
+        self.fanout_launches += len(parts)
+        self.fanout_split_ticks += int(len(parts) > 1)
+        return PendingTranslate(parts, batch.capacity, tracer=tracer,
+                                perf=self.perf)
 
-    def _expand_cm(self, recv, data, length, payload_off, ssrc, idx):
-        """The CM fan-out call's arguments: per-row IVs, rows and width
-        padded to their classes; the packet bytes in a staging plane
-        (core/staging.py) with room behind them for the rest."""
+    def _expand_rows(self, batch, rowv, pw, a, b):
+        """The arguments of ONE per-row fan-out call (`_cm_fanout_call`
+        / `_gcm_fanout_call`) for rows `a:b` of the tick's expansion
+        (`rowv`: its per-row source packet, receiver, length, payload
+        offset, ssrc and index): rows padded to their class, per-row
+        IVs, and the packet bytes gathered once, straight into a
+        staging plane (core/staging.py) of width `pw` with room behind
+        them for the rest."""
+        # class-pad rows AND width: under churn the receiver count
+        # changes every tick, so raw (packets x receivers) shapes
+        # would retrace the fan-out jit unboundedly — bucketing
+        # keeps the compiled-shape space at LENGTH x ROW classes
+        rr_idx = _cycle_rows(b - a) if self._pads_rows else None
+        at = slice(a, b) if rr_idx is None else a + rr_idx
+        src, recv, length, payload_off, ssrc, idx = (v[at] for v in rowv)
+        cw = min(pw, batch.capacity)
+        plane = staging.alloc(len(recv), pw)
+        plane[:, :cw] = batch.data[src, :cw]
+        if self._gcm:
+            return (recv, plane, length, payload_off,
+                    gcm_kernel.srtp_gcm_iv(self._salt[recv], ssrc, idx))
         # per-row IV from the receiver's salt + sender's ssrc/index
         iv = self._salt[recv].copy()
         for k in range(4):
@@ -463,24 +532,7 @@ class RtpTranslator:
         for k in range(6):
             iv[:, 8 + k] ^= ((idx >> (8 * (5 - k))) & 0xFF
                              ).astype(np.uint8)
-
-        # class-pad rows AND width: under churn the receiver count
-        # changes every tick, so raw (packets x receivers) shapes
-        # would retrace the fan-out jit unboundedly — bucketing
-        # keeps the compiled-shape space at LENGTH x ROW classes
-        rr_idx = _cycle_rows(len(recv)) if self._pads_rows else None
-        if rr_idx is None:
-            rr_idx = np.arange(len(recv))
-        # width clips to the tick's largest packet's class, not the
-        # wire buffer: voice riding full-MTU rx buffers would pay
-        # ~7x keystream over every leg
-        pw = _round_width(int(np.max(length, initial=12))
-                          + self.policy.auth_tag_len)
-        cw = min(pw, data.shape[-1])
-        plane = staging.alloc(len(rr_idx), pw)
-        plane[:, :cw] = data[rr_idx][:, :cw]
-        return (recv[rr_idx], plane, length[rr_idx],
-                payload_off[rr_idx], iv[rr_idx], idx[rr_idx])
+        return recv, plane, length, payload_off, iv, idx
 
     def _cm_fanout_call(self, recv, plane, length, payload_off, iv, idx
                         ) -> staging.Launch:
@@ -514,23 +566,13 @@ class RtpTranslator:
 
     # (see PendingTranslate at module scope)
 
-    def _translate_gcm(self, batch, rows, recvs, src, recv, data, length,
-                       hdr, payload_off, ssrc, idx):
-        """AEAD fan-out: per-leg H matrices replace HMAC midstates.
-        Returns (the `staging.Launch` in flight, (packets, legs) for
-        the leg-major grid or None for flat rows).
-
-        Leg-major path: when every routed sender shares one receiver
+    def _gcm_legs(self, batch, rows, recvs, hdr) -> Optional[int]:
+        """The uniform payload offset where this tick takes the
+        leg-major AEAD form, else None (the per-row form: every tick of
+        a bridge of small conferences, whose senders' lists all
+        differ).  Leg-major: every routed sender shares one receiver
         list, headers are uniform and `_gcm_leg_major` says so of the
-        (legs, packets) shape, the matrix seals via
-        `gcm_protect_fanout` — each leg's 16 KiB GHASH matrix is read
-        once per leg, not once per output row.  Everything else — every
-        tick of a bridge of small conferences, whose senders' lists
-        all differ — takes the per-row path.
-        Reference: RTPTranslatorImpl's cipher-agnostic per-leg
-        transform (SURVEY §3.4).
-        """
-        tracer, perf = self.tracer, self.perf
+        (legs, packets) shape."""
         off0 = np.asarray(hdr.payload_off)[rows]
         # the offset bound mirrors _uniform_off: a forged ext_words field
         # can claim a header larger than the packet; such batches take
@@ -543,72 +585,61 @@ class RtpTranslator:
                        r, recvs[0]) for r in recvs[1:])
                    and np.all(off0 == off0[0])
                    and 0 <= int(off0[0]) < batch.capacity)
-        if uniform:
-            with span_of(tracer, "expand") as sp:
-                rr = recvs[0]
-                p_rows = np.asarray(rows, dtype=np.int64)
-                pidx = np.asarray(idx).reshape(len(rows), len(rr))[:, 0]
-                # class-pad BOTH grouped axes (legs and packets,
-                # cycled) plus the data width: churn varies the leg
-                # count every tick, and raw (G, P) shapes would retrace
-                # unboundedly
-                g_real, p_real = len(rr), len(p_rows)
-                g_idx = _cycle_rows(g_real)
-                rr_p = rr[g_idx] if g_idx is not None else rr
-                p_idx = _cycle_rows(p_real)
-                if p_idx is None:
-                    p_idx = np.arange(p_real)
-                pr = p_rows[p_idx]
-                plen = np.asarray(batch.length, dtype=np.int32)[pr]
-                # width clips to the largest packet's class (see the
-                # CM path)
-                pw = _round_width(int(np.max(plen, initial=12))
-                                  + self.policy.auth_tag_len)
-                cw = min(pw, batch.capacity)
-                pdata = np.zeros((len(pr), pw), dtype=np.uint8)
-                pdata[:, :cw] = batch.data[pr][:, :cw]
-                pssrc = hdr.ssrc[pr]
-                pidx = pidx[p_idx]
-                # iv [G, P, 12]: leg salt x sender ssrc/index
-                iv = gcm_kernel.srtp_gcm_iv(
-                    np.broadcast_to(self._salt[rr_p][:, None, :12],
-                                    (len(rr_p), len(pr), 12)),
-                    pssrc[None, :], pidx[None, :])
-                sp.note(rows=g_real * p_real,
-                        rows_padded=len(rr_p) * len(pr), width=pw)
-            with staging.dispatch(tracer, "fanout") as sp, \
-                    phase_of(perf, "dispatch"):
-                # the output is leg-major [G, P, W] at the class-PADDED
-                # shape; cropping to the raw (P, G) and the flip to
-                # packet-major rows (p0r0, p0r1, ...) matching
-                # `src`/`recv` are numpy work at result() time
-                launch = self._gcm_uniform_fanout_call(
-                    rr_p, pdata, plen, iv, int(off0[0]))
-                sp.note(h2d_arrays=launch.h2d_arrays,
-                        h2d_bytes=launch.h2d_bytes, **launch.counts)
-            return launch, (p_real, g_real)
+        return int(off0[0]) if uniform else None
+
+    def _translate_gcm_legs(self, batch, rows, rr, hdr, idx, off0: int):
+        """The leg-major AEAD fan-out of a whole tick: the matrix seals
+        via `gcm_protect_fanout` — each leg's 16 KiB GHASH matrix is
+        read once per leg, not once per output row.  ONE launch
+        whatever the rows (its grid is legs x packets, both padded to
+        their classes; it is not cut at `launch_rows`).  Returns (the
+        `staging.Launch` in flight, (packets, legs) of the grid).
+        Reference: RTPTranslatorImpl's cipher-agnostic per-leg
+        transform (SURVEY §3.4)."""
+        tracer, perf = self.tracer, self.perf
         with span_of(tracer, "expand") as sp:
-            rr_idx = _cycle_rows(len(recv)) if self._pads_rows else None
-            if rr_idx is None:
-                rr_idx = np.arange(len(recv))
-            # width clips to the largest packet's class (see the CM
-            # path)
-            pw = _round_width(int(np.max(length, initial=12))
+            p_rows = np.asarray(rows, dtype=np.int64)
+            pidx = np.asarray(idx).reshape(len(rows), len(rr))[:, 0]
+            # class-pad BOTH grouped axes (legs and packets,
+            # cycled) plus the data width: churn varies the leg
+            # count every tick, and raw (G, P) shapes would retrace
+            # unboundedly
+            g_real, p_real = len(rr), len(p_rows)
+            g_idx = _cycle_rows(g_real)
+            rr_p = rr[g_idx] if g_idx is not None else rr
+            p_idx = _cycle_rows(p_real)
+            if p_idx is None:
+                p_idx = np.arange(p_real)
+            pr = p_rows[p_idx]
+            plen = np.asarray(batch.length, dtype=np.int32)[pr]
+            # width clips to the largest packet's class (see the
+            # per-row path)
+            pw = _round_width(int(np.max(plen, initial=12))
                               + self.policy.auth_tag_len)
-            cw = min(pw, data.shape[-1])
-            plane = staging.alloc(len(rr_idx), pw)
-            plane[:, :cw] = data[rr_idx][:, :cw]
-            iv = gcm_kernel.srtp_gcm_iv(self._salt[recv[rr_idx]],
-                                        ssrc[rr_idx], idx[rr_idx])
-            plen, poff = length[rr_idx], payload_off[rr_idx]
-            sp.note(rows=len(recv), rows_padded=len(rr_idx), width=pw)
+            cw = min(pw, batch.capacity)
+            pdata = np.zeros((len(pr), pw), dtype=np.uint8)
+            pdata[:, :cw] = batch.data[pr][:, :cw]
+            pssrc = hdr.ssrc[pr]
+            pidx = pidx[p_idx]
+            # iv [G, P, 12]: leg salt x sender ssrc/index
+            iv = gcm_kernel.srtp_gcm_iv(
+                np.broadcast_to(self._salt[rr_p][:, None, :12],
+                                (len(rr_p), len(pr), 12)),
+                pssrc[None, :], pidx[None, :])
+            sp.note(rows=g_real * p_real,
+                    rows_padded=len(rr_p) * len(pr), width=pw,
+                    launches=1, legs_max=g_real)
         with staging.dispatch(tracer, "fanout") as sp, \
                 phase_of(perf, "dispatch"):
-            launch = self._gcm_fanout_call(
-                recv[rr_idx], plane, plen, poff, iv)
+            # the output is leg-major [G, P, W] at the class-PADDED
+            # shape; cropping to the raw (P, G) and the flip to
+            # packet-major rows (p0r0, p0r1, ...) matching
+            # `src`/`recv` are numpy work at result() time
+            launch = self._gcm_uniform_fanout_call(
+                rr_p, pdata, plen, iv, off0)
             sp.note(h2d_arrays=launch.h2d_arrays,
                     h2d_bytes=launch.h2d_bytes, **launch.counts)
-        return launch, None
+        return launch, (p_real, g_real)
 
     def _gcm_uniform_fanout_call(self, rr, pdata, plen, iv, aad_const
                                  ) -> staging.Launch:
@@ -654,57 +685,74 @@ class RtpTranslator:
 
 
 class PendingTranslate:
-    """An in-flight `translate_async` fan-out.
+    """An in-flight `translate_async` fan-out: the tick's launches, in
+    row order.
 
-    Device work is enqueued; `result()` materializes once (blocking
-    transfer) and caches.  Mirrors `context.PendingProtect` — the same
-    double-buffering seam, for the SFU's per-leg re-encrypt launch.
+    Device work is enqueued; `each()` materializes a launch at a time
+    (blocking transfer) and `result()` the whole tick, once, cached.
+    Mirrors `context.PendingProtect` — the same double-buffering seam,
+    for the SFU's per-leg re-encrypt launches.
     """
 
-    def __init__(self, launch: "Optional[staging.Launch]",
-                 recv: np.ndarray, capacity: int,
-                 pg=None, tracer=None, perf=None):
-        # the fan-out call in flight; `fetch()` -> (rows, lengths)
-        self._launch = launch
-        self.recv = recv
+    def __init__(self, parts, capacity: int, tracer=None, perf=None):
+        # (launch, pg, recv, nth) a device call in flight: `fetch()` ->
+        # (rows, lengths); `pg` = (p_real, g_real) when the launch is
+        # the leg-major fan-out's padded grid [G_pad, P_pad, W], None
+        # for flat rows; `recv` the receiver leg of each REAL row;
+        # `nth` = {"launch": k} where the tick has several, else {}
+        self._parts = list(parts)
+        self.launches = len(self._parts)
         self._capacity = capacity
         self._tracer = tracer
         self._perf = perf
-        # (p_real, g_real) when the launch is the leg-major fan-out's
-        # padded grid [G_pad, P_pad, W]; None for flat rows
-        self._pg = pg
-        self._done: "Tuple[PacketBatch, np.ndarray] | None" = None
+        self._done: "List[Tuple[PacketBatch, np.ndarray]]" = []
+
+    def each(self):
+        """(wire_batch, receiver_ids) a launch, in row order: each
+        waits for its own launch alone, so a caller can send launch 1
+        while launch 2 is on the device.  Nothing for a tick that
+        routed no packet."""
+        yield from self._done
+        while self._parts:
+            launch, pg, recv, nth = self._parts.pop(0)
+            self._done.append((self._materialize(launch, pg, recv, nth),
+                               recv))
+            yield self._done[-1]
 
     def result(self) -> Tuple[PacketBatch, np.ndarray]:
-        if self._done is None:
-            if self._launch is None:
-                wire = PacketBatch.empty(0, self._capacity)
-            else:
-                wire = self._materialize()
-            self._done = (wire, self.recv)
-            self._launch = None
-        return self._done
+        """The whole tick as one batch (one launch: that launch's
+        plane as it came back; more: their rows in one copy)."""
+        got = list(self.each())
+        if not got:
+            return (PacketBatch.empty(0, self._capacity),
+                    np.zeros(0, np.int64))
+        if len(got) > 1:
+            self._done = got = [(
+                PacketBatch(np.concatenate([w.data for w, _r in got]),
+                            np.concatenate([w.length for w, _r in got]),
+                            np.concatenate([w.stream for w, _r in got])),
+                np.concatenate([r for _w, r in got]))]
+        return got[0]
 
-    def _materialize(self) -> PacketBatch:
-        """Wait for the launch (`fanout_wait`, the `device_compute`
+    def _materialize(self, launch, pg, recv, nth) -> PacketBatch:
+        """Wait for one launch (`fanout_wait`, the `device_compute`
         phase), then copy its rows back (`fanout_d2h`,
         `d2h_transfer`)."""
-        launch = self._launch
-        with span_of(self._tracer, "fanout_wait"), \
+        with span_of(self._tracer, "fanout_wait", **nth), \
                 phase_of(self._perf, "device_compute"):
             launch.block_until_ready()
-        with span_of(self._tracer, "fanout_d2h") as sp, \
+        with span_of(self._tracer, "fanout_d2h", **nth) as sp, \
                 phase_of(self._perf, "d2h_transfer"):
             arr, lens = launch.fetch()
             lens = np.asarray(lens, dtype=np.int32)
             sp.note(d2h_arrays=launch.d2h_arrays,
                     d2h_bytes=launch.d2h_bytes, **launch.d2h_counts)
-            if self._pg is not None:
+            if pg is not None:
                 # crop the padded leg-major (G, P) grid to the real
                 # counts and flatten packet-major — numpy on the
                 # materialized buffer, so no per-raw-shape device
                 # programs
-                p, g = self._pg
+                p, g = pg
                 arr = arr[:g, :p].transpose(1, 0, 2).reshape(
                     p * g, arr.shape[-1])
                 lens = np.repeat(lens[:p], g)
@@ -712,6 +760,6 @@ class PendingTranslate:
                 # drop the class-padding rows (cycled copies appended
                 # by translate_async to keep the fan-out shapes on the
                 # ROW_CLASSES grid)
-                n = len(self.recv)
+                n = len(recv)
                 arr, lens = arr[:n], lens[:n]
-            return PacketBatch(arr, lens, self.recv.astype(np.int32))
+            return PacketBatch(arr, lens, recv.astype(np.int32))

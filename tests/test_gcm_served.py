@@ -490,7 +490,8 @@ def test_fanout_forms_match_the_reference(legs, packets, oracle):
                          [0x2000 + s for s in senders], [96] * packets,
                          stream=senders)
     pend = t.translate_async(b, np.asarray(seqs, dtype=np.int64))
-    assert (pend._pg is not None) is tr_mod._gcm_leg_major(legs, packets)
+    assert (pend._parts[0][1] is not None) \
+        is tr_mod._gcm_leg_major(legs, packets)
     wire, recv = pend.result()
     assert wire.batch_size == legs * packets
     for j in range(wire.batch_size):
@@ -702,4 +703,5 @@ def test_packed_gcm_fanout_one_array_each_way(warmed_launch_guard,
         "gm_gather_bytes": 16 * tr_mod.GM_BYTES, "grouped": 0}
     assert counts["fanout_d2h"] == {"d2h_arrays": 1, "d2h_bytes": plane}
     assert counts["expand"] == {"rows": 12, "rows_padded": 16,
-                                "width": 224}
+                                "width": 224, "launches": 1,
+                                "legs_max": 3}

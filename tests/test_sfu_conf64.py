@@ -1,0 +1,576 @@
+"""Meetings of 64: a fan-out the program bounds in ROWS, not packets.
+
+A packet of a member of a 64-member conference is 63 fan-out rows, so a
+tick's rows outgrow the largest row class the warm ladder compiles
+within a few dozen packets.  `RtpTranslator.translate_async` cuts the
+(packet, receiver) rows into launches of at most `launch_rows` rows and
+`SfuBridge` caches and hands over a launch at a time; no launch has a
+shape the ladder did not warm, whatever the backlog.
+
+Two layers, both on the CPU with seeded keys, both against the scalar
+OpenSSL oracle of `benchmarks/oracle.py`:
+
+* the translator alone with `launch_rows` set to a small class on the
+  instance (64 rows), at the ratios that matter: a tick of exactly the
+  top class, of one row more, of one and a half times, of twice and of
+  nine times it;
+* the bridge as `benchmarks/sut.py` assembles it (supervisor, lifecycle,
+  every endpoint through `request_join`): 128 endpoints in two
+  conferences of 64, whose ladder warms 16 / 64 / 256 rows, so its
+  fan-out is cut at 256 rows = 4 packets (the real classes' 65); ticks
+  of 1, 4, 5, 6, 9 and 37 packets stand for 1, 65, 66, 100, 131 and 584.
+  One module fixture serves the seeded rounds once; the tests each hold
+  one facet of its record.
+
+The real classes (4,096 rows) compile for a minute on XLA:CPU: ONE case
+runs them, marked `slow`.
+"""
+
+import importlib.util
+import os
+import socket
+
+import numpy as np
+import pytest
+
+import libjitsi_tpu
+from libjitsi_tpu.core.packet import ROW_CLASSES, PacketBatch, _round_rows
+from libjitsi_tpu.rtp import rtcp
+from libjitsi_tpu.sfu.translator import RtpTranslator
+from libjitsi_tpu.transform.srtp import SrtpProfile, SrtpStreamTable
+from libjitsi_tpu.utils.compile_cache import compile_stats
+from libjitsi_tpu.utils.tracing import LEAF_STAGES, PipelineTracer
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CM = SrtpProfile.AES_CM_128_HMAC_SHA1_80
+GCM = SrtpProfile.AEAD_AES_128_GCM
+SSRC_BASE = 0x64000000
+ROWS, CONF = 128, 64
+PT = 111
+#: the ladder of 128 endpoints at one packet a stream a tick
+WARMED = ROW_CLASSES[:3]
+#: packets of one tick, a round: 1 and 4 are one launch (4 x 63 = 252
+#: rows, the largest that is), 5 one row class more, 6 one and a half
+#: times the top class, 9 over twice, 37 over nine times it
+ROUNDS = (1, 4, 5, 6, 9, 37)
+
+
+@pytest.fixture(scope="module")
+def oracle():
+    spec = importlib.util.spec_from_file_location(
+        "bench_oracle", os.path.join(_ROOT, "benchmarks", "oracle.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _keys(seed: int, n: int, salt: int = 14) -> np.ndarray:
+    """[n, 2] (client->bridge, bridge->client) of (key 16, salt)."""
+    return np.random.default_rng([seed, 0x64]).integers(
+        0, 256, (n, 2, 16 + salt), dtype=np.uint8)
+
+
+def _pair(raw) -> tuple:
+    b = bytes(raw)
+    return b[:16], b[16:]
+
+
+def _plain(rng, ssrc: int, seq: int) -> bytes:
+    hdr = (bytes([0x80, PT]) + seq.to_bytes(2, "big")
+           + (seq * 960 & 0xFFFFFFFF).to_bytes(4, "big")
+           + ssrc.to_bytes(4, "big"))
+    return hdr + rng.integers(0, 256, int(rng.integers(40, 161)),
+                              dtype=np.uint8).tobytes()
+
+
+# ------------------------------------------------- the translator alone
+
+def _translator(profile, legs_of, top: int):
+    """A translator of 80 receivers whose sender `s` (sid 100 + s)
+    fans out to `legs_of[s]` receivers; `launch_rows` = `top`."""
+    salt = 12 if profile is GCM else 14
+    keys = _keys(7, 80, salt)
+    tr = RtpTranslator(capacity=256, profile=profile)
+    tr.add_receivers(range(80), [k[1][:16] for k in keys],
+                     [k[1][16:] for k in keys])
+    at = 0
+    for s, n in enumerate(legs_of):
+        # lists that differ from sender to sender: the per-row form
+        tr.connect(100 + s, [(at + j) % 80 for j in range(n)])
+        at += 3
+    tr.launch_rows = top
+    return tr, keys
+
+
+def _batch(rng, senders):
+    pls = [_plain(rng, 0x7000 + s, 900 + k)
+           for k, s in enumerate(senders)]
+    b = PacketBatch.from_payloads(pls, capacity=256,
+                                  stream=[100 + s for s in senders])
+    return b, pls, np.arange(900, 900 + len(senders), dtype=np.int64)
+
+
+#: (legs a sender, launches): rows = the sum; the top class is 64
+RATIOS = {
+    "exactly_the_top_class": ((63, 1), 1),
+    "one_row_more": ((63, 2), 2),
+    "one_and_a_half_times": ((63, 33), 2),
+    "twice": ((63, 63, 2), 2),
+    "nine_times": ((63,) * 9 + (9,), 9),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RATIOS))
+def test_rows_are_cut_at_the_top_class_and_come_back_in_order(case, oracle):
+    legs_of, launches = RATIOS[case]
+    rng = np.random.default_rng(11)
+    tr, keys = _translator(CM, legs_of, 64)
+    tr.tracer = tracer = PipelineTracer(annotate=False)
+    b, pls, index = _batch(rng, range(len(legs_of)))
+    pend = tr.translate_async(b, index)
+    assert pend.launches == launches
+    parts = list(pend.each())
+    assert [len(r) for _w, r in parts] == \
+        [64] * (launches - 1) + [sum(legs_of) - 64 * (launches - 1)]
+    wire, recv = pend.result()
+    assert wire.batch_size == sum(legs_of) == len(recv)
+    # a launch's rows are the whole's, in row order
+    assert np.array_equal(np.concatenate([r for _w, r in parts]), recv)
+    at = 0
+    for w, _r in parts:
+        for j in range(w.batch_size):
+            assert w.to_bytes(j) == wire.to_bytes(at + j)
+        at += w.batch_size
+    # every row is the oracle's bytes under its receiver's key
+    j = 0
+    for s, n in enumerate(legs_of):
+        for _ in range(n):
+            assert wire.to_bytes(j) == oracle.protect_cm(
+                *_pair(keys[int(recv[j]), 1]), pls[s], 900 + s), (case, j)
+            j += 1
+    tracer.take_ledger()
+    exp = tracer.last_counts["expand"]
+    assert exp["launches"] == launches and exp["legs_max"] == 63
+    assert exp["rows"] == sum(legs_of)
+    assert exp["rows_padded"] == 64 * (launches - 1) + _round_rows(
+        sum(legs_of) - 64 * (launches - 1))
+    assert tr.fanout_launches == launches
+    assert tr.fanout_split_ticks == int(launches > 1)
+
+
+def test_a_split_tick_is_byte_equal_to_the_same_packets_a_launch_each(
+        oracle):
+    """Five senders of 63 legs through one tick (five launches) and
+    through five ticks of one launch: the same rows, byte for byte."""
+    rng = np.random.default_rng(12)
+    tr, _keys_ = _translator(CM, (63,) * 5, 64)
+    b, _pls, index = _batch(rng, range(5))
+    whole, recv = tr.translate(b, index)
+    assert (tr.fanout_launches, tr.fanout_split_ticks) == (5, 1)
+    at = 0
+    for k in range(5):
+        one = PacketBatch(b.data[k:k + 1], b.length[k:k + 1],
+                          b.stream[k:k + 1])
+        assert tr.single_launch(1)
+        w, r = tr.translate(one, index[k:k + 1])
+        assert np.array_equal(r, recv[at:at + 63])
+        assert [w.to_bytes(j) for j in range(63)] == \
+            [whole.to_bytes(at + j) for j in range(63)]
+        at += 63
+    assert tr.fanout_split_ticks == 1
+
+
+def test_no_launch_has_a_shape_outside_the_classes(oracle):
+    """Whatever the rows, a launch's plane has a row class's rows, at
+    most `launch_rows`: the shapes the ladder warms and no other."""
+    rng = np.random.default_rng(13)
+    tr, _k = _translator(CM, (63,) * 5 + (9,), 64)
+    seen = []
+    call = tr._cm_fanout_call
+
+    def spy(recv, plane, *rest):
+        seen.append(plane.shape[0])
+        return call(recv, plane, *rest)
+
+    tr._cm_fanout_call = spy
+    for n in (1, 2, 6):
+        b, _pls, index = _batch(rng, range(n))
+        tr.translate(b, index)
+    assert seen and set(seen) <= {16, 64}
+    assert max(seen) == tr.launch_rows
+
+
+def test_gcm_conferences_of_8_split_and_open_under_the_oracle(oracle):
+    """Under the AEAD suite a tick of conferences of 8 (7 legs: the
+    per-row form) over the top class splits the same way."""
+    rng = np.random.default_rng(14)
+    tr, keys = _translator(GCM, (7,) * 10, 64)
+    b, pls, index = _batch(rng, range(10))
+    pend = tr.translate_async(b, index)
+    assert pend.launches == 2                 # 70 rows: 64 + 6
+    assert all(pg is None for _l, pg, _r, _n in pend._parts)
+    wire, recv = pend.result()
+    assert wire.batch_size == 70
+    for j in range(70):
+        s = j // 7
+        assert wire.to_bytes(j) == oracle.protect_gcm(
+            *_pair(keys[int(recv[j]), 1]), pls[s], 900 + s), j
+
+
+@pytest.mark.slow
+def test_the_real_classes_split_at_4096_rows(oracle):
+    """66 packets of 63 legs at the real top class: 4,158 rows in two
+    launches (4,096 + 62 -> 64), byte-equal to the oracle."""
+    rng = np.random.default_rng(15)
+    tr, keys = _translator(CM, (63,) * 66, ROW_CLASSES[-1])
+    b, pls, index = _batch(rng, range(66))
+    assert not tr.single_launch(66) and tr.single_launch(65)
+    pend = tr.translate_async(b, index)
+    assert pend.launches == 2
+    wire, recv = pend.result()
+    assert wire.batch_size == 66 * 63
+    for j in range(0, wire.batch_size, 7):
+        assert wire.to_bytes(j) == oracle.protect_cm(
+            *_pair(keys[int(recv[j]), 1]), pls[j // 63], 900 + j // 63)
+
+
+# ------------------------------------------------------ the served bridge
+
+def _serve(oracle, tap_of) -> dict:
+    """Admit ROWS endpoints in two conferences of 64 as `sut.py` does
+    and drive ROUNDS; the record."""
+    from libjitsi_tpu.service import lifecycle as lifecycle_mod
+    from libjitsi_tpu.service import supervisor as supervisor_mod
+    from libjitsi_tpu.service.sfu_bridge import SfuBridge
+
+    libjitsi_tpu.stop()
+    libjitsi_tpu.init()
+    bridge = SfuBridge(libjitsi_tpu.configuration_service(), port=0,
+                       capacity=ROWS, profile=CM, recv_window_ms=0)
+    reg = bridge.loop.metrics
+    sup = supervisor_mod.BridgeSupervisor(
+        bridge, supervisor_mod.SupervisorConfig(deadline_ms=60_000.0),
+        metrics=reg)
+    lc = lifecycle_mod.StreamLifecycleManager(
+        bridge, supervisor=sup,
+        config=lifecycle_mod.LifecycleConfig(
+            install_batch=64, max_pending=512, pkts_per_stream=1,
+            max_conference_size=CONF),
+        metrics=reg)
+    lc.enable_placement(1)
+    tap = tap_of(bridge)
+    keys = _keys(40, ROWS)
+    now = [2000.0]
+    rec = {"sent": {}, "rounds": [], "nack": None}
+    socks = []
+
+    def tick(n=1):
+        got = []
+        for _ in range(n):
+            now[0] += 0.02
+            sup.tick(now=now[0])
+            if "expand" in sup.last_counts:
+                got.append(({k: dict(v)
+                             for k, v in sup.last_counts.items()},
+                            dict(sup.last_ledger),
+                            dict(sup.last_self_ledger), sup.last_tick_s))
+        bridge.flush_egress()
+        return got
+
+    def drain():
+        out = []
+        for r, s in enumerate(socks):
+            while True:
+                try:
+                    pkt = s.recv(2048)
+                except BlockingIOError:
+                    break
+                if len(pkt) >= 12 and (pkt[1] & 0x7F) == PT:
+                    out.append((r, pkt))
+        return out
+
+    try:
+        for i in range(ROWS):
+            if i == CONF:
+                # the first room is full: its 65th member is refused,
+                # by name, while the table has room for 64 more
+                rec["refused"] = lc.request_join(
+                    SSRC_BASE + ROWS, _pair(keys[0, 0]),
+                    _pair(keys[0, 1]), conference=0)
+            ok, why = lc.request_join(SSRC_BASE + i, _pair(keys[i, 0]),
+                                      _pair(keys[i, 1]),
+                                      conference=i // CONF)
+            assert ok, why
+        while lc.admits < ROWS:
+            tick()
+            assert sup.ticks < 64, f"{lc.admits}/{ROWS} live"
+        rec["warm_rows"] = sorted(lc._warm_rows)
+        rec["launch_rows"] = bridge.translator.launch_rows
+        rec["rejected"] = dict(lc.admit_rejected)
+        sid_of = {ssrc: sid for sid, ssrc in bridge._ssrc_of.items()}
+        for i in range(ROWS):
+            s = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+            s.bind(("127.0.0.1", 0))
+            s.setblocking(False)
+            socks.append(s)
+        rng = np.random.default_rng(43)
+        seq = np.full(ROWS, 300, dtype=np.int64)
+
+        def fresh(i):
+            s = int(seq[i])
+            seq[i] += 1
+            plain = _plain(rng, SSRC_BASE + i, s)
+            rec["sent"][(SSRC_BASE + i, s)] = plain
+            return oracle.protect_cm(*_pair(keys[i, 0]), plain, s)
+
+        # the bridge learns a leg's address from its own packets: one
+        # member says how it keeps them, the others' are written as it
+        # would have (128 first packets are 8,064 fan-out rows)
+        socks[0].sendto(fresh(0), ("127.0.0.1", bridge.port))
+        tick(2)
+        loop = bridge.loop
+        ip = loop.addr_ip[sid_of[SSRC_BASE]]
+        assert loop.addr_port[sid_of[SSRC_BASE]] == \
+            socks[0].getsockname()[1]
+        for i in range(ROWS):
+            loop.addr_ip[sid_of[SSRC_BASE + i]] = ip
+            loop.addr_port[sid_of[SSRC_BASE + i]] = \
+                socks[i].getsockname()[1]
+        drain()
+        rec["sent"].clear()
+        tap.handed.clear()
+        events0 = compile_stats().compile_events
+        recompiles0 = lc.datapath_recompiles
+        for n in ROUNDS:
+            # senders of both conferences once a tick can hold both
+            senders = [(k * 5) % CONF + CONF * (k % 2 if n > 9 else 0)
+                       for k in range(n)]
+            assert len(set(senders)) == n
+            for i in senders:
+                socks[i].sendto(fresh(i), ("127.0.0.1", bridge.port))
+            ticks = tick(2)
+            assert len(ticks) == 1, "the round in one tick"
+            rec["rounds"].append({"packets": n, "senders": senders,
+                                  "tick": ticks[0], "got": drain()})
+        rec["compiles"] = compile_stats().compile_events - events0
+        rec["recompiles"] = lc.datapath_recompiles - recompiles0
+        # a row of the third round's SECOND launch (rows 256..314: the
+        # fifth packet's last receivers), asked for again
+        sender = rec["rounds"][2]["senders"][-1]
+        r = max(i for i in range(CONF) if i != sender)
+        lost = 300                      # that member's first packet
+        cl = SrtpStreamTable(capacity=1)
+        cl.add_stream(0, *_pair(keys[r, 0]))
+        blob = rtcp.build_compound([rtcp.build_nack(rtcp.Nack(
+            sender_ssrc=SSRC_BASE + r,
+            media_ssrc=SSRC_BASE + sender, lost_seqs=[lost]))])
+        socks[r].sendto(
+            cl.protect_rtcp(PacketBatch.from_payloads(
+                [blob], stream=[0])).to_bytes(0),
+            ("127.0.0.1", bridge.port))
+        for _ in range(3):
+            now[0] += 0.02
+            sup.tick(now=now[0])
+        bridge.flush_egress()
+        rec["nack"] = {"receiver": r, "sender": sender,
+                       "seq": lost, "got": drain(),
+                       "retransmitted": bridge.retransmitted}
+        rec["keys"] = keys
+        rec["health"] = sup.health()
+        rec["metrics"] = reg.render()
+        rec["handed"] = tap.handed
+        rec["ports"] = [s.getsockname()[1] for s in socks]
+        rec["translator"] = (bridge.translator.fanout_launches,
+                             bridge.translator.fanout_split_ticks)
+        return rec
+    finally:
+        for s in socks:
+            s.close()
+        bridge.close()
+
+
+@pytest.fixture(scope="module")
+def served(oracle, egress_tap):
+    return _serve(oracle, egress_tap)
+
+
+def _launches(packets: int) -> int:
+    return -(-packets * (CONF - 1) // WARMED[-1])
+
+
+def test_the_ladders_top_class_is_the_fanouts_bound(served):
+    assert served["warm_rows"] == list(WARMED)
+    assert served["launch_rows"] == WARMED[-1]
+
+
+@pytest.mark.parametrize("k", range(len(ROUNDS)))
+def test_every_delivery_once_under_its_receivers_key(served, oracle, k):
+    """(a) each packet reaches each of the 63 others of its conference
+    exactly once, as the oracle's bytes under that receiver's key, and
+    nobody else."""
+    rnd, keys = served["rounds"][k], served["keys"]
+    got = {}
+    for r, pkt in rnd["got"]:
+        key = (r, int.from_bytes(pkt[8:12], "big"),
+               int.from_bytes(pkt[2:4], "big"))
+        assert key not in got, f"delivered twice: {key}"
+        got[key] = pkt
+    assert len(got) == rnd["packets"] * (CONF - 1)
+    for (r, ssrc, seq), pkt in got.items():
+        mk, ms = _pair(keys[r, 1])
+        plain = oracle.unprotect_cm(mk, ms, pkt, seq)
+        assert plain is not None, f"bad tag for receiver {r}"
+        sent = served["sent"][(ssrc, seq)]
+        # the bridge stamps abs-send-time: header past the X bit and the
+        # whole payload are the sender's
+        assert plain[1:12] == sent[1:12]
+        assert plain[oracle.payload_off(plain):] == sent[12:]
+        # and the wire bytes are the oracle's over that plaintext
+        assert oracle.protect_cm(mk, ms, plain, seq) == pkt
+        assert (ssrc - SSRC_BASE) // CONF == r // CONF
+        assert ssrc - SSRC_BASE != r
+        assert ssrc - SSRC_BASE in rnd["senders"]
+
+
+def test_per_socket_order_is_the_hand_overs(served):
+    """(a) every socket receives what was handed over for its port, in
+    that order, launch after launch."""
+    got = [x for rnd in served["rounds"] for x in rnd["got"]]
+    assert len(got) == len(served["handed"]) == sum(ROUNDS) * (CONF - 1)
+    for r, port in enumerate(served["ports"]):
+        # (the retransmission leaves by the synchronous call, untapped)
+        assert [p for rr, p in got if rr == r] == \
+            [p for to, p in served["handed"] if to == port]
+
+
+def test_nothing_compiles_whatever_the_backlog(served):
+    """(b) ticks of 1 to 37 packets (one to ten launches) after the
+    ladder: no compile event, no data-path recompile, nothing shed."""
+    assert [r["packets"] for r in served["rounds"]] == list(ROUNDS)
+    assert served["compiles"] == 0 and served["recompiles"] == 0
+    h = served["health"]
+    assert not h["shed"] and not h["quarantined"]
+
+
+def test_a_nack_for_a_row_of_the_second_launch_is_answered(served):
+    """(c) the cache holds a slab a launch: the retransmission is the
+    delivery's bytes."""
+    n = served["nack"]
+    first = [p for rnd in served["rounds"] for r, p in rnd["got"]
+             if r == n["receiver"]
+             and int.from_bytes(p[8:12], "big") == SSRC_BASE + n["sender"]
+             and int.from_bytes(p[2:4], "big") == n["seq"]]
+    assert len(first) == 1
+    assert n["retransmitted"] == 1
+    assert [p for r, p in n["got"] if r == n["receiver"]] == first
+
+
+@pytest.mark.parametrize("k", range(len(ROUNDS)))
+def test_spans_a_launch_and_the_leaves_still_tile_the_tick(served, k):
+    """(f) `launches` on `expand`; one `fanout_dispatch` / `fanout_wait`
+    / `fanout_d2h` / `nack_cache` / `egress` a launch, each saying which
+    where the tick has several; the leaves still cover the tick."""
+    rnd = served["rounds"][k]
+    counts, led, self_led, tick_s = rnd["tick"]
+    n = _launches(rnd["packets"])
+    rows = rnd["packets"] * (CONF - 1)
+    exp = counts["expand"]
+    assert exp["launches"] == n and exp["legs_max"] == CONF - 1
+    assert exp["rows"] == rows
+    assert exp["rows_padded"] == WARMED[-1] * (n - 1) + _round_rows(
+        rows - WARMED[-1] * (n - 1))
+    # one packed plane each way a launch
+    assert counts["fanout_dispatch"]["h2d_arrays"] == n
+    assert counts["fanout_put"]["h2d_arrays"] == n
+    assert counts["fanout_d2h"]["d2h_arrays"] == n
+    assert counts["egress"]["queued"] == n
+    assert counts["nack_cache"]["rows"] == counts["egress"]["rows"] == rows
+    for stage in ("fanout_dispatch", "fanout_wait", "fanout_d2h",
+                  "nack_cache", "egress"):
+        # the ordinals 0..n-1 summed; a tick's only launch books none
+        assert counts.get(stage, {}).get("launch", 0) == n * (n - 1) // 2
+    leaves = sum(led.get(s, 0.0) for s in LEAF_STAGES
+                 if s not in ("supervise", "gc"))
+    assert leaves <= tick_s and leaves > 0.9 * tick_s
+    # self times sum to the time inside the outermost spans: every
+    # launch's wait, copy back, cache insert and hand-over lie inside
+    # `reverse_chain` like the rest of the fan-out (a collection that
+    # lands between two outermost spans is one of its own)
+    outside = sum(self_led.values()) - (
+        led["ingress"] + led["demux"] + led["reverse_chain"]
+        + led["supervise"])
+    assert -1e-6 <= outside <= led.get("gc", 0.0) + 1e-6
+
+
+def test_metrics_count_launches_and_split_ticks(served):
+    launches, split = served["translator"]
+    # + the latch tick's one launch
+    assert launches == 1 + sum(_launches(p) for p in ROUNDS)
+    assert split == sum(_launches(p) > 1 for p in ROUNDS)
+    text = served["metrics"]
+    assert f"fanout_launches_total {launches}" in text
+    assert f"fanout_split_ticks_total {split}" in text
+    assert 'lifecycle_admit_rejected{reason="conference_full"} 1' in text
+
+
+# ------------------------------------------------------------ the room cap
+
+def test_the_65th_member_is_refused_by_name(served):
+    """(g) `max_conference_size` 64: the room's 65th join."""
+    from libjitsi_tpu.service.lifecycle import ADMIT_REASONS
+
+    assert served["refused"] == (False, "conference_full")
+    assert "conference_full" in ADMIT_REASONS
+    assert served["rejected"] == {"conference_full": 1}
+
+
+@pytest.mark.parametrize("cap,fourth", [(3, (False, "conference_full")),
+                                        (0, (True, "queued")),
+                                        (4, (True, "queued"))])
+def test_room_cap_is_an_admission_rule(cap, fourth):
+    """A cap of 3 refuses the fourth member of a room and nobody of
+    another room; 0 states nothing."""
+    from libjitsi_tpu.service.lifecycle import (LifecycleConfig,
+                                                StreamLifecycleManager)
+    from libjitsi_tpu.service.sfu_bridge import SfuBridge
+
+    libjitsi_tpu.stop()
+    libjitsi_tpu.init()
+    bridge = SfuBridge(libjitsi_tpu.configuration_service(), port=0,
+                       capacity=16, recv_window_ms=0)
+    try:
+        lc = StreamLifecycleManager(
+            bridge, config=LifecycleConfig(max_conference_size=cap))
+        lc.enable_placement(1)
+        keys = _keys(3, 8)
+        answers = [lc.request_join(0x900 + i, _pair(keys[i, 0]),
+                                   _pair(keys[i, 1]), conference=5)
+                   for i in range(4)]
+        assert answers[:3] == [(True, "queued")] * 3
+        assert answers[3] == fourth
+        assert lc.request_join(0x910, _pair(keys[4, 0]), _pair(keys[4, 1]),
+                               conference=6) == (True, "queued")
+    finally:
+        bridge.close()
+
+
+def test_a_program_without_the_room_cap_refuses_the_configuration():
+    """The parent's `LifecycleConfig` (its fields, copied) does not know
+    `max_conference_size`: handed the configuration file's `lifecycle`
+    group as `benchmarks/sut.py` hands it over, it raises at once."""
+    import dataclasses
+    import json
+
+    from libjitsi_tpu.service.lifecycle import LifecycleConfig
+
+    with open(os.path.join(_ROOT, "benchmarks", "configs",
+                           "audio-sfu-cm-10k-conf64.json")) as f:
+        group = json.load(f)["lifecycle"]
+    assert group["max_conference_size"] == 64
+    assert LifecycleConfig(**group).max_conference_size == 64
+    parent = dataclasses.make_dataclass("ParentLifecycleConfig", [
+        (f.name, f.type, dataclasses.field(default=f.default))
+        for f in dataclasses.fields(LifecycleConfig)
+        if f.name != "max_conference_size"])
+    with pytest.raises(TypeError, match="max_conference_size"):
+        parent(**group)
