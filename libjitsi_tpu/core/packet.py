@@ -116,6 +116,12 @@ class PacketBatch:
 # which is SRTP-state-safe: a duplicate packet index leaves the
 # per-stream max unchanged on protect and dies in replay dedup on
 # unprotect; callers drop rows >= n_real.
+#
+# A call's rows pad to ONE class here (`_round_rows`), so 1,025 rows cost
+# what 4,096 cost.  The SFU fan-out, whose rows are packets x receivers,
+# does not leave it at that: it cuts a tick's rows into launches of these
+# same classes where several small launches pad less than one large one
+# (sfu/translator.py `plan_launches`), each cut then padded to its class.
 # ---------------------------------------------------------------------------
 
 LENGTH_CLASSES = (192, 512, DEFAULT_CAPACITY)

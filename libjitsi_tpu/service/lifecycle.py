@@ -1058,9 +1058,12 @@ class StreamLifecycleManager:
 
     def _bound_fanout(self) -> None:
         """The translator cuts a tick's fan-out rows into launches of
-        at most the largest row class warmed here (`launch_rows`;
-        `ROW_CLASSES[-1]` once the ladder is whole): a backlog tick of
-        a small population stays inside what its ladder compiled too."""
+        the row classes warmed here, none over the largest of them
+        (`launch_rows`; `ROW_CLASSES[-1]` once the ladder is whole): a
+        backlog tick of a small population stays inside what its
+        ladder compiled too.  Both ladders warm every class up to
+        their cover, so the classes under `launch_rows` are warm and
+        `translator.plan_launches` may cut a tick by them."""
         tr = getattr(self.bridge, "translator", None)
         warmed = self._warm_rows | self._warm_lrows
         if warmed and hasattr(tr, "launch_rows"):

@@ -999,14 +999,16 @@ class SfuBridge:
                     (pend, self.loop.journey_origin()))
             return None
         with tracer.span("forward_chain"):
-            if tr.single_launch(sub.batch_size):
-                # one launch whatever the routes: the call whole
+            if tr.launches(sub.stream) <= 1:
+                # the translator's plan makes one launch of the tick's
+                # rows: the call whole, as it always was
                 parts, several = [tr.translate(sub, idx_sel)], False
             else:
-                # the rows may outgrow the largest warmed class: every
-                # launch is dispatched here; each is waited for, cached
-                # and handed over in turn below, so the egress worker
-                # sends launch 1 while launch 2 is on the device
+                # the plan cuts them (they outgrow the largest warmed
+                # class, or pad less as launches of a smaller one):
+                # every launch is dispatched here; each is waited for,
+                # cached and handed over in turn below, so the egress
+                # worker sends launch 1 while launch 2 is on the device
                 pend = tr.translate_async(sub, idx_sel)
                 parts, several = pend.each(), pend.launches > 1
         self._emit_launches(parts, several)

@@ -799,14 +799,23 @@ class BridgeSupervisor:
             registry.register_scalar(
                 "fanout_launches_total",
                 lambda: self.bridge.translator.fanout_launches,
-                help_="device calls the fan-out has made (one a tick "
-                      "inside the largest warmed row class)",
+                help_="device calls the fan-out has made (one in "
+                      "most ticks)",
                 kind="counter")
             registry.register_scalar(
                 "fanout_split_ticks_total",
                 lambda: self.bridge.translator.fanout_split_ticks,
-                help_="ticks whose fan-out rows outgrew the largest "
-                      "warmed row class and went out in several launches",
+                help_="ticks whose fan-out rows went out in several "
+                      "launches: they outgrew the largest warmed row "
+                      "class, or fitted it and were cut by the classes "
+                      "under it (fanout_class_cut_ticks_total)",
+                kind="counter")
+            registry.register_scalar(
+                "fanout_class_cut_ticks_total",
+                lambda: self.bridge.translator.fanout_class_cut_ticks,
+                help_="ticks whose fan-out rows fitted the largest "
+                      "warmed row class and went out as launches of "
+                      "smaller classes, which padded less",
                 kind="counter")
         if hasattr(self.bridge, "_video"):
             # simulcast/SVC forwarders are per-receiver objects; export

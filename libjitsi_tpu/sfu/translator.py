@@ -21,6 +21,7 @@ from __future__ import annotations
 from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 import functools
+import itertools
 
 import jax
 import jax.numpy as jnp
@@ -79,6 +80,41 @@ def _cycle_rows(n: int) -> Optional[np.ndarray]:
     already sits on a class boundary."""
     n_pad = _round_rows(n)
     return np.resize(np.arange(n), n_pad) if n_pad > n else None
+
+
+#: what a launch after a tick's first costs the tick thread, in rows of
+#: device time.  Measured on the chip with scripts/launch_cost.py
+#: (PERF.md section 3 names the run): its `fanout_dispatch` +
+#: `fanout_wait` + `fanout_d2h` + `nack_cache` + `egress` read 1.31 ms
+#: (quartiles 1.23-1.39) over the 1,024-row program's 1.786 us a row =
+#: 734 rows (686-775).  Kept at 752 = 1,024 - 256 - 16, inside those
+#: quartiles: the least value at which no tick of at most 1,024 rows is
+#: cut (under it ticks of 257-272 rows go out as 256 + 16, which the
+#: GCM cell, beside the parent, did not read better for)
+LAUNCH_COST_ROWS = 752
+
+
+def plan_launches(rows: int, top: int,
+                  launch_cost: Optional[int] = None) -> List[int]:
+    """The real rows of each launch of a tick of `rows` fan-out rows,
+    in row order; every launch is padded to its own row class, and the
+    classes up to `top` (`RtpTranslator.launch_rows`) are the warmed
+    ones.  Whole launches of `top` rows go first while more than `top`
+    remain.  What is left goes out as one launch in its covering class
+    or, for a class c under that, as whole launches of c rows and the
+    remainder in the remainder's own class: whichever pads least,
+    where a launch after the first counts `launch_cost` rows (fewer
+    launches on a tie).  1,164 rows are 1,024 + 140 (padded 1,024 +
+    256) and not one launch of 4,096.  `launch_cost` None: cuts at
+    `top` alone (a translator that pads nothing here)."""
+    whole, tail = divmod(rows, top)
+    if not tail or launch_cost is None:
+        return [top] * whole + [tail] * bool(tail)
+    plans = [[tail]] + [[c] * (tail // c) + [tail % c] * bool(tail % c)
+                        for c in ROW_CLASSES if c < _round_rows(tail)]
+    return [top] * whole + min(plans, key=lambda cut: (
+        sum(map(_round_rows, cut)) + launch_cost * (len(cut) - 1),
+        len(cut)))
 
 
 @functools.partial(jax.jit,
@@ -164,17 +200,21 @@ class RtpTranslator:
         # the longest receiver list ever connected: what `_gcm_leg_major`
         # can select here, hence what `fanout_warmups` warms
         self._max_legs = 0
-        #: rows of the largest per-row fan-out launch, a row class: a
-        #: tick with more rows is cut into several launches
-        #: (`translate_async`).  The lifecycle's warm ladder lowers it
-        #: to the largest class it has compiled while that is not yet
-        #: the top one (`_bound_fanout`); no option sets it
+        #: rows of the largest per-row fan-out launch, a row class: no
+        #: launch is larger, and the classes up to it are the shapes a
+        #: tick's rows are cut into (`translate_async`,
+        #: `plan_launches`).  The lifecycle's warm ladder lowers it to
+        #: the largest class it has compiled while that is not yet the
+        #: top one (`_bound_fanout`); no option sets it
         self.launch_rows = ROW_CLASSES[-1]
-        # device calls the fan-out has made, and ticks it cut in two or
-        # more (/metrics: `fanout_launches_total`,
-        # `fanout_split_ticks_total`)
+        # device calls the fan-out has made, ticks it cut in two or
+        # more, and those of them that fit `launch_rows` and were cut
+        # by the row classes under it (/metrics:
+        # `fanout_launches_total`, `fanout_split_ticks_total`,
+        # `fanout_class_cut_ticks_total`)
         self.fanout_launches = 0
         self.fanout_split_ticks = 0
+        self.fanout_class_cut_ticks = 0
         # the bridge hands its loop's PipelineTracer and PhaseProfiler
         # here; a translator standing alone spans and samples nothing
         self.tracer = None
@@ -404,13 +444,26 @@ class RtpTranslator:
         pend = self.translate_async(batch, index)
         return pend.result()
 
-    def single_launch(self, packets: int) -> bool:
-        """True where `packets` packets fan out in ONE launch whatever
-        their routes: even at the longest receiver list ever connected
-        their rows fit `launch_rows`.  Such a tick is `translate`'s,
-        whole; a longer one comes back a launch at a time
+    def _plan(self, rows: int) -> List[int]:
+        """`plan_launches` of `rows` fan-out rows for this translator:
+        by the row classes up to `launch_rows` where it pads its rows
+        to them here, at `launch_rows` alone where it does not (the
+        mesh's: `_OwnerPlan` pads lanes a chip, and a launch there
+        costs twice a one-chip launch)."""
+        return plan_launches(
+            rows, self.launch_rows,
+            LAUNCH_COST_ROWS if self._pads_rows else None)
+
+    def launches(self, stream) -> int:
+        """How many launches `translate_async` cuts a tick of these
+        senders' packets into: the plan's answer for their real rows
+        (each packet's own receiver list, not the longest ever
+        connected).  At most one: the tick is `translate`'s, whole;
+        more: it comes back a launch at a time
         (`translate_async(...).each()`)."""
-        return packets * self._max_legs <= self.launch_rows
+        routes = self._routes
+        return len(self._plan(sum(
+            len(routes.get(s, ())) for s in np.asarray(stream).tolist())))
 
     def translate_async(self, batch: PacketBatch, index: np.ndarray
                         ) -> "PendingTranslate":
@@ -419,13 +472,20 @@ class RtpTranslator:
         — the SFU's pipelined tick overlaps the launch with its next
         recv window.
 
-        The (packet, receiver) rows are cut into launches of at most
-        `launch_rows` rows, the largest row class the warm ladder
-        compiles: whatever the conference size and the backlog, no
-        launch has a shape the ladder did not warm.  A tick inside one
-        class is one launch, as it always was.  Every launch of a tick
-        is dispatched before the first is waited for, and they come
-        back in row order."""
+        The (packet, receiver) rows are cut into launches by the row
+        classes the warm ladder compiled (`plan_launches`): none over
+        `launch_rows`, the largest of them, so whatever the conference
+        size and the backlog no launch has a shape the ladder did not
+        warm; and a tick that fits `launch_rows` but would pad far up
+        to its class goes out as whole launches of a smaller class and
+        a remainder in its own (1,164 rows: 1,024 + 256 computed, not
+        4,096), where that saves more rows than `LAUNCH_COST_ROWS` a
+        further launch.  `pend.launches` says how many; most ticks are
+        one launch, as they always were.  Every launch of a tick is
+        dispatched before the first is waited for, and they come back
+        in row order.  A translator that does not pad rows here (the
+        mesh's, `_pads_rows` False) cuts at `launch_rows` alone
+        (`_plan`)."""
         tracer = self.tracer
         stream = np.asarray(batch.stream, dtype=np.int64)
         index = np.asarray(index, dtype=np.int64)
@@ -469,10 +529,14 @@ class RtpTranslator:
                 rowv = (src, recv, length, hdr.payload_off[src],
                         hdr.ssrc[src], idx)
                 top = self.launch_rows
-                cuts = [(a, min(a + top, len(recv)))
-                        for a in range(0, len(recv), top)]
+                sizes = self._plan(len(recv))
+                cuts = [(b - n, b) for n, b in
+                        zip(sizes, itertools.accumulate(sizes))]
+                # the row classes cut a tick that fits the largest
+                class_cut = int(len(cuts) > 1 and len(recv) <= top)
                 sp.note(rows=len(recv), width=pw, launches=len(cuts),
-                        legs_max=int(counts.max()), rows_padded=sum(
+                        legs_max=int(counts.max()), class_cut=class_cut,
+                        rows_padded=sum(
                             _round_rows(b - a) if self._pads_rows
                             else b - a for a, b in cuts))
                 args = self._expand_rows(batch, rowv, pw, *cuts[0])
@@ -500,6 +564,7 @@ class RtpTranslator:
             parts.append((launch, None, recv[a:b], nth))
         self.fanout_launches += len(parts)
         self.fanout_split_ticks += int(len(parts) > 1)
+        self.fanout_class_cut_ticks += class_cut
         return PendingTranslate(parts, batch.capacity, tracer=tracer,
                                 perf=self.perf)
 
@@ -628,7 +693,7 @@ class RtpTranslator:
                 pssrc[None, :], pidx[None, :])
             sp.note(rows=g_real * p_real,
                     rows_padded=len(rr_p) * len(pr), width=pw,
-                    launches=1, legs_max=g_real)
+                    launches=1, legs_max=g_real, class_cut=0)
         with staging.dispatch(tracer, "fanout") as sp, \
                 phase_of(perf, "dispatch"):
             # the output is leg-major [G, P, W] at the class-PADDED

@@ -306,9 +306,11 @@ def test_served_path_never_times_providers(served):
         == c["expand"]["rows_padded"] * tr_mod.GM_BYTES
     assert c["expand"]["rows"] <= c["expand"]["rows_padded"]
     # every tick: one packed plane in and one back for each launch
-    # (one size class, so one unprotect launch a tick)
+    # (one size class, so one unprotect launch a tick; no tick of
+    # these, all under 1,024 rows, is cut by the row classes)
     assert all(a in ((1, 1, 1, 1), (1, 1, None, None))
                for a in served["arrays"]), served["arrays"]
+    assert c["expand"]["class_cut"] == 0
     u, f = c["unprotect_wait"], c["fanout_dispatch"]
     plane = 224 + staging.TAIL
     assert u["h2d_bytes"] == u["d2h_bytes"] == u["rows_padded"] * plane
@@ -704,4 +706,4 @@ def test_packed_gcm_fanout_one_array_each_way(warmed_launch_guard,
     assert counts["fanout_d2h"] == {"d2h_arrays": 1, "d2h_bytes": plane}
     assert counts["expand"] == {"rows": 12, "rows_padded": 16,
                                 "width": 224, "launches": 1,
-                                "legs_max": 3}
+                                "legs_max": 3, "class_cut": 0}

@@ -1,29 +1,39 @@
-"""Meetings of 64: a fan-out the program bounds in ROWS, not packets.
+"""Meetings of 64: a fan-out the program bounds in ROWS, not packets,
+and cuts by the row classes it has.
 
 A packet of a member of a 64-member conference is 63 fan-out rows, so a
 tick's rows outgrow the largest row class the warm ladder compiles
 within a few dozen packets.  `RtpTranslator.translate_async` cuts the
-(packet, receiver) rows into launches of at most `launch_rows` rows and
-`SfuBridge` caches and hands over a launch at a time; no launch has a
-shape the ladder did not warm, whatever the backlog.
+(packet, receiver) rows into launches of the warmed row classes
+(`plan_launches`): none over `launch_rows`, and a tick that fits it but
+would pad far up to its class goes out as whole launches of a smaller
+class and a remainder in its own (1,164 rows: 1,024 + 256 computed, not
+4,096) where that saves more padded rows than `LAUNCH_COST_ROWS` a
+further launch.  `SfuBridge` caches and hands over a launch at a time;
+no launch has a shape the ladder did not warm, whatever the backlog.
 
-Two layers, both on the CPU with seeded keys, both against the scalar
-OpenSSL oracle of `benchmarks/oracle.py`:
+Three layers, on the CPU with seeded keys, the last two against the
+scalar OpenSSL oracle of `benchmarks/oracle.py`:
 
+* the plan as a pure function, over a table of row counts against
+  hand-written cuts at the real classes and the shipped constant;
 * the translator alone with `launch_rows` set to a small class on the
   instance (64 rows), at the ratios that matter: a tick of exactly the
   top class, of one row more, of one and a half times, of twice and of
-  nine times it;
+  nine times it; and, with the launch cost set to a few rows on the
+  module, ticks that the classes under `launch_rows` cut;
 * the bridge as `benchmarks/sut.py` assembles it (supervisor, lifecycle,
   every endpoint through `request_join`): 128 endpoints in two
   conferences of 64, whose ladder warms 16 / 64 / 256 rows, so its
-  fan-out is cut at 256 rows = 4 packets (the real classes' 65); ticks
-  of 1, 4, 5, 6, 9 and 37 packets stand for 1, 65, 66, 100, 131 and 584.
-  One module fixture serves the seeded rounds once; the tests each hold
-  one facet of its record.
+  fan-out is cut at 256 rows = 4 packets (the real classes' 65) and,
+  the launch cost set to 8 rows, by the 64- and 16-row classes under
+  it; ticks of 1, 4, 5, 6, 9 and 37 packets stand for 1, 65, 66, 100,
+  131 and 584, ticks of 2 and 3 for the 1,164 rows that fit the top
+  class and pad less as 64 + 64 (+ 64).  One module fixture serves the
+  seeded rounds once; the tests each hold one facet of its record.
 
-The real classes (4,096 rows) compile for a minute on XLA:CPU: ONE case
-runs them, marked `slow`.
+The real classes (4,096 rows) compile for a minute on XLA:CPU: two
+cases run them, marked `slow`.
 """
 
 import importlib.util
@@ -36,7 +46,9 @@ import pytest
 import libjitsi_tpu
 from libjitsi_tpu.core.packet import ROW_CLASSES, PacketBatch, _round_rows
 from libjitsi_tpu.rtp import rtcp
-from libjitsi_tpu.sfu.translator import RtpTranslator
+from libjitsi_tpu.sfu import translator as translator_mod
+from libjitsi_tpu.sfu.translator import (LAUNCH_COST_ROWS, RtpTranslator,
+                                         plan_launches)
 from libjitsi_tpu.transform.srtp import SrtpProfile, SrtpStreamTable
 from libjitsi_tpu.utils.compile_cache import compile_stats
 from libjitsi_tpu.utils.tracing import LEAF_STAGES, PipelineTracer
@@ -49,10 +61,20 @@ ROWS, CONF = 128, 64
 PT = 111
 #: the ladder of 128 endpoints at one packet a stream a tick
 WARMED = ROW_CLASSES[:3]
-#: packets of one tick, a round: 1 and 4 are one launch (4 x 63 = 252
-#: rows, the largest that is), 5 one row class more, 6 one and a half
-#: times the top class, 9 over twice, 37 over nine times it
-ROUNDS = (1, 4, 5, 6, 9, 37)
+#: rows a launch after a tick's first costs while the bridge below
+#: serves (the module's `LAUNCH_COST_ROWS` is sized for the real
+#: classes: under it nothing at or below 256 rows is ever cut)
+COST = 8
+#: packets of one tick, a round -> the real rows of its launches, by
+#: hand (a packet is 63 rows; classes 16 / 64 / 256, a further launch 8
+#: rows): 1 and 4 are one launch (252 rows pad less as 256 than as 4 x
+#: 64 + 3 x 8), 2 and 3 fit the top class and are cut by the 64-row
+#: class (128 + 8 and 192 + 16 against 256), 5 is one row class more, 6
+#: one and a half times the top class with a tail the classes cut, 9
+#: over twice, 37 over nine times it with a tail of 27 rows as 16 + 11
+SIZES = {1: [63], 2: [64, 62], 3: [64, 64, 61], 4: [252], 5: [256, 59],
+         6: [256, 64, 58], 9: [256, 256, 55], 37: [256] * 9 + [16, 11]}
+ROUNDS = tuple(SIZES)
 
 
 @pytest.fixture(scope="module")
@@ -83,6 +105,75 @@ def _plain(rng, ssrc: int, seq: int) -> bytes:
                               dtype=np.uint8).tobytes()
 
 
+# -------------------------------------------------------------- the plan
+
+#: rows of a tick -> the real rows of its launches at the real classes
+#: (16 / 64 / 256 / 1,024 / 4,096, all warmed) and the shipped launch
+#: cost (752 rows), written by hand: up to 1,024 rows a tick is one
+#: launch, byte for byte what it was (256 + 16 + 752 = 1,024 is a tie,
+#: and a tie is fewer launches); 1,025-2,304 rows pad less as one or
+#: two 1,024-row launches and a small one than as 4,096; above that the
+#: 4,096-row program runs as before, and whole launches of it go first
+PLAN = {
+    256: [256], 257: [257], 272: [272], 700: [700], 1024: [1024],
+    1025: [1024, 1], 1164: [1024, 140], 2048: [1024, 1024],
+    2112: [1024, 1024, 64], 2400: [2400], 4096: [4096],
+    4097: [4096, 1], 36792: [4096] * 8 + [4024],
+}
+
+
+@pytest.mark.parametrize("rows", sorted(PLAN))
+def test_the_plan_against_hand_written_cuts(rows):
+    assert plan_launches(rows, ROW_CLASSES[-1], LAUNCH_COST_ROWS) == \
+        PLAN[rows]
+    # a translator that pads nothing here (the mesh's) cuts at the top
+    # class alone, as every translator did
+    assert plan_launches(rows, ROW_CLASSES[-1]) == \
+        [ROW_CLASSES[-1]] * (rows // ROW_CLASSES[-1]) + \
+        [rows % ROW_CLASSES[-1]] * bool(rows % ROW_CLASSES[-1])
+
+
+@pytest.mark.parametrize("top", ROW_CLASSES[1:])
+@pytest.mark.parametrize("cost", [0, 8, LAUNCH_COST_ROWS, 10 ** 6])
+def test_no_planned_launch_lies_outside_the_warmed_classes(top, cost):
+    """On a ladder warmed up to `top` (whole or partly), whatever the
+    rows and the launch cost: launches in row order that sum to the
+    rows, none over `top` (so each pads to a warmed class), whole
+    launches first, and never more padded rows plus launch costs than
+    cuts at `top` alone."""
+    def padded(cut):
+        return sum(map(_round_rows, cut)) + cost * (len(cut) - 1)
+
+    for rows in [*range(1, 600), 1024, 1025, 1164, 2304, 2305, 4095,
+                 4097, 5260, 9 * top + 27, 36792]:
+        cut = plan_launches(rows, top, cost)
+        assert sum(cut) == rows and min(cut) > 0, (rows, cut)
+        assert max(map(_round_rows, cut)) <= top, (rows, cut)
+        assert cut[:rows // top] == [top] * (rows // top), (rows, cut)
+        # full launches of one class, then at most one remainder
+        tail = cut[rows // top:]
+        assert len(set(tail[:-1])) <= 1 and all(
+            n == _round_rows(n) for n in tail[:-1]), (rows, cut)
+        assert padded(cut) <= padded(plan_launches(rows, top)), (rows, cut)
+    # at a launch cost beyond any padding nothing under `top` is cut
+    if cost >= 10 ** 6:
+        assert plan_launches(top - 1, top, cost) == [top - 1]
+
+
+def test_the_shipped_launch_cost_cuts_no_tick_of_1024_rows_or_fewer():
+    """At 752 rows (1,024 - 256 - 16), which the shipped constant is,
+    every tick of the `talk-*` cells and of GCM (160-700 rows) stays
+    one launch, as before; one row less and ticks of 257-272 rows (37
+    and 38 packets of a conference of 8) would go out as 256 + 16."""
+    assert LAUNCH_COST_ROWS == 752
+    for top in ROW_CLASSES:
+        assert all(plan_launches(r, top, LAUNCH_COST_ROWS)
+                   == plan_launches(r, top) for r in range(1, 1025))
+    assert [r for r in range(1, 1025)
+            if len(plan_launches(r, ROW_CLASSES[-1], 751)) > 1] \
+        == list(range(257, 273))
+
+
 # ------------------------------------------------- the translator alone
 
 def _translator(profile, legs_of, top: int):
@@ -110,30 +201,54 @@ def _batch(rng, senders):
     return b, pls, np.arange(900, 900 + len(senders), dtype=np.int64)
 
 
-#: (legs a sender, launches): rows = the sum; the top class is 64
+#: (legs a sender, `launch_rows`, launch cost or None for the shipped
+#: one, the real rows of each launch): rows = the sum of the legs.  At
+#: the shipped cost a top class of 64 is cut at 64 alone; at a cost of
+#: 8 rows the classes under the top one cut the tick (or its tail)
 RATIOS = {
-    "exactly_the_top_class": ((63, 1), 1),
-    "one_row_more": ((63, 2), 2),
-    "one_and_a_half_times": ((63, 33), 2),
-    "twice": ((63, 63, 2), 2),
-    "nine_times": ((63,) * 9 + (9,), 9),
+    "exactly_the_top_class": ((63, 1), 64, None, [64]),
+    "one_row_more": ((63, 2), 64, None, [64, 1]),
+    "one_and_a_half_times": ((63, 33), 64, None, [64, 32]),
+    "twice": ((63, 63, 2), 64, None, [64, 64]),
+    "nine_times": ((63,) * 9 + (9,), 64, None, [64] * 9),
+    "fits_the_top_class_and_is_cut": ((63, 9), 256, 8, [64, 8]),
+    "three_whole_launches_and_a_small_one":
+        ((63, 63, 63, 9), 256, 8, [64, 64, 64, 6]),
+    "over_the_top_class_with_a_tail_that_is_cut":
+        ((63, 30), 64, 8, [64, 16, 13]),
 }
 
 
 @pytest.mark.parametrize("case", sorted(RATIOS))
-def test_rows_are_cut_at_the_top_class_and_come_back_in_order(case, oracle):
-    legs_of, launches = RATIOS[case]
+def test_rows_are_cut_by_the_classes_and_come_back_in_order(
+        case, oracle, monkeypatch):
+    legs_of, top, cost, sizes = RATIOS[case]
+    if cost is not None:
+        monkeypatch.setattr(translator_mod, "LAUNCH_COST_ROWS", cost)
+    launches, rows = len(sizes), sum(legs_of)
+    assert sum(sizes) == rows
     rng = np.random.default_rng(11)
-    tr, keys = _translator(CM, legs_of, 64)
+    tr, keys = _translator(CM, legs_of, top)
     tr.tracer = tracer = PipelineTracer(annotate=False)
+    seen = []
+    call = tr._cm_fanout_call
+
+    def spy(recv, plane, *rest):
+        seen.append(plane.shape[0])
+        return call(recv, plane, *rest)
+
+    tr._cm_fanout_call = spy
     b, pls, index = _batch(rng, range(len(legs_of)))
+    # the plan's answer before the call is the call's
+    assert tr.launches(b.stream) == launches
     pend = tr.translate_async(b, index)
     assert pend.launches == launches
+    # every launch's plane has its own class's rows, none over the top
+    assert seen == [_round_rows(n) for n in sizes] and max(seen) <= top
     parts = list(pend.each())
-    assert [len(r) for _w, r in parts] == \
-        [64] * (launches - 1) + [sum(legs_of) - 64 * (launches - 1)]
+    assert [len(r) for _w, r in parts] == sizes
     wire, recv = pend.result()
-    assert wire.batch_size == sum(legs_of) == len(recv)
+    assert wire.batch_size == rows == len(recv)
     # a launch's rows are the whole's, in row order
     assert np.array_equal(np.concatenate([r for _w, r in parts]), recv)
     at = 0
@@ -151,11 +266,14 @@ def test_rows_are_cut_at_the_top_class_and_come_back_in_order(case, oracle):
     tracer.take_ledger()
     exp = tracer.last_counts["expand"]
     assert exp["launches"] == launches and exp["legs_max"] == 63
-    assert exp["rows"] == sum(legs_of)
-    assert exp["rows_padded"] == 64 * (launches - 1) + _round_rows(
-        sum(legs_of) - 64 * (launches - 1))
+    assert exp["rows"] == rows
+    assert exp["rows_padded"] == sum(seen)
+    # the classes cut a tick that FITS `launch_rows`
+    class_cut = int(launches > 1 and rows <= top)
+    assert exp["class_cut"] == class_cut
     assert tr.fanout_launches == launches
     assert tr.fanout_split_ticks == int(launches > 1)
+    assert tr.fanout_class_cut_ticks == class_cut
 
 
 def test_a_split_tick_is_byte_equal_to_the_same_packets_a_launch_each(
@@ -171,33 +289,43 @@ def test_a_split_tick_is_byte_equal_to_the_same_packets_a_launch_each(
     for k in range(5):
         one = PacketBatch(b.data[k:k + 1], b.length[k:k + 1],
                           b.stream[k:k + 1])
-        assert tr.single_launch(1)
-        w, r = tr.translate(one, index[k:k + 1])
+        pend = tr.translate_async(one, index[k:k + 1])
+        assert pend.launches == 1
+        w, r = pend.result()
         assert np.array_equal(r, recv[at:at + 63])
         assert [w.to_bytes(j) for j in range(63)] == \
             [whole.to_bytes(at + j) for j in range(63)]
         at += 63
-    assert tr.fanout_split_ticks == 1
+    assert (tr.fanout_split_ticks, tr.fanout_class_cut_ticks) == (1, 0)
 
 
-def test_no_launch_has_a_shape_outside_the_classes(oracle):
-    """Whatever the rows, a launch's plane has a row class's rows, at
-    most `launch_rows`: the shapes the ladder warms and no other."""
-    rng = np.random.default_rng(13)
-    tr, _k = _translator(CM, (63,) * 5 + (9,), 64)
-    seen = []
-    call = tr._cm_fanout_call
-
-    def spy(recv, plane, *rest):
-        seen.append(plane.shape[0])
-        return call(recv, plane, *rest)
-
-    tr._cm_fanout_call = spy
-    for n in (1, 2, 6):
-        b, _pls, index = _batch(rng, range(n))
-        tr.translate(b, index)
-    assert seen and set(seen) <= {16, 64}
-    assert max(seen) == tr.launch_rows
+@pytest.mark.parametrize("profile", [CM, GCM], ids=["cm", "gcm"])
+def test_a_class_cut_tick_is_byte_equal_to_one_launch_in_the_top_class(
+        profile, oracle, monkeypatch):
+    """28 rows that fit a top class of 64: as 16 + 12 (a launch costs 4
+    rows) and as ONE 64-row launch (a launch costs more than any
+    padding), CM and per-row GCM: the same bytes, the oracle's."""
+    protect = oracle.protect_gcm if profile is GCM else oracle.protect_cm
+    legs_of = (20, 8) if profile is CM else (7,) * 4
+    got = {}
+    for cost, launches in ((4, 2), (10 ** 6, 1)):
+        monkeypatch.setattr(translator_mod, "LAUNCH_COST_ROWS", cost)
+        tr, keys = _translator(profile, legs_of, 64)
+        b, pls, index = _batch(np.random.default_rng(16),
+                               range(len(legs_of)))
+        pend = tr.translate_async(b, index)
+        assert pend.launches == launches
+        assert all(pg is None for _l, pg, _r, _n in pend._parts)
+        assert tr.fanout_class_cut_ticks == launches - 1
+        wire, recv = pend.result()
+        got[launches] = [wire.to_bytes(j) for j in range(28)]
+    assert got[2] == got[1]
+    j = 0
+    for s, n in enumerate(legs_of):
+        for _ in range(n):
+            assert got[2][j] == protect(
+                *_pair(keys[int(recv[j]), 1]), pls[s], 900 + s), j
+            j += 1
 
 
 def test_gcm_conferences_of_8_split_and_open_under_the_oracle(oracle):
@@ -217,18 +345,28 @@ def test_gcm_conferences_of_8_split_and_open_under_the_oracle(oracle):
             *_pair(keys[int(recv[j]), 1]), pls[s], 900 + s), j
 
 
+#: (senders of 63 legs + one of `last`, the real rows of each launch)
+#: at the real classes and the shipped launch cost
+REAL = {"over_the_top_class": (66, 0, [4096, 62]),
+        "fits_it_and_pads_less_as_1024_plus_256": (18, 30, [1024, 140])}
+
+
 @pytest.mark.slow
-def test_the_real_classes_split_at_4096_rows(oracle):
-    """66 packets of 63 legs at the real top class: 4,158 rows in two
-    launches (4,096 + 62 -> 64), byte-equal to the oracle."""
+@pytest.mark.parametrize("case", sorted(REAL))
+def test_the_real_classes_cut_the_tick(case, oracle):
+    """66 packets of 63 legs: 4,158 rows in two launches (4,096 + 62 ->
+    64); 18 and one of 30 legs: 1,164 rows as 1,024 + 140 -> 256, not
+    one launch of 4,096.  Byte-equal to the oracle."""
+    senders, last, sizes = REAL[case]
+    legs_of = (63,) * senders + (last,) * bool(last)
     rng = np.random.default_rng(15)
-    tr, keys = _translator(CM, (63,) * 66, ROW_CLASSES[-1])
-    b, pls, index = _batch(rng, range(66))
-    assert not tr.single_launch(66) and tr.single_launch(65)
+    tr, keys = _translator(CM, legs_of, ROW_CLASSES[-1])
+    b, pls, index = _batch(rng, range(len(legs_of)))
     pend = tr.translate_async(b, index)
-    assert pend.launches == 2
+    assert [len(r) for _l, _pg, r, _n in pend._parts] == sizes
+    assert tr.fanout_class_cut_ticks == int(sum(sizes) <= ROW_CLASSES[-1])
     wire, recv = pend.result()
-    assert wire.batch_size == 66 * 63
+    assert wire.batch_size == sum(legs_of)
     for j in range(0, wire.batch_size, 7):
         assert wire.to_bytes(j) == oracle.protect_cm(
             *_pair(keys[int(recv[j]), 1]), pls[j // 63], 900 + j // 63)
@@ -247,6 +385,7 @@ def _serve(oracle, tap_of) -> dict:
     libjitsi_tpu.init()
     bridge = SfuBridge(libjitsi_tpu.configuration_service(), port=0,
                        capacity=ROWS, profile=CM, recv_window_ms=0)
+    cost0 = translator_mod.LAUNCH_COST_ROWS
     reg = bridge.loop.metrics
     sup = supervisor_mod.BridgeSupervisor(
         bridge, supervisor_mod.SupervisorConfig(deadline_ms=60_000.0),
@@ -259,6 +398,15 @@ def _serve(oracle, tap_of) -> dict:
         metrics=reg)
     lc.enable_placement(1)
     tap = tap_of(bridge)
+    # `translate` wrapped on the instance, as the benchmark's fault
+    # `bridge-bitflip` wraps it (`benchmarks/sut.py:break_fanout`)
+    whole, inner = [], bridge.translator.translate
+
+    def translate(batch, index):
+        whole.append(batch.batch_size)
+        return inner(batch, index)
+
+    bridge.translator.translate = translate
     keys = _keys(40, ROWS)
     now = [2000.0]
     rec = {"sent": {}, "rounds": [], "nack": None}
@@ -290,6 +438,9 @@ def _serve(oracle, tap_of) -> dict:
         return out
 
     try:
+        # a launch costs COST rows while this bridge serves, so that the
+        # classes its small ladder warms cut a tick as the real ones do
+        translator_mod.LAUNCH_COST_ROWS = COST
         for i in range(ROWS):
             if i == CONF:
                 # the first room is full: its 65th member is refused,
@@ -348,15 +499,19 @@ def _serve(oracle, tap_of) -> dict:
             assert len(set(senders)) == n
             for i in senders:
                 socks[i].sendto(fresh(i), ("127.0.0.1", bridge.port))
+            del whole[:]
             ticks = tick(2)
             assert len(ticks) == 1, "the round in one tick"
             rec["rounds"].append({"packets": n, "senders": senders,
-                                  "tick": ticks[0], "got": drain()})
+                                  "tick": ticks[0], "got": drain(),
+                                  "whole": list(whole)})
         rec["compiles"] = compile_stats().compile_events - events0
         rec["recompiles"] = lc.datapath_recompiles - recompiles0
-        # a row of the third round's SECOND launch (rows 256..314: the
-        # fifth packet's last receivers), asked for again
-        sender = rec["rounds"][2]["senders"][-1]
+        # a row of the second round's SMALL launch (126 rows that fit
+        # the top class, cut 64 + 62: rows 64..125, the second
+        # packet's last receivers), asked for again
+        assert ROUNDS[1] == 2
+        sender = rec["rounds"][1]["senders"][-1]
         r = max(i for i in range(CONF) if i != sender)
         lost = 300                      # that member's first packet
         cl = SrtpStreamTable(capacity=1)
@@ -381,9 +536,11 @@ def _serve(oracle, tap_of) -> dict:
         rec["handed"] = tap.handed
         rec["ports"] = [s.getsockname()[1] for s in socks]
         rec["translator"] = (bridge.translator.fanout_launches,
-                             bridge.translator.fanout_split_ticks)
+                             bridge.translator.fanout_split_ticks,
+                             bridge.translator.fanout_class_cut_ticks)
         return rec
     finally:
+        translator_mod.LAUNCH_COST_ROWS = cost0
         for s in socks:
             s.close()
         bridge.close()
@@ -394,8 +551,10 @@ def served(oracle, egress_tap):
     return _serve(oracle, egress_tap)
 
 
-def _launches(packets: int) -> int:
-    return -(-packets * (CONF - 1) // WARMED[-1])
+def _class_cut(packets: int) -> bool:
+    """The classes cut a tick that fits the ladder's top class."""
+    return len(SIZES[packets]) > 1 and \
+        packets * (CONF - 1) <= WARMED[-1]
 
 
 def test_the_ladders_top_class_is_the_fanouts_bound(served):
@@ -432,6 +591,17 @@ def test_every_delivery_once_under_its_receivers_key(served, oracle, k):
         assert ssrc - SSRC_BASE in rnd["senders"]
 
 
+@pytest.mark.parametrize("k", range(len(ROUNDS)))
+def test_a_tick_of_one_launch_is_translates_whole(served, k):
+    """One rule decides: where the plan makes one launch of the tick's
+    rows the bridge calls `translate` (the seam the benchmark's fault
+    `bridge-bitflip` wraps), where it cuts them `translate_async` and a
+    launch at a time."""
+    rnd = served["rounds"][k]
+    one = len(SIZES[rnd["packets"]]) == 1
+    assert rnd["whole"] == [rnd["packets"]] * one
+
+
 def test_per_socket_order_is_the_hand_overs(served):
     """(a) every socket receives what was handed over for its port, in
     that order, launch after launch."""
@@ -444,17 +614,19 @@ def test_per_socket_order_is_the_hand_overs(served):
 
 
 def test_nothing_compiles_whatever_the_backlog(served):
-    """(b) ticks of 1 to 37 packets (one to ten launches) after the
-    ladder: no compile event, no data-path recompile, nothing shed."""
+    """(b) ticks of 1 to 37 packets (one to eleven launches, of 16, 64
+    and 256 rows) after the ladder: no compile event, no data-path
+    recompile, nothing shed."""
     assert [r["packets"] for r in served["rounds"]] == list(ROUNDS)
     assert served["compiles"] == 0 and served["recompiles"] == 0
     h = served["health"]
     assert not h["shed"] and not h["quarantined"]
 
 
-def test_a_nack_for_a_row_of_the_second_launch_is_answered(served):
-    """(c) the cache holds a slab a launch: the retransmission is the
-    delivery's bytes."""
+def test_a_nack_for_a_row_of_the_small_launch_is_answered(served):
+    """(c) the cache holds a slab a launch, the remainder's small one
+    of a class-cut tick too: the retransmission is the delivery's
+    bytes."""
     n = served["nack"]
     first = [p for rnd in served["rounds"] for r, p in rnd["got"]
              if r == n["receiver"]
@@ -467,18 +639,20 @@ def test_a_nack_for_a_row_of_the_second_launch_is_answered(served):
 
 @pytest.mark.parametrize("k", range(len(ROUNDS)))
 def test_spans_a_launch_and_the_leaves_still_tile_the_tick(served, k):
-    """(f) `launches` on `expand`; one `fanout_dispatch` / `fanout_wait`
-    / `fanout_d2h` / `nack_cache` / `egress` a launch, each saying which
-    where the tick has several; the leaves still cover the tick."""
+    """(f) `launches` and `class_cut` on `expand`; one `fanout_dispatch`
+    / `fanout_wait` / `fanout_d2h` / `nack_cache` / `egress` a launch,
+    each saying which where the tick has several; the leaves still
+    cover the tick."""
     rnd = served["rounds"][k]
     counts, led, self_led, tick_s = rnd["tick"]
-    n = _launches(rnd["packets"])
+    sizes = SIZES[rnd["packets"]]
+    n = len(sizes)
     rows = rnd["packets"] * (CONF - 1)
     exp = counts["expand"]
     assert exp["launches"] == n and exp["legs_max"] == CONF - 1
-    assert exp["rows"] == rows
-    assert exp["rows_padded"] == WARMED[-1] * (n - 1) + _round_rows(
-        rows - WARMED[-1] * (n - 1))
+    assert exp["rows"] == rows == sum(sizes)
+    assert exp["rows_padded"] == sum(map(_round_rows, sizes))
+    assert exp["class_cut"] == _class_cut(rnd["packets"])
     # one packed plane each way a launch
     assert counts["fanout_dispatch"]["h2d_arrays"] == n
     assert counts["fanout_put"]["h2d_arrays"] == n
@@ -502,14 +676,16 @@ def test_spans_a_launch_and_the_leaves_still_tile_the_tick(served, k):
     assert -1e-6 <= outside <= led.get("gc", 0.0) + 1e-6
 
 
-def test_metrics_count_launches_and_split_ticks(served):
-    launches, split = served["translator"]
+def test_metrics_count_launches_split_and_class_cut_ticks(served):
+    launches, split, class_cut = served["translator"]
     # + the latch tick's one launch
-    assert launches == 1 + sum(_launches(p) for p in ROUNDS)
-    assert split == sum(_launches(p) > 1 for p in ROUNDS)
+    assert launches == 1 + sum(len(SIZES[p]) for p in ROUNDS)
+    assert split == sum(len(SIZES[p]) > 1 for p in ROUNDS) == 6
+    assert class_cut == sum(_class_cut(p) for p in ROUNDS) == 2
     text = served["metrics"]
     assert f"fanout_launches_total {launches}" in text
     assert f"fanout_split_ticks_total {split}" in text
+    assert f"fanout_class_cut_ticks_total {class_cut}" in text
     assert 'lifecycle_admit_rejected{reason="conference_full"} 1' in text
 
 

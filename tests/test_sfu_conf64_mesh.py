@@ -8,6 +8,12 @@ tables are row-partitioned over four of the host devices `conftest.py`
 forces, and through a one-chip bridge with the same keys, gives every
 receiver the same bytes.  Both compile as they go: a `shard_map` program
 compiles slowly on XLA:CPU, so this is one tick on each.
+
+The mesh translator cuts at `launch_rows` ALONE: it pads nothing in
+`expand` (`_pads_rows` False: the owner plan pads lanes a chip), so the
+one-chip translator's cut by the row classes under `launch_rows`
+(`plan_launches`) is not its: the last test holds both to 1,164 rows,
+with the device call stubbed out so that nothing compiles.
 """
 
 import socket
@@ -16,7 +22,10 @@ import numpy as np
 import pytest
 
 import libjitsi_tpu
+from libjitsi_tpu.core import staging
+from libjitsi_tpu.core.packet import ROW_CLASSES, PacketBatch
 from libjitsi_tpu.mesh import ShardedRtpTranslator, make_media_mesh
+from libjitsi_tpu.sfu.translator import RtpTranslator
 from libjitsi_tpu.transform.srtp import SrtpProfile, SrtpStreamTable
 from libjitsi_tpu.rtp import header as rtp_header
 
@@ -113,3 +122,32 @@ def test_the_split_tick_is_byte_equal_to_the_one_chip_bridge(both):
     assert len(one) == PACKETS * (CONF - 1)
     assert mesh.keys() == one.keys()
     assert all(mesh[k] == one[k] for k in one)
+
+
+@pytest.mark.parametrize("sharded", [False, True], ids=["one_chip", "mesh"])
+def test_the_mesh_translator_cuts_at_launch_rows_alone(sharded):
+    """1,164 rows (18 senders of 63 legs and one of 30) at the real
+    classes: the one-chip translator launches 1,024 + 256 rows, the
+    mesh translator one call of 1,164 unpadded rows, as before."""
+    keys = _keys(80)
+    if sharded:
+        tr = ShardedRtpTranslator(
+            CAPACITY, make_media_mesh(__import__("jax").devices()[:SHARDS]))
+    else:
+        tr = RtpTranslator(CAPACITY)
+    tr.add_receivers(range(80), [bytes(k[1][:16]) for k in keys],
+                     [bytes(k[1][16:]) for k in keys])
+    legs_of = (63,) * 18 + (30,)
+    for s, n in enumerate(legs_of):
+        tr.connect(100 + s, [(3 * s + j) % 80 for j in range(n)])
+    assert tr.launch_rows == ROW_CLASSES[-1]
+    seen = []
+    tr._cm_fanout_call = lambda recv, plane, *rest: (
+        seen.append(plane.shape[0]) or staging.Launch(()))
+    b = PacketBatch.from_payloads(
+        [bytes([0x80, 111]) + bytes(10) + b"x" * 60] * len(legs_of),
+        capacity=256, stream=[100 + s for s in range(len(legs_of))])
+    pend = tr.translate_async(b, np.arange(len(legs_of), dtype=np.int64))
+    assert seen == ([1164] if sharded else [1024, 256])
+    assert pend.launches == len(seen)
+    assert tr.fanout_class_cut_ticks == int(not sharded)
