@@ -628,14 +628,14 @@ def fanout(deadline: float, packets: int = 128, receivers: int = 512
     iv = rng.integers(0, 256, (rows, 16), dtype=np.uint8)
     roc = np.zeros(rows, np.uint32)
 
-    # same math as translator._fanout_protect (which takes the
-    # uniform-offset fast path for fan-outs), without buffer
-    # donation (donation would invalidate the timed args)
+    # same math as translator._fanout_protect (the payload offset is
+    # an operand), without buffer donation (donation would invalidate
+    # the timed args)
     @jax.jit
     def step(tab_rk, tab_mid, recv, data, length, off, iv, roc):
-        return kernel.srtp_protect(data, length, off, tab_rk[recv], iv,
-                                   tab_mid[recv], roc, TAG_LEN, True,
-                                   payload_off_const=12)
+        return kernel.srtp_protect_rows(data, length, off, tab_rk[recv],
+                                        iv, tab_mid[recv], roc, TAG_LEN,
+                                        True)
 
     args = [jnp.asarray(x) for x in
             (tab_rk, tab_mid, recv, data, length, off, iv, roc)]

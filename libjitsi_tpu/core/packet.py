@@ -129,6 +129,15 @@ ROW_CLASSES = (16, 64, 256, 1024, 4096)
 CLASS_HEADROOM = 32   # room for auth tag + SRTCP index word growth
 
 
+def payload_blocks(width: int, tag_len: int) -> int:
+    """The 16-byte cipher blocks the payload of an RTP row `width`
+    bytes wide can span: it starts behind the fixed header at least and
+    ends where the `tag_len`-byte tag still fits in the row (13 blocks
+    for the 224-byte class, not 14: what a protect that compiles its
+    payload offset in gets from the offset)."""
+    return max(0, width - tag_len - RTP_FIXED_HEADER_LEN + 15) // 16
+
+
 def _round_rows(n: int) -> int:
     for r in ROW_CLASSES:
         if n <= r:

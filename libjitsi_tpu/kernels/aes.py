@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from libjitsi_tpu.kernels.scatter import shift_right
+
 
 # ---------------------------------------------------------------------------
 # GF(2^8) tables (host, generated once)
@@ -447,6 +449,42 @@ def _xor_window_offset(data, ks, offset, length):
     ln = jnp.asarray(length, dtype=jnp.int32)[:, None]
     rel = jnp.clip(col - off, 0, ks.shape[1] - 1)
     ks_aligned = jnp.take_along_axis(ks, rel, axis=1)
+    inside = (col >= off) & (col < off + ln)
+    return jnp.where(inside, data ^ ks_aligned, data)
+
+
+@functools.partial(jax.jit, static_argnames=("nblocks",))
+def ctr_crypt_rows(round_keys, iv, data, offset, length, nblocks=None):
+    """`ctr_crypt_offset` at `ctr_crypt_uniform`'s cost: the keystream
+    is aligned to each row's own offset by a ladder of static shifts
+    (`scatter.shift_right`), not by a [B, W] gather, so ONE program
+    serves every header length, and a batch that mixes them, where the
+    uniform form is a program an offset.  Same contract and bytes as
+    `ctr_crypt_offset` for 0 <= offset (an offset at or past the width
+    leaves the row as it is).
+
+    `nblocks` (static): the keystream blocks computed, for a caller
+    that knows no row's window is longer than 16 x `nblocks` bytes (the
+    uniform form computes what lies behind its offset, and nothing for
+    columns no payload can reach); default the whole width."""
+    data = jnp.asarray(data, dtype=jnp.uint8)
+    if nblocks is None:
+        nblocks = (data.shape[1] + 15) // 16
+    with jax.named_scope("keystream"):
+        ks = ctr_keystream(round_keys, iv, nblocks)
+    with jax.named_scope("xor_payload"):
+        return _xor_window_rows(data, ks, offset, length)
+
+
+def _xor_window_rows(data, ks, offset, length):
+    """XOR keystream into per-row windows (shift-ladder alignment)."""
+    width = data.shape[1]
+    col = jnp.arange(width, dtype=jnp.int32)[None, :]
+    off = jnp.asarray(offset, dtype=jnp.int32)
+    ln = jnp.asarray(length, dtype=jnp.int32)[:, None]
+    ks = jnp.pad(ks, ((0, 0), (0, max(0, width - ks.shape[1]))))
+    ks_aligned = shift_right(ks[:, :width], off, width)
+    off = off[:, None]
     inside = (col >= off) & (col < off + ln)
     return jnp.where(inside, data ^ ks_aligned, data)
 

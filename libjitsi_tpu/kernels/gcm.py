@@ -18,9 +18,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from libjitsi_tpu.core.packet import payload_blocks
 from libjitsi_tpu.kernels.scatter import gather_span as _gather_span
-from libjitsi_tpu.kernels.scatter import scatter_bytes
+from libjitsi_tpu.kernels.scatter import scatter_bytes, shift_right
 from libjitsi_tpu.kernels.aes import (aes_encrypt, ctr_crypt_offset,
+                                      ctr_crypt_rows,
                                       ctr_crypt_uniform)
 from libjitsi_tpu.kernels.ghash import ghash
 
@@ -58,11 +60,17 @@ def _length_block(cols, ap, cp, abits, cbits):
     return byte, p
 
 
-def _build_ghash_input(data, aad_len, ct_len, width: int):
+def _build_ghash_input(data, aad_len, ct_len, width: int,
+                       shifted: bool = False):
     """[B, W] packet bytes -> [B, width] GHASH block stream + counts.
 
     Row layout: AAD (0-padded to 16) || ciphertext (0-padded) ||
     be64(aad_bits) || be64(ct_bits).
+
+    `shifted`: the same bytes without the [B, width] gather: the AAD
+    stays where it is and the ciphertext moves right by ceil16(aad) -
+    aad < 16 columns, a four-select ladder (`scatter.shift_right`); the
+    edge padding reads what the gather's clip reads past the packet.
     """
     bsz, cap = data.shape
     a = aad_len.astype(jnp.int32)
@@ -74,9 +82,13 @@ def _build_ghash_input(data, aad_len, ct_len, width: int):
     in_aad = cols < a[:, None]
     k = cols - ap[:, None]
     in_ct = (k >= 0) & (k < c[:, None])
-    src = jnp.where(in_aad, cols, jnp.where(in_ct, a[:, None] + k, 0))
-    gathered = jnp.take_along_axis(
-        data, jnp.clip(src, 0, cap - 1), axis=1)
+    if shifted:
+        wide = jnp.pad(data, ((0, 0), (0, width - cap)), mode="edge")
+        gathered = jnp.where(in_aad, wide, shift_right(wide, ap - a, 16))
+    else:
+        src = jnp.where(in_aad, cols, jnp.where(in_ct, a[:, None] + k, 0))
+        gathered = jnp.take_along_axis(
+            data, jnp.clip(src, 0, cap - 1), axis=1)
 
     len_byte, p = _length_block(cols, ap[:, None], cp[:, None],
                                 (a * 8)[:, None], (c * 8)[:, None])
@@ -142,13 +154,14 @@ def _scatter_tag(data, pos, tag):
 
 
 def _tag(round_keys, gmat, data, aad_len, ct_len, j0, width: int,
-         aad_const=None):
+         aad_const=None, shifted: bool = False):
     with jax.named_scope("tag"):
         if aad_const is not None:
             gin, nblk = _build_ghash_input_uniform(data, aad_const,
                                                    ct_len, width)
         else:
-            gin, nblk = _build_ghash_input(data, aad_len, ct_len, width)
+            gin, nblk = _build_ghash_input(data, aad_len, ct_len, width,
+                                           shifted)
         with jax.named_scope("ghash"):
             s = ghash(gmat, gin, nblk, width // 16)
         ek_j0 = aes_encrypt(round_keys, j0)
@@ -179,6 +192,27 @@ def gcm_protect(data, length, aad_len, round_keys, gmat, iv12,
                aad_const)
     out = _scatter_tag(enc, length, tag)
     return out, length + TAG_LEN
+
+
+@jax.jit
+def gcm_protect_rows(data, length, aad_len, round_keys, gmat, iv12):
+    """`gcm_protect` whose AAD length is DATA: arguments and bytes as
+    its `aad_const=None` form, with neither of that form's gathers
+    (`ctr_crypt_rows`, `_build_ghash_input`'s `shifted`), so there is
+    one program a shape whatever header lengths a batch carries, at the
+    static form's cost.  The SFU's per-row fan-out form.  Rows are RTP
+    packets with room for their tag: `aad_len` >= 12 and `length` + 16
+    <= W bound the keystream (`payload_blocks`)."""
+    data = jnp.asarray(data, dtype=jnp.uint8)
+    length = jnp.asarray(length, dtype=jnp.int32)
+    aad_len = jnp.asarray(aad_len, dtype=jnp.int32)
+    j0 = _j0(jnp.asarray(iv12))
+    ct_len = length - aad_len
+    enc = ctr_crypt_rows(round_keys, _inc32(j0), data, aad_len, ct_len,
+                         nblocks=payload_blocks(data.shape[1], TAG_LEN))
+    tag = _tag(round_keys, gmat, enc, aad_len, ct_len, j0,
+               _ghash_width(data.shape[1]), shifted=True)
+    return _scatter_tag(enc, length, tag), length + TAG_LEN
 
 
 @functools.partial(jax.jit, static_argnames=("aad_const",))

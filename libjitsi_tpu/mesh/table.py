@@ -42,6 +42,7 @@ control traffic must not silently hop to a single-chip path.
 
 from __future__ import annotations
 
+import functools
 import weakref
 from typing import Dict, Tuple
 
@@ -325,15 +326,15 @@ class ShardedRowsMixin:
                               h2d_bytes=lanes.nbytes, counts=counts,
                               d2h_counts=counts)
 
-    def _packed_fn(self, key: Tuple, name: str, kfn, tag_len: int,
-                   encrypt: bool, off_const):
+    def _packed_fn(self, key: Tuple, name: str, kfn):
         """The shard_map program of a packed seam, shared a mesh under
         `key` and named `name` in the trace: the one-chip packed
         program's body (`context._unprotect_rtp_packed_impl`,
         `sfu.translator._fanout_protect`) over this chip's lane block
         and this chip's shard of the key tables.  `kfn` is
-        `kernel.srtp_unprotect` or `kernel.srtp_protect`; whatever it
-        returns beside the bytes rides back as the plane's words."""
+        `kernel.srtp_unprotect` or `kernel.srtp_protect_rows` with its
+        static arguments bound; whatever it returns beside the bytes
+        rides back as the plane's words."""
         fn = self._sh_fns.get(key)
         if fn is not None:
             return fn
@@ -344,8 +345,7 @@ class ShardedRowsMixin:
             rk, mid = kernel.gather_keys(staging.as_i32(w[:, 0]), tab_rk,
                                          tab_mid)
             out = kfn(data, staging.as_i32(w[:, 1]),
-                      staging.as_i32(w[:, 2]), rk, iv, mid, w[:, 3],
-                      tag_len, encrypt, payload_off_const=off_const)
+                      staging.as_i32(w[:, 2]), rk, iv, mid, w[:, 3])
             return staging.repack(*out)[None]
 
         row3 = P(self._axes, None, None)
@@ -815,9 +815,10 @@ class ShardedSrtpTable(ShardedRowsMixin, SrtpStreamTable):
         f8 = op.startswith("f8_") or op.startswith("rtcp_f8_")
         if op == "unprotect":
             # the served CM unprotect: one packed plane each way
-            return self._packed_fn(key, "mesh_unprotect_rtp",
-                                   kernel.srtp_unprotect, tag_len,
-                                   encrypt, off_const)
+            return self._packed_fn(
+                key, "mesh_unprotect_rtp", functools.partial(
+                    kernel.srtp_unprotect, tag_len=tag_len,
+                    encrypt=encrypt, payload_off_const=off_const))
         if op.startswith("gcm_"):
             fn = self._build_gcm_fn(op, off_const, row3, lanes)
         elif op.startswith("rtcp_"):

@@ -19,6 +19,8 @@ pin both against the single-chip translator.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
@@ -77,12 +79,8 @@ class ShardedRtpTranslator(ShardedRowsMixin, RtpTranslator):
         (`ShardedRowsMixin._packed_call`).  Returns the
         `staging.Launch` in flight, whose `fetch()` gives host (wire
         bytes, wire lengths)."""
-        from libjitsi_tpu.transform.srtp.context import _uniform_off
-
-        fn = self._fanout_fn(_uniform_off(
-            payload_off, plane.shape[-1] - staging.TAIL))
         return self._packed_call(
-            fn, plane, recv,
+            self._fanout_fn(), plane, recv,
             (length, payload_off, (np.asarray(idx) >> 16) & 0xFFFFFFFF),
             iv, _split_fanout)
 
@@ -93,13 +91,9 @@ class ShardedRtpTranslator(ShardedRowsMixin, RtpTranslator):
         arrays are routed to their owning chips, counted as they cross,
         and the launch holds the two outputs in lane layout until
         `fetch` scatters them back (`_mesh_launch`)."""
-        from libjitsi_tpu.transform.srtp.context import _uniform_off
-
         data = plane[:, :plane.shape[-1] - staging.TAIL]
-        fn = self._gcm_fanout_fn(_uniform_off(payload_off,
-                                              data.shape[-1]))
         outs, plan, n, nbytes = self._sharded_call(
-            fn, self._sharded_device(), recv,
+            self._gcm_fanout_fn(), self._sharded_device(), recv,
             [data, np.asarray(length, dtype=np.int32), payload_off,
              iv12])
         return self._mesh_launch(outs, plan, n, nbytes, (None, np.int32))
@@ -152,17 +146,19 @@ class ShardedRtpTranslator(ShardedRowsMixin, RtpTranslator):
         # translator of the mesh, share ONE jit
         return self._sh_fns.setdefault(key, fn)
 
-    def _gcm_fanout_fn(self, off_const):
-        key = ("gcm_fanout", off_const)
+    def _gcm_fanout_fn(self):
+        """The per-row GCM fan-out program of this mesh: one a shape,
+        the payload offset a lane array (`gcm_protect_rows`)."""
+        key = ("gcm_fanout",)
         fn = self._sh_fns.get(key)
         if fn is not None:
             return fn
         from libjitsi_tpu.kernels import gcm as gcm_kernel
 
         def _run(tab_rk, tab_gm, local, data, length, off, iv12):
-            out = gcm_kernel.gcm_protect(
+            out = gcm_kernel.gcm_protect_rows(
                 data[0], length[0], off[0], tab_rk[local[0]],
-                tab_gm[local[0]], iv12[0], aad_const=off_const)
+                tab_gm[local[0]], iv12[0])
             return tuple(o[None] for o in out)
 
         row3 = P(self._axes, None, None)
@@ -175,11 +171,12 @@ class ShardedRtpTranslator(ShardedRowsMixin, RtpTranslator):
         # translator of the mesh, share ONE jit
         return self._sh_fns.setdefault(key, fn)
 
-    def _fanout_fn(self, off_const=None):
-        """The packed CM fan-out program of this mesh."""
+    def _fanout_fn(self):
+        """The packed CM fan-out program of this mesh: one a shape, the
+        payload offset a word of the plane (`srtp_protect_rows`)."""
         tag_len = self.policy.auth_tag_len
         encrypt = self.policy.cipher != Cipher.NULL
         return self._packed_fn(
-            ("fanout", tag_len, encrypt, off_const),
-            "mesh_fanout_protect", kernel.srtp_protect, tag_len, encrypt,
-            off_const)
+            ("fanout", tag_len, encrypt), "mesh_fanout_protect",
+            functools.partial(kernel.srtp_protect_rows, tag_len=tag_len,
+                              encrypt=encrypt))

@@ -117,19 +117,20 @@ def plan_launches(rows: int, top: int,
         len(cut)))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("tag_len", "encrypt", "off_const"),
+@functools.partial(jax.jit, static_argnames=("tag_len", "encrypt"),
                    donate_argnums=(2,))
-def _fanout_protect(tab_rk, tab_mid, plane, tag_len: int, encrypt: bool,
-                    off_const=None):
+def _fanout_protect(tab_rk, tab_mid, plane, tag_len: int, encrypt: bool):
     """The CM fan-out on one packed plane (core/staging.py): words
     receiver, length, payload offset, ROC; out word the wire length.
-    The plane that comes back has the donated plane's shape."""
+    The plane that comes back has the donated plane's shape.  The
+    payload offset is the plane's word and nothing else: ONE program a
+    (row class, width) whatever header lengths a tick carries
+    (`kernel.srtp_protect_rows`)."""
     data, w, iv = staging.unpack(plane)
     rk, mid = kernel.gather_keys(staging.as_i32(w[:, 0]), tab_rk, tab_mid)
-    out, out_len = kernel.srtp_protect(
+    out, out_len = kernel.srtp_protect_rows(
         data, staging.as_i32(w[:, 1]), staging.as_i32(w[:, 2]), rk, iv,
-        mid, w[:, 3], tag_len, encrypt, payload_off_const=off_const)
+        mid, w[:, 3], tag_len, encrypt)
     return staging.repack(out, out_len)
 
 
@@ -139,18 +140,19 @@ def _split_fanout(host):
     return data, words[:, 0]
 
 
-@functools.partial(jax.jit, static_argnames=("aad_const",),
-                   donate_argnums=(2,))
-def _fanout_protect_gcm(tab_rk, tab_gm, plane, aad_const=None):
+@functools.partial(jax.jit, donate_argnums=(2,))
+def _fanout_protect_gcm(tab_rk, tab_gm, plane):
     """The per-row GCM fan-out on one packed plane (core/staging.py):
     words receiver, length, payload offset (GCM has no ROC word), the
     12-byte IV in the first 12 IV columns; out word the wire length.
-    The plane that comes back has the donated plane's shape."""
+    The plane that comes back has the donated plane's shape.  As
+    `_fanout_protect`, the payload offset is data
+    (`gcm_kernel.gcm_protect_rows`)."""
     data, w, iv = staging.unpack(plane)
     rk, gm = kernel.gather_keys(staging.as_i32(w[:, 0]), tab_rk, tab_gm)
-    out, out_len = gcm_kernel.gcm_protect(
+    out, out_len = gcm_kernel.gcm_protect_rows(
         data, staging.as_i32(w[:, 1]), staging.as_i32(w[:, 2]), rk, gm,
-        iv[:, :12], aad_const=aad_const)
+        iv[:, :12])
     return staging.repack(out, out_len)
 
 
@@ -361,11 +363,10 @@ class RtpTranslator:
         run them side by side (StreamLifecycleManager does, when the
         population bucket grows, before any admit can drive traffic at
         the new scale).  Covers the class-padded shapes
-        translate_async produces: the common uniform payload offsets
-        (bare RTP header at 12, header + one-byte abs-send-time ext at
-        20) plus the general mixed-offset entry.  Reads the live key
-        tables (row 0, key material irrelevant); outputs are garbage
-        and discarded.
+        translate_async produces.  The payload offset is an operand of
+        the per-row program, so one thunk a width covers every header
+        length.  Reads the live key tables (row 0, key material
+        irrelevant); outputs are garbage and discarded.
 
         Under GCM the per-row form always; the leg-major form only
         where `_gcm_leg_major` can select it for this translator: some
@@ -383,14 +384,9 @@ class RtpTranslator:
         recv = np.zeros(rows, dtype=np.int64)
         idx = np.zeros(rows, dtype=np.int64)
         length = np.full(rows, 12 + payload_len, dtype=np.int32)
-        offs = [np.full(rows, 12, dtype=np.int32),
-                np.full(rows, 20, dtype=np.int32)]
-        mixed = np.full(rows, 12, dtype=np.int32)
-        if rows > 1:
-            mixed[0] = 16            # non-uniform: off_const=None entry
-        offs.append(mixed)
+        off = np.full(rows, 12, dtype=np.int32)
 
-        def one(w: int, off) -> None:
+        def one(w: int) -> None:
             # fetch the output: compile NOW, off-tick
             plane = staging.alloc(rows, w)
             plane[:, 0] = 0x80
@@ -413,8 +409,7 @@ class RtpTranslator:
             self._gcm_uniform_fanout_call(recv, pdata, plen, iv,
                                           aad).fetch()
 
-        thunks = [functools.partial(one, w, off)
-                  for w in widths for off in offs]
+        thunks = [functools.partial(one, w) for w in widths]
         if self._gcm and self._max_legs >= GCM_LEG_MAJOR_MIN_LEGS:
             thunks += [functools.partial(leg_major, w, aad)
                        for w in widths for aad in (12, 20)]
@@ -611,21 +606,13 @@ class RtpTranslator:
         ROC (`idx >> 16` mod 2**32) and IV are packed behind them here,
         so ONE array goes to the device and one plane comes back.
         Returns the `staging.Launch` in flight, whose `fetch()` gives
-        host arrays (wire bytes `[rows, width]`, wire lengths).
-
-        Uniform payload offsets (the fan-out common case: one sender's
-        fixed header replicated per leg) take the static-pad keystream
-        alignment instead of the per-row offset gathers."""
-        from libjitsi_tpu.transform.srtp.context import _uniform_off
-
+        host arrays (wire bytes `[rows, width]`, wire lengths)."""
         tab_rk, tab_mid = self._device()
         staging.pack(plane, (recv, length, payload_off,
                              (idx >> 16) & 0xFFFFFFFF), iv)
         out = _fanout_protect(
             tab_rk, tab_mid, staging.put(plane),
-            self.policy.auth_tag_len, self.policy.cipher != Cipher.NULL,
-            off_const=_uniform_off(payload_off,
-                                   plane.shape[-1] - staging.TAIL))
+            self.policy.auth_tag_len, self.policy.cipher != Cipher.NULL)
         return staging.Launch((out,), _split_fanout, h2d_arrays=1,
                               h2d_bytes=plane.nbytes)
 
@@ -735,14 +722,9 @@ class RtpTranslator:
         them here, so ONE array goes to the device and one plane comes
         back.  Returns the `staging.Launch` in flight, whose `fetch()`
         gives host (wire bytes `[rows, width]`, wire lengths)."""
-        from libjitsi_tpu.transform.srtp.context import _uniform_off
-
         tab_rk, tab_gm = self._device()
         staging.pack(plane, (recv, length, payload_off), iv12)
-        out = _fanout_protect_gcm(
-            tab_rk, tab_gm, staging.put(plane),
-            aad_const=_uniform_off(payload_off,
-                                   plane.shape[-1] - staging.TAIL))
+        out = _fanout_protect_gcm(tab_rk, tab_gm, staging.put(plane))
         return staging.Launch(
             (out,), _split_fanout, h2d_arrays=1, h2d_bytes=plane.nbytes,
             counts={"gm_gather_bytes": len(recv) * GM_BYTES,

@@ -19,8 +19,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from libjitsi_tpu.kernels.aes import (ctr_crypt_offset, ctr_crypt_uniform,
-                                      f8_crypt_offset, f8_crypt_uniform)
+from libjitsi_tpu.core.packet import payload_blocks
+from libjitsi_tpu.kernels.aes import (ctr_crypt_offset, ctr_crypt_rows,
+                                      ctr_crypt_uniform, f8_crypt_offset,
+                                      f8_crypt_uniform)
 from libjitsi_tpu.kernels.scatter import gather_span as _gather_span
 from libjitsi_tpu.kernels.scatter import scatter_bytes
 from libjitsi_tpu.kernels.sha1 import hmac_sha1
@@ -111,11 +113,37 @@ def srtp_protect(
             data = ctr_crypt_offset(
                 round_keys, iv, data, payload_off, length - payload_off
             )
+    return _append_tag(data, length, roc, midstates, tag_len)
+
+
+def _append_tag(data, length, roc, midstates, tag_len: int):
+    """HMAC-SHA1 over data[:length] || ROC, truncated to `tag_len` and
+    appended (RFC 3711 section 4.2); `tag_len` 0 appends nothing."""
     if tag_len:
         tags = _auth_tags(data, length, _u32_bytes(jnp.asarray(roc)), midstates)
         data = _scatter_tag(data, length, tags, tag_len)
         length = length + tag_len
     return data, length
+
+
+@functools.partial(jax.jit, static_argnames=("tag_len", "encrypt"))
+def srtp_protect_rows(data, length, payload_off, round_keys, iv, midstates,
+                      roc, tag_len: int, encrypt: bool = True):
+    """`srtp_protect` (AES-CM) whose payload offset is DATA: arguments
+    and bytes as `srtp_protect`'s, but the keystream is aligned a row by
+    `ctr_crypt_rows`' shift ladder, so there is one program a shape
+    whatever header lengths a batch carries, at the static form's cost.
+    The SFU fan-out's form (sfu/translator.py, mesh/translator.py).
+    Rows are RTP packets with room for their tag: `payload_off` >= 12
+    and `length + tag_len` <= W bound the keystream (`payload_blocks`)."""
+    data = jnp.asarray(data, dtype=jnp.uint8)
+    length = jnp.asarray(length, dtype=jnp.int32)
+    payload_off = jnp.asarray(payload_off, dtype=jnp.int32)
+    if encrypt:
+        data = ctr_crypt_rows(
+            round_keys, iv, data, payload_off, length - payload_off,
+            nblocks=payload_blocks(data.shape[1], tag_len))
+    return _append_tag(data, length, roc, midstates, tag_len)
 
 
 @functools.partial(

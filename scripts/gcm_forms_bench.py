@@ -87,8 +87,11 @@ def part2_jobs(tab_rk, tab_gm, rng, tiny: bool):
 
     # the served per-row fan-out donates its plane: time its undonated
     # twin, so that one staged plane serves every launch
-    fanout_per_row = jax.jit(tr._fanout_protect_gcm.__wrapped__,
-                             static_argnames=("aad_const",))
+    per_row = jax.jit(tr._fanout_protect_gcm.__wrapped__)
+
+    def fanout_per_row(*a, aad_const=None):
+        # one form since PR 43: the AAD length is a word of the plane
+        return per_row(*a)
 
     def args(rows, aad):
         return (rng.integers(0, 256, (rows, WIDTH), dtype=np.uint8),

@@ -116,16 +116,18 @@ def test_srtp_cm_served_shape(one_chip, no_persistent_cache, tower_core,
 
 # The two CM calls the served tick makes, on one packed plane each
 # (core/staging.py) at the top warmed row class; the donated plane comes
-# back in the same shape, so the compiler may write over it.
-@pytest.mark.parametrize("module,fn_name,off_const", [
-    ("libjitsi_tpu.sfu.translator", "_fanout_protect", 12),
+# back in the same shape, so the compiler may write over it.  The
+# fan-out has ONE form a shape (its payload offset is a word of the
+# plane); its full-MTU width is `slow`.
+@pytest.mark.parametrize("module,fn_name,width,static", [
+    ("libjitsi_tpu.sfu.translator", "_fanout_protect", WIDTH, {}),
     ("libjitsi_tpu.transform.srtp.context",
-     "_unprotect_rtp_packed_donated", 12),
-    pytest.param("libjitsi_tpu.sfu.translator", "_fanout_protect", None,
-                 marks=pytest.mark.slow),
+     "_unprotect_rtp_packed_donated", WIDTH, {"off_const": 12}),
+    pytest.param("libjitsi_tpu.sfu.translator", "_fanout_protect", 1536,
+                 {}, marks=pytest.mark.slow),
 ])
 def test_packed_cm_served_shape(one_chip, no_persistent_cache, tower_core,
-                                module, fn_name, off_const):
+                                module, fn_name, width, static):
     import importlib
 
     from libjitsi_tpu.core import staging
@@ -133,8 +135,8 @@ def test_packed_cm_served_shape(one_chip, no_persistent_cache, tower_core,
     s = _on(one_chip)
     c = getattr(importlib.import_module(module), fn_name).lower(
         s((CAP, 11, 16), jnp.uint8), s((CAP, 2, 5), jnp.uint32),
-        s((4096, WIDTH + staging.TAIL), jnp.uint8), tag_len=10,
-        encrypt=True, off_const=off_const).compile()
+        s((4096, width + staging.TAIL), jnp.uint8), tag_len=10,
+        encrypt=True, **static).compile()
     _fits(c)
     assert "input_output_alias" in c.as_text()    # the donation took
 
@@ -157,16 +159,17 @@ def test_gcm_grouped_protect_served_shape(one_chip, no_persistent_cache,
 # The two GCM programs the served tick makes (per-row GHASH, the form
 # `context._gcm_form_grid` picks inside the row classes), each on one
 # packed plane `[rows, WIDTH + TAIL]` (core/staging.py): the fan-out at
-# the top warmed row class with the bridge's abs-send-time header (AAD
-# 20), the unprotect at the class 584 uplink packets pad to.  Each
+# the top warmed row class (one form: the AAD length is a word of the
+# plane), the unprotect at the class 584 uplink packets pad to.  Each
 # donates its plane.
-@pytest.mark.parametrize("module,fn_name,rows,aad", [
-    ("libjitsi_tpu.sfu.translator", "_fanout_protect_gcm", 4096, 20),
+@pytest.mark.parametrize("module,fn_name,rows,static", [
+    ("libjitsi_tpu.sfu.translator", "_fanout_protect_gcm", 4096, {}),
     ("libjitsi_tpu.transform.srtp.context", "_unprotect_gcm_dev_donated",
-     1024, 12),
+     1024, {"aad_const": 12}),
 ])
 def test_gcm_per_row_served_shape(one_chip, no_persistent_cache,
-                                  tower_core, module, fn_name, rows, aad):
+                                  tower_core, module, fn_name, rows,
+                                  static):
     import importlib
 
     from libjitsi_tpu.core import staging
@@ -174,8 +177,7 @@ def test_gcm_per_row_served_shape(one_chip, no_persistent_cache,
     s = _on(one_chip)
     c = getattr(importlib.import_module(module), fn_name).lower(
         s((CAP, 11, 16), jnp.uint8), s((CAP, 128, 128), jnp.int8),
-        s((rows, WIDTH + staging.TAIL), jnp.uint8),
-        aad_const=aad).compile()
+        s((rows, WIDTH + staging.TAIL), jnp.uint8), **static).compile()
     _fits(c)
     assert "input_output_alias" in c.as_text()    # the donation took
 
@@ -262,7 +264,7 @@ def test_mesh_served_programs_four_chips(topo, no_persistent_cache,
 
     fn = (ShardedSrtpTable(cap, mesh)._shard_fn("unprotect", 10, True, 12)
           if what == "unprotect"
-          else ShardedRtpTranslator(cap, mesh)._fanout_fn(20))
+          else ShardedRtpTranslator(cap, mesh)._fanout_fn())
     assert fn.__name__ == {"unprotect": "mesh_unprotect_rtp",
                            "fanout": "mesh_fanout_protect"}[what]
     # one packed lane plane (core/staging.py) beside the tables

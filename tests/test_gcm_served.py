@@ -574,7 +574,7 @@ def test_packed_per_row_programs_match_the_unpacked_reference(
     plane[:, :width] = data
     staging.pack(plane, (streams, length, off), iv12)
     back = np.asarray(tr_mod._fanout_protect_gcm(
-        tab_rk, tab_gm, jax.device_put(plane), aad_const=aad))
+        tab_rk, tab_gm, jax.device_put(plane)))
     assert back.shape == plane.shape and back.dtype == np.uint8
     out, out_len = tr_mod._split_fanout(back)
     np.testing.assert_array_equal(out_len, ref_len)
@@ -707,3 +707,78 @@ def test_packed_gcm_fanout_one_array_each_way(warmed_launch_guard,
     assert counts["expand"] == {"rows": 12, "rows_padded": 16,
                                 "width": 224, "launches": 1,
                                 "legs_max": 3, "class_cut": 0}
+
+
+# --------------------- the payload offset is an operand (PR 43) ---
+
+def _rtp_with_header(hlen, seq: int, ssrc: int, payload: bytes) -> bytes:
+    """An RTP packet whose header is `hlen` bytes: 12 bare, 16 one CSRC,
+    20 one extension word, 24 one CSRC and one extension word (what
+    WebRTC clients send); "forged": an X bit whose `ext_words` claims
+    1,000 words, past any packet."""
+    cc = 1 if hlen in (16, 24) else 0
+    ext = {20: b"\xbe\xde\x00\x01\x32\xaa\xbb\xcc",
+           24: b"\xbe\xde\x00\x01\x32\xaa\xbb\xcc",
+           "forged": b"\xbe\xde\x03\xe8"}.get(hlen, b"")
+    first = 0x80 | (0x10 if ext else 0) | cc
+    return (bytes([first, PT]) + seq.to_bytes(2, "big")
+            + (seq * 960 & 0xFFFFFFFF).to_bytes(4, "big")
+            + ssrc.to_bytes(4, "big") + b"\x00\x00\x00\x07" * cc + ext
+            + payload)
+
+
+@pytest.mark.parametrize("header", [12, 16, 20, 24, "mixed", "forged"],
+                         ids=str)
+@pytest.mark.parametrize("suite", ["cm", "gcm"])
+def test_fanout_takes_any_header_length_after_the_ladder(suite, header,
+                                                         oracle):
+    """The per-row fan-out has ONE program a (row class, width): its
+    payload offset is a word of the plane.  `fanout_warmups` gives one
+    thunk a width; after them a tick of 12-, 16-, 20- or 24-byte
+    headers, of all four at once, or with a forged `ext_words` among
+    them compiles NOTHING (a uniform 16 or 24 compiled a program on the
+    tick thread until PR 43), and every row is the scalar oracle's."""
+    prof = GCM if suite == "gcm" else SrtpProfile.AES_CM_128_HMAC_SHA1_80
+    rng = np.random.default_rng(4300)
+    t = tr_mod.RtpTranslator(24, prof)     # no other test's table shape
+    keys = {r: (rng.integers(0, 256, 16, dtype=np.uint8).tobytes(),
+                rng.integers(0, 256, prof.policy.salt_len,
+                             dtype=np.uint8).tobytes())
+            for r in range(1, 6)}
+    for r, (k, s) in keys.items():
+        t.add_receiver(r, k, s)
+    # two receiver lists: the per-row form under GCM too
+    t.connect(0, [1, 2, 3])
+    t.connect(1, [2, 3, 4, 5])
+    thunks = t.fanout_warmups(16)
+    assert len(thunks) == 2                # the audio width, the MTU
+    for thunk in thunks:
+        thunk()
+    before = compile_stats().compile_events
+
+    kinds = {"mixed": [12, 16, 20, 24],
+             "forged": [12, "forged", "forged", 12]}.get(header,
+                                                         [header] * 4)
+    index = [(2 << 16) + 500 + i for i in range(4)]    # ROC 2
+    plain = [_rtp_with_header(
+        k, index[i] & 0xFFFF, 0x3000 + i % 2,
+        rng.integers(0, 256, 60 + 17 * i, dtype=np.uint8).tobytes())
+        for i, k in enumerate(kinds)]
+    src = np.repeat(np.arange(4), [3, 4, 3, 4])
+    seal = oracle.protect_oracle_gcm if suite == "gcm" \
+        else oracle.protect_oracle
+    # one tick at the audio width, one at the MTU's: the last packet
+    # fills its row up to the room the tag needs (the longest payload
+    # window a row of that width can have)
+    tag = prof.policy.auth_tag_len
+    for full in (192 + 32 - tag, 1504 - tag):
+        pkts = plain[:-1] + [plain[-1] + bytes(full - len(plain[-1]))]
+        b = PacketBatch.from_payloads(pkts, stream=[0, 1, 0, 1])
+        out, recv = t.translate(b, np.asarray(index))
+        assert out.batch_size == len(src) == 14      # the 16-row class
+        for j, i in enumerate(src.tolist()):
+            if suite == "gcm" and kinds[i] == "forged":
+                continue      # no AEAD of a header longer than the packet
+            assert out.to_bytes(j) == seal(*keys[int(recv[j])], pkts[i],
+                                           index[i]), (j, kinds[i])
+    assert compile_stats().compile_events == before

@@ -321,7 +321,9 @@ def test_rtp_warmup_runs_beside_the_rest_rx_before_tx(monkeypatch):
     monkeypatch.setattr(os, "cpu_count", lambda: 4)   # pool: 1 per core
     lc, bridge = _lc(capacity=64, min_bucket=4)
     log, lock = [], threading.Lock()
-    # rx RTP + 2 fan-outs + SRTCP: passes only if all four overlap
+    # rx RTP + 2 fan-outs (one a width: `fanout_warmups`' count since
+    # the payload offset is an operand) + SRTCP: passes only if all
+    # four overlap
     together = threading.Barrier(4, timeout=30)
 
     def span(name, meet):

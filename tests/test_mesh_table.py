@@ -506,9 +506,8 @@ def test_warmups_on_sharded_objects_compile_the_mesh_programs(what,
         else:
             tr = ShardedRtpTranslator(cap, mesh)
             thunks = tr.fanout_warmups(16)
-            assert len(thunks) == 6       # 2 widths x 3 offset forms
-            for t in thunks[:3]:          # one width: three programs
-                t()
+            assert len(thunks) == 2       # one program a width
+            thunks[0]()
             assert tr.placements == 0 and tr._sh_dev == {}
             want = {"mesh_fanout_protect"}
     names = _compiled_names(caplog)
