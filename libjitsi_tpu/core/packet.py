@@ -122,10 +122,15 @@ class PacketBatch:
 # does not leave it at that: it cuts a tick's rows into launches of these
 # same classes where several small launches pad less than one large one
 # (sfu/translator.py `plan_launches`), each cut then padded to its class.
+# It pads, plans and warms by a class tuple of its own, which has 512
+# between 256 and 1,024 (a launch's device time follows the class, and
+# a conference of 8 at 37-73 packets a tick stands in that step);
+# nothing but the one-chip fan-out reads it.
 # ---------------------------------------------------------------------------
 
 LENGTH_CLASSES = (192, 512, DEFAULT_CAPACITY)
 ROW_CLASSES = (16, 64, 256, 1024, 4096)
+FANOUT_ROW_CLASSES = tuple(sorted(ROW_CLASSES + (512,)))
 CLASS_HEADROOM = 32   # room for auth tag + SRTCP index word growth
 
 
@@ -138,16 +143,21 @@ def payload_blocks(width: int, tag_len: int) -> int:
     return max(0, width - tag_len - RTP_FIXED_HEADER_LEN + 15) // 16
 
 
-def _round_rows(n: int) -> int:
-    for r in ROW_CLASSES:
+def _round_rows(n: int, classes=ROW_CLASSES) -> int:
+    for r in classes:
         if n <= r:
             return r
     # beyond the table: round up to a multiple of the largest class so
     # big batches still land on a bounded set of compiled shapes (a raw
     # row count here would jit-compile fresh for EVERY distinct batch
     # size — cache churn that melts a production tick)
-    top = ROW_CLASSES[-1]
+    top = classes[-1]
     return (n + top - 1) // top * top
+
+
+def _round_fanout_rows(n: int) -> int:
+    """`_round_rows` over the fan-out's own classes."""
+    return _round_rows(n, FANOUT_ROW_CLASSES)
 
 
 def bucket_by_size(batch: "PacketBatch",

@@ -9,11 +9,14 @@ and overload shedding can "restore" a stream that already left.  One
 
 1. **O(1) slot admit/evict into pre-compiled bucketed shapes** — the
    device only ever sees the size-class shapes of core/packet.py
-   (`LENGTH_CLASSES` x `ROW_CLASSES`); the manager warms each row class
-   OFF-TICK the first time the population bucket (power of two) could
-   reach it, so growing from 63 to 64 streams compiles nothing on the
-   media path.  `utils/compile_cache.CompileCacheStats` brackets every
-   tick (`tick_begin`/`tick_end`, wired by BridgeSupervisor): any
+   (`LENGTH_CLASSES` x `ROW_CLASSES`, and for the one-chip fan-out,
+   which has a 512-row class of its own, `FANOUT_ROW_CLASSES`); the
+   manager warms each row class, and with it the fan-out's classes
+   between it and the class below, OFF-TICK the first time the
+   population bucket (power of two) could reach it, so growing from 63
+   to 64 streams compiles nothing on the media path.
+   `utils/compile_cache.CompileCacheStats` brackets every tick
+   (`tick_begin`/`tick_end`, wired by BridgeSupervisor): any
    compile event inside the window increments `datapath_recompiles`,
    and `assert_datapath_clean()` turns the "zero recompiles ever land
    on the data path" claim into a checkable invariant.
@@ -59,7 +62,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from libjitsi_tpu.core.packet import ROW_CLASSES
+from libjitsi_tpu.core.packet import FANOUT_ROW_CLASSES, ROW_CLASSES
 from libjitsi_tpu.utils.compile_cache import (compile_concurrently,
                                                compile_stats)
 from libjitsi_tpu.utils.flight import FlightRecorder
@@ -1062,8 +1065,9 @@ class StreamLifecycleManager:
         (`launch_rows`; `ROW_CLASSES[-1]` once the ladder is whole): a
         backlog tick of a small population stays inside what its
         ladder compiled too.  Both ladders warm every class up to
-        their cover, so the classes under `launch_rows` are warm and
-        `translator.plan_launches` may cut a tick by them."""
+        their cover, the fan-out's own classes between them too
+        (`_warm_class`), so the classes under `launch_rows` are warm
+        and `translator.plan_launches` may cut a tick by them."""
         tr = getattr(self.bridge, "translator", None)
         warmed = self._warm_rows | self._warm_lrows
         if warmed and hasattr(tr, "launch_rows"):
@@ -1074,7 +1078,11 @@ class StreamLifecycleManager:
         (`rtp`; listener rows have none), the fan-out expansion
         (packets x receivers has its own class-padded shape space) and
         control traffic (NACK/RR/SR ride the same zero-recompile
-        discipline as media).
+        discipline as media).  A translator that pads its rows here
+        (`_pads_rows`: not the mesh's, whose lanes `_OwnerPlan` pads to
+        `ROW_CLASSES`) pads them to `FANOUT_ROW_CLASSES`: the rung
+        warms the fan-out at every such class over the rung below, so
+        the 512-row fan-out joins the pool of the 1,024-row rung.
 
         Nothing here is timed (the GCM form is a rule of the shape,
         `context._gcm_form_grid`), so the RTP pair, the fan-out variants
@@ -1089,7 +1097,11 @@ class StreamLifecycleManager:
             thunks.append(lambda: (rx.warmup_rtp(rc, payload_len=plen),
                                    tx.warmup_rtp(rc, payload_len=plen)))
         if tr is not None and hasattr(tr, "fanout_warmups"):
-            thunks += tr.fanout_warmups(rc, payload_len=plen)
+            under = max((r for r in ROW_CLASSES if r < rc), default=0)
+            for frc in (FANOUT_ROW_CLASSES
+                        if getattr(tr, "_pads_rows", False) else (rc,)):
+                if under < frc <= rc:
+                    thunks += tr.fanout_warmups(frc, payload_len=plen)
         if hasattr(rx, "warmup_rtcp"):
             thunks.append(lambda: (rx.warmup_rtcp(rc),
                                    tx.warmup_rtcp(rc)))

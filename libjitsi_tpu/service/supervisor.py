@@ -817,6 +817,14 @@ class BridgeSupervisor:
                       "warmed row class and went out as launches of "
                       "smaller classes, which padded less",
                 kind="counter")
+            registry.register_multi(
+                "fanout_launch_rows_total",
+                lambda: [({"rows": str(c)}, float(n)) for c, n in sorted(
+                    self.bridge.translator.fanout_launch_rows.items())],
+                help_="per-row fan-out device calls by the row class "
+                      "their rows were padded to (none on a mesh, "
+                      "whose owner plan pads the lanes a chip)",
+                kind="counter")
         if hasattr(self.bridge, "_video"):
             # simulcast/SVC forwarders are per-receiver objects; export
             # the fleet-wide sums (drift rule: every bumped counter is

@@ -29,8 +29,9 @@ import numpy as np
 
 from libjitsi_tpu.core import staging
 from libjitsi_tpu.core.packet import (CLASS_HEADROOM, DEFAULT_CAPACITY,
-                                      LENGTH_CLASSES, ROW_CLASSES,
-                                      PacketBatch, _round_rows)
+                                      FANOUT_ROW_CLASSES, LENGTH_CLASSES,
+                                      ROW_CLASSES, PacketBatch,
+                                      _round_fanout_rows)
 from libjitsi_tpu.kernels import gcm as gcm_kernel
 from libjitsi_tpu.kernels.aes import aes_encrypt_np, expand_key
 from libjitsi_tpu.kernels.ghash import GM_BYTES, ghash_matrix
@@ -46,7 +47,8 @@ from libjitsi_tpu.utils.tracing import span_of
 def _round_width(w: int) -> int:
     """Fan-out data width quantized to the packet size classes (+ tag
     headroom) so the compiled-shape space stays (LENGTH_CLASSES x
-    ROW_CLASSES), independent of the tick's exact longest packet."""
+    FANOUT_ROW_CLASSES), independent of the tick's exact longest
+    packet."""
     for c in LENGTH_CLASSES:
         if w <= c + CLASS_HEADROOM:
             return c + CLASS_HEADROOM
@@ -68,17 +70,17 @@ def _gcm_leg_major(legs: int, packets: int) -> bool:
     classes: it serves from `GCM_LEG_MAJOR_MIN_LEGS` legs, where that
     grid is at most twice the per-row form's padded rows."""
     return (legs >= GCM_LEG_MAJOR_MIN_LEGS and packets >= 2
-            and _round_rows(legs) * _round_rows(packets)
-            <= 2 * _round_rows(legs * packets))
+            and _round_fanout_rows(legs) * _round_fanout_rows(packets)
+            <= 2 * _round_fanout_rows(legs * packets))
 
 
 def _cycle_rows(n: int) -> Optional[np.ndarray]:
-    """Row indices padding `n` up to its ROW_CLASSES bucket by cycling
-    the real rows (the bucket_by_size idiom — fan-out encrypt reads
-    table state but never writes it, so repeats are SRTP-safe; padded
-    output rows are sliced off in PendingTranslate).  None when `n`
-    already sits on a class boundary."""
-    n_pad = _round_rows(n)
+    """Row indices padding `n` up to its FANOUT_ROW_CLASSES bucket by
+    cycling the real rows (the bucket_by_size idiom — fan-out encrypt
+    reads table state but never writes it, so repeats are SRTP-safe;
+    padded output rows are sliced off in PendingTranslate).  None when
+    `n` already sits on a class boundary."""
+    n_pad = _round_fanout_rows(n)
     return np.resize(np.arange(n), n_pad) if n_pad > n else None
 
 
@@ -87,33 +89,40 @@ def _cycle_rows(n: int) -> Optional[np.ndarray]:
 #: (PERF.md section 3 names the run): its `fanout_dispatch` +
 #: `fanout_wait` + `fanout_d2h` + `nack_cache` + `egress` read 1.31 ms
 #: (quartiles 1.23-1.39) over the 1,024-row program's 1.786 us a row =
-#: 734 rows (686-775).  Kept at 752 = 1,024 - 256 - 16, inside those
-#: quartiles: the least value at which no tick of at most 1,024 rows is
-#: cut (under it ticks of 257-272 rows go out as 256 + 16, which the
-#: GCM cell, beside the parent, did not read better for)
+#: 734 rows (686-775).  Kept at 752, inside those quartiles, as PR 41
+#: measured and shipped it: it was then also the least value at which
+#: no tick of at most 1,024 rows is cut (1,024 - 256 - 16; under it
+#: ticks of 257-272 rows went out as 256 + 16, which the GCM cell,
+#: beside the parent, did not read better for).  With the fan-out's
+#: 512-row class that edge lies at 496 (the binding case is 513 rows
+#: as 512 + 16), so 752 stands for the measurement alone; no tick of
+#: at most 1,024 rows is cut at it either
 LAUNCH_COST_ROWS = 752
 
 
 def plan_launches(rows: int, top: int,
                   launch_cost: Optional[int] = None) -> List[int]:
     """The real rows of each launch of a tick of `rows` fan-out rows,
-    in row order; every launch is padded to its own row class, and the
-    classes up to `top` (`RtpTranslator.launch_rows`) are the warmed
-    ones.  Whole launches of `top` rows go first while more than `top`
-    remain.  What is left goes out as one launch in its covering class
-    or, for a class c under that, as whole launches of c rows and the
+    in row order; every launch is padded to its own row class (the
+    fan-out's: `FANOUT_ROW_CLASSES`), and the classes up to `top`
+    (`RtpTranslator.launch_rows`) are the warmed ones.  Whole launches
+    of `top` rows go first while more than `top` remain.  What is left
+    goes out as one launch in its covering class or, for a class c
+    under that, as whole launches of c rows and the
     remainder in the remainder's own class: whichever pads least,
     where a launch after the first counts `launch_cost` rows (fewer
     launches on a tie).  1,164 rows are 1,024 + 140 (padded 1,024 +
-    256) and not one launch of 4,096.  `launch_cost` None: cuts at
-    `top` alone (a translator that pads nothing here)."""
+    256) and not one launch of 4,096; 300 rows are one launch, padded
+    512.  `launch_cost` None: cuts at `top` alone (a translator that
+    pads nothing here)."""
     whole, tail = divmod(rows, top)
     if not tail or launch_cost is None:
         return [top] * whole + [tail] * bool(tail)
     plans = [[tail]] + [[c] * (tail // c) + [tail % c] * bool(tail % c)
-                        for c in ROW_CLASSES if c < _round_rows(tail)]
+                        for c in FANOUT_ROW_CLASSES
+                        if c < _round_fanout_rows(tail)]
     return [top] * whole + min(plans, key=lambda cut: (
-        sum(map(_round_rows, cut)) + launch_cost * (len(cut) - 1),
+        sum(map(_round_fanout_rows, cut)) + launch_cost * (len(cut) - 1),
         len(cut)))
 
 
@@ -210,13 +219,16 @@ class RtpTranslator:
         #: top one (`_bound_fanout`); no option sets it
         self.launch_rows = ROW_CLASSES[-1]
         # device calls the fan-out has made, ticks it cut in two or
-        # more, and those of them that fit `launch_rows` and were cut
-        # by the row classes under it (/metrics:
-        # `fanout_launches_total`, `fanout_split_ticks_total`,
-        # `fanout_class_cut_ticks_total`)
+        # more, those of them that fit `launch_rows` and were cut by
+        # the row classes under it, and the calls by the rows they
+        # were padded to (/metrics: `fanout_launches_total`,
+        # `fanout_split_ticks_total`, `fanout_class_cut_ticks_total`,
+        # `fanout_launch_rows_total{rows}`: the per-row launches of a
+        # translator that pads them here, so a row class a label)
         self.fanout_launches = 0
         self.fanout_split_ticks = 0
         self.fanout_class_cut_ticks = 0
+        self.fanout_launch_rows: Dict[int, int] = {}
         # the bridge hands its loop's PipelineTracer and PhaseProfiler
         # here; a translator standing alone spans and samples nothing
         self.tracer = None
@@ -357,12 +369,12 @@ class RtpTranslator:
     # ------------------------------------------------------------- warmup
     def fanout_warmups(self, rows: int, payload_len: int = 160
                        ) -> List[Callable[[], None]]:
-        """Pre-compiling the fan-out kernels of one ROW_CLASSES bucket,
-        off the data path, as one thunk per program: each compiles its
-        own program when called and they share nothing, so a caller may
-        run them side by side (StreamLifecycleManager does, when the
-        population bucket grows, before any admit can drive traffic at
-        the new scale).  Covers the class-padded shapes
+        """Pre-compiling the fan-out kernels of one FANOUT_ROW_CLASSES
+        bucket, off the data path, as one thunk per program: each
+        compiles its own program when called and they share nothing, so
+        a caller may run them side by side (StreamLifecycleManager
+        does, when the population bucket grows, before any admit can
+        drive traffic at the new scale).  Covers the class-padded shapes
         translate_async produces.  The payload offset is an operand of
         the per-row program, so one thunk a width covers every header
         length.  Reads the live key tables (row 0, key material
@@ -377,7 +389,7 @@ class RtpTranslator:
         largest packet's LENGTH_CLASSES bucket, so this warms the class
         covering `payload_len` (the configured media size) and the
         full-MTU class (video keyframes, FEC bursts)."""
-        rows = _round_rows(max(1, rows))
+        rows = _round_fanout_rows(max(1, rows))
         tag = self.policy.auth_tag_len
         widths = sorted({_round_width(12 + payload_len + tag),
                          _round_width(DEFAULT_CAPACITY + tag)})
@@ -402,7 +414,7 @@ class RtpTranslator:
         def leg_major(w: int, aad: int) -> None:
             # legs = this bucket, packets = the smallest row class
             # (both axes class-padded live)
-            p = _round_rows(1)
+            p = _round_fanout_rows(1)
             pdata = np.zeros((p, w), dtype=np.uint8)
             plen = np.full(p, 12 + payload_len, dtype=np.int32)
             iv = np.zeros((rows, p, 12), dtype=np.uint8)
@@ -527,13 +539,16 @@ class RtpTranslator:
                 sizes = self._plan(len(recv))
                 cuts = [(b - n, b) for n, b in
                         zip(sizes, itertools.accumulate(sizes))]
+                # the rows of each launch as it leaves here: its row
+                # class where this translator pads (each launch's own
+                # `expand` books it, `row_class`)
+                padded = [_round_fanout_rows(n) if self._pads_rows else n
+                          for n in sizes]
                 # the row classes cut a tick that fits the largest
                 class_cut = int(len(cuts) > 1 and len(recv) <= top)
                 sp.note(rows=len(recv), width=pw, launches=len(cuts),
                         legs_max=int(counts.max()), class_cut=class_cut,
-                        rows_padded=sum(
-                            _round_rows(b - a) if self._pads_rows
-                            else b - a for a, b in cuts))
+                        rows_padded=sum(padded), row_class=padded[0])
                 args = self._expand_rows(batch, rowv, pw, *cuts[0])
         if off0 is not None:
             self.fanout_launches += 1
@@ -549,7 +564,7 @@ class RtpTranslator:
             # launch books what it always did
             nth = {"launch": k} if len(cuts) > 1 else {}
             if k:
-                with span_of(tracer, "expand"):
+                with span_of(tracer, "expand", row_class=padded[k]):
                     args = self._expand_rows(batch, rowv, pw, a, b)
             with staging.dispatch(tracer, "fanout", **nth) as sp, \
                     phase_of(self.perf, "dispatch"):
@@ -560,6 +575,10 @@ class RtpTranslator:
         self.fanout_launches += len(parts)
         self.fanout_split_ticks += int(len(parts) > 1)
         self.fanout_class_cut_ticks += class_cut
+        if self._pads_rows:
+            by_rows = self.fanout_launch_rows
+            for c in padded:
+                by_rows[c] = by_rows.get(c, 0) + 1
         return PendingTranslate(parts, batch.capacity, tracer=tracer,
                                 perf=self.perf)
 
@@ -806,7 +825,7 @@ class PendingTranslate:
             else:
                 # drop the class-padding rows (cycled copies appended
                 # by translate_async to keep the fan-out shapes on the
-                # ROW_CLASSES grid)
+                # FANOUT_ROW_CLASSES grid)
                 n = len(recv)
                 arr, lens = arr[:n], lens[:n]
             return PacketBatch(arr, lens, recv.astype(np.int32))

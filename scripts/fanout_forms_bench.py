@@ -5,9 +5,10 @@ the ladder warmed such a form twice a width, and a third, until
 PR 43), the per-row
 gathers (`gather`, the old `off_const=None` form) and the shift ladder
 the fan-out runs now (`rows`: `kernel.srtp_protect_rows`,
-`gcm.gcm_protect_rows`), CM and GCM, at the 256-, 1,024- and 4,096-row
-classes of the 224-byte width (and the 1,024-row class of the 1,536
-one).
+`gcm.gcm_protect_rows`), CM and GCM, at the 256-, 512-, 1,024- and
+4,096-row classes of the 224-byte width (512 is the fan-out's own
+class, `core/packet.py:FANOUT_ROW_CLASSES`: PR 44 sized it here before
+the ladder warmed it) and the 1,024-row class of the 1,536 one.
 
     chiprun -- python3 scripts/fanout_forms_bench.py [--tiny]
 
@@ -90,7 +91,8 @@ def main() -> int:
         return 1
     capacity = 512 if tiny else CAPACITY
     shapes = ([(16, 224)] if tiny else
-              [(256, 224), (1024, 224), (4096, 224), (1024, 1536)])
+              [(256, 224), (512, 224), (1024, 224), (4096, 224),
+               (1024, 1536)])
     rng = np.random.default_rng(43)
     tabs = {
         "cm": (jnp.asarray(rng.integers(0, 256, (capacity, 11, 16),

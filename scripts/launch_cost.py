@@ -14,7 +14,9 @@ second launch with `launch` 1: the cost is that launch's
 events of the fan-out program that last what a 1,024-row launch lasts).
 Ticks whose second launch pads to the 1,024-row class wait for a
 program of their own and are told apart from those whose second launch
-is a small one (`rows_padded` of the tick).  One JSON line a trace.
+is a small one (`rows_padded` of the tick; `second_by_rows` counts them
+by the class of that launch, the fan-out's own 512-row class among
+them since PR 44).  One JSON line a trace.
 """
 
 import json
@@ -30,8 +32,10 @@ import xstats  # noqa: E402
 
 STAGES = ("fanout_dispatch", "fanout_wait", "fanout_d2h", "nack_cache",
           "egress")
-#: a 1,024-row CM or per-row GCM fan-out program lasts 1.87 / 2.03 ms on
-#: a v5e, the 256-row one 0.53 and the 4,096-row one 8.4 (PERF.md)
+#: a 1,024-row CM or per-row GCM fan-out program lasts 1.84 / 2.04 ms on
+#: a v5e, the 256-row one 0.53 / 0.58, the 512-row one 0.93 / 1.06 and
+#: the 4,096-row one 8.4 / 8.0 (PERF.md section 6, PR 44): only the
+#: 1,024-row one falls inside this window
 PROGRAM_1024_MS = (1.4, 2.8)
 
 
@@ -59,7 +63,8 @@ def read(path: str) -> dict:
     out = {"trace": path, "ticks": len(ticks),
            "ticks_by_launches": {}, "class_cut_ticks": 0,
            "program_1024_ms": med(p1024), "programs_1024": len(p1024),
-           "device_us_a_row": round(us_row, 4) if us_row else None}
+           "device_us_a_row": round(us_row, 4) if us_row else None,
+           "second_by_rows": {}}
     kinds = {"second_small": [], "second_1024": []}
     for t in ticks.values():
         exp = t.get("expand")
@@ -75,6 +80,8 @@ def read(path: str) -> dict:
                 and len(t["second"]) == len(STAGES)):
             kinds["second_small" if second < 1024
                   else "second_1024"].append(t)
+            by = out["second_by_rows"]
+            by[int(second)] = by.get(int(second), 0) + 1
     for kind, ts in kinds.items():
         cost = [sum(t["second"].values()) for t in ts]
         out[kind] = {

@@ -182,6 +182,36 @@ def test_gcm_per_row_served_shape(one_chip, no_persistent_cache,
     assert "input_output_alias" in c.as_text()    # the donation took
 
 
+# The fan-out's own 512-row class (`core/packet.py:FANOUT_ROW_CLASSES`;
+# the ladder warms it beside the 1,024-row rung): the same two
+# functions at `[512, WIDTH + TAIL]`, under the names the benchmark's
+# readers look for.
+@pytest.mark.parametrize("suite", ["cm", "gcm"])
+def test_fanout_512_row_class_served_shape(one_chip, no_persistent_cache,
+                                           tower_core, suite):
+    from libjitsi_tpu.core import staging
+    from libjitsi_tpu.core.packet import FANOUT_ROW_CLASSES
+    from libjitsi_tpu.sfu import translator
+
+    assert 512 in FANOUT_ROW_CLASSES
+    s = _on(one_chip)
+    plane = s((512, WIDTH + staging.TAIL), jnp.uint8)
+    if suite == "cm":
+        lowered = translator._fanout_protect.lower(
+            s((CAP, 11, 16), jnp.uint8), s((CAP, 2, 5), jnp.uint32),
+            plane, tag_len=10, encrypt=True)
+    else:
+        lowered = translator._fanout_protect_gcm.lower(
+            s((CAP, 11, 16), jnp.uint8), s((CAP, 128, 128), jnp.int8),
+            plane)
+    c = lowered.compile()
+    _fits(c)
+    text = c.as_text()
+    assert "input_output_alias" in text           # the donation took
+    assert ("jit__fanout_protect_gcm" if suite == "gcm"
+            else "jit__fanout_protect") in text
+
+
 @pytest.mark.slow
 def test_keystream_fill_chunk(one_chip, no_persistent_cache, tower_core):
     from libjitsi_tpu.transform.srtp import keystream as ks

@@ -22,7 +22,7 @@ import pytest
 import libjitsi_tpu
 from libjitsi_tpu.core import staging
 from libjitsi_tpu.core.packet import (ROW_CLASSES, PacketBatch,
-                                      _round_rows)
+                                      _round_fanout_rows, _round_rows)
 from libjitsi_tpu.kernels import registry
 from libjitsi_tpu.rtp import header as rtp_header
 from libjitsi_tpu.sfu import translator as tr_mod
@@ -138,6 +138,8 @@ def served(oracle, egress_tap):
             assert sup.ticks < 64, f"{lc.admits}/{ROWS} live"
         rec["after_ladder"] = (lc.datapath_recompiles,
                                compile_stats().compile_events)
+        rec["ladder"] = (sorted(lc._warm_rows),
+                         bridge.translator.launch_rows)
         for i in range(ROWS):
             s = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
             s.bind(("127.0.0.1", 0))
@@ -194,6 +196,24 @@ def served(oracle, egress_tap):
             tick(2)
             drain(rec["got"])
         tap.synchronous = False
+        # a round that stands in the fan-out's own 512-row class: 40
+        # senders, a packet each, 280 (packet, receiver) rows in one
+        # tick, judged with the rest
+        for i in range(0, ROWS, 8):
+            for j in range(5):
+                send(i + j, fresh(i + j))
+        before = dict(bridge.translator.fanout_launch_rows)
+        counts = {}
+        for _ in range(2):
+            tick()
+            if "expand" in sup.last_counts:
+                counts = {k: dict(v) for k, v in sup.last_counts.items()}
+        drain(rec["got"])
+        rec["round_of_280"] = (
+            counts,
+            {c: n - before.get(c, 0) for c, n in
+             bridge.translator.fanout_launch_rows.items()
+             if n > before.get(c, 0)})
         rec["forwarded"] = bridge.forwarded - forwarded0
         rec["handed"] = list(tap.handed)
         rec["ports"] = [s.getsockname()[1] for s in socks]
@@ -292,6 +312,26 @@ def test_served_zero_datapath_recompiles_after_the_ladder(served):
     assert not h["shed"] and not h["quarantined"] and not h["level"]
 
 
+def test_a_served_tick_of_280_rows_runs_the_512_row_fanout(served):
+    """The ladder of 64 endpoints covers 1,024 rows and warmed the
+    fan-out's own 512-row class with that rung: a tick of 40 packets x
+    7 receivers is ONE launch padded to 512 rows, not 1,024 (and its
+    deliveries opened under the oracle, stayed in their conferences
+    and compiled nothing, with the rounds' above)."""
+    assert served["ladder"] == ([16, 64, 256, 1024], 1024)
+    counts, by_class = served["round_of_280"]
+    exp = counts["expand"]
+    assert (exp["rows"], exp["rows_padded"], exp["row_class"]) == \
+        (280, 512, 512)
+    assert (exp["launches"], exp["class_cut"]) == (1, 0)
+    assert by_class == {512: 1}
+    plane = 512 * (224 + staging.TAIL)
+    assert counts["fanout_dispatch"]["h2d_bytes"] == plane \
+        == counts["fanout_d2h"]["d2h_bytes"]
+    assert counts["fanout_dispatch"]["gm_gather_bytes"] \
+        == 512 * tr_mod.GM_BYTES
+
+
 def test_served_path_never_times_providers(served):
     """The ladder and 20 served ticks ran with `_time_once` raising; the
     single-chip GCM ops are not in the timed registry at all; and every
@@ -359,12 +399,15 @@ def test_equal_shapes_give_equal_forms(rows, per):
 @pytest.mark.parametrize("legs,packets,want", [
     (7, 2, False), (7, 16, False), (15, 64, False), (16, 1, False),
     (16, 2, False), (16, 16, True), (64, 16, True), (256, 16, True),
-    (4096, 16, True), (17, 2, False)])
+    (4096, 16, True), (17, 2, False),
+    # legs and packets round by the fan-out's own classes: 300 legs
+    # are a grid of 512 x 16 (not 1,024 x 16) against 8,192 rows
+    (300, 16, True), (300, 2, False), (512, 64, True), (513, 2, False)])
 def test_leg_major_rule_is_a_function_of_the_shape(legs, packets, want):
     assert tr_mod._gcm_leg_major(legs, packets) is want
     if want:
-        assert (_round_rows(legs) * _round_rows(packets)
-                <= 2 * _round_rows(legs * packets))
+        assert (_round_fanout_rows(legs) * _round_fanout_rows(packets)
+                <= 2 * _round_fanout_rows(legs * packets))
 
 
 # ------------------------------- both forms against the reference (c)
@@ -706,7 +749,8 @@ def test_packed_gcm_fanout_one_array_each_way(warmed_launch_guard,
     assert counts["fanout_d2h"] == {"d2h_arrays": 1, "d2h_bytes": plane}
     assert counts["expand"] == {"rows": 12, "rows_padded": 16,
                                 "width": 224, "launches": 1,
-                                "legs_max": 3, "class_cut": 0}
+                                "legs_max": 3, "class_cut": 0,
+                                "row_class": 16}
 
 
 # --------------------- the payload offset is an operand (PR 43) ---

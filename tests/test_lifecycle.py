@@ -354,6 +354,86 @@ def test_rtp_warmup_runs_beside_the_rest_rx_before_tx(monkeypatch):
     assert {n for _, n in log} == {"fan0", "fan1", "rtcp"}
 
 
+class FanStub:
+    """A translator as the ladder sees it: whether it pads its rows in
+    `expand` (the one-chip translator does, the mesh's does not), its
+    `launch_rows`, and `fanout_warmups` giving one thunk a width, which
+    records (rows, width) when the pool runs it."""
+
+    def __init__(self, pads_rows: bool):
+        self._pads_rows = pads_rows
+        self.launch_rows = 4096
+        self.warmed = []
+
+    def fanout_warmups(self, rows, payload_len):
+        return [lambda w=w: self.warmed.append((rows, w))
+                for w in (224, 1536)]
+
+
+@pytest.mark.parametrize("pads_rows, programs, fanout_classes", [
+    (True, 32, [16, 64, 256, 512, 1024, 4096]),
+    (False, 30, [16, 64, 256, 1024, 4096])], ids=["one_chip", "mesh"])
+def test_a_whole_ladder_warms_the_fanouts_own_classes(
+        pads_rows, programs, fanout_classes):
+    """Five rungs of six programs (the RTP pair, the fan-out at its two
+    widths, the SRTCP pair) and, for a translator that pads its rows to
+    the fan-out's own classes, the 512-row fan-out beside the 1,024-row
+    rung: 32 programs on one chip, the parent's 30 on a mesh, whose
+    lanes `_OwnerPlan` pads to `ROW_CLASSES`.  `launch_rows` is the
+    largest rung either way."""
+    lc, bridge = _lc(capacity=4096, min_bucket=4, pkts_per_stream=4)
+    tr = bridge.translator = FanStub(pads_rows)
+    lc._ensure_warm(2048)
+    rx, tx = bridge.rx_table, bridge.tx_table
+    assert rx.rtp_warms == tx.rtp_warms == [16, 64, 256, 1024, 4096]
+    assert rx.rtcp_warms == tx.rtcp_warms == [16, 64, 256, 1024, 4096]
+    assert sorted(tr.warmed) == [(c, w) for c in fanout_classes
+                                 for w in (224, 1536)]
+    assert (len(rx.rtp_warms) + len(tx.rtp_warms) + len(rx.rtcp_warms)
+            + len(tx.rtcp_warms) + len(tr.warmed)) == programs
+    assert sorted(lc._warm_rows) == [16, 64, 256, 1024, 4096]
+    assert tr.launch_rows == 4096
+
+
+def test_the_512_row_fanout_joins_the_pool_of_the_1024_row_rung():
+    """A ladder that stops at 256 rows warms no 512-row fan-out (no
+    tick of at most `launch_rows` = 256 rows pads to it); the rung that
+    warms 1,024 warms 512 in the same pool, so every class under
+    `launch_rows` is warm whenever `plan_launches` may cut by it."""
+    lc, bridge = _lc(capacity=4096, min_bucket=4, pkts_per_stream=1)
+    tr = bridge.translator = FanStub(True)
+    lc._ensure_warm(64)                   # 64 rows: cover 256
+    assert sorted({c for c, _w in tr.warmed}) == [16, 64, 256]
+    assert tr.launch_rows == 256
+    del tr.warmed[:]
+    lc._ensure_warm(256)                  # 256 rows: cover 1,024
+    assert sorted(tr.warmed) == [(c, w) for c in (512, 1024)
+                                 for w in (224, 1536)]
+    assert bridge.rx_table.rtp_warms == [16, 64, 256, 1024]
+    assert tr.launch_rows == 1024
+
+
+def test_a_listener_only_ladder_warms_the_512_row_fanout_too():
+    """Listener rows warm no uplink RTP, and the fan-out at every one
+    of its own classes under the cover."""
+    lc, bridge = _lc(capacity=4096, min_bucket=4)
+    tr = bridge.translator = FanStub(True)
+    lc._ensure_warm_listeners(200)        # bucket 256: cover 1,024
+    assert bridge.rx_table.rtp_warms == []
+    assert bridge.rx_table.rtcp_warms == [16, 64, 256, 1024]
+    assert sorted({c for c, _w in tr.warmed}) == [16, 64, 256, 512, 1024]
+    assert sorted(lc._warm_lrows) == [16, 64, 256, 1024]
+    assert tr.launch_rows == 1024
+
+
+def test_the_translators_say_whether_they_pad_their_rows():
+    from libjitsi_tpu.mesh.translator import ShardedRtpTranslator
+    from libjitsi_tpu.sfu.translator import RtpTranslator
+
+    assert RtpTranslator._pads_rows is True
+    assert ShardedRtpTranslator._pads_rows is False
+
+
 # -------------------------------------------- tick compile bracket
 
 def test_tick_bracket_counts_in_window_compiles(monkeypatch):

@@ -462,6 +462,11 @@ def test_owner_plan_lanes_are_row_classes(n_dev):
     # past the largest class: multiples of it
     big = _OwnerPlan(np.zeros(5000, np.int64), cap, rows_per, n_dev)
     assert big.per == 2 * ROW_CLASSES[-1]
+    # the fan-out's own 512-row class is the one-chip translator's: a
+    # hot shard of 257-512 rows still takes 1,024 lanes a chip
+    for hot in (257, 300, 512):
+        assert _OwnerPlan(np.zeros(hot, np.int64), cap, rows_per,
+                          n_dev).per == 1024
 
 
 def _compiled_names(caplog):
@@ -507,6 +512,9 @@ def test_warmups_on_sharded_objects_compile_the_mesh_programs(what,
             tr = ShardedRtpTranslator(cap, mesh)
             thunks = tr.fanout_warmups(16)
             assert len(thunks) == 2       # one program a width
+            # the ladder hands it `ROW_CLASSES` values, which round to
+            # themselves: the mesh ladder's shapes are what they were
+            assert tr._pads_rows is False
             thunks[0]()
             assert tr.placements == 0 and tr._sh_dev == {}
             want = {"mesh_fanout_protect"}

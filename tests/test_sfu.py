@@ -365,4 +365,5 @@ def test_packed_cm_fanout_one_array_each_way(warmed_launch_guard):
     assert counts["fanout_d2h"] == {"d2h_arrays": 1, "d2h_bytes": plane}
     assert counts["expand"] == {"rows": 12, "rows_padded": 16,
                                 "width": 224, "launches": 1,
-                                "legs_max": 3, "class_cut": 0}
+                                "legs_max": 3, "class_cut": 0,
+                                "row_class": 16}

@@ -44,7 +44,8 @@ import numpy as np
 import pytest
 
 import libjitsi_tpu
-from libjitsi_tpu.core.packet import ROW_CLASSES, PacketBatch, _round_rows
+from libjitsi_tpu.core.packet import (FANOUT_ROW_CLASSES, ROW_CLASSES,
+                                      PacketBatch, _round_fanout_rows)
 from libjitsi_tpu.rtp import rtcp
 from libjitsi_tpu.sfu import translator as translator_mod
 from libjitsi_tpu.sfu.translator import (LAUNCH_COST_ROWS, RtpTranslator,
@@ -107,19 +108,30 @@ def _plain(rng, ssrc: int, seq: int) -> bytes:
 
 # -------------------------------------------------------------- the plan
 
-#: rows of a tick -> the real rows of its launches at the real classes
-#: (16 / 64 / 256 / 1,024 / 4,096, all warmed) and the shipped launch
-#: cost (752 rows), written by hand: up to 1,024 rows a tick is one
-#: launch, byte for byte what it was (256 + 16 + 752 = 1,024 is a tie,
-#: and a tie is fewer launches); 1,025-2,304 rows pad less as one or
-#: two 1,024-row launches and a small one than as 4,096; above that the
-#: 4,096-row program runs as before, and whole launches of it go first
+#: rows of a tick -> the real rows of its launches at the fan-out's
+#: real classes (16 / 64 / 256 / 512 / 1,024 / 4,096, all warmed) and
+#: the shipped launch cost (752 rows), written by hand: up to 1,024
+#: rows a tick is one launch (300 rows pad to 512, 513 to 1,024: 512 +
+#: 16 + 752 is more); 1,025-2,560 rows pad less as one or two
+#: 1,024-row launches and a small one than as 4,096 (1,300 rows: 1,024
+#: + 512 computed where the row classes without 512 made 1,024 +
+#: 1,024; 2,400: 2,048 + 512 + 2 x 752 = 4,064, which the 512-row
+#: class brought under 4,096); above that the 4,096-row program runs as
+#: before, and whole launches of it go first
 PLAN = {
-    256: [256], 257: [257], 272: [272], 700: [700], 1024: [1024],
-    1025: [1024, 1], 1164: [1024, 140], 2048: [1024, 1024],
-    2112: [1024, 1024, 64], 2400: [2400], 4096: [4096],
-    4097: [4096, 1], 36792: [4096] * 8 + [4024],
+    256: [256], 257: [257], 272: [272], 300: [300], 512: [512],
+    513: [513], 528: [528], 700: [700], 1024: [1024],
+    1025: [1024, 1], 1164: [1024, 140], 1300: [1024, 276],
+    1536: [1024, 512], 1537: [1024, 513], 2048: [1024, 1024],
+    2112: [1024, 1024, 64], 2400: [1024, 1024, 352],
+    2560: [1024, 1024, 512], 2561: [2561], 4096: [4096],
+    4097: [4096, 1], 5396: [4096, 1024, 276],
+    36792: [4096] * 8 + [4024],
 }
+#: the rows those launches are padded to, where the fan-out's own
+#: class shows: a tick -> the classes its launches run
+PADDED = {300: [512], 513: [1024], 1164: [1024, 256], 1300: [1024, 512],
+          1537: [1024, 1024], 2400: [1024, 1024, 512]}
 
 
 @pytest.mark.parametrize("rows", sorted(PLAN))
@@ -133,27 +145,46 @@ def test_the_plan_against_hand_written_cuts(rows):
         [rows % ROW_CLASSES[-1]] * bool(rows % ROW_CLASSES[-1])
 
 
-@pytest.mark.parametrize("top", ROW_CLASSES[1:])
+@pytest.mark.parametrize("rows", sorted(PADDED))
+def test_the_plan_pads_to_the_fanouts_own_classes(rows):
+    assert [_round_fanout_rows(n) for n in plan_launches(
+        rows, ROW_CLASSES[-1], LAUNCH_COST_ROWS)] == PADDED[rows]
+
+
+def test_the_fanouts_classes_are_the_row_classes_and_512():
+    """One definition: `ROW_CLASSES` as it was, a subset of the
+    fan-out's tuple, which adds 512 and nothing else."""
+    assert ROW_CLASSES == (16, 64, 256, 1024, 4096)
+    assert FANOUT_ROW_CLASSES == (16, 64, 256, 512, 1024, 4096)
+    assert set(FANOUT_ROW_CLASSES) - set(ROW_CLASSES) == {512}
+    assert [_round_fanout_rows(n) for n in (1, 256, 257, 512, 513, 4097)] \
+        == [16, 256, 512, 512, 1024, 8192]
+
+
+@pytest.mark.parametrize("top", FANOUT_ROW_CLASSES[1:])
 @pytest.mark.parametrize("cost", [0, 8, LAUNCH_COST_ROWS, 10 ** 6])
 def test_no_planned_launch_lies_outside_the_warmed_classes(top, cost):
     """On a ladder warmed up to `top` (whole or partly), whatever the
     rows and the launch cost: launches in row order that sum to the
-    rows, none over `top` (so each pads to a warmed class), whole
+    rows, none over `top` and each padded to one of the fan-out's
+    classes up to it (which the ladder warms, all of them), whole
     launches first, and never more padded rows plus launch costs than
     cuts at `top` alone."""
     def padded(cut):
-        return sum(map(_round_rows, cut)) + cost * (len(cut) - 1)
+        return sum(map(_round_fanout_rows, cut)) + cost * (len(cut) - 1)
 
-    for rows in [*range(1, 600), 1024, 1025, 1164, 2304, 2305, 4095,
-                 4097, 5260, 9 * top + 27, 36792]:
+    for rows in [*range(1, 600), 1024, 1025, 1164, 1300, 2304, 2305,
+                 2560, 2561, 4095, 4097, 5260, 9 * top + 27, 36792]:
         cut = plan_launches(rows, top, cost)
         assert sum(cut) == rows and min(cut) > 0, (rows, cut)
-        assert max(map(_round_rows, cut)) <= top, (rows, cut)
+        assert all(_round_fanout_rows(n) in FANOUT_ROW_CLASSES
+                   and _round_fanout_rows(n) <= top
+                   for n in cut), (rows, cut)
         assert cut[:rows // top] == [top] * (rows // top), (rows, cut)
         # full launches of one class, then at most one remainder
         tail = cut[rows // top:]
         assert len(set(tail[:-1])) <= 1 and all(
-            n == _round_rows(n) for n in tail[:-1]), (rows, cut)
+            n == _round_fanout_rows(n) for n in tail[:-1]), (rows, cut)
         assert padded(cut) <= padded(plan_launches(rows, top)), (rows, cut)
     # at a launch cost beyond any padding nothing under `top` is cut
     if cost >= 10 ** 6:
@@ -161,17 +192,20 @@ def test_no_planned_launch_lies_outside_the_warmed_classes(top, cost):
 
 
 def test_the_shipped_launch_cost_cuts_no_tick_of_1024_rows_or_fewer():
-    """At 752 rows (1,024 - 256 - 16), which the shipped constant is,
-    every tick of the `talk-*` cells and of GCM (160-700 rows) stays
-    one launch, as before; one row less and ticks of 257-272 rows (37
-    and 38 packets of a conference of 8) would go out as 256 + 16."""
+    """At 752 rows, which the shipped constant is (PR 41's
+    measurement), every tick of the `talk-*` cells and of GCM (160-700
+    rows) stays one launch: every tail of 1-1,024 rows is.  With the
+    fan-out's 512-row class the least such cost is 496: one row less
+    and ticks of 513-528 rows would go out as 512 + 16 (without 512 it
+    was 752 itself, for 257-272 rows as 256 + 16)."""
     assert LAUNCH_COST_ROWS == 752
-    for top in ROW_CLASSES:
+    for top in FANOUT_ROW_CLASSES:
         assert all(plan_launches(r, top, LAUNCH_COST_ROWS)
                    == plan_launches(r, top) for r in range(1, 1025))
-    assert [r for r in range(1, 1025)
-            if len(plan_launches(r, ROW_CLASSES[-1], 751)) > 1] \
-        == list(range(257, 273))
+    cut_at = {cost: [r for r in range(1, 1025) if len(
+        plan_launches(r, ROW_CLASSES[-1], cost)) > 1]
+        for cost in (495, 496)}
+    assert cut_at == {495: list(range(513, 529)), 496: []}
 
 
 # ------------------------------------------------- the translator alone
@@ -244,7 +278,7 @@ def test_rows_are_cut_by_the_classes_and_come_back_in_order(
     pend = tr.translate_async(b, index)
     assert pend.launches == launches
     # every launch's plane has its own class's rows, none over the top
-    assert seen == [_round_rows(n) for n in sizes] and max(seen) <= top
+    assert seen == [_round_fanout_rows(n) for n in sizes] and max(seen) <= top
     parts = list(pend.each())
     assert [len(r) for _w, r in parts] == sizes
     wire, recv = pend.result()
@@ -267,13 +301,61 @@ def test_rows_are_cut_by_the_classes_and_come_back_in_order(
     exp = tracer.last_counts["expand"]
     assert exp["launches"] == launches and exp["legs_max"] == 63
     assert exp["rows"] == rows
-    assert exp["rows_padded"] == sum(seen)
+    # each launch's own `expand` books the class it is padded to
+    assert exp["rows_padded"] == exp["row_class"] == sum(seen)
+    assert tr.fanout_launch_rows == {c: seen.count(c) for c in seen}
     # the classes cut a tick that FITS `launch_rows`
     class_cut = int(launches > 1 and rows <= top)
     assert exp["class_cut"] == class_cut
     assert tr.fanout_launches == launches
     assert tr.fanout_split_ticks == int(launches > 1)
     assert tr.fanout_class_cut_ticks == class_cut
+
+
+@pytest.mark.parametrize("senders", [37, 43, 73], ids=str)
+@pytest.mark.parametrize("profile", [CM, GCM], ids=["cm", "gcm"])
+def test_a_tick_of_257_to_512_rows_runs_the_512_row_program(
+        profile, senders, oracle):
+    """37, 43 and 73 packets of conferences of 8 in one tick (259, 301
+    and 511 rows, where the GCM cell's ticks stand) on a ladder that
+    warmed 1,024 rows: ONE launch padded to the fan-out's own 512-row
+    class and not to 1,024, nothing compiled after the 512-row
+    warm-ups, every row the oracle's under its receiver's key."""
+    protect = oracle.protect_gcm if profile is GCM else oracle.protect_cm
+    legs_of = (7,) * senders
+    rows = 7 * senders
+    tr, keys = _translator(profile, legs_of, 1024)
+    tr.tracer = tracer = PipelineTracer(annotate=False)
+    thunks = tr.fanout_warmups(300)        # rounds to the 512-row class
+    assert len(thunks) == 2                # the audio width, the MTU
+    for thunk in thunks:
+        thunk()
+    seen = []
+    name = "_gcm_fanout_call" if profile is GCM else "_cm_fanout_call"
+    call = getattr(tr, name)
+
+    def spy(recv, plane, *rest):
+        seen.append(plane.shape)
+        return call(recv, plane, *rest)
+
+    setattr(tr, name, spy)
+    b, pls, index = _batch(np.random.default_rng(44), range(senders))
+    before = compile_stats().compile_events
+    assert tr.launches(b.stream) == 1
+    wire, recv = tr.translate(b, index)
+    assert compile_stats().compile_events == before
+    assert [s[0] for s in seen] == [512]
+    assert wire.batch_size == rows == len(recv)
+    for j in range(rows):
+        assert wire.to_bytes(j) == protect(
+            *_pair(keys[int(recv[j]), 1]), pls[j // 7], 900 + j // 7), j
+    tracer.take_ledger()
+    exp = tracer.last_counts["expand"]
+    assert (exp["rows"], exp["rows_padded"], exp["row_class"]) == \
+        (rows, 512, 512)
+    assert (exp["launches"], exp["class_cut"]) == (1, 0)
+    assert tr.fanout_launch_rows == {512: 1}
+    assert (tr.fanout_launches, tr.fanout_split_ticks) == (1, 0)
 
 
 def test_a_split_tick_is_byte_equal_to_the_same_packets_a_launch_each(
@@ -538,6 +620,8 @@ def _serve(oracle, tap_of) -> dict:
         rec["translator"] = (bridge.translator.fanout_launches,
                              bridge.translator.fanout_split_ticks,
                              bridge.translator.fanout_class_cut_ticks)
+        rec["launch_rows_by_class"] = dict(
+            bridge.translator.fanout_launch_rows)
         return rec
     finally:
         translator_mod.LAUNCH_COST_ROWS = cost0
@@ -651,7 +735,8 @@ def test_spans_a_launch_and_the_leaves_still_tile_the_tick(served, k):
     exp = counts["expand"]
     assert exp["launches"] == n and exp["legs_max"] == CONF - 1
     assert exp["rows"] == rows == sum(sizes)
-    assert exp["rows_padded"] == sum(map(_round_rows, sizes))
+    assert exp["rows_padded"] == exp["row_class"] \
+        == sum(map(_round_fanout_rows, sizes))
     assert exp["class_cut"] == _class_cut(rnd["packets"])
     # one packed plane each way a launch
     assert counts["fanout_dispatch"]["h2d_arrays"] == n
@@ -686,6 +771,16 @@ def test_metrics_count_launches_split_and_class_cut_ticks(served):
     assert f"fanout_launches_total {launches}" in text
     assert f"fanout_split_ticks_total {split}" in text
     assert f"fanout_class_cut_ticks_total {class_cut}" in text
+    # the launches by the class they were padded to (the latch tick's
+    # 63 rows: one more of 64)
+    by_class = {c: 0 for c in WARMED}
+    for c in [64] + [_round_fanout_rows(n) for p in ROUNDS
+                     for n in SIZES[p]]:
+        by_class[c] += 1
+    assert served["launch_rows_by_class"] == by_class
+    assert sum(by_class.values()) == launches
+    for c, n in by_class.items():
+        assert f'fanout_launch_rows_total{{rows="{c}"}} {n}' in text
     assert 'lifecycle_admit_rejected{reason="conference_full"} 1' in text
 
 

@@ -124,11 +124,24 @@ def test_the_split_tick_is_byte_equal_to_the_one_chip_bridge(both):
     assert all(mesh[k] == one[k] for k in one)
 
 
+#: legs a sender -> the rows of the one-chip translator's launches as
+#: they leave `expand`, padded to the fan-out's own classes
+TICKS = {"1164_rows": ((63,) * 18 + (30,), [1024, 256]),
+         "301_rows": ((7,) * 43, [512]),
+         "1300_rows": ((63,) * 20 + (40,), [1024, 512])}
+
+
+@pytest.mark.parametrize("tick", sorted(TICKS))
 @pytest.mark.parametrize("sharded", [False, True], ids=["one_chip", "mesh"])
-def test_the_mesh_translator_cuts_at_launch_rows_alone(sharded):
+def test_the_mesh_translator_cuts_at_launch_rows_alone(sharded, tick):
     """1,164 rows (18 senders of 63 legs and one of 30) at the real
     classes: the one-chip translator launches 1,024 + 256 rows, the
-    mesh translator one call of 1,164 unpadded rows, as before."""
+    mesh translator one call of 1,164 unpadded rows, as before.  The
+    fan-out's own 512-row class is the one-chip translator's alone:
+    301 rows leave it as 512 and 1,300 as 1,024 + 512, the mesh's
+    `expand` as the 301 and the 1,300 they are (its lanes a chip are
+    `_OwnerPlan`'s, padded to `ROW_CLASSES`), and it books no launch
+    by class."""
     keys = _keys(80)
     if sharded:
         tr = ShardedRtpTranslator(
@@ -137,7 +150,7 @@ def test_the_mesh_translator_cuts_at_launch_rows_alone(sharded):
         tr = RtpTranslator(CAPACITY)
     tr.add_receivers(range(80), [bytes(k[1][:16]) for k in keys],
                      [bytes(k[1][16:]) for k in keys])
-    legs_of = (63,) * 18 + (30,)
+    legs_of, padded = TICKS[tick]
     for s, n in enumerate(legs_of):
         tr.connect(100 + s, [(3 * s + j) % 80 for j in range(n)])
     assert tr.launch_rows == ROW_CLASSES[-1]
@@ -148,6 +161,8 @@ def test_the_mesh_translator_cuts_at_launch_rows_alone(sharded):
         [bytes([0x80, 111]) + bytes(10) + b"x" * 60] * len(legs_of),
         capacity=256, stream=[100 + s for s in range(len(legs_of))])
     pend = tr.translate_async(b, np.arange(len(legs_of), dtype=np.int64))
-    assert seen == ([1164] if sharded else [1024, 256])
+    assert seen == ([sum(legs_of)] if sharded else padded)
     assert pend.launches == len(seen)
-    assert tr.fanout_class_cut_ticks == int(not sharded)
+    assert tr.fanout_class_cut_ticks == int(len(seen) > 1)
+    assert tr.fanout_launch_rows == (
+        {} if sharded else {c: 1 for c in padded})
