@@ -44,6 +44,7 @@ which route an array per lane to its owning chip (mesh/table.py
 from __future__ import annotations
 
 import threading
+import time
 from typing import Callable, Optional, Sequence, Tuple
 
 import jax
@@ -151,7 +152,9 @@ class Launch:
     or a cache-hit unprotect; an unpacked mesh seam's outputs in lane
     layout); `split` turns their host copies into what the caller
     reads.  `fetch` waits, copies each
-    output once and caches; `h2d_arrays` / `h2d_bytes` / `d2h_arrays` /
+    output once and caches; `is_ready` says without waiting whether
+    the wait would be none, for a caller that collects the launch long
+    after it made it (`dispatched_at`); `h2d_arrays` / `h2d_bytes` / `d2h_arrays` /
     `d2h_bytes` count the arrays that really crossed.  `counts` is what
     else the caller's span should book for the call (the GCM calls:
     `gm_gather_bytes`, `grouped`; a mesh call: `shards`, `lanes`,
@@ -160,7 +163,8 @@ class Launch:
     four again)."""
 
     __slots__ = ("_outs", "_split", "_host", "h2d_arrays", "h2d_bytes",
-                 "d2h_arrays", "d2h_bytes", "counts", "d2h_counts")
+                 "d2h_arrays", "d2h_bytes", "counts", "d2h_counts",
+                 "dispatched_at")
 
     def __init__(self, outs, split: Optional[Callable] = None,
                  h2d_arrays: int = 0, h2d_bytes: int = 0,
@@ -173,6 +177,9 @@ class Launch:
         self.d2h_arrays = self.d2h_bytes = 0
         self.counts = counts or {}
         self.d2h_counts = d2h_counts or {}
+        #: `time.perf_counter()` when the call had been made (the jit
+        #: call returned): what a later collection measures from
+        self.dispatched_at = time.perf_counter()
 
     def copy_back_async(self) -> "Launch":
         """Ask for the outputs' host copies now: each starts when the
@@ -185,6 +192,14 @@ class Launch:
             if isinstance(o, jax.Array):
                 o.copy_to_host_async()
         return self
+
+    def is_ready(self) -> bool:
+        """Whether a `block_until_ready` now would return at once:
+        every output that is a device array has been computed (one
+        that is none counts as ready, as `copy_back_async` passes it
+        over).  Never waits."""
+        return all(o.is_ready() for o in self._outs
+                   if isinstance(o, jax.Array))
 
     def block_until_ready(self) -> "Launch":
         if self._host is None:

@@ -159,17 +159,15 @@ def assert_bridge_parity(cfg, mesh, capacity: int,
             "assembled mesh ConferenceBridge egress != single-chip")
 
 
-def run_sfu_once(cfg, mesh, capacity: int, rounds: int = 3,
-                 pipelined: bool = False) -> dict:
+def run_sfu_once(cfg, mesh, capacity: int, rounds: int = 3) -> dict:
     """One tiny 3-endpoint audio SFU conference over loopback UDP
-    (mesh-mode when `mesh` is not None; pipelined fan-out dispatch when
-    `pipelined`), deterministic tick clock; returns
-    {(endpoint, sender_ssrc, seq): wire_bytes}."""
+    (mesh-mode when `mesh` is not None), deterministic tick clock;
+    returns {(endpoint, sender_ssrc, seq): wire_bytes}."""
     from libjitsi_tpu.io import UdpEngine
     from libjitsi_tpu.service.sfu_bridge import SfuBridge
 
     sfu = SfuBridge(cfg, port=0, capacity=capacity, recv_window_ms=0,
-                    mesh=mesh, pipelined=pipelined)
+                    mesh=mesh)
     eps = []
     for k in range(3):
         ssrc = 0x600 + 9 * k
@@ -210,13 +208,13 @@ def run_sfu_once(cfg, mesh, capacity: int, rounds: int = 3,
     return got
 
 
-def assert_sfu_parity(cfg, mesh, capacity: int,
-                     pipelined: bool = False) -> None:
+def assert_sfu_parity(cfg, mesh, capacity: int) -> None:
     """Assembled mesh-mode SfuBridge fan-out must be byte-identical to
-    the single-chip SYNC bridge for the same conference (pipelined
-    mesh dispatch included)."""
+    the single-chip bridge for the same conference (both dispatch a
+    tick's fan-out and collect it in the next: the bridge's one
+    shape)."""
     plain = run_sfu_once(cfg, None, capacity)
-    meshed = run_sfu_once(cfg, mesh, capacity, pipelined=pipelined)
+    meshed = run_sfu_once(cfg, mesh, capacity)
     if len(plain) < 6:
         raise AssertionError("sfu parity run produced too little egress")
     if plain != meshed:

@@ -22,9 +22,9 @@ partitioned over a `jax.sharding.Mesh`:
 - results stay DEVICE-RESIDENT in lane layout until materialized: the
   scatter back to wire order is deferred (`_LazyArray`), so
   `protect_rtp_async` keeps its launch-overlap contract in mesh mode
-  and the bridges compose `mesh=...` with `pipelined=True`
-  (the 8-chip deployment is exactly the one that needs
-  launch overlap).
+  and the bridges compose with a mesh as they are: the mixer's
+  `pipelined=True`, and the SFU's one tick shape, which dispatches a
+  tick's fan-out and collects it in the next.
 
 Reference: `SRTPTransformer`'s per-SSRC context map scaled by running
 more JVMs; here the ONE table spans the mesh and `RTPTranslatorImpl`-

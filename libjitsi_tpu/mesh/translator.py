@@ -40,8 +40,8 @@ class ShardedRtpTranslator(ShardedRowsMixin, RtpTranslator):
     `translate_async` keeps its overlap contract in mesh mode: the
     sharded seams return a `staging.Launch` whose outputs stay on the
     mesh in lane layout (the CM fan-out: one packed plane), so
-    `PendingTranslate` holds device-resident lane buffers until
-    `.result()` — SfuBridge composes mesh with pipelined ticks.
+    `PendingTranslate` holds device-resident lane buffers until it is
+    collected: the SfuBridge's next tick, on a mesh as on one chip.
     """
 
     def __init__(self, capacity: int, mesh: Mesh,

@@ -153,7 +153,6 @@ class SfuBridge:
                  recv_window_ms: int = 1,
                  kernel_timestamps: bool = False,
                  abs_send_time_ext_id: int = 3,
-                 pipelined: bool = False,
                  pipeline_depth: int = 1,
                  mesh=None,
                  recovery_config: Optional[RecoveryConfig] = None,
@@ -164,8 +163,9 @@ class SfuBridge:
         self.ast_ext_id = abs_send_time_ext_id
         self.engine_mode = engine_mode
         self.ingest_rings = max(1, int(ingest_rings))
-        self.pipelined = pipelined or pipeline_depth > 1
-        self._pending_fanout: list = []
+        # the fan-out a tick has dispatched and the next collects:
+        # (PendingTranslate, the journey origin of its packets)
+        self._pending_fanout: Optional[tuple] = None
         self._media_ran = False
         self.registry = StreamRegistry(config, capacity=capacity)
         # rx_table: what endpoints SEND us (media + their SRTCP);
@@ -176,9 +176,9 @@ class SfuBridge:
         # sharded, not just its kernels.
         self._mesh = mesh
         if mesh is not None:
-            # composes with pipelined=True: the sharded seams defer
-            # their wire-order scatter (mesh/table._LazyArray), so the
-            # fan-out launch overlaps the next recv window in mesh mode
+            # the sharded seams leave their outputs on the mesh in lane
+            # layout until they are fetched, so the fan-out dispatched
+            # by one tick is collected by the next here as on one chip
             from libjitsi_tpu.mesh import (ShardedRtpTranslator,
                                            ShardedSrtpTable)
             self.rx_table = ShardedSrtpTable(capacity, mesh, profile)
@@ -215,8 +215,9 @@ class SfuBridge:
             on_dtls=lambda d, a: self._dtls.on_dtls(d, a), chain=None,
             recv_window_ms=recv_window_ms,
             # the SFU unprotects inside _on_media (chain=None), so deep
-            # reverse pipelining doesn't engage here — depth > 1 still
-            # turns on pipelined replies/fan-out (loop.pipelined)
+            # reverse pipelining doesn't engage here: depth > 1 turns
+            # on the loop's dispatched replies (loop.pipelined) and
+            # nothing of the fan-out, whose one shape is `_on_media`'s
             pipeline_depth=pipeline_depth)
         self._share_tracer()
         self.port = self.loop.engine.port
@@ -376,6 +377,7 @@ class SfuBridge:
         ticks (one route rebuild for the whole batch, held early media
         replayed atomically).  `_install_dtls` stays as the inline twin
         for bridges running without a lifecycle manager."""
+        self._quiesce_fanout()
         profile, tk, tsalt, rk, rsalt = ep.srtp_keys()
         self.rx_table.add_stream(sid, rk, rsalt)
         self.tx_table.add_stream(sid, tk, tsalt)
@@ -930,16 +932,23 @@ class SfuBridge:
 
     # --------------------------------------------------------------- tick
     def _on_media(self, batch: PacketBatch, _ok) -> None:
-        """Decrypt once, fan out, cache per-leg copies, send.
+        """Decrypt once, fan out; the NEXT tick caches and sends.
 
-        Pipelined mode: the fan-out re-encrypt is DISPATCHED here and
-        its bytes ship at the start of the next tick's media handling
-        (after the recv window — the launch overlaps the socket wait),
-        same seam as MediaLoop's pipelined replies."""
+        The fan-out's re-encrypt is DISPATCHED here, its copy back
+        asked for at once, and the tick returns: the launch, the
+        runtime's notice of its end and the copy run under the host
+        work that follows (`supervise`, the next tick's reap, `ingress`
+        and `demux`) where the tick thread used to sleep beside an idle
+        chip.  The next tick collects it first thing here (wait, copy,
+        NACK cache, hand-over to the egress worker: `_flush_fanout`),
+        before it touches anything the launch read.  A tick that reads
+        no media collects at its end (`tick`), `close` /
+        `flush_egress` and every mutating entry point collect first
+        (`_quiesce_fanout`): a fan-out in flight never waits for
+        traffic.  There is no other shape, on one chip or on a mesh."""
         self._media_ran = True
         perf, tracer = self.loop.perf, self.loop.tracer
-        if self._pending_fanout:
-            self._flush_fanout()
+        self._quiesce_fanout()
         perf.note_h2d(batch.data.nbytes +
                       np.asarray(batch.length).nbytes)
         # the table splits the call into its host part and the wait for
@@ -988,62 +997,51 @@ class SfuBridge:
         # `translate_async` and fanout_wait / fanout_d2h where a launch
         # is waited for, each with the phase it is (the route loop and
         # the expansion are host_python, the residual)
-        tr = self.translator
-        if self.pipelined:
-            with tracer.span("forward_chain"):
-                # dispatch carries its ingress origin: the flush lands
-                # on a LATER tick, and the journey must charge the
-                # pipelining delay to the tick the packets arrived on
-                pend = tr.translate_async(sub, idx_sel)
-                self._pending_fanout.append(
-                    (pend, self.loop.journey_origin()))
-            return None
         with tracer.span("forward_chain"):
-            if tr.launches(sub.stream) <= 1:
-                # the translator's plan makes one launch of the tick's
-                # rows: the call whole, as it always was
-                parts, several = [tr.translate(sub, idx_sel)], False
-            else:
-                # the plan cuts them (they outgrow the largest warmed
-                # class, or pad less as launches of a smaller one):
-                # every launch is dispatched here; each is waited for,
-                # cached and handed over in turn below, so the egress
-                # worker sends launch 1 while launch 2 is on the device
-                pend = tr.translate_async(sub, idx_sel)
-                parts, several = pend.each(), pend.launches > 1
-        self._emit_launches(parts, several)
+            # the pending carries its ingress origin: it is collected
+            # on a LATER tick, and the journey charges that wait to the
+            # tick the packets arrived on
+            self._pending_fanout = (
+                self.translator.translate_async(
+                    sub, idx_sel).copy_back_async(),
+                self.loop.journey_origin())
         return None
 
     def _quiesce_fanout(self) -> None:
-        """Ship any in-flight pipelined fan-out BEFORE mutating state it
-        may still read: SRTP/translator key tensors are rewritten in
-        place (a dispatched launch can alias them zero-copy on CPU),
-        and a recycled row must not receive a departed endpoint's
-        old-key packets.  Every mutating entry point (add/remove
-        endpoint, DTLS install, video track/receiver attach) calls this
-        first."""
-        if self._pending_fanout:
+        """Collect the fan-out in flight BEFORE mutating state it may
+        still read: SRTP/translator key tensors are rewritten in place
+        (a dispatched launch can alias them zero-copy on CPU), and a
+        recycled row must not receive a departed endpoint's old-key
+        packets.  Every mutating entry point (add/remove endpoint,
+        DTLS install, video track/receiver attach) calls this first."""
+        if self._pending_fanout is not None:
             self._flush_fanout()
 
     def _flush_fanout(self) -> None:
-        pending, self._pending_fanout = self._pending_fanout, []
-        for pend, origin in pending:
-            self._emit_launches(pend.each(), pend.launches > 1, origin)
-
-    def _emit_launches(self, parts, several: bool, origin=None) -> None:
-        """`_emit_fanout` each (wire, recv) of `parts`, a tick's
-        launches in row order: the egress worker is FIFO, so every
-        socket sees the order one launch would have given it.  Where
-        the tick has `several` the spans say which launch they belong
-        to (`launch`)."""
-        for k, (wire, recv) in enumerate(parts):
-            self._emit_fanout(wire, recv, origin,
-                              {"launch": k} if several else None)
+        """Collect the fan-out in flight: wait for, cache and hand
+        over each of its launches in row order (`_emit_fanout`; the
+        egress worker is FIFO, so every socket sees the order one
+        launch would have given it, and it sends launch 1 while launch
+        2 is awaited).  A tick of one launch comes out of
+        `translator.translate`, which finds the pending in flight and
+        dispatches nothing (the seam `benchmarks/sut.py:break_fanout`
+        wraps); a tick cut into several a launch at a time, its spans
+        saying which (`launch`).  The spans are this tick's time and
+        carry the dispatching tick's id (`tracer.on_behalf_of`)."""
+        (pend, origin), self._pending_fanout = self._pending_fanout, None
+        several = pend.launches > 1
+        with self.loop.tracer.on_behalf_of(origin[0]):
+            parts = pend.each() if several else \
+                [self.translator.translate(pend.batch, pend.index)]
+            for k, (wire, recv) in enumerate(parts):
+                self._emit_fanout(wire, recv, origin,
+                                  {"launch": k} if several else None)
 
     def _emit_fanout(self, wire: PacketBatch, recv: np.ndarray,
-                     origin=None, nth=None) -> None:
-        """Cache, then hand over, the rows of ONE fan-out launch (`nth`:
-        what its spans book beside their own counts)."""
+                     origin, nth=None) -> None:
+        """Cache, then hand over, the rows of ONE fan-out launch whose
+        packets arrived at journey `origin` (`nth`: what its spans book
+        beside their own counts)."""
         if wire.batch_size == 0:
             return
         nth = nth or {}
@@ -1083,9 +1081,7 @@ class SfuBridge:
             job = self.loop.engine.send_batch_async(
                 wire, self.loop.addr_ip[recv], self.loop.addr_port[recv])
             sp.note(queued=1, behind=int(job.behind))
-        self._egress_jobs[job.id] = (
-            origin if origin is not None else self.loop.journey_origin(),
-            recv)
+        self._egress_jobs[job.id] = (origin, recv)
         # adaptive FEC over the PROTECTED per-leg copies: XOR of SRTP
         # ciphertexts is opaque, and a recovered packet still passes the
         # receiver's normal SRTP auth — FEC adds redundancy, never an
@@ -1280,10 +1276,12 @@ class SfuBridge:
             raise OSError(-failed.sent, os.strerror(-failed.sent))
 
     def flush_egress(self) -> None:
-        """Wait until every fan-out burst handed over has left, and
-        book it.  For shutdown and for tests that read a client socket
-        right after a tick; the tick itself never calls it (that would
-        be the synchronous send again)."""
+        """Collect the fan-out in flight, wait until every burst
+        handed over has left, and book it.  For shutdown and for tests
+        that read a client socket right after a tick; the tick itself
+        never calls it (that would be the serial tick and the
+        synchronous send again)."""
+        self._quiesce_fanout()
         self.loop.engine.flush()
         self._reap_egress()
 
@@ -1293,11 +1291,11 @@ class SfuBridge:
         if self._egress_jobs:
             self._reap_egress()
         rx = self.loop.tick()
-        if self._pending_fanout and not self._media_ran:
-            # no media drove _on_media this tick: flush here instead
-            # (flushing a batch dispatched THIS tick would kill its
-            # overlap window, hence the flag, not an rx check)
-            self._flush_fanout()
+        if not self._media_ran:
+            # no media drove _on_media this tick: collect here instead
+            # (collecting a fan-out dispatched THIS tick would put the
+            # wait back, hence the flag, not an rx check)
+            self._quiesce_fanout()
         if self._dtls.pending and not self._dtls.deferred:
             # inline mode only: with a lifecycle manager attached the
             # flight pass runs off-tick (HandshakeQueue.drain)
@@ -1423,9 +1421,7 @@ class SfuBridge:
 
     def close(self) -> None:
         try:
-            if self._pending_fanout:
-                self._flush_fanout()  # the last tick's media still ships
-            self.flush_egress()
+            self.flush_egress()       # the last tick's media still ships
         finally:
             for eng in self.loop.rings:
                 eng.close()

@@ -817,6 +817,19 @@ class BridgeSupervisor:
                       "warmed row class and went out as launches of "
                       "smaller classes, which padded less",
                 kind="counter")
+            registry.register_scalar(
+                "fanout_collect_total",
+                lambda: self.bridge.translator.fanout_collects,
+                help_="fan-out launches collected (a tick dispatches "
+                      "its fan-out and the next collects it)",
+                kind="counter")
+            registry.register_scalar(
+                "fanout_collect_ready_total",
+                lambda: self.bridge.translator.fanout_collects_ready,
+                help_="fan-out launches whose every array was ready "
+                      "when they were collected: the host work since "
+                      "their dispatch hid the whole launch",
+                kind="counter")
             registry.register_multi(
                 "fanout_launch_rows_total",
                 lambda: [({"rows": str(c)}, float(n)) for c, n in sorted(

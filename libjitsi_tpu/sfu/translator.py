@@ -22,6 +22,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 import functools
 import itertools
+import time
 
 import jax
 import jax.numpy as jnp
@@ -229,6 +230,16 @@ class RtpTranslator:
         self.fanout_split_ticks = 0
         self.fanout_class_cut_ticks = 0
         self.fanout_launch_rows: Dict[int, int] = {}
+        # launches collected, and those of them whose every array was
+        # ready when the collection reached them, so that the wait for
+        # them was none (/metrics: `fanout_collect_total`,
+        # `fanout_collect_ready_total`: how often the work between a
+        # dispatch and its collection hid the whole launch)
+        self.fanout_collects = 0
+        self.fanout_collects_ready = 0
+        # the newest `translate_async` not collected yet: what
+        # `translate` of that very batch hands back
+        self._in_flight: Optional["PendingTranslate"] = None
         # the bridge hands its loop's PipelineTracer and PhaseProfiler
         # here; a translator standing alone spans and samples nothing
         self.tracer = None
@@ -447,8 +458,17 @@ class RtpTranslator:
         protected with its receiver's session key; `receiver_ids` says
         which leg each row goes to.  Packets from senders with no route
         produce no rows.
+
+        Where `translate_async` has dispatched this very batch and
+        nobody has collected it, this call collects THAT fan-out and
+        dispatches nothing: the bridge dispatches in one tick and calls
+        `translate` in the next, so whoever wraps this method on the
+        instance (the benchmark's fault `bridge-bitflip`) still sees
+        the rows of every one-launch tick.
         """
-        pend = self.translate_async(batch, index)
+        pend = self._in_flight
+        if pend is None or pend.batch is not batch:
+            pend = self.translate_async(batch, index)
         return pend.result()
 
     def _plan(self, rows: int) -> List[int]:
@@ -461,23 +481,15 @@ class RtpTranslator:
             rows, self.launch_rows,
             LAUNCH_COST_ROWS if self._pads_rows else None)
 
-    def launches(self, stream) -> int:
-        """How many launches `translate_async` cuts a tick of these
-        senders' packets into: the plan's answer for their real rows
-        (each packet's own receiver list, not the longest ever
-        connected).  At most one: the tick is `translate`'s, whole;
-        more: it comes back a launch at a time
-        (`translate_async(...).each()`)."""
-        routes = self._routes
-        return len(self._plan(sum(
-            len(routes.get(s, ())) for s in np.asarray(stream).tolist())))
-
     def translate_async(self, batch: PacketBatch, index: np.ndarray
                         ) -> "PendingTranslate":
         """Dispatch-only `translate`: the fan-out is enqueued, results
-        materialize on `.result()` (or a launch at a time on `.each()`)
-        — the SFU's pipelined tick overlaps the launch with its next
-        recv window.
+        materialize on `.result()` (or a launch at a time on `.each()`).
+        The SFU's tick dispatches here, asks for the copy back
+        (`pend.copy_back_async()`) and collects in its NEXT tick, so
+        the launch and its copy run under the host work between the
+        two (`SfuBridge._on_media`).  Until it is collected the pending
+        is what `translate` of the same batch returns the result of.
 
         The (packet, receiver) rows are cut into launches by the row
         classes the warm ladder compiled (`plan_launches`): none over
@@ -507,7 +519,7 @@ class RtpTranslator:
                 rows.append(i)
                 recvs.append(rr)
         if not rows:
-            return PendingTranslate([], batch.capacity)
+            return self._dispatched(batch, index, [])
         with span_of(tracer, "expand") as sp:
             counts = np.array([len(r) for r in recvs])
             src = np.repeat(np.array(rows, dtype=np.int64), counts)
@@ -552,10 +564,9 @@ class RtpTranslator:
                 args = self._expand_rows(batch, rowv, pw, *cuts[0])
         if off0 is not None:
             self.fanout_launches += 1
-            return PendingTranslate(
-                [self._translate_gcm_legs(batch, rows, recvs[0], hdr,
-                                          idx, off0) + (recv, {})],
-                batch.capacity, tracer=tracer, perf=self.perf)
+            return self._dispatched(batch, index, [
+                self._translate_gcm_legs(batch, rows, recvs[0], hdr,
+                                         idx, off0) + (recv, {})])
         call = self._gcm_fanout_call if self._gcm else self._cm_fanout_call
         parts = []
         for k, (a, b) in enumerate(cuts):
@@ -579,8 +590,14 @@ class RtpTranslator:
             by_rows = self.fanout_launch_rows
             for c in padded:
                 by_rows[c] = by_rows.get(c, 0) + 1
-        return PendingTranslate(parts, batch.capacity, tracer=tracer,
-                                perf=self.perf)
+        return self._dispatched(batch, index, parts)
+
+    def _dispatched(self, batch, index, parts) -> "PendingTranslate":
+        """The pending of `batch`'s launches `parts`, kept as the one
+        in flight until it is collected (`translate`)."""
+        pend = self._in_flight = PendingTranslate(parts, batch, index,
+                                                  self)
+        return pend
 
     def _expand_rows(self, batch, rowv, pw, a, b):
         """The arguments of ONE per-row fan-out call (`_cm_fanout_call`
@@ -756,11 +773,20 @@ class PendingTranslate:
 
     Device work is enqueued; `each()` materializes a launch at a time
     (blocking transfer) and `result()` the whole tick, once, cached.
-    Mirrors `context.PendingProtect` — the same double-buffering seam,
-    for the SFU's per-leg re-encrypt launches.
+    Mirrors `context.PendingProtect`, the same double-buffering seam,
+    for the SFU's per-leg re-encrypt launches.  The SFU keeps it across
+    the tick boundary: dispatched by tick N, collected by tick N+1,
+    with the copy back asked for at dispatch (`copy_back_async`), so a
+    collection finds its launch done, or nearly.  Each launch's
+    `fanout_wait` says what it found: `collected` 1, `ready` 1 / 0
+    (every array of the launch was ready when the collection reached
+    it) and `hidden_us`, the tick thread's time from the launch's jit
+    call's return (`Launch.dispatched_at`: the end of its
+    `fanout_dispatch`, to microseconds) to the start of this wait: the
+    host work the launch ran under.
     """
 
-    def __init__(self, parts, capacity: int, tracer=None, perf=None):
+    def __init__(self, parts, batch: PacketBatch, index, translator):
         # (launch, pg, recv, nth) a device call in flight: `fetch()` ->
         # (rows, lengths); `pg` = (p_real, g_real) when the launch is
         # the leg-major fan-out's padded grid [G_pad, P_pad, W], None
@@ -768,10 +794,19 @@ class PendingTranslate:
         # `nth` = {"launch": k} where the tick has several, else {}
         self._parts = list(parts)
         self.launches = len(self._parts)
-        self._capacity = capacity
-        self._tracer = tracer
-        self._perf = perf
+        #: what this fans out: `translator.translate(batch, index)`
+        #: collects this pending while it is the one in flight
+        self.batch, self.index = batch, index
+        self._translator = translator
         self._done: "List[Tuple[PacketBatch, np.ndarray]]" = []
+
+    def copy_back_async(self) -> "PendingTranslate":
+        """Ask for every launch's copy back now
+        (`staging.Launch.copy_back_async`): each starts when its
+        program ends, whatever the tick thread is doing then."""
+        for launch, _pg, _recv, _nth in self._parts:
+            launch.copy_back_async()
+        return self
 
     def each(self):
         """(wire_batch, receiver_ids) a launch, in row order: each
@@ -784,13 +819,15 @@ class PendingTranslate:
             self._done.append((self._materialize(launch, pg, recv, nth),
                                recv))
             yield self._done[-1]
+        if self._translator._in_flight is self:
+            self._translator._in_flight = None    # collected
 
     def result(self) -> Tuple[PacketBatch, np.ndarray]:
         """The whole tick as one batch (one launch: that launch's
         plane as it came back; more: their rows in one copy)."""
         got = list(self.each())
         if not got:
-            return (PacketBatch.empty(0, self._capacity),
+            return (PacketBatch.empty(0, self.batch.capacity),
                     np.zeros(0, np.int64))
         if len(got) > 1:
             self._done = got = [(
@@ -804,11 +841,17 @@ class PendingTranslate:
         """Wait for one launch (`fanout_wait`, the `device_compute`
         phase), then copy its rows back (`fanout_d2h`,
         `d2h_transfer`)."""
-        with span_of(self._tracer, "fanout_wait", **nth), \
-                phase_of(self._perf, "device_compute"):
+        tr = self._translator
+        hidden = time.perf_counter() - launch.dispatched_at
+        with span_of(tr.tracer, "fanout_wait", **nth) as sp, \
+                phase_of(tr.perf, "device_compute"):
+            ready = int(launch.is_ready())
+            sp.note(collected=1, ready=ready, hidden_us=int(1e6 * hidden))
             launch.block_until_ready()
-        with span_of(self._tracer, "fanout_d2h", **nth) as sp, \
-                phase_of(self._perf, "d2h_transfer"):
+        tr.fanout_collects += 1
+        tr.fanout_collects_ready += ready
+        with span_of(tr.tracer, "fanout_d2h", **nth) as sp, \
+                phase_of(tr.perf, "d2h_transfer"):
             arr, lens = launch.fetch()
             lens = np.asarray(lens, dtype=np.int32)
             sp.note(d2h_arrays=launch.d2h_arrays,

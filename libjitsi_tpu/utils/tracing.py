@@ -27,6 +27,15 @@ carry).  A span feeds four sinks at once:
      clock and say which tick they belong to and how much they carried.
      It does nothing unless a profiler session is active.
 
+A span is the tick thread's time of the tick that RUNS it: that tick's
+ledgers book it.  The `tick` it carries (and writes into the trace) is
+the tick its work BELONGS to, which is another where one tick finishes
+what an earlier one began: the SFU's fan-out is dispatched by tick N
+and collected by tick N+1, under `tracer.on_behalf_of(N)`, so the
+collection's `fanout_wait`, `fanout_d2h`, `nack_cache` and `egress`
+say `tick=N` in the trace and a reader that joins spans by `tick` pairs
+a dispatch with its own wait (benchmarks/seams.py, xstats.py).
+
 Spans are per tick or per batch, never per packet or per row.  A stage
 entered twice in a tick sums.  Spans open and close on the tick thread,
 innermost first (`with`); the tree has no lock.
@@ -34,6 +43,7 @@ innermost first (`with`); the tree has no lock.
 
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Dict, Optional, Tuple
 
@@ -130,7 +140,7 @@ class _StageSpan:
 
     def __enter__(self) -> "_StageSpan":
         t = self._tracer
-        self.tick = t.tick
+        self.tick = t.tick if t._behalf is None else t._behalf
         if t.annotate:
             self._ann = _TraceAnnotation(t._sink(self.stage)[1],
                                          tick=self.tick, **self.counts)
@@ -187,6 +197,9 @@ class PipelineTracer:
         self.annotate = bool(annotate) and _TraceAnnotation is not None
         #: id of the tick under way; the loop sets it as a tick begins
         self.tick = 0
+        # the tick whose work the spans opening now finish, where that
+        # is not the tick under way (`on_behalf_of`)
+        self._behalf: Optional[int] = None
         self._open: Optional[_StageSpan] = None
         # stage -> (ring, annotation name), resolved once per name
         self._sinks: Dict[str, tuple] = {}
@@ -213,6 +226,18 @@ class PipelineTracer:
 
     def span(self, stage: str, **counts) -> _StageSpan:
         return _StageSpan(self, stage, counts)
+
+    @contextlib.contextmanager
+    def on_behalf_of(self, tick: int):
+        """Spans opened inside carry `tick` (the id an EARLIER tick's
+        spans carry) and are booked, like any span, in the ledgers of
+        the tick under way: for work one tick began and this one
+        finishes."""
+        prev, self._behalf = self._behalf, tick
+        try:
+            yield
+        finally:
+            self._behalf = prev
 
     def book(self, stage: str, seconds: float, **counts) -> None:
         """Book a duration that was measured elsewhere (a worker

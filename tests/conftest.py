@@ -124,8 +124,12 @@ def sfu_with_traffic():
     a supervisor; `send()` puts one protected packet of each endpoint
     on the bridge's socket (`send.csrcs[k]`: endpoint k's CSRC list,
     empty to begin with), `send.until_forwarded()` sends and ticks
-    until a tick fans out (addresses latch on an endpoint's first
-    packet, so the first round forwards to nobody)."""
+    back to back until a tick is the STEADY one: it collected and
+    handed over the fan-out the tick before it dispatched, and
+    dispatched its own (addresses latch on an endpoint's first packet,
+    so the first round forwards to nobody).  That tick's ledgers hold
+    every leaf; its own fan-out is still in flight and the burst it
+    handed over not reaped (`sfu.flush_egress()` settles both)."""
     import time
 
     import libjitsi_tpu
@@ -165,13 +169,8 @@ def sfu_with_traffic():
         for _ in range(ticks):
             send()
             time.sleep(0.01)
-            before = sfu.forwarded
             sup.tick(now=50.0)
-            # `forwarded` counts at the reap: have the tick's own burst
-            # out and booked, so that the tick that fanned out is the
-            # one the caller reads the ledgers of
-            sfu.flush_egress()
-            if sfu.forwarded > before:
+            if {"egress", "fanout_dispatch"} <= set(sup.last_ledger):
                 return
         raise AssertionError("the bridge never forwarded")
 

@@ -203,11 +203,14 @@ def served(oracle, egress_tap):
             for j in range(5):
                 send(i + j, fresh(i + j))
         before = dict(bridge.translator.fanout_launch_rows)
+        # (dispatched by the tick that reads it; `tick` collects with
+        # `flush_egress`, which books after the tick's drain: the
+        # collection's counts are in the next tick's)
         counts = {}
         for _ in range(2):
             tick()
-            if "expand" in sup.last_counts:
-                counts = {k: dict(v) for k, v in sup.last_counts.items()}
+            for k, v in sup.last_counts.items():
+                counts.setdefault(k, dict(v))
         drain(rec["got"])
         rec["round_of_280"] = (
             counts,
@@ -347,9 +350,16 @@ def test_served_path_never_times_providers(served):
     assert c["expand"]["rows"] <= c["expand"]["rows_padded"]
     # every tick: one packed plane in and one back for each launch
     # (one size class, so one unprotect launch a tick; no tick of
-    # these, all under 1,024 rows, is cut by the row classes)
-    assert all(a in ((1, 1, 1, 1), (1, 1, None, None))
+    # these, all under 1,024 rows, is cut by the row classes).  The
+    # fan-out's plane comes back where it is collected: after the
+    # tick's drain here (`tick` ends on `flush_egress`), so in the
+    # counts of the tick after the one that dispatched it
+    assert all(a in ((1, 1, 1, None), (1, 1, None, None),
+                     (1, 1, None, 1))
                for a in served["arrays"]), served["arrays"]
+    assert c["fanout_d2h"]["d2h_arrays"] \
+        == c["fanout_dispatch"]["h2d_arrays"] \
+        == c["fanout_wait"]["collected"]
     assert c["expand"]["class_cut"] == 0
     u, f = c["unprotect_wait"], c["fanout_dispatch"]
     plane = 224 + staging.TAIL

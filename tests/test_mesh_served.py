@@ -140,6 +140,14 @@ def _serve(oracle, mesh, ladder: bool, tap_of, synchronous: bool) -> dict:
                     lanes = counts.get(stage, {}).get("lanes")
                     if lanes is not None:
                         rec["lanes"][stage].add(int(lanes))
+            elif "fanout_wait" in counts and rec["counts"]:
+                # the tick that read the round dispatched its fan-out;
+                # this one read nothing and collected it at its end:
+                # one record a round, as one serial tick booked it
+                for stage, kv in counts.items():
+                    mine = rec["counts"][-1].setdefault(stage, {})
+                    for k, v in kv.items():
+                        mine[k] = mine.get(k, 0) + v
         # the fan-out's datagrams leave on the engine's egress worker:
         # have them out before a caller reads a client socket
         bridge.flush_egress()

@@ -410,9 +410,11 @@ def test_tracer_counts_reach_ledger_and_profiler_stats(tmp_path):
 
 def test_sfu_tick_emits_every_leaf_and_self_times_tile_it(
         sfu_with_traffic):
-    """One SfuBridge tick with traffic opens every leaf stage, and
-    `tracing.LEAF_STAGES` is exactly that set (with `gc`, which the
-    next test forces); self times sum to the outermost spans' time."""
+    """One steady SfuBridge tick with traffic (it collects the fan-out
+    the tick before it dispatched, then dispatches its own) opens every
+    leaf stage, and `tracing.LEAF_STAGES` is exactly that set (with
+    `gc`, which the next test forces); self times sum to the outermost
+    spans' time: the collection lies inside `reverse_chain` too."""
     import gc
 
     from libjitsi_tpu.utils import tracing
@@ -452,6 +454,10 @@ def test_sfu_tick_emits_every_leaf_and_self_times_tile_it(
         assert counts["expand"]["rows_padded"] >= 6
         assert counts["fanout_dispatch"]["h2d_bytes"] > 0
         assert counts["fanout_d2h"]["d2h_bytes"] > 0
+        # the launch collected says what the collection found
+        wait = counts["fanout_wait"]
+        assert wait["collected"] == 1 and wait["ready"] in (0, 1)
+        assert wait["hidden_us"] > 0
         # a put books what its call books, where it happens
         assert counts["unprotect_put"] == {
             k: counts["unprotect_wait"][k]
@@ -478,13 +484,17 @@ def test_sfu_warm_tick_is_two_launches_one_array_each_way(
     sfu, sup, send = sfu_with_traffic
     send.until_forwarded()
     send.until_forwarded()           # the forwarding tick's shapes: warm
+    sfu.flush_egress()               # nothing in flight, all counted
+    before = sfu.forwarded
     send()                           # the clients' own protect: outside
     time.sleep(0.01)
-    before = sfu.forwarded
+    sup.tick(now=50.0)               # dispatches a fan-out
+    send()
+    time.sleep(0.01)
     with warmed_launch_guard():
-        sup.tick(now=50.0)
+        sup.tick(now=50.0)           # collects it, dispatches the next
     sfu.flush_egress()               # `forwarded` counts at the reap
-    assert sfu.forwarded == before + 6
+    assert sfu.forwarded == before + 12
     counts = sup.last_counts
     up, down = counts["unprotect_wait"], counts["fanout_d2h"]
     assert up["h2d_arrays"] == up["d2h_arrays"] == 1
@@ -497,9 +507,13 @@ def test_sfu_warm_tick_is_two_launches_one_array_each_way(
 def test_sfu_tick_spans_in_profile_match_the_ledger(sfu_with_traffic,
                                                     tmp_path):
     """Read back through the benchmark's `xstats`: every `stage:*`
-    event of a profiled tick carries its tick id, the root its
-    `wall_ns`, and a tick's leaf durations on the profiler's clock
-    agree with the same tick's ledger within 2 %."""
+    event of a profiled tick carries a tick id, the root its `wall_ns`;
+    the leaf durations that lie INSIDE a tick's root on the profiler's
+    clock agree with that tick's ledger within 2 % (a span is the time
+    of the tick that runs it); and the spans that collect a fan-out
+    (`fanout_wait`, `fanout_d2h`, `nack_cache`, `egress`) carry the id
+    of the tick that DISPATCHED it, the one before, so a reader that
+    joins by `tick` pairs a dispatch with its own wait."""
     import os
     import sys
     import time
@@ -534,30 +548,40 @@ def test_sfu_tick_spans_in_profile_match_the_ledger(sfu_with_traffic,
     path = reduce.find_xplane(str(tmp_path))
     evs = xstats.load(path)["host"]
     assert evs and all("tick" in st for _n, _s, _d, st in evs)
-    roots = [st for n, _s, _d, st in evs if n == "stage:tick"]
-    assert [r["tick"] for r in roots] == sorted(ledgers)
+    roots = [(s, s + d, st) for n, s, d, st in evs if n == "stage:tick"]
+    assert [st["tick"] for _s, _e, st in roots] == sorted(ledgers)
     leaves = [k for k in tracing.LEAF_STAGES if k not in ("supervise",
                                                           "gc")]
-    for root in roots:
+    collection = ("fanout_wait", "fanout_d2h", "nack_cache", "egress")
+    for lo, hi, root in roots:
         led, counts, t_wall = ledgers[root["tick"]]
         assert root["rx"] == 3
         assert 0 <= root["wall_ns"] - t_wall < 50e6
-        mine = [(n, d, st) for n, _s, d, st in evs
-                if st["tick"] == root["tick"]]
-        by_profile = sum(d for n, d, _st in mine
-                         if n.split(":")[1] in leaves) / 1e9
+        mine = [(n.split(":")[1], d, st) for n, s, d, st in evs
+                if lo <= s < hi and n != "stage:tick"]
+        by_profile = sum(d for n, d, _st in mine if n in leaves) / 1e9
         by_ledger = sum(led[k] for k in leaves)
         assert by_profile == pytest.approx(by_ledger, rel=0.02)
-        (exp,) = [st for n, _d, st in mine if n == "stage:expand"]
+        for n, _d, st in mine:
+            assert st["tick"] == root["tick"] - (n in collection), n
+        (exp,) = [st for n, _d, st in mine if n == "expand"]
         assert exp["rows"] == counts["expand"]["rows"] == 6
+        (wait,) = [st for n, _d, st in mine if n == "fanout_wait"]
+        assert wait["collected"] == 1 and wait["ready"] in (0, 1)
+        assert wait["hidden_us"] == counts["fanout_wait"]["hidden_us"] > 0
+    ctx = {"trace": {"xplane": path}}
+    assert xstats.count_ratio_pct(ctx, "fanout_wait", "ready",
+                                  "collected") in (
+        pytest.approx(0.0), pytest.approx(100.0 / 3),
+        pytest.approx(200.0 / 3), pytest.approx(100.0))
     ctx = {"trace": {"xplane": path}}
     assert xstats.count_ratio_pct(ctx, "expand", "rows", "rows_padded") \
         == pytest.approx(600.0 / exp["rows_padded"])
-    # from the batch in hand to the last datagram out: inside the
-    # tick's `demux` + `reverse_chain`
-    assert 0.0 < xstats.residence_p50_ms(ctx) < 1e3 * max(
-        led["demux"] + led["reverse_chain"]
-        for led, _c, _t in ledgers.values())
+    # from the batch in hand to the last datagram handed over, joined
+    # by `tick`: across the tick boundary, so over a whole
+    # `reverse_chain` and the pause between two ticks
+    assert xstats.residence_p50_ms(ctx) > 10.0 + 1e3 * min(
+        led["reverse_chain"] for led, _c, _t in ledgers.values())
     assert xstats.scope_share_pct(ctx, "jit__fanout_protect", "auth") \
         is None                     # no device plane off the chip
     assert xstats.slice_events({"trace": None}) is None

@@ -319,3 +319,111 @@ def test_skew_needs_two_chips_and_every_chip_paired(monkeypatch,
     chips[2] = lost
     monkeypatch.setattr(seams, "load", lambda _p: dict(evs, chips=chips))
     assert seams.chip_skew_p50_ms(CTX, "end") is None
+
+
+# ------------------------- the overlapped tick: dispatched by N, collected
+# ------------------------- by N + 1, its spans carrying N
+
+def _overlapped(n_ticks, period=5000):
+    """`n_ticks` ticks as the bridge runs them: tick k collects tick
+    k-1's fan-out (its `fanout_wait` / `fanout_d2h` carry k-1), makes
+    its unprotect call, then dispatches its own fan-out, whose program
+    runs while the tick ends and the next begins."""
+    host, progs = [], []
+    for k in range(1, n_ticks + 1):
+        t0 = period * k
+        host.append(_ev("tick", k, t0, period - 200, rx=3))
+        if k > 1:
+            # the collection: the launch was ready 900 us before
+            host += [_ev("fanout_wait", k - 1, t0 + 400, 30, collected=1,
+                         ready=1, hidden_us=1300),
+                     _ev("fanout_d2h", k - 1, t0 + 430, 120)]
+        host.append(_ev("unprotect_wait", k, t0 + 600, 680))
+        host += _call(k, t0 + 610)
+        st = seams.SEAMS["fanout"]
+        host += [_ev(st["dispatch"], k, t0 + 3800, 200),
+                 _ev(st["put"], k, t0 + 3820, 30)]
+        progs += [(2 * k, t0 + 610 + 180, 120),
+                  (2 * k + 1, t0 + 3800 + 260, 500)]
+    return host, progs
+
+
+def test_an_overlapped_slice_pairs_a_dispatch_with_its_own_wait(
+        monkeypatch):
+    """The fan-out's block closes in the NEXT tick, carrying its
+    dispatch's `tick`: every tick but the slice's last (whose
+    collection lies outside) pairs, the lags and the overhead are read,
+    and the end lag is program end to COLLECTION."""
+    evs = _slice(_overlapped(20))
+    monkeypatch.setattr(seams, "load", lambda _p: evs)
+    got = seams.pair(evs["host"], evs["chips"][0], "fanout_dispatch",
+                     "fanout_wait")
+    assert sorted(got) == list(range(1, 21))
+    assert [t for t, c in got.items() if c is None] == [20]
+    assert seams.lag_p50_ms(CTX, "fanout", "start") == pytest.approx(0.26)
+    # the next tick opens 1,000 us after the dispatch began; the wait
+    # ends 430 us into it; the program ended 760 us after its dispatch
+    # opened: 1,000 + 200 + 430 - 760
+    assert seams.lag_p50_ms(CTX, "fanout", "end") == pytest.approx(0.87)
+    assert seams.lag_p50_ms(CTX, "unprotect", "start") == \
+        pytest.approx(0.18)
+    # 680 + 200 + 30 + 120 us of spans less 120 + 500 of programs
+    assert seams.overhead_p50_ms(CTX) == pytest.approx(0.41)
+
+
+def test_a_captured_trace_of_the_bridge_pairs(sfu_with_traffic, tmp_path,
+                                              monkeypatch):
+    """A profiler trace of the real bridge (off the chip: the host
+    plane alone): each `stage:fanout_wait` carries its DISPATCH's
+    `tick` beside `collected`, `ready` and `hidden_us`, and with a
+    program laid between each dispatch and its wait `pair` pairs at
+    least nine ticks in ten; the two readers read."""
+    import time
+
+    import jax
+
+    import reduce
+    import xstats
+
+    sfu, sup, send = sfu_with_traffic
+    send.until_forwarded()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        for _ in range(12):
+            send()
+            time.sleep(0.005)
+            with jax.profiler.TraceAnnotation("bench:tick"):
+                sup.tick(now=50.0)
+    finally:
+        jax.profiler.stop_trace()
+    path = reduce.find_xplane(str(tmp_path))
+    host = xstats.load(path)["host"]
+    dispatched = seams.by_tick(host, "fanout_dispatch")
+    waits = [(s, st) for n, s, _d, st in host if n == "stage:fanout_wait"]
+    assert len(dispatched) == 12 and len(waits) == 12
+    for start, st in waits[1:]:         # (the first: dispatched before)
+        assert st["collected"] == 1 and st["ready"] in (0, 1)
+        ((_d0, d1),) = dispatched[st["tick"]]
+        # its own dispatch: the one that ended `hidden_us` before
+        assert start - d1 == pytest.approx(st["hidden_us"] * 1e3,
+                                           abs=150e3)
+    # the device's side, as a chip would have written it: a program
+    # 20 us after each call's dispatch ended, 50 us long
+    progs = [(k, (e + 20_000) // US, 50) for k, e in enumerate(sorted(
+        e for stage in ("fanout_dispatch", "unprotect_dispatch")
+        for evs in seams.by_tick(host, stage).values() for _s, e in evs))]
+    chip = _chip(progs, pickup_us=5)
+    for st in seams.SEAMS.values():
+        got = seams.pair(host, chip, st["dispatch"], st["block"])
+        assert len(got) == 12
+        assert sum(c is not None for c in got.values()) >= 0.9 * 12 - 1
+    monkeypatch.setattr(seams, "load", lambda _p: {
+        "host": host, "lo": 0, "hi": 1 << 62, "chips": {0: chip}})
+    assert seams.lag_p50_ms(CTX, "fanout", "end") > 0.0
+    ctx = {"trace": {"xplane": path}}
+    assert _reader("fanout_collect_ready_pct.paced")(ctx) is not None
+    assert _reader("fanout_hidden_p50_ms.sat")(ctx) > 0.0
+    assert _reader("fanout_hidden_p50_ms.paced")({"trace": None}) is None

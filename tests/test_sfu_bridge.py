@@ -458,14 +458,14 @@ def test_sfu_video_simulcast_layer_switch_and_rtx():
 
 @pytest.mark.slow
 def test_sfu_pipelined_fanout_delivers_everything():
-    """Pipelined SfuBridge: the fan-out launch dispatched in tick N
-    ships at tick N+1 (overlapping the recv window); every endpoint
-    still hears every other endpoint's media, and NACK service still
-    works against the flushed cache."""
+    """The SfuBridge's one tick shape: the fan-out launch dispatched
+    in tick N ships at tick N+1 (it runs under the host work in
+    between); every endpoint still hears every other endpoint's media,
+    and NACK service still works against the flushed cache."""
     libjitsi_tpu.stop()
     libjitsi_tpu.init()
     sfu = SfuBridge(libjitsi_tpu.configuration_service(), port=0,
-                    capacity=8, recv_window_ms=0, pipelined=True)
+                    capacity=8, recv_window_ms=0)
     eps = [_Endpoint(0x300 + 5 * k, sfu.port) for k in range(3)]
     for e in eps:
         sfu.add_endpoint(e.ssrc, e.rx_key, e.tx_key)
@@ -483,7 +483,7 @@ def test_sfu_pipelined_fanout_delivers_everything():
             for _ in range(4):
                 e.drain()
     assert sfu.forwarded > 0
-    assert not sfu._pending_fanout, "pending fan-out never flushed"
+    assert sfu._pending_fanout is None, "pending fan-out never flushed"
     for e in eps:
         payloads = b"".join(e.got.values())
         for other in eps:
@@ -816,15 +816,18 @@ def test_sfu_bridge_snapshot_resume_mid_conference():
 
 # ------------------------------------------------- the egress worker
 
-def _served_rounds(egress_tap, synchronous, pipelined, rounds=4):
+def _served_rounds(egress_tap, synchronous, pipeline_depth, rounds=4):
     """Three endpoints, `rounds` rounds of four packets each: what
     every endpoint's socket received, raw and in arrival order, with
     the fan-out sent by the egress worker or (`synchronous`) by the
-    synchronous call it replaced."""
+    synchronous call it replaced.  `pipeline_depth` is the loop's (its
+    dispatched replies); the fan-out has one shape at every depth:
+    dispatched by one tick, collected by the next."""
     libjitsi_tpu.stop()
     libjitsi_tpu.init()
     sfu = SfuBridge(libjitsi_tpu.configuration_service(), port=0,
-                    capacity=8, recv_window_ms=0, pipelined=pipelined)
+                    capacity=8, recv_window_ms=0,
+                    pipeline_depth=pipeline_depth)
     tap = egress_tap(sfu)
     tap.synchronous = synchronous
     eps = [_Endpoint(0x100 + 7 * k, sfu.port) for k in range(3)]
@@ -852,16 +855,16 @@ def _served_rounds(egress_tap, synchronous, pipelined, rounds=4):
             e.engine.close()
 
 
-@pytest.mark.parametrize("pipelined", (False, True),
+@pytest.mark.parametrize("pipeline_depth", (1, 3),
                          ids=("inline", "pipelined"))
 def test_worker_round_delivers_what_the_synchronous_round_delivers(
-        egress_tap, pipelined):
+        egress_tap, pipeline_depth):
     """The same served rounds through the egress worker and through the
     synchronous `send_batch`: every receiver gets the same datagrams,
     byte for byte and in the same order, which is the order they were
     handed over in; `forwarded` after `flush_egress()` is the sends."""
-    worker = _served_rounds(egress_tap, False, pipelined)
-    sync = _served_rounds(egress_tap, True, pipelined)
+    worker = _served_rounds(egress_tap, False, pipeline_depth)
+    sync = _served_rounds(egress_tap, True, pipeline_depth)
     assert worker["jobs"] and all(j > 0 for j in worker["jobs"])
     assert sync["jobs"] and all(j < 0 for j in sync["jobs"])
     # rounds after the address latch forward 2 x 4 packets a receiver
@@ -883,12 +886,14 @@ def test_journey_is_measured_to_the_workers_end_stamp(sfu_with_traffic,
 
     sfu, sup, send = sfu_with_traffic
     send.until_forwarded()
+    sfu.flush_egress()
     tap = egress_tap(sfu)
     hist = sfu.loop.journey_hist
     send()
     time.sleep(0.01)
     sum0, count0 = hist.sum, hist.count
-    sup.tick(now=50.0)
+    sup.tick(now=50.0)               # the dispatch
+    sup.tick(now=50.0)               # no media: collected, handed over
     ((job, (origin, recv)),) = sfu._egress_jobs.items()
     assert (hist.sum, hist.count) == (sum0, count0)   # nothing booked yet
     time.sleep(0.05)                     # the reap comes late ...
@@ -914,11 +919,14 @@ def test_egress_send_is_booked_by_the_reaping_tick_and_is_no_leaf(
 
     sfu, sup, send = sfu_with_traffic
     send.until_forwarded()
+    sfu.flush_egress()
     sup.tick(now=50.0)               # drains what that flush booked
     send()
     time.sleep(0.01)
     before = sfu.forwarded
-    sup.tick(now=50.0)               # the hand-over
+    sup.tick(now=50.0)               # the dispatch
+    assert "egress" not in sup.last_ledger
+    sup.tick(now=50.0)               # no media: collection, hand-over
     led, counts = sup.last_ledger, sup.last_counts
     assert counts["egress"] == {"rows": 6, "bytes": counts["egress"][
         "bytes"], "queued": 1, "behind": 0}
@@ -951,6 +959,7 @@ def test_reap_books_what_the_worker_reports(sfu_with_traffic, outcome):
 
     sfu, sup, send = sfu_with_traffic
     send.until_forwarded()
+    sfu.flush_egress()
     sup.tick(now=50.0)
     eng = sfu.loop.engine
     hand, reap = eng.send_batch_async, eng.reap
@@ -964,7 +973,8 @@ def test_reap_books_what_the_worker_reports(sfu_with_traffic, outcome):
         send()
         time.sleep(0.01)
         before = sfu.forwarded
-        sup.tick(now=50.0)
+        sup.tick(now=50.0)           # the dispatch
+        sup.tick(now=50.0)           # the collection and the hand-over
         assert sup.last_counts["egress"]["behind"] == (
             1 if outcome == "behind" else 0)
         if outcome == "failed":

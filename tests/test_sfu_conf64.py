@@ -273,8 +273,9 @@ def test_rows_are_cut_by_the_classes_and_come_back_in_order(
 
     tr._cm_fanout_call = spy
     b, pls, index = _batch(rng, range(len(legs_of)))
-    # the plan's answer before the call is the call's
-    assert tr.launches(b.stream) == launches
+    # the plan's answer for the tick's real rows is the call's
+    assert len(tr._plan(sum(len(tr._routes[int(x)])
+                            for x in b.stream))) == launches
     pend = tr.translate_async(b, index)
     assert pend.launches == launches
     # every launch's plane has its own class's rows, none over the top
@@ -341,7 +342,7 @@ def test_a_tick_of_257_to_512_rows_runs_the_512_row_program(
     setattr(tr, name, spy)
     b, pls, index = _batch(np.random.default_rng(44), range(senders))
     before = compile_stats().compile_events
-    assert tr.launches(b.stream) == 1
+    assert len(tr._plan(rows)) == 1
     wire, recv = tr.translate(b, index)
     assert compile_stats().compile_events == before
     assert [s[0] for s in seen] == [512]
@@ -495,17 +496,38 @@ def _serve(oracle, tap_of) -> dict:
     socks = []
 
     def tick(n=1):
+        """`n` ticks; the record of each that dispatched a fan-out
+        (`expand`) or collected one (`fanout_wait`): a round of
+        packets is dispatched by the tick that reads them and
+        collected by the next, which reads none and collects at its
+        end."""
         got = []
         for _ in range(n):
             now[0] += 0.02
             sup.tick(now=now[0])
-            if "expand" in sup.last_counts:
+            if {"expand", "fanout_wait"} & set(sup.last_counts):
                 got.append(({k: dict(v)
                              for k, v in sup.last_counts.items()},
                             dict(sup.last_ledger),
                             dict(sup.last_self_ledger), sup.last_tick_s))
         bridge.flush_egress()
         return got
+
+    def merged(ticks):
+        """A round's two ticks as one record: counts and ledgers
+        summed, so that what one serial tick booked is read as it
+        was."""
+        counts, led, self_led, tick_s = {}, {}, {}, 0.0
+        for c, inc, own, s in ticks:
+            for stage, kv in c.items():
+                mine = counts.setdefault(stage, {})
+                for k, v in kv.items():
+                    mine[k] = mine.get(k, 0) + v
+            for total, part in ((led, inc), (self_led, own)):
+                for stage, sec in part.items():
+                    total[stage] = total.get(stage, 0.0) + sec
+            tick_s += s
+        return counts, led, self_led, tick_s
 
     def drain():
         out = []
@@ -583,9 +605,12 @@ def _serve(oracle, tap_of) -> dict:
                 socks[i].sendto(fresh(i), ("127.0.0.1", bridge.port))
             del whole[:]
             ticks = tick(2)
-            assert len(ticks) == 1, "the round in one tick"
+            assert ["expand" in c for c, *_ in ticks] == [True, False] \
+                and ["fanout_wait" in c for c, *_ in ticks] == \
+                [False, True], "dispatched by one tick, collected by " \
+                "the next"
             rec["rounds"].append({"packets": n, "senders": senders,
-                                  "tick": ticks[0], "got": drain(),
+                                  "tick": merged(ticks), "got": drain(),
                                   "whole": list(whole)})
         rec["compiles"] = compile_stats().compile_events - events0
         rec["recompiles"] = lc.datapath_recompiles - recompiles0
@@ -677,10 +702,11 @@ def test_every_delivery_once_under_its_receivers_key(served, oracle, k):
 
 @pytest.mark.parametrize("k", range(len(ROUNDS)))
 def test_a_tick_of_one_launch_is_translates_whole(served, k):
-    """One rule decides: where the plan makes one launch of the tick's
-    rows the bridge calls `translate` (the seam the benchmark's fault
-    `bridge-bitflip` wraps), where it cuts them `translate_async` and a
-    launch at a time."""
+    """One rule decides: where the plan made one launch of the tick's
+    rows the bridge collects it through `translate` (the seam the
+    benchmark's fault `bridge-bitflip` wraps, which finds the launch
+    in flight and dispatches none), where it cut them a launch at a
+    time."""
     rnd = served["rounds"][k]
     one = len(SIZES[rnd["packets"]]) == 1
     assert rnd["whole"] == [rnd["packets"]] * one
@@ -725,8 +751,10 @@ def test_a_nack_for_a_row_of_the_small_launch_is_answered(served):
 def test_spans_a_launch_and_the_leaves_still_tile_the_tick(served, k):
     """(f) `launches` and `class_cut` on `expand`; one `fanout_dispatch`
     / `fanout_wait` / `fanout_d2h` / `nack_cache` / `egress` a launch,
-    each saying which where the tick has several; the leaves still
-    cover the tick."""
+    each saying which where the tick has several; every launch
+    collected, and found ready or waited for; the leaves still cover
+    the round's two ticks (the one that dispatched and the one that
+    collected)."""
     rnd = served["rounds"][k]
     counts, led, self_led, tick_s = rnd["tick"]
     sizes = SIZES[rnd["packets"]]
@@ -743,6 +771,9 @@ def test_spans_a_launch_and_the_leaves_still_tile_the_tick(served, k):
     assert counts["fanout_put"]["h2d_arrays"] == n
     assert counts["fanout_d2h"]["d2h_arrays"] == n
     assert counts["egress"]["queued"] == n
+    wait = counts["fanout_wait"]
+    assert wait["collected"] == n and 0 <= wait["ready"] <= n
+    assert wait["hidden_us"] > 0
     assert counts["nack_cache"]["rows"] == counts["egress"]["rows"] == rows
     for stage in ("fanout_dispatch", "fanout_wait", "fanout_d2h",
                   "nack_cache", "egress"):
@@ -751,13 +782,17 @@ def test_spans_a_launch_and_the_leaves_still_tile_the_tick(served, k):
     leaves = sum(led.get(s, 0.0) for s in LEAF_STAGES
                  if s not in ("supervise", "gc"))
     assert leaves <= tick_s and leaves > 0.9 * tick_s
-    # self times sum to the time inside the outermost spans: every
-    # launch's wait, copy back, cache insert and hand-over lie inside
-    # `reverse_chain` like the rest of the fan-out (a collection that
-    # lands between two outermost spans is one of its own)
+    # self times sum to the time inside the outermost spans.  The
+    # tick that collected read no media, so it collected at its end:
+    # every launch's wait, copy back, cache insert and hand-over are
+    # outermost spans there (inside `reverse_chain` where the next
+    # tick has media: tests/test_observability.py) (a collection of
+    # the interpreter's that lands between two outermost spans is one
+    # of its own)
     outside = sum(self_led.values()) - (
         led["ingress"] + led["demux"] + led["reverse_chain"]
-        + led["supervise"])
+        + led["supervise"] + sum(led[s] for s in (
+            "fanout_wait", "fanout_d2h", "nack_cache", "egress")))
     assert -1e-6 <= outside <= led.get("gc", 0.0) + 1e-6
 
 

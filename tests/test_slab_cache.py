@@ -394,7 +394,6 @@ def test_emit_fanout_creates_no_per_row_objects(rows):
         tracer=_Tracer(), engine=_Engine(),
         addr_port=np.full(64, 5004, dtype=np.int64),
         addr_ip=np.full(64, 0x7F000001, dtype=np.int64),
-        journey_origin=lambda: None,
         note_journey_at=lambda *a, **k: None)
     me = types.SimpleNamespace(
         loop=loop, cache=SlabCache(), _now=1.0, forwarded=0, flight=None,
@@ -406,7 +405,7 @@ def test_emit_fanout_creates_no_per_row_objects(rows):
         wire = PacketBatch(plane[:, :224],
                            np.full(rows, 100, dtype=np.int32),
                            legs.astype(np.int32))
-        SfuBridge._emit_fanout(me, wire, legs)
+        SfuBridge._emit_fanout(me, wire, legs, (1, 0.0))
 
     one()                                    # warm caches, lazy imports
     gc.collect()

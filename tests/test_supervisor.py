@@ -193,13 +193,13 @@ def test_sfu_overrun_in_forward_chain_escalates_on_self_time(
 
     sfu, sup, send = sfu_with_traffic
     send.until_forwarded()                  # shapes compiled, warm
-    translate = sfu.translator.translate
+    dispatch = sfu.translator.translate_async
 
     def slow(batch, index):
         time.sleep(1.0)                     # forward_chain's own time
-        return translate(batch, index)
+        return dispatch(batch, index)
 
-    monkeypatch.setattr(sfu.translator, "translate", slow)
+    monkeypatch.setattr(sfu.translator, "translate_async", slow)
     sup.cfg.overload_after = 1
     sup.watchdog.deadline_s = 0.5
     send.until_forwarded()
@@ -234,8 +234,10 @@ def test_sfu_overrun_inside_the_device_seam_escalates_as_it_did(
     slept = []
 
     def slow(self):
-        if not slept:                       # the tick's first call: the
-            slept.append(1)                 # unprotect's
+        # the unprotect's call (the tick's first such call collects
+        # the fan-out the tick before dispatched)
+        if not slept and sfu.loop.tracer._open.stage == stage:
+            slept.append(1)
             time.sleep(1.0)
         return real(self)
 
