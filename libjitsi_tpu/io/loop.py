@@ -29,7 +29,7 @@ from libjitsi_tpu.rtp import header as rtp_header
 from libjitsi_tpu.utils.flight import FlightRecorder
 from libjitsi_tpu.utils.logging import get_logger
 from libjitsi_tpu.utils.metrics import MetricsRegistry
-from libjitsi_tpu.utils.perf import PhaseProfiler
+from libjitsi_tpu.utils.perf import LoopPerf
 from libjitsi_tpu.utils.tracing import PipelineTracer
 
 _log = get_logger("io.loop")
@@ -75,8 +75,7 @@ class MediaLoop:
                  pipelined: bool = False,
                  pipeline_depth: int = 1,
                  tracer: Optional[PipelineTracer] = None,
-                 flight: Optional[FlightRecorder] = None,
-                 phase_sample_every: int = 16):
+                 flight: Optional[FlightRecorder] = None):
         self.engine = engine
         # drain rings: the primary engine plus any SO_REUSEPORT
         # siblings attached via `add_ring` — each tick drains all of
@@ -243,12 +242,10 @@ class MediaLoop:
         # age (in ticks) of the oldest un-flushed async dispatch; >1
         # means protected bytes sat across a full tick — pipeline depth
         self.dispatch_inflight_ticks = 0
-        # host/device phase attribution: fenced probes every
-        # `phase_sample_every` ticks, byte counters every tick
-        self.perf = PhaseProfiler(
-            metrics=self.metrics, sample_every=phase_sample_every,
-            tracer=self.tracer,
-            inflight_fn=lambda: self._inflight_age())
+        # the tick's host/device phase split (read off the tracer's
+        # spans) and the transfer byte counters
+        self.perf = LoopPerf(self.tracer, self.metrics,
+                             inflight_fn=self._inflight_age)
 
     # ------------------------------------------------------ drain rings
     @property
@@ -385,15 +382,14 @@ class MediaLoop:
                                 for e in self.rings)
         ring_batches = []
         with self.tracer.span("ingress"):
-            with self.perf.phase("idle"):    # socket wait dominates here
-                for k, eng in enumerate(self.rings):
-                    if self.ring_sinks[k] is not None:
-                        continue             # control ring: drained below
-                    # primary ring pays the batching window; sibling
-                    # rings poll — their packets arrived during the wait
-                    ring_batches.append((eng, self._recv_ring(
-                        eng, self.recv_window_ms if k == 0 else 0,
-                        use_view)))
+            for k, eng in enumerate(self.rings):
+                if self.ring_sinks[k] is not None:
+                    continue             # control ring: drained below
+                # primary ring pays the batching window; sibling
+                # rings poll — their packets arrived during the wait
+                ring_batches.append((eng, self._recv_ring(
+                    eng, self.recv_window_ms if k == 0 else 0,
+                    use_view)))
             # control rings (cascade trunk sockets): non-blocking copy
             # drain in the same ingress span; frames go to the sink,
             # never the RTP body, and don't count as RTP ingest
@@ -591,17 +587,16 @@ class MediaLoop:
                     # pending and is released at materialization
                     self.perf.note_h2d(rtp.data.nbytes +
                                        np.asarray(rtp.length).nbytes)
-                    self.perf.probe_h2d((rtp.data,))
                     # the serialization barrier (previous window's
-                    # replay-state commit) is a fenced wait on already-
+                    # replay-state commit) is a wait on already-
                     # dispatched device auth work — run it here so the
                     # dispatch span below measures only the new launch
                     commit = getattr(self.chain.rtp_transformer,
                                      "commit_inflight", None)
                     if commit is not None:
-                        with self.perf.phase("device_compute"):
+                        with self.tracer.span("chain_device"):
                             commit()
-                    with self.perf.phase("dispatch"):
+                    with self.tracer.span("chain_dispatch"):
                         pend = (self.chain.rtp_transformer
                                 .reverse_transform_async(rtp))
                     self._rx_inflight.append({
@@ -615,11 +610,10 @@ class MediaLoop:
                     if self.chain is not None:
                         self.perf.note_h2d(rtp.data.nbytes +
                                            np.asarray(rtp.length).nbytes)
-                        self.perf.probe_h2d((rtp.data,))
                         # the sync reverse call blends dispatch + compute
                         # + d2h; attributed wholesale to device_compute
                         # (the async seams split them properly)
-                        with self.perf.phase("device_compute"):
+                        with self.tracer.span("chain_device"):
                             rtp, ok = (self.chain.rtp_transformer
                                        .reverse_transform(rtp))
                         self.perf.note_d2h(rtp.data.nbytes)
@@ -710,10 +704,9 @@ class MediaLoop:
 
     def _finish_rx(self, e: dict) -> int:
         pend = e["pend"]
-        with self.tracer.span("reverse_chain"):
-            self.perf.fence(pend)
-            with self.perf.phase("d2h_transfer"):
-                rtp, ok = pend.result()
+        with self.tracer.span("reverse_chain"), \
+                self.tracer.span("chain_d2h"):
+            rtp, ok = pend.result()
         self.perf.note_d2h(rtp.data.nbytes)
         # the original arena bytes were last read inside result() (the
         # failed-row passthrough) — safe to recycle from here on
@@ -811,15 +804,7 @@ class MediaLoop:
                 tr = self.chain.rtp_transformer
                 self.perf.note_h2d(batch.data.nbytes +
                                    np.asarray(batch.length).nbytes)
-                if self.perf.sampled and hasattr(tr, "transform_async"):
-                    # sampled tick: run the same work through the async
-                    # seam so dispatch / device_compute / d2h split out
-                    with self.perf.phase("dispatch"):
-                        pending, ok = tr.transform_async(batch)
-                    self.perf.fence(pending)
-                    with self.perf.phase("d2h_transfer"):
-                        batch = pending.result()
-                else:
+                with self.tracer.span("chain_device"):
                     batch, ok = tr.transform(batch)
                 self.perf.note_d2h(batch.data.nbytes)
             else:
@@ -844,7 +829,7 @@ class MediaLoop:
         with self.tracer.span("forward_chain"):
             self.perf.note_h2d(batch.data.nbytes +
                                np.asarray(batch.length).nbytes)
-            with self.perf.phase("dispatch"):
+            with self.tracer.span("chain_dispatch"):
                 pending, mask = (self.chain.rtp_transformer
                                  .transform_async(batch))
         self._inflight.append((
@@ -859,8 +844,7 @@ class MediaLoop:
         inflight, self._inflight = self._inflight, []
         with self.tracer.span("egress"):
             for pending, mask, origin, _tick in inflight:
-                self.perf.fence(pending)
-                with self.perf.phase("d2h_transfer"):
+                with self.tracer.span("chain_d2h"):
                     out = pending.result()
                 self.perf.note_d2h(out.data.nbytes)
                 k = self._send_masked(out, mask)

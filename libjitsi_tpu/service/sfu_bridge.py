@@ -291,11 +291,11 @@ class SfuBridge:
         self._trunks: Dict[int, object] = {}
 
     def _share_tracer(self) -> None:
-        """Hand the loop's tracer and phase profiler to the pieces
-        that span their own parts of the tick (the way the supervisor
-        hands out `flight`); again after a restore replaced a table."""
+        """Hand the loop's tracer to the pieces that span their own
+        parts of the tick (the way the supervisor hands out `flight`);
+        again after a restore replaced a table."""
         for obj in (self.rx_table, self.translator):
-            obj.tracer, obj.perf = self.loop.tracer, self.loop.perf
+            obj.tracer = self.loop.tracer
 
     # ---------------------------------------------------------- endpoints
     def has_ssrc(self, ssrc: int) -> bool:
@@ -951,9 +951,9 @@ class SfuBridge:
         self._quiesce_fanout()
         perf.note_h2d(batch.data.nbytes +
                       np.asarray(batch.length).nbytes)
-        # the table splits the call into its host part and the wait for
-        # the device (`unprotect_host` / `unprotect_wait`, the latter
-        # the `device_compute` phase)
+        # the table splits the call into its host part and the device
+        # call (`unprotect_host` / `unprotect_wait`, the latter the
+        # seam's four host phases)
         with tracer.span("unprotect"):
             dec, ok, idx = self.rx_table.unprotect_rtp(
                 batch, return_index=True)

@@ -56,7 +56,8 @@ from libjitsi_tpu.core.packet import PacketBatch
 from libjitsi_tpu.utils.flight import FlightRecorder
 from libjitsi_tpu.utils.health import (ExponentialBackoff, SlidingWindowCounter,
                                        Watchdog, retrying, state_code)
-from libjitsi_tpu.utils.tracing import PipelineTracer, span_of
+from libjitsi_tpu.utils.tracing import (PipelineTracer, phase_split,
+                                        span_of)
 
 CKPT_MAGIC = "ljt-ckpt"
 CKPT_VERSION = 1
@@ -141,11 +142,17 @@ class BridgeSupervisor:
         self._gc_t0 = 0.0
         self._gc_span = None
         self._gc_unhook = self._hook_gc() if self._tree else None
-        # host/device phase ledger (utils/perf.PhaseProfiler via the
-        # tracer): escalations say host-bound vs device-bound, not just
-        # which stage.  getattr-guarded — test stubs carry only
-        # take_ledger
+        # the host/device phase split of the tick the ladder judges
+        # (`_take_ledgers`): escalations say host-bound vs
+        # device-bound, not just which stage.  Taken here, where the
+        # ledgers are drained, and handed on to the loop's `perf`
+        # (utils/perf.py: its totals and histogram), which then takes
+        # none of its own (test stubs carry no `perf`)
         self.last_phases: Dict[str, float] = {}
+        self._perf = (getattr(self.loop, "perf", None)
+                      if self.tracer is not None else None)
+        if self._perf is not None:
+            self._perf.drained_by_supervisor = True
         cap = self.loop.registry.capacity
         self.watchdog = Watchdog(self.cfg.deadline_ms / 1000.0,
                                  overload_after=self.cfg.overload_after,
@@ -243,10 +250,13 @@ class BridgeSupervisor:
             self._gc_span = None
 
     def close(self) -> None:
-        """Take the collector hook out (the bridge is the caller's to
+        """Take the collector hook out and leave the loop's phase
+        split to the loop again (the bridge is the caller's to
         close)."""
         if self._gc_unhook is not None:
             self._gc_unhook()
+        if self._perf is not None:
+            self._perf.drained_by_supervisor = False
 
     # ------------------------------------------------------------- tick
 
@@ -328,11 +338,12 @@ class BridgeSupervisor:
         self.last_self_ledger = getattr(
             self.tracer, "last_self_ledger", self.last_ledger)
         self.last_counts = getattr(self.tracer, "last_counts", {})
-        take_phases = getattr(self.tracer, "take_phase_ledger", None)
-        if take_phases is not None:
-            phases = take_phases()
-            if phases:       # sampled ticks only; keep last split
-                self.last_phases = phases
+        # `supervise` is still open: the ledger and `last_tick_s` both
+        # cover `bridge.tick()` and nothing else
+        self.last_phases = phase_split(self.last_self_ledger,
+                                       self.last_tick_s)
+        if self._perf is not None:
+            self._perf.take(self.last_phases)
 
     # ------------------------------------------- overload escalation
 
@@ -886,7 +897,7 @@ class BridgeSupervisor:
                 bank.register_metrics(registry)
 
     def _phase_attr(self):
-        """(phase, seconds, share, bound) of the last sampled phase
+        """(phase, seconds, share, bound) of the last tick's phase
         split — "which phase owns the tick, and is that host-side or
         device-side?"."""
         from libjitsi_tpu.utils.perf import classify_bound
@@ -936,8 +947,8 @@ class BridgeSupervisor:
             if self.lifecycle is not None else None
         if hq is not None:
             # same rule as the keystream ledger: handshake OpenSSL work
-            # runs on the between-ticks window, so the PhaseProfiler's
-            # tick split never contains it — its wall time is attributed
+            # runs on the between-ticks window, so the tick's phase
+            # split never contains it — its wall time is attributed
             # here, and `tick_thread_feeds` must stay 0 (the reconnect
             # soak gates on it)
             out.setdefault("off_tick", {}).update({

@@ -41,7 +41,6 @@ from libjitsi_tpu.rtp import header as rtp_header
 from libjitsi_tpu.transform.srtp import kernel
 from libjitsi_tpu.transform.srtp.kdf import derive_session_keys
 from libjitsi_tpu.transform.srtp.policy import Cipher, SrtpProfile
-from libjitsi_tpu.utils.perf import phase_of
 from libjitsi_tpu.utils.tracing import span_of
 
 
@@ -240,10 +239,9 @@ class RtpTranslator:
         # the newest `translate_async` not collected yet: what
         # `translate` of that very batch hands back
         self._in_flight: Optional["PendingTranslate"] = None
-        # the bridge hands its loop's PipelineTracer and PhaseProfiler
-        # here; a translator standing alone spans and samples nothing
+        # the bridge hands its loop's PipelineTracer here; a
+        # translator standing alone spans nothing
         self.tracer = None
-        self.perf = None
 
     # ---------------------------------------------------------- receivers
     def add_receiver(self, rid: int, master_key: bytes,
@@ -577,8 +575,7 @@ class RtpTranslator:
             if k:
                 with span_of(tracer, "expand", row_class=padded[k]):
                     args = self._expand_rows(batch, rowv, pw, a, b)
-            with staging.dispatch(tracer, "fanout", **nth) as sp, \
-                    phase_of(self.perf, "dispatch"):
+            with staging.dispatch(tracer, "fanout", **nth) as sp:
                 launch = call(*args)
                 sp.note(h2d_arrays=launch.h2d_arrays,
                         h2d_bytes=launch.h2d_bytes, **launch.counts)
@@ -684,7 +681,7 @@ class RtpTranslator:
         `staging.Launch` in flight, (packets, legs) of the grid).
         Reference: RTPTranslatorImpl's cipher-agnostic per-leg
         transform (SURVEY §3.4)."""
-        tracer, perf = self.tracer, self.perf
+        tracer = self.tracer
         with span_of(tracer, "expand") as sp:
             p_rows = np.asarray(rows, dtype=np.int64)
             pidx = np.asarray(idx).reshape(len(rows), len(rr))[:, 0]
@@ -717,8 +714,7 @@ class RtpTranslator:
             sp.note(rows=g_real * p_real,
                     rows_padded=len(rr_p) * len(pr), width=pw,
                     launches=1, legs_max=g_real, class_cut=0)
-        with staging.dispatch(tracer, "fanout") as sp, \
-                phase_of(perf, "dispatch"):
+        with staging.dispatch(tracer, "fanout") as sp:
             # the output is leg-major [G, P, W] at the class-PADDED
             # shape; cropping to the raw (P, G) and the flip to
             # packet-major rows (p0r0, p0r1, ...) matching
@@ -843,15 +839,13 @@ class PendingTranslate:
         `d2h_transfer`)."""
         tr = self._translator
         hidden = time.perf_counter() - launch.dispatched_at
-        with span_of(tr.tracer, "fanout_wait", **nth) as sp, \
-                phase_of(tr.perf, "device_compute"):
+        with span_of(tr.tracer, "fanout_wait", **nth) as sp:
             ready = int(launch.is_ready())
             sp.note(collected=1, ready=ready, hidden_us=int(1e6 * hidden))
             launch.block_until_ready()
         tr.fanout_collects += 1
         tr.fanout_collects_ready += ready
-        with span_of(tr.tracer, "fanout_d2h", **nth) as sp, \
-                phase_of(tr.perf, "d2h_transfer"):
+        with span_of(tr.tracer, "fanout_d2h", **nth) as sp:
             arr, lens = launch.fetch()
             lens = np.asarray(lens, dtype=np.int32)
             sp.note(d2h_arrays=launch.d2h_arrays,

@@ -50,7 +50,6 @@ from libjitsi_tpu.transform.srtp import kernel, replay
 from libjitsi_tpu.transform.srtp.kdf import (derive_session_keys,
                                              derive_session_keys_batch)
 from libjitsi_tpu.transform.srtp.policy import Cipher, SrtpPolicy, SrtpProfile
-from libjitsi_tpu.utils.perf import phase_of
 from libjitsi_tpu.utils.tracing import span_of
 
 
@@ -83,9 +82,9 @@ _unprotect_rtp_dev = jax.jit(
 # the recv arena (`jnp.asarray(batch.data)`) is consumed exactly once,
 # so donating it lets XLA alias the decrypted output into the staged
 # input instead of allocating a second batch-width buffer — the last
-# host-side copy of the ingest leg, attributed by the PhaseProfiler's
-# h2d_transfer phase.  Selected only off-CPU (`_donate_ingest`):
-# the CPU backend ignores donation with a per-call warning.
+# host-side copy of the ingest leg.  Selected only off-CPU
+# (`_donate_ingest`): the CPU backend ignores donation with a per-call
+# warning.
 _unprotect_rtp_dev_donated = jax.jit(
     _unprotect_rtp_impl, static_argnames=("tag_len", "encrypt", "off_const"),
     donate_argnums=(3,))
@@ -438,11 +437,10 @@ class SrtpStreamTable:
         # instead of recomputed + re-device_put per batch (the cached
         # fast path is host-bound without this)
         self._grid_memo: dict = {}
-        # a bridge hands its loop's PipelineTracer and PhaseProfiler
-        # here (`unprotect_host` / `unprotect_wait`); a table standing
-        # alone spans and samples nothing
+        # a bridge hands its loop's PipelineTracer here
+        # (`unprotect_host` / `unprotect_wait`); a table standing
+        # alone spans nothing
         self.tracer = None
-        self.perf = None
 
     def enable_keystream_cache(self, window: int = 64,
                                ks_bytes: int = 256,
@@ -1575,8 +1573,7 @@ class SrtpStreamTable:
         # inside the call: core/staging.py)
         with span_of(tracer, "unprotect_wait",
                      rows=batch.batch_size if n_real is None else n_real,
-                     rows_padded=batch.batch_size) as sp, \
-                phase_of(self.perf, "device_compute"):
+                     rows_padded=batch.batch_size) as sp:
             with staging.dispatch(tracer, "unprotect"):
                 if self._gcm:
                     out = (None if self._ks_cache is None

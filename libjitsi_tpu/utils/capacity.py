@@ -9,7 +9,7 @@ a per-resource utilization model from signals that are already
 flowing, with no new instrumentation on the data path:
 
   tick_budget   watchdog-observed tick wall time over the deadline
-  host          PhaseProfiler host share of the non-idle tick
+  host          host share of the non-idle tick (the phase split)
                 (host_python + dispatch; the PR 8 host ceiling)
   rows          SRTP registry row occupancy (hard per-chip slots)
   backlog       lifecycle admit queue depth over `max_pending`

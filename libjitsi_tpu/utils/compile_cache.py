@@ -29,7 +29,7 @@ class CompileCacheStats:
     (a miss is an entry WRITTEN, so compiles under the min-compile-time
     threshold count as neither), "compil" covers the trace / lower /
     backend-compile durations every in-process jit miss emits.
-    `PhaseProfiler.register_metrics` exports
+    `LoopPerf.register_metrics` exports
     them as `compile_cache_hits` / `compile_cache_misses` /
     `compile_events` (+ `compile_seconds_total`): a recompile landing
     on the data path shows up as a counter step in the scrape, not a

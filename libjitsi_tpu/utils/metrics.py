@@ -27,6 +27,7 @@ type (`render(openmetrics=True)`), which also appends the mandatory
 
 from __future__ import annotations
 
+import bisect
 import math
 import time
 from typing import (Callable, Dict, Iterable, List, Optional, Sequence,
@@ -172,6 +173,9 @@ class Histogram:
             raise ValueError("bucket bounds must be finite; +Inf is "
                              "implicit")
         self.uppers = uppers
+        # the scalar path's copy: a bisect over a list is a tenth of a
+        # `np.searchsorted` of one value
+        self._bounds = uppers.tolist()
         # one slot per finite bucket + the +Inf overflow slot
         self.bucket_counts = np.zeros(len(uppers) + 1, dtype=np.int64)
         self.sum = 0.0
@@ -194,7 +198,7 @@ class Histogram:
         if n <= 0:
             return False
         v = float(value)
-        idx = int(np.searchsorted(self.uppers, v, side="left"))
+        idx = bisect.bisect_left(self._bounds, v)
         self.bucket_counts[idx] += int(n)
         self.sum += v * int(n)
         self.count += int(n)

@@ -91,6 +91,48 @@ OFF_TICK_STAGES = ("egress_send",)
 STAGES = (LEAF_STAGES + CONTAINER_STAGES + OFF_TICK_STAGES
           + ("decode", "mixer"))
 
+#: the phases a tick's wall time splits into (`phase_split`;
+#: utils/perf.py says which are the host's and which the device's)
+PHASES = ("host_python", "dispatch", "h2d_transfer", "device_compute",
+          "d2h_transfer", "idle")
+
+#: THE rule from a span's SELF time to a phase, and the one place that
+#: knows it.  Of a device call's four host phases (above) the put is
+#: the copy in, the rest of the dispatch the launch, the block the wait
+#: for the program and the d2h the copy back; `ingress` is the socket's
+#: batching window.  A stage not named here, and what no span covers,
+#: is the interpreter's: `host_python`.  The `chain_*` spans are the
+#: same seams of a `MediaLoop` that runs a transform chain
+#: (io/loop.py): a call that blends launch, wait and copy back is
+#: booked as the wait, a `result()` as the copy back.  None opens on
+#: an SfuBridge tick
+PHASE_OF_STAGE = {
+    "ingress": "idle",
+    "unprotect_put": "h2d_transfer", "fanout_put": "h2d_transfer",
+    "unprotect_dispatch": "dispatch", "fanout_dispatch": "dispatch",
+    "chain_dispatch": "dispatch",
+    "unprotect_block": "device_compute", "fanout_wait": "device_compute",
+    "chain_device": "device_compute",
+    "unprotect_d2h": "d2h_transfer", "fanout_d2h": "d2h_transfer",
+    "chain_d2h": "d2h_transfer",
+}
+
+
+def phase_split(self_ledger: Dict[str, float],
+                wall_s: float) -> Dict[str, float]:
+    """One tick's self ledger and its wall seconds -> {phase: seconds}
+    over `PHASES`.  `host_python` is the residual (clamped at 0 where
+    a caller's clock is not the spans'), so the six sum to the wall:
+    what makes a share of them meaningful.  `OFF_TICK_STAGES` enter
+    nowhere, as they enter no self ledger."""
+    out = dict.fromkeys(PHASES, 0.0)
+    for stage, phase in PHASE_OF_STAGE.items():
+        seconds = self_ledger.get(stage)
+        if seconds:
+            out[phase] += seconds
+    out["host_python"] = max(0.0, wall_s - sum(out.values()))
+    return out
+
 
 class _NullSpan:
     """What a component with no tracer opens: costs one call."""
@@ -209,12 +251,6 @@ class PipelineTracer:
         self.last_ledger: Dict[str, float] = {}
         self.last_self_ledger: Dict[str, float] = {}
         self.last_counts: Dict[str, Dict[str, float]] = {}
-        # host/device *phase* ledger (host_python/dispatch/h2d/... from
-        # utils.perf.PhaseProfiler) — kept separate from the stage
-        # ledger so phase rows can never outrank stages in the
-        # supervisor's rung choice, but drained on the same cadence
-        self._phase_ledger: Dict[str, float] = {}
-        self.last_phase_ledger: Dict[str, float] = {}
 
     def _sink(self, stage: str) -> tuple:
         sink = self._sinks.get(stage)
@@ -226,6 +262,13 @@ class PipelineTracer:
 
     def span(self, stage: str, **counts) -> _StageSpan:
         return _StageSpan(self, stage, counts)
+
+    @property
+    def self_ledger(self) -> Dict[str, float]:
+        """The self ledger as it stands, not drained: for a reader
+        that is not the drainer (utils/perf.py reads what a region
+        added to it)."""
+        return self._self_ledger
 
     @contextlib.contextmanager
     def on_behalf_of(self, tick: int):
@@ -265,22 +308,6 @@ class PipelineTracer:
             return NULL_SPAN
         return _TraceAnnotation(f"{self.prefix}:tick", tick=tick,
                                 **counts)
-
-    def merge_phases(self, phases: Dict[str, float]) -> None:
-        """Accumulate a tick's phase split (phase -> seconds) into the
-        phase ledger; the PhaseProfiler calls this at end_tick on
-        sampled ticks."""
-        led = self._phase_ledger
-        for phase, seconds in phases.items():
-            led[phase] = led.get(phase, 0.0) + float(seconds)
-
-    def take_phase_ledger(self) -> Dict[str, float]:
-        """Drain and return the accumulated phase ledger (same
-        contract as `take_ledger`, retained as `last_phase_ledger`)."""
-        led, self._phase_ledger = self._phase_ledger, {}
-        if led:
-            self.last_phase_ledger = led
-        return led
 
     def take_ledger(self, fold: bool = False) -> Dict[str, float]:
         """Drain this tick's ledgers and return the inclusive one

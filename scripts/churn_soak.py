@@ -773,7 +773,7 @@ def run_reconnect_soak(n_clients: int = 1000, dt: float = 0.02,
     - ZERO data-path recompiles inside tick windows after priming, on
       both the original and the recovered bridge;
     - ZERO handshake work attributed to the tick thread: every OpenSSL
-      feed runs on the between-ticks drain (PhaseProfiler off-tick
+      feed runs on the between-ticks drain (`phase_attribution` off-tick
       ledger + the lifecycle feed bracket both say so);
     - every refusal TYPED (`handshake_backlog` observed, with a
       retry-after hint clients honor via exponential backoff) and the

@@ -254,7 +254,7 @@ def gen_dashboard_json(registry, slo) -> str:
          fam("supervisor_ticks_overrun"),
          fam("supervisor_streams_shed"),
          fam("bridge_forwarded")], 12, 16)
-    add("Host/device phase split (sampled ticks)",
+    add("Host/device phase split",
         [f"sum by (phase) (rate({ns}_tick_phase_seconds_sum[5m]))"]
         if fam("tick_phase_seconds") else [], 0, 24)
     add("Dispatch depth / transfer bytes",

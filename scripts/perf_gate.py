@@ -213,9 +213,9 @@ def _scenario_loop_echo():
 
 def _scenario_loop_host_share():
     """Phase-ledger host share of the pipelined loop-echo tick:
-    (host_python + dispatch) / non-idle time, captured with an
-    every-tick fenced PhaseProfiler (trace_report's capture
-    discipline).  Median of three passes — a ratio of two noisy sums
+    (host_python + dispatch) / non-idle time, off the phase split the
+    loop reads from its spans every tick (utils/perf.py).  Median of
+    three passes — a ratio of two noisy sums
     on a shared box needs the repeat-and-median treatment, same as
     bench.py's timer discipline.  Lower is better; the baseline entry
     carries a hard `ceiling` — the gate fails if the share exceeds it
@@ -231,10 +231,9 @@ def _scenario_loop_host_share():
 
     def one_pass():
         profilers = []
-        orig_init = perf_mod.PhaseProfiler.__init__
+        orig_init = perf_mod.LoopPerf.__init__
 
-        def every_tick_init(self, *a, **kw):
-            kw["sample_every"] = 1
+        def noting_init(self, *a, **kw):
             orig_init(self, *a, **kw)
             profilers.append(self)
 
@@ -245,7 +244,7 @@ def _scenario_loop_host_share():
                 (prof, dict(getattr(prof, "phase_totals", {})))
                 for prof in profilers)
 
-        perf_mod.PhaseProfiler.__init__ = every_tick_init
+        perf_mod.LoopPerf.__init__ = noting_init
         try:
             # saturated offered load (128-pkt bursts -> up to 512-pkt
             # windows): host share is the overload-classification
@@ -254,7 +253,7 @@ def _scenario_loop_host_share():
                                         pipeline_depth=3,
                                         on_steady=snapshot_warm)
         finally:
-            perf_mod.PhaseProfiler.__init__ = orig_init
+            perf_mod.LoopPerf.__init__ = orig_init
         # steady-state delta only: warmup bucket compiles land in the
         # `dispatch` phase and would swamp the share otherwise
         phases = {}

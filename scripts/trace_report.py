@@ -23,8 +23,8 @@ and the report lists each cross-bridge journey's spans — the packet's
 path across the cascade trunk.
 
 The capture mode runs the small loop-echo scenario (perf_gate's
-`loop_echo_pps` twin) under both `jax.profiler.trace` and an
-every-tick `PhaseProfiler`, then reports the trace occupancy AND the
+`loop_echo_pps` twin) once for the loop's phase split and once under
+`jax.profiler.trace`, then reports the trace occupancy AND the
 phase-ledger host share — the two independent views the host-bound
 diagnosis rests on.  On a CPU-only box the profiler may not emit a
 device track; the report says so instead of inventing one.
@@ -182,10 +182,9 @@ def format_report(report: dict) -> str:
 def capture_loop_echo(log_dir: str) -> dict:
     """Two-pass loop-echo evidence capture: {trace report, phase ledger}.
 
-    Pass 1 (phase ledger + pps): the gate's windowed loop-echo with an
-    every-tick-fenced PhaseProfiler and NO jax.profiler trace active —
-    profiler instrumentation overhead lands inside the dispatch spans
-    and would misattribute the tick.  Warmup totals are snapshotted out
+    Pass 1 (phase ledger + pps): the gate's windowed loop-echo with NO
+    jax.profiler trace active — profiler instrumentation overhead
+    lands inside the dispatch spans and would misattribute the tick.  Warmup totals are snapshotted out
     so bucket compiles don't pollute the steady-state ledger (the same
     discipline as perf_gate's `loop_host_share` scenario).
 
@@ -199,11 +198,10 @@ def capture_loop_echo(log_dir: str) -> dict:
 
     profilers = []
     warm_marks = []
-    orig_init = perf_mod.PhaseProfiler.__init__
+    orig_init = perf_mod.LoopPerf.__init__
 
-    def every_tick_init(self, *a, **kw):
-        kw["sample_every"] = 1          # fence every tick: evidence
-        orig_init(self, *a, **kw)       # capture, not steady state
+    def noting_init(self, *a, **kw):
+        orig_init(self, *a, **kw)
         profilers.append(self)
 
     def snapshot_warm():
@@ -211,7 +209,7 @@ def capture_loop_echo(log_dir: str) -> dict:
             (prof, dict(getattr(prof, "phase_totals", {})))
             for prof in profilers)
 
-    perf_mod.PhaseProfiler.__init__ = every_tick_init
+    perf_mod.LoopPerf.__init__ = noting_init
     try:
         # saturated offered load (128-pkt bursts, the gate scenario's
         # configuration): host share is workload-dependent — per-call
@@ -221,7 +219,7 @@ def capture_loop_echo(log_dir: str) -> dict:
             n_pkts=128, cycles=16, pipeline_depth=3,
             on_steady=snapshot_warm)
     finally:
-        perf_mod.PhaseProfiler.__init__ = orig_init
+        perf_mod.LoopPerf.__init__ = orig_init
     # steady-state delta only (warmup compiles land in `dispatch`)
     phases = {}
     for prof, warm in warm_marks:

@@ -238,6 +238,9 @@ def _bridge(n_eps=3):
 def _ticks(sfu, now, n=6):
     for _ in range(n):
         sfu.tick(now=now)
+    # the last fan-out leaves on the egress worker: a client socket is
+    # read only once that thread has sent it (never a sleep)
+    sfu.flush_egress()
 
 
 @pytest.mark.parametrize("fec", (False, True), ids=("plain", "fec"))

@@ -137,19 +137,17 @@ def test_escalation_ladder_then_full_recovery():
 
 
 def _stage_sup(ledger, durations, with_recovery=True, slo=None,
-               phases=None, **cfg_kwargs):
+               **cfg_kwargs):
     """Supervisor over a DummyBridge with a seeded stage ledger (the
     tracer stub returns the same per-stage seconds every tick) and an
     optional recovery stub that records shed_fec/throttle_rtx calls.
-    `phases` seeds a phase ledger (host/device split) the same way."""
+    The phase split is read off that ledger and the fake clock's tick
+    (`durations`), by the rule in utils/tracing.py."""
     cfg = SupervisorConfig(deadline_ms=10.0, overload_after=1,
                            **cfg_kwargs)
     bridge = DummyBridge()
     bridge.loop.tracer = types.SimpleNamespace(
         take_ledger=lambda: dict(ledger))
-    if phases is not None:
-        bridge.loop.tracer.take_phase_ledger = \
-            lambda: dict(phases)
     calls = []
     if with_recovery:
         bridge.recovery = types.SimpleNamespace(
@@ -317,38 +315,42 @@ def test_escalation_names_host_phase_when_host_bound():
     """A host-dominant phase split must reach the ladder_escalate
     event: the page says "host-bound, host_python owns the tick", not
     just which pipeline stage overran."""
-    ledger = {"ingress": 0.008, "forward_chain": 0.001}
-    phases = {"host_python": 0.016, "dispatch": 0.002,
-              "device_compute": 0.001, "idle": 0.001}
-    sup, _bridge, _calls = _stage_sup(ledger, [0.05], phases=phases)
+    ledger = {"ingress": 0.001, "forward_chain": 0.0135,
+              "fanout_dispatch": 0.002, "fanout_put": 0.0005,
+              "unprotect_block": 0.001}
+    phases = {"host_python": 0.0155, "dispatch": 0.002,
+              "h2d_transfer": 0.0005, "device_compute": 0.001,
+              "d2h_transfer": 0.0, "idle": 0.001}
+    sup, _bridge, _calls = _stage_sup(ledger, [0.02])
     sup.tick()
     (ev,) = _escalations(sup)
     assert ev["phase"] == "host_python"
     assert ev["bound"] == "host"
-    assert ev["phase_share"] == pytest.approx(0.8, abs=0.01)
+    assert ev["phase_share"] == pytest.approx(0.775, abs=0.001)
     attr = sup.phase_attribution()
     assert attr["bound"] == "host"
     assert attr["phase"] == "host_python"
-    assert attr["phases"] == phases
+    assert attr["phases"] == pytest.approx(phases)
+    assert sum(attr["phases"].values()) == pytest.approx(sup.last_tick_s)
     assert sup.health()["bound"] == "host"
 
 
 def test_escalation_names_device_phase_when_device_bound():
-    ledger = {"forward_chain": 0.009, "ingress": 0.001}
-    phases = {"host_python": 0.001, "dispatch": 0.001,
-              "device_compute": 0.015, "d2h_transfer": 0.002}
-    sup, _bridge, _calls = _stage_sup(ledger, [0.05], phases=phases)
+    ledger = {"forward_chain": 0.001, "unprotect_dispatch": 0.001,
+              "unprotect_block": 0.006, "fanout_wait": 0.009,
+              "fanout_d2h": 0.002}
+    sup, _bridge, _calls = _stage_sup(ledger, [0.02])
     sup.tick()
     (ev,) = _escalations(sup)
     assert ev["phase"] == "device_compute"
     assert ev["bound"] == "device"
 
 
-def test_escalation_without_phase_ledger_reports_unknown():
-    """Tracer stubs (and pre-profiler loops) have no phase ledger at
-    all — attribution degrades to unknown, never crashes."""
-    ledger = {"forward_chain": 0.009, "ingress": 0.001}
-    sup, _bridge, _calls = _stage_sup(ledger, [0.05])
+def test_escalation_without_a_tracer_reports_unknown():
+    """A bridge whose loop has no tracer has no ledger to read a split
+    off — attribution degrades to unknown, never crashes."""
+    sup, _bridge = _sup([0.05], overload_after=1)
+    assert sup.tracer is None
     sup.tick()
     (ev,) = _escalations(sup)
     assert ev["phase"] == "unknown"
@@ -356,19 +358,21 @@ def test_escalation_without_phase_ledger_reports_unknown():
     assert sup.phase_attribution()["phases"] == {}
 
 
-def test_phase_ledger_keeps_last_sampled_split_across_empty_drains():
-    """Supervisor ticks outpace sampled profiler ticks: an empty drain
-    must NOT wipe the last real split."""
-    ledger = {"forward_chain": 0.009, "ingress": 0.001}
-    drains = [{"host_python": 0.01, "device_compute": 0.002}, {}, {}]
-    sup, _bridge, _calls = _stage_sup(ledger, [0.05] * 3)
-    sup.tracer.take_phase_ledger = lambda: drains.pop(0) if drains \
-        else {}
+def test_phase_split_is_of_the_tick_the_ladder_judges():
+    """Every drain takes the split anew: an escalation is labelled
+    with the phases of the tick that overran, not of an earlier one."""
+    drains = [{"unprotect_block": 0.04, "ingress": 0.001},
+              {"forward_chain": 0.04, "ingress": 0.001},
+              {"ingress": 0.045}]
+    sup, bridge, _calls = _stage_sup({}, [0.05] * 3)
+    bridge.loop.tracer.take_ledger = lambda: drains.pop(0)
+    bounds = []
     for _ in range(3):
         sup.tick()
-    assert sup.last_phases == {"host_python": 0.01,
-                               "device_compute": 0.002}
-    assert _escalations(sup)[-1]["bound"] == "host"
+        bounds.append(_escalations(sup)[-1]["bound"])
+        assert sum(sup.last_phases.values()) == pytest.approx(0.05)
+    assert bounds == ["device", "host", "idle"]
+    assert sup.last_phases["idle"] == 0.045
 
 
 def test_shed_is_deterministic_and_priority_ordered():
