@@ -436,8 +436,14 @@ class MediaLoop:
         split, rtcp-mux demux, holds/fanout/shed masks, shard-major
         reorder, reverse-chain dispatch.  Shared by every drain ring;
         DTLS replies and arena pins stay with the ring they came in on."""
-        with self.tracer.span("demux", rows=batch.batch_size):
+        with self.tracer.span("demux", rows=batch.batch_size) as sp:
+            dropped0 = self.fanout_rtp_dropped
             split = self._demux_batch(eng, batch, sip, sport, ats)
+            if self._fanout_only_n:
+                # a bridge with listener rows says how many uplink rows
+                # the mask (not the router) silenced this tick
+                sp.note(fanout_only_dropped=self.fanout_rtp_dropped
+                        - dropped0)
         if split is None:
             self._release_token(token, eng)
             return

@@ -118,6 +118,20 @@ class LifecycleConfig:
     # broadcast conference is not held to it (its listeners are what it
     # is for)
     max_conference_size: int = 0
+    # ------------------------------------------------ the visitors rule
+    # the most members of a conference who take part (Jicofo's
+    # `jicofo.visitors.max-participants`; 0: not stated).  An admission
+    # rule over the broadcast plane that is here, read by nothing on
+    # the data path: with placement enabled `request_join` declares a
+    # conference a broadcast conference when its first member joins
+    # under the rule, admits a member as a speaker while the conference
+    # holds fewer speakers than this and as a fanout-only listener (a
+    # receive-only visitor) from then on; a caller's `role` still
+    # wins, and `promote_speaker` stays what it is.  A visitor is never
+    # refused `conference_full`: `max_conference_size` holds the rooms
+    # of peers alone, so where both are stated it caps the panel and
+    # the manager refuses a panel larger than the room
+    max_conference_participants: int = 0
 
 
 class HandshakeQueue:
@@ -261,6 +275,11 @@ class StreamLifecycleManager:
             raise ValueError(
                 f"the configuration states {self.cfg.table_shards} table "
                 f"shards, the bridge's tables have {shards}")
+        if 0 < self.cfg.max_conference_size \
+                < self.cfg.max_conference_participants:
+            raise ValueError(
+                f"a panel of {self.cfg.max_conference_participants} in "
+                f"a room capped at {self.cfg.max_conference_size}")
         if flight is None:
             flight = (supervisor.flight if supervisor is not None
                       else getattr(bridge, "flight", None))
@@ -286,6 +305,8 @@ class StreamLifecycleManager:
         self.key_installs = 0
         self.datapath_recompiles = 0
         self.admit_rejected: Dict[str, int] = {}
+        # joins queued into broadcast conferences, by the role given
+        self.admit_roles = {"speaker": 0, "listener": 0}
         # broadcast conferences (mesh/hierarchy.py): conf ->
         # {"speakers": set of sids, "join_good"/"join_bad": cumulative
         # listener-join outcomes feeding the label="conference" burn
@@ -584,7 +605,10 @@ class StreamLifecycleManager:
         conference.  Joins into a declared BROADCAST conference default
         to role="listener" (fanout-only row on any shard); pass
         role="speaker" to join the mixed speaker set on the home
-        shard."""
+        shard.  Where the configuration states the visitors rule
+        (`max_conference_participants`), a conference is declared by
+        its first member's join and the default role is "speaker"
+        while it holds fewer speakers than the rule says."""
         ssrc = int(ssrc) & 0xFFFFFFFF
         reason = self._admission_reason(ssrc)
         if (reason is None and conference is not None
@@ -600,10 +624,23 @@ class StreamLifecycleManager:
         bcast = False
         if reason is None and self.placer is not None:
             conf = self._conf_key(ssrc, conference)
+            panel = self.cfg.max_conference_participants
+            declared = bool(panel and conference is not None
+                            and self.placer.shard_of(conf) is None)
+            if declared:
+                # the visitors rule: the room's first member declares it
+                self.declare_broadcast(conf)
             bcast = conf in self._bcast
             if bcast:
-                role = role or "listener"
+                role = role or (
+                    "speaker" if self.placer.size_of(conf) < panel
+                    else "listener")
                 shard, reason = self._place_bcast_join(conf, role)
+                if declared and reason is not None:
+                    # refused at the door: the room it would have
+                    # opened is not kept
+                    self._release_broadcast(conf)
+                    bcast = False
             else:
                 role = None
                 conf, reason = self._place_join(ssrc, conference)
@@ -619,6 +656,8 @@ class StreamLifecycleManager:
         self._join_q.append((ssrc, tuple(rx_key), tuple(tx_key), name,
                              conf, role if bcast else None, shard))
         self._queued_ssrcs.add(ssrc)
+        if bcast:
+            self.admit_roles[role] = self.admit_roles.get(role, 0) + 1
         self.flight.record("admit_queued", tick=self.ticks(), ssrc=ssrc)
         return True, "queued"
 
@@ -828,15 +867,20 @@ class StreamLifecycleManager:
                         if any(c == conf for s, c in conf_of.items()
                                if s in self.bridge._ssrc_of):
                             continue
-                        self.placer.release(conf)
-                        self._drop_conference_slices(conf)
-                        self._bcast.pop(conf, None)
+                        self._release_broadcast(conf)
                         touched.discard(conf)
-                        if hasattr(self.bridge, "clear_broadcast"):
-                            self.bridge.clear_broadcast(conf)
                     for conf in sorted(touched):
                         self._push_speakers(conf)
         self._apply_role_flips()
+
+    def _release_broadcast(self, conf: int) -> None:
+        """A broadcast conference nobody is in any more: its home-shard
+        reservation, burn slice and routing go."""
+        self.placer.release(conf)
+        self._drop_conference_slices(conf)
+        self._bcast.pop(conf, None)
+        if hasattr(self.bridge, "clear_broadcast"):
+            self.bridge.clear_broadcast(conf)
 
     def _push_speakers(self, conf: int) -> None:
         if hasattr(self.bridge, "set_broadcast_speakers"):
@@ -1380,6 +1424,22 @@ class StreamLifecycleManager:
         registry.register_multi(
             f"{prefix}_admit_rejected", self._rejected_samples,
             help_="admissions refused, by typed reason", kind="counter")
+        registry.register_multi(
+            "admit_roles_total",
+            lambda: [({"role": r}, float(c))
+                     for r, c in sorted(self.admit_roles.items())],
+            help_="joins queued into broadcast conferences, by role",
+            kind="counter")
+        registry.register_scalar(
+            "bcast_conferences", lambda: float(len(self._bcast)),
+            help_="broadcast conferences declared (by a caller or by "
+                  "the visitors rule)")
+        registry.register_scalar(
+            "bcast_speakers",
+            lambda: float(sum(len(st["speakers"])
+                              for st in self._bcast.values())),
+            help_="speaker rows staged or live across all broadcast "
+                  "conferences")
         registry.register_scalar(
             "bcast_listeners", lambda: float(len(self._listener_sids)),
             help_="fanout-only listener rows live across all "

@@ -279,6 +279,10 @@ class SfuBridge:
         # without one (direct add_endpoint) form one shared mesh, which
         # keeps the single-conference bridge behavior unchanged.
         self._conf_of: Dict[int, int] = {}
+        # the same relation by conference (conference id -> its sids):
+        # what a conference-scoped call walks, so a room's admission,
+        # panel change or route rebuild costs the room and not the table
+        self._members_of: Dict[int, set] = {}
         # broadcast conferences (mesh/hierarchy.py): conference id ->
         # current speaker sids.  Speakers fan out to every member;
         # every other member is a fanout-only listener row (routes to
@@ -420,7 +424,7 @@ class SfuBridge:
             self._rx_keys.pop(sid, None)
             self._tx_keys.pop(sid, None)
             self._recv_bw.pop(sid, None)
-            conf = self._conf_of.pop(sid, None)
+            conf = self._leave_conference(sid)
             if conf is not None and conf in self._bcast_speakers:
                 self._bcast_speakers[conf].discard(sid)
                 self.loop.set_fanout_only(sid, False)
@@ -503,7 +507,7 @@ class SfuBridge:
         if conferences is not None:
             for sid, conf in zip(sids, conferences):
                 if conf is not None:
-                    self._conf_of[sid] = int(conf)
+                    self._join_conference(sid, int(conf))
         arr = np.asarray(sids, dtype=np.int64)
         rx_mks = np.stack([np.frombuffer(rx[0], np.uint8)
                            for _, rx, _, _ in specs])
@@ -570,10 +574,11 @@ class SfuBridge:
             return
         self._quiesce_fanout()
         self._bcast_speakers[conference] = speakers
-        for sid, conf in self._conf_of.items():
-            if conf == conference:
-                self.loop.set_fanout_only(sid, sid not in speakers)
-        self._rebuild_routes()
+        # this conference's members and routes and no other's: a room's
+        # panel changing costs nothing that grows with the bridge
+        for sid in self._members_of.get(conference, ()):
+            self.loop.set_fanout_only(sid, sid not in speakers)
+        self._rebuild_routes({conference})
         tr = self._trunks.get(conference)
         if tr is not None:
             # propagate the top-K flip across the trunk: the peer
@@ -614,12 +619,12 @@ class SfuBridge:
 
     def clear_broadcast(self, conference: int) -> None:
         """Drop a conference's broadcast routing (back to full mesh)."""
-        if self._bcast_speakers.pop(int(conference), None) is not None:
-            for sid, conf in self._conf_of.items():
-                if conf == int(conference):
-                    self.loop.set_fanout_only(sid, False)
+        conference = int(conference)
+        if self._bcast_speakers.pop(conference, None) is not None:
+            for sid in self._members_of.get(conference, ()):
+                self.loop.set_fanout_only(sid, False)
             self._quiesce_fanout()
-            self._rebuild_routes()
+            self._rebuild_routes({conference})
 
     def migrate_endpoints(self, mapping: Dict[int, int]) -> None:
         """Move live endpoints to new rows BIT-EXACT — the execution
@@ -672,9 +677,9 @@ class SfuBridge:
             self._tx_keys[d] = self._tx_keys.pop(s)
             if s in self._recv_bw:
                 self._recv_bw[d] = self._recv_bw.pop(s)
-            if s in self._conf_of:
-                self._conf_of[d] = self._conf_of.pop(s)
-                conf = self._conf_of[d]
+            conf = self._leave_conference(s)
+            if conf is not None:
+                self._join_conference(d, conf)
                 if conf in self._bcast_speakers:
                     spk = self._bcast_speakers[conf]
                     if s in spk:
@@ -888,6 +893,21 @@ class SfuBridge:
             return True
         return False
 
+    def _join_conference(self, sid: int, conf: int) -> None:
+        self._leave_conference(sid)
+        self._conf_of[sid] = conf
+        self._members_of.setdefault(conf, set()).add(sid)
+
+    def _leave_conference(self, sid: int):
+        """Forget `sid`'s conference; returns it (None: it had none)."""
+        conf = self._conf_of.pop(sid, None)
+        if conf is not None:
+            members = self._members_of[conf]
+            members.discard(sid)
+            if not members:
+                del self._members_of[conf]
+        return conf
+
     def _rebuild_routes(self, conferences=None) -> None:
         """Full mesh: every sender forwards to every OTHER endpoint.
         DTLS-pending rows have no leg keys yet and stay out of the mesh
@@ -901,10 +921,17 @@ class SfuBridge:
         live one.  None: all."""
         conf_of = self._conf_of
         only = conferences if conf_of else None
+        if only is None:
+            members = self._ssrc_of
+        else:
+            members = [s for c in only
+                       for s in self._members_of.get(c, ())]
+            if -1 in only:
+                members += [s for s in self._ssrc_of if s not in conf_of]
         sids = sorted(
-            s for s in self._ssrc_of
-            if s not in self._dtls.pending and s not in self._staged
-            and (only is None or conf_of.get(s, -1) in only))
+            s for s in members
+            if s in self._ssrc_of and s not in self._dtls.pending
+            and s not in self._staged)
         if self._conf_of:
             # conference-scoped mesh: a sender fans out only within its
             # conference (rows without an id share the -1 group)
@@ -1386,8 +1413,8 @@ class SfuBridge:
         bridge._rx_keys = dict(snap["rx_keys"])
         bridge._tx_keys = dict(snap["tx_keys"])
         bridge._recv_bw = dict(snap["recv_bw"])
-        bridge._conf_of = {int(s): int(c) for s, c in
-                           snap.get("conf_of", {}).items()}
+        for s, c in snap.get("conf_of", {}).items():
+            bridge._join_conference(int(s), int(c))
         bridge._bcast_speakers = {
             int(c): {int(s) for s in spk}
             for c, spk in snap.get("bcast_speakers", {}).items()}

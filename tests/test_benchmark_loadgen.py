@@ -86,3 +86,72 @@ def test_meeting_traffic_file_states_its_pitch():
     assert traffic["speakers_per_conference"] == 3
     assert traffic["attempted"] == "offered"
     assert traffic["require_no_shedding"] is True
+
+
+# ------------------------------------------ the webinar room's schedule
+
+WEBINAR = "audio-sfu-cm-10k-webinar1k.presenter-paced"
+ROOM = 512          # ISSUE 47's fallback size (PERF.md section 6, PR 47)
+
+
+@pytest.fixture(scope="module")
+def webinar():
+    plan = _cell_plan(WEBINAR)
+    return plan, loadgen.build_schedule(plan)
+
+
+def test_webinar_cell_is_one_live_room_of_512_with_one_presenter(webinar):
+    plan, _sched = webinar
+    assert (plan["conf_size"], plan["speakers"], plan["rows"]) == \
+        (ROOM, 1, 10240)
+    assert plan["talk_spurt"] is None and plan["burst_factor"] == 1.0
+    assert plan["active"] == [0]                   # A = 1: room 0
+    assert len(loadgen.plan_endpoints(plan)) == ROOM     # sockets
+
+
+def test_webinar_rate_is_its_arithmetic(webinar):
+    """One presenter at 50 packets/s, 511 deliveries a packet: 25,550
+    deliveries/s due, 1,022,000 a 40 s window."""
+    plan, sched = webinar
+    assert sched["period_ns"] == 20_000_000
+    lo = 10 * 10 ** 9
+    in_s = (sched["due_ns"] >= lo) & (sched["due_ns"] < lo + 10 ** 9)
+    assert int(in_s.sum()) == 50
+    assert set(sched["sock"][in_s].tolist()) == {0}     # the presenter
+    assert int(in_s.sum()) * (plan["conf_size"] - 1) == 25550
+    in_w = (sched["due_ns"] >= lo) & (sched["due_ns"] < lo + 40 * 10 ** 9)
+    assert int(in_w.sum()) * (plan["conf_size"] - 1) == 1_022_000
+
+
+def test_webinar_every_other_member_sends_exactly_one_packet(webinar):
+    """The 7 silent panelists and the 504 visitors each reach the
+    bridge once, inside the plan's first period, and then listen."""
+    plan, sched = webinar
+    per_sock = np.bincount(sched["sock"], minlength=ROOM)
+    assert (per_sock[1:] == 1).all() and per_sock[0] > 2000
+    first = {}
+    for s, d in zip(sched["sock"].tolist(), sched["due_ns"].tolist()):
+        first.setdefault(s, d)
+    assert len(first) == ROOM and max(first.values()) < 20_000_000
+
+
+def test_webinar_files_state_the_rule_and_the_pitch():
+    traffic = _load(_BENCH, "traffic", "presenter-paced.json")
+    assert traffic["rate"] == {"active_conferences": 1}
+    assert loadgen.resolve_rate(traffic, "audio-sfu-cm-10k-webinar1k") == 1
+    assert traffic["speakers_per_conference"] == 1
+    assert traffic["talk_spurt"] is None
+    assert traffic["attempted"] == "offered"
+    assert traffic["require_no_shedding"] is True
+    config = _load(_BENCH, "configs", "audio-sfu-cm-10k-webinar1k.json")
+    assert config["lifecycle"] == {
+        "install_batch": 64, "max_pending": 512,
+        "max_conference_participants": 8}
+    assert config["conference_sizes"] == [ROOM]
+    assert config["egress_multiplier"] == ROOM - 1
+    cm = _load(_BENCH, "configs", "audio-sfu-cm-10k.json")
+    for key in ("profile", "capacity", "replay_window", "ingest_max_batch",
+                "packet_period_ms", "supervisor", "bridge_class"):
+        assert config[key] == cm[key], key
+    assert {k: v for k, v in config["guarantees"].items()
+            if k != "roles"} == cm["guarantees"]
